@@ -4,7 +4,7 @@ One authoritative inventory of every ``sparknet_*`` metric the
 framework emits (``obs/__init__.py`` TrainingMetrics) and every
 ``span(...)`` name by category — the sets the folding side consumes:
 ``tools/trace_report.py`` (comm-span folding), ``tools/perf_gate.py``
-(live-profile fields), the PERF.md "Telemetry reference" tables, and
+(live-profile fields), the ARCHITECTURE.md "Telemetry reference" tables, and
 the ``/metrics`` scrapers people build dashboards on.
 
 ``analysis/registry_audit.py`` cross-checks this module against the
@@ -12,7 +12,7 @@ code, both directions: an emitter whose name is missing here fails the
 lint (a dashboard can't find it, ``trace_report`` won't fold it), and
 an entry here that nothing emits fails too (documentation of a ghost).
 Adding a metric/span is therefore a two-line change: the emitter and
-this registry (plus the PERF.md table row, which the audit also
+this registry (plus the ARCHITECTURE.md table row, which the audit also
 enforces).  Import cost discipline: this module must stay stdlib-only
 — ``tools/trace_report.py`` imports it at CLI startup.
 """

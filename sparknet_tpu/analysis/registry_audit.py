@@ -15,7 +15,7 @@ both directions, plus the docs:
 - canonical-but-never-emitted: the registry documents a ghost;
 - label drift: same name, different label tuple;
 - docs drift (PERF.md / ARCHITECTURE.md / README.md): every canonical
-  metric and phase must appear in the PERF.md telemetry reference, and
+  metric and phase must appear in the ARCHITECTURE.md telemetry reference, and
   every ``sparknet_*`` token the docs mention must be canonical
   (tokens ending in ``_`` are accepted as explicit prefix mentions).
 
@@ -104,7 +104,7 @@ def audit(
                     message=f"metric {name!r} emitted but not in the "
                     "canonical registry (analysis/registry.py) — "
                     "folders and dashboards won't know it exists",
-                    fixit="add it to CANONICAL_METRICS and the PERF.md "
+                    fixit="add it to CANONICAL_METRICS and the ARCHITECTURE.md "
                     "telemetry reference",
                 ))
                 break  # one report per name suffices for this class
@@ -140,7 +140,7 @@ def audit(
                 message=f"span {name!r} (cat={cat!r}) emitted but not "
                 "in the canonical span set — trace_report/profile "
                 "folding won't attribute it",
-                fixit="add it to CANONICAL_SPANS[%r] (and the PERF.md "
+                fixit="add it to CANONICAL_SPANS[%r] (and the ARCHITECTURE.md "
                 "phase table for phase-cat spans)" % cat,
             ))
     for cat, names in CANONICAL_SPANS.items():
@@ -155,23 +155,23 @@ def audit(
 
     if docs:
         all_text = "\n".join(docs.values())
-        perf = docs.get("PERF.md", "")
+        reference = docs.get("ARCHITECTURE.md", "")
         for name in sorted(CANONICAL_METRICS):
-            if name not in perf:
+            if name not in reference:
                 rep.findings.append(Finding(
-                    checker=CHECKER, path="PERF.md", line=1,
+                    checker=CHECKER, path="ARCHITECTURE.md", line=1,
                     scope="<docs>",
                     message=f"canonical metric {name!r} missing from "
-                    "the PERF.md telemetry reference",
+                    "the ARCHITECTURE.md telemetry reference",
                     fixit="add a row to the metrics table",
                 ))
         for name in sorted(CANONICAL_SPANS["phase"]):
-            if name not in perf:
+            if name not in reference:
                 rep.findings.append(Finding(
-                    checker=CHECKER, path="PERF.md", line=1,
+                    checker=CHECKER, path="ARCHITECTURE.md", line=1,
                     scope="<docs>",
                     message=f"canonical phase {name!r} missing from "
-                    "the PERF.md telemetry reference",
+                    "the ARCHITECTURE.md telemetry reference",
                     fixit="add it to the phase table",
                 ))
         doc_tokens = set(_DOC_TOKEN_RE.findall(all_text))

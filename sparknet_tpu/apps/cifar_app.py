@@ -35,9 +35,8 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument(
         "--serial_feed", action="store_true",
-        help="disable the pipelined round feed (assemble+H2D on the "
-        "training loop) — for relay-degraded links where overlapped "
-        "transfers collapse throughput (PERF.md)",
+        help="disable the pipelined round feed: assemble and H2D run "
+        "on the training loop instead of a producer thread",
     )
     from sparknet_tpu import obs
     from sparknet_tpu.io import journal as journal_mod
@@ -48,6 +47,10 @@ def main(argv=None) -> int:
     hierarchy.add_cli_args(parser)  # --slices/--cross_slice_every/--elastic
     journal_mod.add_cli_args(parser)  # --journal / --no_journal / ...
     args = parser.parse_args(argv)
+
+    from sparknet_tpu.utils.devices import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -55,8 +55,8 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument(
         "--serial_feed", action="store_true",
-        help="disable the pipelined round feed (PERF.md: relay-degraded "
-        "links)",
+        help="disable the pipelined round feed: assemble and H2D run "
+        "on the training loop instead of a producer thread",
     )
     from sparknet_tpu import obs
     from sparknet_tpu.io import journal as journal_mod
@@ -67,6 +67,10 @@ def main(argv=None) -> int:
     hierarchy.add_cli_args(parser)  # --slices / --cross_slice_every / --elastic
     journal_mod.add_cli_args(parser)  # --journal / --no_journal / ...
     args = parser.parse_args(argv)
+
+    from sparknet_tpu.utils.devices import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

@@ -32,6 +32,10 @@ def main(argv=None) -> int:
     parser.add_argument("--out", default=None)
     args = parser.parse_args(argv)
 
+    from sparknet_tpu.utils.devices import enable_compile_cache
+
+    enable_compile_cache()
+
     import jax
 
     from sparknet_tpu import models

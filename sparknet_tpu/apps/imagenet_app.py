@@ -87,8 +87,8 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument(
         "--serial_feed", action="store_true",
-        help="disable the pipelined round feed (assemble+H2D on the "
-        "training loop) — for relay-degraded links (PERF.md)",
+        help="disable the pipelined round feed: assemble and H2D run "
+        "on the training loop instead of a producer thread",
     )
     parser.add_argument(
         "--cache_dir", default=None,
@@ -121,6 +121,10 @@ def main(argv=None) -> int:
     hierarchy.add_cli_args(parser)  # --slices / --cross_slice_every / --elastic
     journal_mod.add_cli_args(parser)  # --journal / --no_journal / ...
     args = parser.parse_args(argv)
+
+    from sparknet_tpu.utils.devices import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 
@@ -156,17 +160,19 @@ def main(argv=None) -> int:
         args.tau = args.tau or 4
         args.full_size = args.full_size or 64
         args.crop = args.crop or 56
-        args.classes = min(args.classes, 4)
+        # the generator tells classes apart by a bounded channel shift,
+        # so it draws at most 4 labels; the net keeps --classes outputs
+        label_classes = min(args.classes, 4)
         data_dir = tempfile.mkdtemp(prefix="imagenet_synth_")
         n_shards = max(2, args.workers or jax.local_device_count())
         write_synthetic_imagenet(
             data_dir, num_shards=n_shards,
             images_per_shard=args.train_batch * (args.tau + 1),
-            classes=args.classes, seed=args.seed,
+            classes=label_classes, seed=args.seed,
         )
         write_synthetic_imagenet(
             data_dir, num_shards=n_shards,
-            images_per_shard=args.test_batch * 2, classes=args.classes,
+            images_per_shard=args.test_batch * 2, classes=label_classes,
             labels_file="val.txt", shard_prefix="val.", seed=args.seed + 1,
         )
         log.log(f"synthesized JPEG tar shards in {data_dir}")

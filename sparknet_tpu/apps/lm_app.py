@@ -11,10 +11,10 @@ Mesh layout: ``dp x sp``.  The ``dp`` axis is the familiar worker
 axis (tau local steps, then parameter averaging); ``--sp N`` addition-
 ally shards every worker's SEQUENCE dimension N ways — attention runs
 the ``parallel/ring_attention.py`` construction inside the round's
-``shard_map`` (KV rotating one ICI hop per ring step), gradients psum
-over the ring (``Solver(grad_reduce_axes=("sp",))``), and the
-trajectory matches the sp=1 run up to float associativity (pinned by
-``bench.py --mode=lm``).
+``shard_map`` (KV rotating one ICI hop per ring step), the gradients
+of the sp-replicated params are summed over the ring by ``shard_map``'s
+varying-axes typing, and the trajectory matches the sp=1 run up to
+float associativity (pinned by ``bench.py --mode=lm``).
 
 Data: documents fetched through ``object_store`` + ``ChunkCache``
 (``data/text.py``), windows drawn by absolute-iteration cursor — the
@@ -85,11 +85,7 @@ def build_lm_solver(args, sp: int):
         f"weight_decay: {args.weight_decay} "
         "average_loss: 20"
     )
-    solver = Solver(
-        solver_param,
-        net=lm,
-        grad_reduce_axes=("sp",) if sp > 1 else (),
-    )
+    solver = Solver(solver_param, net=lm)
     from sparknet_tpu import obs
     from sparknet_tpu.ops import pallas_attention
 
@@ -207,8 +203,8 @@ def main(argv=None) -> int:
     parser.add_argument("--log_every", type=int, default=5)
     parser.add_argument(
         "--serial_feed", action="store_true",
-        help="disable the pipelined round feed (assemble+H2D on the "
-        "training loop) — for relay-degraded links (PERF.md)",
+        help="disable the pipelined round feed: assemble and H2D run "
+        "on the training loop instead of a producer thread",
     )
     parser.add_argument(
         "--snapshot_prefix", default=None,
@@ -234,6 +230,10 @@ def main(argv=None) -> int:
     hierarchy.add_cli_args(parser)
     journal_mod.add_cli_args(parser)
     args = parser.parse_args(argv)
+
+    from sparknet_tpu.utils.devices import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
 

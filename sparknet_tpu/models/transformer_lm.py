@@ -31,8 +31,10 @@ net=lm)`` — it exposes ``init`` / ``loss_fn`` / ``param_multipliers``
 compression, the hierarchy schedule and journal jobstate all compose
 onto the LM unchanged.  The loss is next-token cross-entropy over the
 GLOBAL token count (``psum`` over ``sp`` of per-shard sums), so the
-loss value is identical on every sp shard; the cross-shard gradient
-reduction lives in ``Solver(grad_reduce_axes=("sp",))``.
+loss value is identical on every sp shard.  Parameters are replicated
+over ``sp`` and the activations vary over it, so ``shard_map``'s
+varying-axes typing sums each parameter's per-shard gradient over the
+ring when it transposes that broadcast — no explicit gradient psum.
 
 Naming note: ``data/transformer.py`` is the Caffe **image augmenter**
 (DataTransformer — crop/mirror/mean-subtract), not this model; see its
@@ -285,7 +287,11 @@ class TransformerLM:
         contract.  With sp sharding the per-shard sums ``psum`` over
         the ring axis, so the loss value is bit-identical on every sp
         shard (and equals the dense sp=1 loss up to float
-        associativity)."""
+        associativity).  The psum is differentiable: its transpose hands
+        every shard the same cotangent, each shard back-propagates its
+        own tokens, and the gradient of the sp-replicated params comes
+        out summed over the ring (the varying-axes type system inserts
+        that reduction), i.e. exactly the global gradient."""
         logits = self.forward_logits(params, batch["tokens"])
         tgt = batch["targets"].astype(jnp.int32)
         logp = jax.nn.log_softmax(logits, axis=-1)
@@ -293,20 +299,7 @@ class TransformerLM:
         local_sum = jnp.sum(nll)
         count = tgt.shape[0] * tgt.shape[1] * max(1, self.sp_size)
         if self.sp_axis is not None and self.sp_size > 1:
-            # global VALUE, local GRADIENT: the psum runs on the
-            # stop_gradient'd sum (every shard reports the same global
-            # loss, bit-identically), while the differentiable path is
-            # purely local — so each shard's grad is exactly its own
-            # contribution / global count, and the solver's explicit
-            # psum over sp (``grad_reduce_axes``) yields the exact
-            # global gradient REGARDLESS of how this jax build
-            # transposes psum under check_rep=False (pre-varying jax
-            # transposes psum to psum, which would double-count a
-            # differentiable psum here — measured, not theoretical).
-            sg = jax.lax.stop_gradient
-            total = jax.lax.psum(sg(local_sum), self.sp_axis) + (
-                local_sum - sg(local_sum)
-            )
+            total = jax.lax.psum(local_sum, self.sp_axis)
         else:
             total = local_sum
         loss = total / jnp.asarray(count, jnp.float32)

@@ -189,7 +189,7 @@ class JaxNet:
 
         self._plp_fused: Dict[int, Tuple[str, object]] = {}
         self._plp_skip: set = set()
-        # Opt-in (SPARKNET_FUSION=1): on the current virtualized v5e the
+        # Opt-in (SPARKNET_FUSION=1): when last measured on a v5e the
         # Mosaic kernel's per-band overheads outweigh its HBM savings
         # (measured 2-5x slower than the XLA lowering — see
         # ops/pallas_plp.py and PERF.md); the kernel is kept correct and

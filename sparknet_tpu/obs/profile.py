@@ -74,36 +74,6 @@ PHASE_RESOURCE = {
     "restore": "host",
 }
 
-# bf16 peak FLOP/s per device kind substring (MXU peak; public numbers;
-# mirrors bench.py's table).  CPU has no meaningful peak — MFU is None.
-_PEAK_BF16 = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5 lite", 197e12),
-    ("v5e", 197e12),
-    ("v5", 459e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-
-
-def device_peak_flops() -> float:
-    """bf16 peak FLOP/s of device 0, or 0.0 when unknown (CPU)."""
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        return 0.0
-    if "tpu" not in kind:
-        return 0.0
-    for key, peak in _PEAK_BF16:
-        if key in kind:
-            return peak
-    return 0.0
-
-
 def _overlap_s(interval, others) -> float:
     """Seconds of ``interval`` covered by the union-ish of ``others``
     (greedy pairwise sum clamped to the interval length — the consumer
@@ -186,7 +156,13 @@ class RoundProfiler:
         self.last_straggler_worker: Optional[int] = None
         self.last_straggler_round: Optional[int] = None
         self._records: deque = deque(maxlen=int(window))
-        self._peak_flops = device_peak_flops()
+        # bf16 peak of device 0 (utils/devices.py's table); None on the
+        # CPU, where MFU is omitted
+        import jax
+
+        from sparknet_tpu.utils.devices import peak_bf16_flops
+
+        self._peak_flops = peak_bf16_flops(jax.devices()[0])
 
     # ------------------------------------------------------------------
     # span stream (installed via trace.set_span_observer)
@@ -439,7 +415,7 @@ class RoundProfiler:
             rec["achieved_flops_per_s"] = self.flops_per_round / round_s
             rec["mfu"] = (
                 rec["achieved_flops_per_s"] / self._peak_flops
-                if self._peak_flops > 0
+                if self._peak_flops
                 else None
             )
         with self._lock:
@@ -623,7 +599,7 @@ class RoundProfiler:
             out["achieved_flops_per_s"] = ach
             out["mfu"] = (
                 round(ach / self._peak_flops, 6)
-                if self._peak_flops > 0 else None
+                if self._peak_flops else None
             )
         if flops and payload:
             out["arithmetic_intensity_flops_per_byte"] = round(

@@ -38,6 +38,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def lowerable() -> bool:
@@ -51,16 +52,26 @@ def lowerable() -> bool:
     return jax.default_backend() in ("tpu",)
 
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T without materializing b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(
+        a, b, dims, preferred_element_type=jnp.float32
+    )
+
+
 def _causal_mask(offs_ref, rows, tk, row0):
     """(rows, tk) bool mask from ABSOLUTE positions: query row r of
     this block sits at ``q_offset + row0 + r``, key column c at
-    ``k_offset + c``.  Offsets ride in as a (1, 2) f32 block (traced
-    scalars — the ring's ``axis_index`` arithmetic — can't be static
-    kernel params)."""
-    q0 = offs_ref[0, 0].astype(jnp.int32)
-    k0 = offs_ref[0, 1].astype(jnp.int32)
-    q_pos = q0 + row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, tk), 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, tk), 1)
+    ``k_offset + c``.  Offsets ride in as two int32 scalars in SMEM
+    (traced — the ring's ``axis_index`` arithmetic — so they can't be
+    static kernel params)."""
+    q_pos = offs_ref[0] + row0 + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, tk), 0
+    )
+    k_pos = offs_ref[1] + jax.lax.broadcasted_iota(jnp.int32, (rows, tk), 1)
     return k_pos <= q_pos
 
 
@@ -71,7 +82,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     k = k_ref[0]  # (tk, d)
     v = v_ref[0]
     tk = k.shape[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = _dot(q, k, _NT) * scale
     if causal:
         mask = _causal_mask(offs_ref, q.shape[0], tk, j * block_q)
         s = jnp.where(mask, s, -jnp.inf)
@@ -81,36 +92,36 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     m_safe = jnp.where(m == -jnp.inf, 0.0, m)
     p = jnp.exp(s - m_safe)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.dot(p, v, preferred_element_type=jnp.float32)
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(
-        l[:, 0] > 0, m_safe[:, 0] + jnp.log(l[:, 0]), -jnp.inf
+    o = jnp.dot(
+        p, v.astype(jnp.float32), preferred_element_type=jnp.float32
     )
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    lse_ref[0] = jnp.where(l > 0, m_safe + jnp.log(l), -jnp.inf)
+
+
+def _recompute_p(offs_ref, q, k, lse, scale, causal, row0):
+    """Normalized probabilities rebuilt from the lse residual — the
+    flash backward's recompute.  A fully-masked row has lse=-inf and
+    s=-inf: substitute lse=0 so exp(-inf - 0) = 0 instead of exp(nan)."""
+    s = _dot(q, k, _NT) * scale
+    if causal:
+        mask = _causal_mask(offs_ref, q.shape[0], k.shape[0], row0)
+        s = jnp.where(mask, s, -jnp.inf)
+    return jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0))
 
 
 def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                    lse_ref, dlse_ref, dq_ref, *, scale, causal, block_q):
     j = pl.program_id(1)
-    q = q_ref[0]
     k = k_ref[0]
-    v = v_ref[0]
     do = do_ref[0].astype(jnp.float32)
     o = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    tk = k.shape[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if causal:
-        mask = _causal_mask(offs_ref, q.shape[0], tk, j * block_q)
-        s = jnp.where(mask, s, -jnp.inf)
-    # recompute normalized probabilities from the lse residual; a
-    # fully-masked row has lse=-inf and s=-inf — substitute lse=0 so
-    # exp(-inf - 0) = 0 instead of exp(nan)
-    lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    p = jnp.exp(s - lse_safe[:, None])
-    delta = jnp.sum(do * o, axis=-1) - dlse_ref[0]
-    dp = jnp.dot(do, v.astype(jnp.float32).T,
-                 preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    p = _recompute_p(
+        offs_ref, q_ref[0], k, lse_ref[0], scale, causal, j * block_q
+    )
+    delta = jnp.sum(do * o, axis=-1, keepdims=True) - dlse_ref[0]
+    dp = _dot(do, v_ref[0].astype(jnp.float32), _NT)
+    ds = p * (dp - delta) * scale
     dq_ref[0] = jnp.dot(
         ds, k.astype(jnp.float32), preferred_element_type=jnp.float32
     ).astype(dq_ref.dtype)
@@ -119,28 +130,38 @@ def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
 def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
                     lse_ref, dlse_ref, dk_ref, dv_ref, *, scale, causal):
     q = q_ref[0]  # (tq, d) — whole padded T_q per (batch*head) cell
-    k = k_ref[0]
-    v = v_ref[0]
     do = do_ref[0].astype(jnp.float32)
     o = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    tk = k.shape[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if causal:
-        mask = _causal_mask(offs_ref, q.shape[0], tk, 0)
-        s = jnp.where(mask, s, -jnp.inf)
-    lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
-    p = jnp.exp(s - lse_safe[:, None])
-    dv_ref[0] = jnp.dot(
-        p.T, do, preferred_element_type=jnp.float32
-    ).astype(dv_ref.dtype)
-    delta = jnp.sum(do * o, axis=-1) - dlse_ref[0]
-    dp = jnp.dot(do, v.astype(jnp.float32).T,
-                 preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
-    dk_ref[0] = jnp.dot(
-        ds.T, q.astype(jnp.float32), preferred_element_type=jnp.float32
-    ).astype(dk_ref.dtype)
+    p = _recompute_p(offs_ref, q, k_ref[0], lse_ref[0], scale, causal, 0)
+    dv_ref[0] = _dot(p, do, _TN).astype(dv_ref.dtype)
+    delta = jnp.sum(do * o, axis=-1, keepdims=True) - dlse_ref[0]
+    dp = _dot(do, v_ref[0].astype(jnp.float32), _NT)
+    ds = p * (dp - delta) * scale
+    dk_ref[0] = _dot(ds, q.astype(jnp.float32), _TN).astype(dk_ref.dtype)
+
+
+# Scoped-VMEM ceiling handed to Mosaic.  The default (16 MiB on v5e)
+# refuses the dk/dv pass — whole q/k/v/do/o plus four (T_q, T_k) f32
+# temporaries per cell — well before the chip's 128 MiB runs out.
+VMEM_LIMIT_BYTES = 100 << 20
+
+
+def _compiler_params(*semantics):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT_BYTES
+    )
+
+
+def _out_struct(shape, dtype, *operands):
+    """Output aval for a ``pallas_call``.  Under ``shard_map`` the
+    output's varying mesh axes must be declared; they are the union of
+    the operands' (the empty set outside ``shard_map``)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+# whole small array of scalars (offsets, lengths) in scalar memory
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _fwd_call(qf, kf, vf, offs, causal, block_q, interpret):
@@ -153,19 +174,20 @@ def _fwd_call(qf, kf, vf, offs, causal, block_q, interpret):
         kernel,
         grid=(n, tq // block_q),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),
+            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, tq, d), qf.dtype),
-            jax.ShapeDtypeStruct((n, tq), jnp.float32),
+            _out_struct((n, tq, d), qf.dtype, qf, kf, vf, offs),
+            _out_struct((n, tq, 1), jnp.float32, qf, kf, vf, offs),
         ],
+        compiler_params=_compiler_params("parallel", "parallel"),
         interpret=interpret,
     )(offs, qf, kf, vf)
 
@@ -173,10 +195,11 @@ def _fwd_call(qf, kf, vf, offs, causal, block_q, interpret):
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_core(qf, kf, vf, offs, causal, block_q, interpret):
     """(o, lse) over flattened (B*H, T, D) inputs; T_q already padded
-    to a ``block_q`` multiple.  ``offs`` is the f32 (1, 2) absolute
-    (q_offset, k_offset) pair; differentiable in q/k/v AND honest in
-    the lse output (nonzero dlse cotangents — the ring merge — feed
-    the backward's delta term)."""
+    to a ``block_q`` multiple.  ``offs`` is the int32 (2,) absolute
+    (q_offset, k_offset) pair; ``lse`` is (B*H, T_q, 1) — rows on the
+    sublane axis, the layout every kernel consumes it in.
+    Differentiable in q/k/v AND honest in the lse output (nonzero dlse
+    cotangents — the ring merge — feed the backward's delta term)."""
     return _fwd_call(qf, kf, vf, offs, causal, block_q, interpret)
 
 
@@ -192,44 +215,44 @@ def _flash_core_bwd(causal, block_q, interpret, res, cts):
     tk = kf.shape[1]
     scale = 1.0 / math.sqrt(d)
     dlse = dlse.astype(jnp.float32)
+    operands = (offs, qf, kf, vf, do, o, lse, dlse)
     dq_kernel = partial(_bwd_dq_kernel, scale=scale, causal=causal,
                         block_q=block_q)
-    whole_q = pl.BlockSpec((1, tq, d), lambda i: (i, 0, 0))
-    whole_k = pl.BlockSpec((1, tk, d), lambda i: (i, 0, 0))
-    row_q = pl.BlockSpec((1, tq), lambda i: (i, 0))
+    block_qd = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
+    block_q1 = pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
+    all_k = pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0))
     dq = pl.pallas_call(
         dq_kernel,
         grid=(n, tq // block_q),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, j)),
+            _SMEM, block_qd, all_k, all_k, block_qd, block_qd,
+            block_q1, block_q1,
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, tq, d), qf.dtype),
+        out_specs=block_qd,
+        out_shape=_out_struct((n, tq, d), qf.dtype, *operands),
+        compiler_params=_compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(offs, qf, kf, vf, do, o, lse, dlse)
+    )(*operands)
     dkv_kernel = partial(_bwd_dkv_kernel, scale=scale, causal=causal)
+    whole_q = pl.BlockSpec((1, tq, d), lambda i: (i, 0, 0))
+    whole_k = pl.BlockSpec((1, tk, d), lambda i: (i, 0, 0))
+    col_q = pl.BlockSpec((1, tq, 1), lambda i: (i, 0, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            whole_q, whole_k, whole_k, whole_q, whole_q, row_q, row_q,
+            _SMEM,
+            whole_q, whole_k, whole_k, whole_q, whole_q, col_q, col_q,
         ],
         out_specs=[whole_k, whole_k],
         out_shape=[
-            jax.ShapeDtypeStruct((n, tk, d), kf.dtype),
-            jax.ShapeDtypeStruct((n, tk, d), vf.dtype),
+            _out_struct((n, tk, d), kf.dtype, *operands),
+            _out_struct((n, tk, d), vf.dtype, *operands),
         ],
+        compiler_params=_compiler_params("parallel"),
         interpret=interpret,
-    )(offs, qf, kf, vf, do, o, lse, dlse)
-    return dq, dk, dv, jnp.zeros_like(offs)
+    )(*operands)
+    return dq, dk, dv, None  # integer offsets carry no cotangent
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -272,7 +295,7 @@ def flash_attention(
     block_q = min(block_q, tq)
     qf, pad = _pad_to_block(_flatten_heads(q), block_q)
     kf, vf = _flatten_heads(k), _flatten_heads(v)
-    offs = jnp.asarray([[tk - tq, 0]], jnp.float32)
+    offs = jnp.asarray([tk - tq, 0], jnp.int32)
     o, _ = _flash_core(qf, kf, vf, offs, causal, block_q, bool(interpret))
     if pad:
         o = o[:, :tq]
@@ -300,14 +323,14 @@ def flash_attention_step(
     kf, vf = _flatten_heads(k), _flatten_heads(v)
     if causal:
         offs = jnp.stack(
-            [jnp.asarray(q_offset, jnp.float32),
-             jnp.asarray(k_offset, jnp.float32)]
-        ).reshape(1, 2)
+            [jnp.asarray(q_offset, jnp.int32),
+             jnp.asarray(k_offset, jnp.int32)]
+        )
     else:
         # non-causal kernels never read the offsets; keeping the traced
         # axis-index arithmetic out of the (DCE'd) operand sidesteps an
         # XLA SPMD PartitionId lowering bug under shard_map
-        offs = jnp.zeros((1, 2), jnp.float32)
+        offs = jnp.zeros((2,), jnp.int32)
     o, lse = _flash_core(qf, kf, vf, offs, causal, block_q, bool(interpret))
     if pad:
         o, lse = o[:, :tq], lse[:, :tq]
@@ -320,14 +343,15 @@ def flash_attention_step(
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s):
     q = q_ref[0]  # (1, d)
     k = k_ref[0]  # (s, d)
-    v = v_ref[0]
-    n = len_ref[0, 0]
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    n = len_ref[pl.program_id(0)]
+    scores = _dot(q, k, _NT) * scale
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
     scores = jnp.where(k_pos < n, scores, -jnp.inf)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.exp(scores - m)
-    o = jnp.dot(p, v, preferred_element_type=jnp.float32)
+    o = jnp.dot(
+        p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32
+    )
     o_ref[0] = (o / jnp.sum(p, axis=-1, keepdims=True)).astype(o_ref.dtype)
 
 
@@ -380,21 +404,22 @@ def decode_attention(q, k, v, lengths=None, interpret=None):
     def flat(x):
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, x.shape[1], d)
 
-    # one grid cell per (batch*head); the sequence length rides in as a
-    # per-cell scalar block so the mask is computed on the VPU in-cell
-    len_bh = jnp.repeat(lengths.astype(jnp.int32), h).reshape(b * h, 1)
+    # one grid cell per (batch*head); the sequence lengths ride in as
+    # an int32 vector in SMEM, each cell reads its own entry as a scalar
+    len_bh = jnp.repeat(lengths.astype(jnp.int32), h)
     kernel = partial(_decode_kernel, scale=scale, s=s)
     out = pl.pallas_call(
         kernel,
         grid=(b * h,),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            _SMEM,
             pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, s, d), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, s, d), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
-        interpret=True if interpret else (not lowerable()),
+        compiler_params=_compiler_params("parallel"),
+        interpret=bool(interpret),
     )(len_bh, flat(q), flat(k), flat(v))
     return jnp.transpose(out.reshape(b, h, 1, d), (0, 2, 1, 3))

@@ -29,13 +29,20 @@ explicit test/bench tool, unfused XLA closures elsewhere (the
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from sparknet_tpu.ops.pallas_attention import lowerable
+from sparknet_tpu.ops.pallas_attention import VMEM_LIMIT_BYTES, lowerable
+
+
+_LANES = 128
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _resolve_interpret(interpret):
@@ -44,26 +51,50 @@ def _resolve_interpret(interpret):
     return bool(interpret)
 
 
-def _leaf_block(leaf):
-    """Per-worker block spec of a worker-stacked (W, ...) leaf: one
-    worker's slice per grid cell."""
-    shape = (1,) + tuple(leaf.shape[1:])
-    nd = leaf.ndim
+def _call(kernel, w, in_specs, out_specs, out_shape, interpret):
+    return pl.pallas_call(
+        kernel,
+        grid=(w,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        # "arbitrary": the per-worker scalar outputs share one SMEM
+        # array across the grid.  A chunk rides whole through VMEM —
+        # every leaf's inputs and outputs, double-buffered — so one
+        # beyond roughly a tenth of the limit per worker is refused by
+        # the compiler, loudly.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=_resolve_interpret(interpret),
+    )
 
-    def index(i, _nd=nd):
-        return (i,) + (0,) * (_nd - 1)
 
-    return pl.BlockSpec(shape, index)
+def _rows(shape):
+    """(rows, cols) view of one worker's leaf: lane-dense when the
+    element count allows, a single row otherwise.  Every op on a leaf
+    is elementwise or a whole-leaf reduction, so the view is free to
+    differ from the leaf's own shape."""
+    n = math.prod(shape)
+    return (n // _LANES, _LANES) if n % _LANES == 0 else (1, n)
 
 
-def _whole_block(arr):
-    """Every cell reads the same unstacked array (a chunk mean)."""
-    nd = arr.ndim
+def _stacked(leaf):
+    """Worker-stacked (W, ...) leaf as (W, rows, cols)."""
+    return leaf.reshape((leaf.shape[0],) + _rows(leaf.shape[1:]))
 
-    def index(i, _nd=nd):
-        return (0,) * _nd
 
-    return pl.BlockSpec(tuple(arr.shape), index)
+def _leaf_block(leaf3):
+    """One worker's (rows, cols) slice per grid cell.  The block's last
+    two dims equal the array's, which Mosaic accepts at any size."""
+    return pl.BlockSpec((1,) + leaf3.shape[1:], lambda i: (i, 0, 0))
+
+
+def _whole_block(arr2):
+    """Every cell reads the same unstacked (rows, cols) array (a chunk
+    mean)."""
+    return pl.BlockSpec(arr2.shape, lambda i: (0, 0))
 
 
 def _quantize(delta, mode):
@@ -83,30 +114,30 @@ def _quantize(delta, mode):
 def _encode_kernel(*refs, modes, with_err):
     n = len(modes)
     xs, anchors, resids = refs[0:n], refs[n:2 * n], refs[2 * n:3 * n]
-    qs, scales, new_resids = (
-        refs[3 * n:4 * n], refs[4 * n:5 * n], refs[5 * n:6 * n]
-    )
-    err_ref = refs[6 * n] if with_err else None
+    qs, new_resids = refs[3 * n:4 * n], refs[4 * n:5 * n]
+    scales_ref = refs[5 * n]  # SMEM (W, n)
+    err_ref = refs[5 * n + 1] if with_err else None  # SMEM (W, 3)
+    w = pl.program_id(0)
     max_abs = jnp.float32(0.0)
     delta_sq = jnp.float32(0.0)
     err_sq = jnp.float32(0.0)
-    for x_ref, a_ref, r_ref, q_ref, s_ref, nr_ref, mode in zip(
-        xs, anchors, resids, qs, scales, new_resids, modes
-    ):
+    for j, (x_ref, a_ref, r_ref, q_ref, nr_ref, mode) in enumerate(zip(
+        xs, anchors, resids, qs, new_resids, modes
+    )):
         delta = (x_ref[0] - a_ref[0]) + r_ref[0]
         q, scale, dq = _quantize(delta, mode)
         err = delta - dq
         q_ref[0] = q
-        s_ref[0, 0] = scale
+        scales_ref[w, j] = scale
         nr_ref[0] = err
         if with_err:
             max_abs = jnp.maximum(max_abs, jnp.max(jnp.abs(err)))
             err_sq = err_sq + jnp.sum(jnp.square(err))
             delta_sq = delta_sq + jnp.sum(jnp.square(delta))
     if with_err:
-        err_ref[0, 0] = max_abs
-        err_ref[0, 1] = delta_sq
-        err_ref[0, 2] = err_sq
+        err_ref[w, 0] = max_abs
+        err_ref[w, 1] = delta_sq
+        err_ref[w, 2] = err_sq
 
 
 @partial(jax.jit, static_argnums=(3, 4, 5))
@@ -121,57 +152,49 @@ def fused_encode(leaves, anchors, resids, modes, with_err, interpret):
     partials (None unless ``with_err``) — delta, quantize, and the
     error-feedback residual all written in the SAME kernel pass."""
     w = leaves[0].shape[0]
+    n = len(leaves)
     modes = tuple(modes)
     kernel = partial(_encode_kernel, modes=modes, with_err=with_err)
-    in_specs = (
-        [_leaf_block(x) for x in leaves]
-        + [_leaf_block(a) for a in anchors]
-        + [_leaf_block(r) for r in resids]
-    )
+    xs = [_stacked(x) for x in leaves]
+    ins = xs + [_stacked(a) for a in anchors] + [_stacked(r) for r in resids]
     qdt = {"bf16": jnp.bfloat16, "int8": jnp.int8}
-    out_specs = (
-        [_leaf_block(x) for x in leaves]
-        + [pl.BlockSpec((1, 1), lambda i: (i, 0)) for _ in leaves]
-        + [_leaf_block(r) for r in resids]
-    )
+    out_specs = [_leaf_block(x) for x in xs] * 2 + [_SMEM]
     out_shape = (
         [
             jax.ShapeDtypeStruct(x.shape, qdt.get(m, x.dtype))
-            for x, m in zip(leaves, modes)
+            for x, m in zip(xs, modes)
         ]
-        + [jax.ShapeDtypeStruct((w, 1), jnp.float32) for _ in leaves]
-        + [jax.ShapeDtypeStruct(r.shape, r.dtype) for r in resids]
+        + [jax.ShapeDtypeStruct(x.shape, r.dtype)
+           for x, r in zip(xs, resids)]
+        + [jax.ShapeDtypeStruct((w, n), jnp.float32)]
     )
     if with_err:
-        out_specs.append(pl.BlockSpec((1, 3), lambda i: (i, 0)))
+        out_specs.append(_SMEM)
         out_shape.append(jax.ShapeDtypeStruct((w, 3), jnp.float32))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(w,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=_resolve_interpret(interpret),
-    )(*leaves, *anchors, *resids)
-    n = len(leaves)
-    qs = tuple(outs[0:n])
-    scales = tuple(s.reshape(-1) for s in outs[n:2 * n])
-    new_resids = tuple(outs[2 * n:3 * n])
-    err = outs[3 * n] if with_err else None
+    outs = _call(
+        kernel, w, [_leaf_block(x) for x in ins], out_specs, out_shape,
+        interpret,
+    )(*ins)
+    qs = tuple(q.reshape(x.shape) for q, x in zip(outs[0:n], leaves))
+    new_resids = tuple(
+        r.reshape(x.shape) for r, x in zip(outs[n:2 * n], leaves)
+    )
+    scales = tuple(outs[2 * n][:, j] for j in range(n))
+    err = outs[2 * n + 1] if with_err else None
     return qs, scales, new_resids, err
 
 
 def _apply_barriered_kernel(*refs, nleaves):
     n = nleaves
-    alive_ref, denom0_ref = refs[0], refs[1]
+    alive_ref, denom0_ref = refs[0], refs[1]  # SMEM (W,), (1,)
     xs = refs[2:2 + n]
     anchors = refs[2 + n:2 + 2 * n]
     means = refs[2 + 2 * n:2 + 3 * n]
     resids = refs[2 + 3 * n:2 + 4 * n]
     new_xs = refs[2 + 4 * n:2 + 5 * n]
     new_rs = refs[2 + 5 * n:2 + 6 * n]
-    have = denom0_ref[0, 0] > 0
-    rejoin = jnp.logical_and(alive_ref[0, 0] <= 0, have)
+    have = denom0_ref[0] > 0
+    rejoin = jnp.logical_and(alive_ref[pl.program_id(0)] <= 0, have)
     for x_ref, a_ref, m_ref, r_ref, nx_ref, nr_ref in zip(
         xs, anchors, means, resids, new_xs, new_rs
     ):
@@ -192,50 +215,48 @@ def fused_apply_barriered(leaves, anchors, means, resids, alive, denom0,
     kernel.  ``means`` are the unstacked chunk means; ``alive`` (W,),
     ``denom0`` scalar."""
     w = leaves[0].shape[0]
-    kernel = partial(_apply_barriered_kernel, nleaves=len(leaves))
-    alive2 = alive.astype(jnp.float32).reshape(w, 1)
-    denom2 = jnp.asarray(denom0, jnp.float32).reshape(1, 1)
-    in_specs = (
-        [pl.BlockSpec((1, 1), lambda i: (i, 0)),
-         pl.BlockSpec((1, 1), lambda i: (0, 0))]
-        + [_leaf_block(x) for x in leaves]
-        + [_leaf_block(a) for a in anchors]
-        + [_whole_block(m) for m in means]
-        + [_leaf_block(r) for r in resids]
-    )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(w,),
-        in_specs=in_specs,
-        out_specs=(
-            [_leaf_block(x) for x in leaves]
-            + [_leaf_block(r) for r in resids]
-        ),
-        out_shape=(
-            [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in leaves]
-            + [jax.ShapeDtypeStruct(r.shape, r.dtype) for r in resids]
-        ),
-        interpret=_resolve_interpret(interpret),
-    )(alive2, denom2, *leaves, *anchors, *means, *resids)
     n = len(leaves)
-    return tuple(outs[0:n]), tuple(outs[n:2 * n])
+    kernel = partial(_apply_barriered_kernel, nleaves=n)
+    xs = [_stacked(x) for x in leaves]
+    ms = [m.reshape(_rows(m.shape)) for m in means]
+    stacked = xs + [_stacked(a) for a in anchors]
+    rs = [_stacked(r) for r in resids]
+    in_specs = (
+        [_SMEM, _SMEM]
+        + [_leaf_block(x) for x in stacked]
+        + [_whole_block(m) for m in ms]
+        + [_leaf_block(r) for r in rs]
+    )
+    outs = _call(
+        kernel, w, in_specs,
+        [_leaf_block(x) for x in xs + rs],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs + rs],
+        interpret,
+    )(
+        alive.astype(jnp.float32).reshape(w),
+        jnp.asarray(denom0, jnp.float32).reshape(1),
+        *stacked, *ms, *rs,
+    )
+    unview = [o.reshape(x.shape) for o, x in zip(outs, leaves + resids)]
+    return tuple(unview[0:n]), tuple(unview[n:2 * n])
 
 
 def _apply_correction_kernel(*refs, modes):
     n = len(modes)
-    xs = refs[0:n]
-    anchors = refs[n:2 * n]
-    qs = refs[2 * n:3 * n]
-    scales = refs[3 * n:4 * n]
-    means = refs[4 * n:5 * n]
-    new_xs = refs[5 * n:6 * n]
-    new_as = refs[6 * n:7 * n]
-    for x_ref, a_ref, q_ref, s_ref, m_ref, nx_ref, na_ref, mode in zip(
-        xs, anchors, qs, scales, means, new_xs, new_as, modes
+    scales_ref = refs[0]  # SMEM (W, n)
+    xs = refs[1:1 + n]
+    anchors = refs[1 + n:1 + 2 * n]
+    qs = refs[1 + 2 * n:1 + 3 * n]
+    means = refs[1 + 3 * n:1 + 4 * n]
+    new_xs = refs[1 + 4 * n:1 + 5 * n]
+    new_as = refs[1 + 5 * n:1 + 6 * n]
+    w = pl.program_id(0)
+    for j, (x_ref, a_ref, q_ref, m_ref, nx_ref, na_ref, mode) in enumerate(
+        zip(xs, anchors, qs, means, new_xs, new_as, modes)
     ):
         q = q_ref[0]
         if mode == "int8":
-            dq = q.astype(jnp.float32) * s_ref[0, 0]
+            dq = q.astype(jnp.float32) * scales_ref[w, j]
         elif mode == "bf16":
             dq = q.astype(jnp.float32)
         else:
@@ -253,29 +274,24 @@ def fused_apply_correction(leaves, anchors, qs, scales, means, modes,
     correction to params AND anchor — the unfused
     ``apply_correction_fn`` semantics, bit-identical, one kernel."""
     w = leaves[0].shape[0]
+    n = len(leaves)
     modes = tuple(modes)
     kernel = partial(_apply_correction_kernel, modes=modes)
-    scales2 = tuple(s.reshape(w, 1) for s in scales)
-    in_specs = (
-        [_leaf_block(x) for x in leaves]
-        + [_leaf_block(a) for a in anchors]
-        + [_leaf_block(q) for q in qs]
-        + [pl.BlockSpec((1, 1), lambda i: (i, 0)) for _ in scales2]
-        + [_whole_block(m) for m in means]
+    xs = [_stacked(x) for x in leaves]
+    stacked = (
+        xs + [_stacked(a) for a in anchors] + [_stacked(q) for q in qs]
     )
-    outs = pl.pallas_call(
-        kernel,
-        grid=(w,),
-        in_specs=in_specs,
-        out_specs=(
-            [_leaf_block(x) for x in leaves]
-            + [_leaf_block(a) for a in anchors]
-        ),
-        out_shape=(
-            [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in leaves]
-            + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in anchors]
-        ),
-        interpret=_resolve_interpret(interpret),
-    )(*leaves, *anchors, *qs, *scales2, *means)
-    n = len(leaves)
-    return tuple(outs[0:n]), tuple(outs[n:2 * n])
+    ms = [m.reshape(_rows(m.shape)) for m in means]
+    in_specs = (
+        [_SMEM]
+        + [_leaf_block(x) for x in stacked]
+        + [_whole_block(m) for m in ms]
+    )
+    outs = _call(
+        kernel, w, in_specs,
+        [_leaf_block(x) for x in xs] * 2,
+        [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs] * 2,
+        interpret,
+    )(jnp.stack([s.reshape(w) for s in scales], axis=1), *stacked, *ms)
+    unview = [o.reshape(x.shape) for o, x in zip(outs, leaves * 2)]
+    return tuple(unview[0:n]), tuple(unview[n:2 * n])

@@ -36,11 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover - import path differs across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 
 # canonical implementation lives beside the XLA LRN path (no cycle:
 # vision.py imports this module only lazily inside its env-gated branch)

@@ -47,11 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover - import path differs across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from sparknet_tpu.ops.vision import _fast_negpow
 
@@ -260,15 +256,10 @@ def _use_interpret(interpret):
     return interpret
 
 
-def _compiler_kwargs(interp):
-    if interp or pltpu is None:
-        return {}
-    return {
-        "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=64 * 1024 * 1024,
-        )
-    }
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=64 * 1024 * 1024,
+)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
@@ -302,7 +293,7 @@ def _fwd(x, n, alpha, beta, k, interpret):
         ],
         out_specs=pl.BlockSpec((1, C, tp, pw), lambda i, j: (i, 0, j, 0)),
         interpret=interp,
-        **_compiler_kwargs(interp),
+        compiler_params=_COMPILER_PARAMS,
     )(x, x, jnp.asarray(s0))
     return y, x
 
@@ -349,7 +340,7 @@ def _bwd(n, alpha, beta, k, interpret, x, dy):
             (1, C, 2 * tp, W), lambda i, j: (i, 0, j, 0)
         ),
         interpret=interp,
-        **_compiler_kwargs(interp),
+        compiler_params=_COMPILER_PARAMS,
     )(x, x, x, dy, dy, *args)
     return (dx,)
 

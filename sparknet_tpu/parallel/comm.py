@@ -66,12 +66,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from sparknet_tpu import obs
 
@@ -281,23 +277,14 @@ class CommPlane:
         # fused default path keeps its donating round; delta averaging
         # inherently carries one extra param copy — PERF.md).
         # batch_spec: the trainer's generalized batch partitioning
-        # (sequence parallelism) — same in_spec + check_rep backport
-        # rules as the fused round (trainers.py)
-        if batch_spec is None:
-            batch_in_spec, shmap_kw = P(axis), {}
-        else:
-            from sparknet_tpu.parallel.ring_attention import (
-                seq_shmap_kwargs,
-            )
-
-            batch_in_spec, shmap_kw = batch_spec, seq_shmap_kwargs()
+        # (sequence parallelism) — same in_spec as the fused round
+        batch_in_spec = P(axis) if batch_spec is None else batch_spec
         self._local = jax.jit(
             shard_map(
                 local_body,
                 mesh=mesh,
                 in_specs=(P(axis), batch_in_spec, P(), P(axis)),
                 out_specs=out_specs,
-                **shmap_kw,
             )
         )
         obs.track_jit(self._local)

@@ -25,33 +25,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-try:
-    _pcast = lax.pcast  # jax >= 0.7: the varying-type system
-    _SHMAP_KW = {}
-except AttributeError:  # pragma: no cover - version-dependent
-    def _pcast(x, axis_name, to="varying"):
-        # pre-varying jax has no replication typing to satisfy; the
-        # loop-carry semantics are identical without the annotation
-        return x
-
-    # pre-varying shard_map mis-types the ppermute loop carries under
-    # autodiff (replication checker, not semantics) — disable the check
-    _SHMAP_KW = {"check_rep": False}
-
-
-def seq_shmap_kwargs() -> dict:
-    """Extra ``shard_map`` kwargs any program needs when its body
-    carries ring collectives (ppermute loop carries / sp psums) under
-    autodiff on this jax build — the check_rep backport, shared with
-    the trainers so their sequence-parallel rounds lower on the same
-    jax versions this module does.  Empty on varying-typed jax
-    (>= 0.7), ``{"check_rep": False}`` before it."""
-    return dict(_SHMAP_KW)
+def _ring_carry(x, axis_name, *like):
+    """Type a freshly built loop carry as varying over the ring axis and
+    over every mesh axis the operands ``like`` already vary on.  The scan
+    that carries it requires equal varying-axes types in and out, and the
+    loop body mixes the carry with q/k/v — which on a dp x sp mesh vary
+    over both axes, not ``axis_name`` alone."""
+    axes = {axis_name}.union(*(jax.typeof(a).vma for a in like))
+    return lax.pcast(x, tuple(sorted(axes)), to="varying")
 
 
 def _merge_partials(o1, lse1, o2, lse2):
@@ -113,12 +94,11 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
             v_next = lax.ppermute(v_cur, axis_name, perm)
             return o_acc, lse_acc, k_next, v_next
 
-        o_acc = _pcast(
-            jnp.zeros((b, h, tq, d), jnp.float32), axis_name, to="varying"
+        o_acc = _ring_carry(
+            jnp.zeros((b, h, tq, d), jnp.float32), axis_name, q, k, v
         )
-        lse_acc = _pcast(
-            jnp.full((b, h, tq), -jnp.inf, jnp.float32),
-            axis_name, to="varying",
+        lse_acc = _ring_carry(
+            jnp.full((b, h, tq), -jnp.inf, jnp.float32), axis_name, q, k, v
         )
         o_acc, lse_acc, k_last, v_last = lax.fori_loop(
             0, n - 1, flash_body, (o_acc, lse_acc, k, v)
@@ -153,11 +133,11 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
         v_next = lax.ppermute(v_cur, axis_name, perm)
         return acc, m, l, k_next, v_next
 
-    # carries must be typed as varying over the ring axis from the start
-    # (the loop body makes them so) — pcast marks the replicated zeros
-    acc = _pcast(jnp.zeros((b, h, tq, d), q.dtype), axis_name, to="varying")
-    m = _pcast(jnp.full((b, h, tq), -jnp.inf, q.dtype), axis_name, to="varying")
-    l = _pcast(jnp.zeros((b, h, tq), q.dtype), axis_name, to="varying")
+    acc = _ring_carry(jnp.zeros((b, h, tq, d), q.dtype), axis_name, q, k, v)
+    m = _ring_carry(
+        jnp.full((b, h, tq), -jnp.inf, q.dtype), axis_name, q, k, v
+    )
+    l = _ring_carry(jnp.zeros((b, h, tq), q.dtype), axis_name, q, k, v)
     # n-1 rotate-and-accumulate steps, then the last shard accumulates
     # without the (discarded) final exchange
     acc, m, l, k_last, v_last = lax.fori_loop(
@@ -176,16 +156,24 @@ def ring_self_attention(
     evenly by the axis size (the ring rotates equal shards) — a ragged
     T is rejected up front with the fix spelled out, instead of the
     shard_map partitioner's generic shape error."""
+    from sparknet_tpu.ops import pallas_attention
+
     spec = P(None, axis, None, None)
     n = mesh.shape[axis]
+    # Off the TPU a forced flash kernel runs in Pallas's HLO interpreter,
+    # which slices each (sp-varying) block by its own unvarying grid
+    # indices — a mix jax 0.9.0's varying-axes check rejects.  Every
+    # operand here is sharded over ``axis`` alone, so nothing depends on
+    # the inferred replication; compiled kernels keep the check.
+    interpreted = bool(use_flash) and not pallas_attention.lowerable()
 
     @jax.jit
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        **_SHMAP_KW,
+        check_vma=not interpreted,
     )
     def inner(q, k, v):
         return ring_attention(q, k, v, axis, causal=causal,

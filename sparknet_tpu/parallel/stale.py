@@ -73,12 +73,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from sparknet_tpu import obs
 from sparknet_tpu.parallel.hierarchy import HierarchySpec
@@ -355,13 +351,6 @@ class BoundedStalenessTrainer:
         batch_in_spec = (
             P(axis) if batch_spec is None else batch_spec
         )
-        shmap_kw = {}
-        if batch_spec is not None:
-            from sparknet_tpu.parallel.ring_attention import (
-                seq_shmap_kwargs,
-            )
-
-            shmap_kw = seq_shmap_kwargs()
         self._stale_round = jax.jit(
             shard_map(
                 stale_body,
@@ -370,7 +359,6 @@ class BoundedStalenessTrainer:
                     P(axis), batch_in_spec, P(), P(axis), P(axis)
                 ),
                 out_specs=out_specs,
-                **shmap_kw,
             ),
             donate_argnums=(0, 1),
         )
@@ -427,7 +415,6 @@ class BoundedStalenessTrainer:
                         P(axis), batch_in_spec, P(), P(axis), P(axis)
                     ),
                     out_specs=out_specs,
-                    **shmap_kw,
                 ),
                 donate_argnums=(0, 1),
             )

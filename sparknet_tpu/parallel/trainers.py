@@ -29,12 +29,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from sparknet_tpu import obs
 from sparknet_tpu.obs import profile as obs_profile
@@ -278,10 +274,7 @@ class ParameterAveragingTrainer:
         (num_workers, tau, B, T) token arrays shard their sequence
         dim over the ``sp`` ring while the leading dim keeps the dp
         worker split.  ``None`` keeps today's ``P(axis)`` (every CNN
-        app, bit-identical).  A spec naming axes beyond ``axis``
-        implies ring collectives inside the body, which needs the
-        check_rep backport on pre-varying jax
-        (``ring_attention.seq_shmap_kwargs``)."""
+        app, bit-identical)."""
         self.solver = solver
         self.mesh = mesh
         self.axis = axis
@@ -292,19 +285,9 @@ class ParameterAveragingTrainer:
         self.average_stats = bool(average_stats)
         # batch pytree partitioning: P(axis) (worker-major, the CNN
         # apps) unless the caller declares per-leaf specs (sequence
-        # parallelism).  Extra axes in the spec mean ring collectives
-        # run inside the round body, which trips pre-varying jax's
-        # replication checker — same backport as ring_attention.
+        # parallelism)
         self.batch_spec = batch_spec
         batch_in_spec = P(axis) if batch_spec is None else batch_spec
-        if batch_spec is None:
-            shmap_kw = {}
-        else:
-            from sparknet_tpu.parallel.ring_attention import (
-                seq_shmap_kwargs,
-            )
-
-            shmap_kw = seq_shmap_kwargs()
 
         # the comm plane (parallel/comm.py): engaged for compressed
         # and/or overlapped averaging; None on the default path, which
@@ -454,7 +437,6 @@ class ParameterAveragingTrainer:
                 mesh=mesh,
                 in_specs=(P(axis), batch_in_spec, P(), P(axis)),
                 out_specs=out_specs,
-                **shmap_kw,
             ),
             donate_argnums=(0, 1),
         )
@@ -561,7 +543,6 @@ class ParameterAveragingTrainer:
                     mesh=mesh,
                     in_specs=(P(axis), batch_in_spec, P(), P(axis)),
                     out_specs=out_specs,
-                    **shmap_kw,
                 ),
                 donate_argnums=(0, 1),
             )
@@ -596,15 +577,11 @@ class ParameterAveragingTrainer:
         sharded over ``dp``."""
         st = self.solver.init_state(seed)
         n = self.num_workers
-        if jax.process_count() == 1:
-            stacked = tree_map(
-                lambda x: jnp.broadcast_to(x, (n,) + x.shape), st
-            )
-            return shard_leading(stacked, self.mesh, self.axis)
-        # multi-host: identical init everywhere; each process materializes
-        # its local workers' shards from the broadcast value
         sharding = leading_sharding(self.mesh, self.axis)
 
+        # identical init in every process; each device's shard is cut
+        # from a broadcast VIEW of the one host replica, so the n-fold
+        # stack never exists on the host or on any single device
         def mk(x):
             x = np.asarray(x)
             full = np.broadcast_to(x, (n,) + x.shape)

@@ -2,9 +2,12 @@
 
 The native side (``native/sparknet_runtime/runtime.cpp``) replaces the
 reference's C++ data plane: db::DB over LevelDB/LMDB, BlockingQueue,
-DataReader's reader thread and DataTransformer.  A pure-Python fallback
-keeps everything working when the .so hasn't been built (``make -C
-native``); ``native_available()`` reports which path is active.
+DataReader's reader thread and DataTransformer.  The library is built
+from the tracked sources at first use (``make -C native``; make's
+timestamp rule replaces one older than ``runtime.cpp``).  A pure-Python
+fallback keeps the record DB and pipeline working where it cannot be
+built; ``native_available()`` reports which path is active and
+``require_native()`` is for paths that measure the native one.
 """
 
 from __future__ import annotations
@@ -31,17 +34,15 @@ def _load():
     if _lib is not None or _lib_error is not None:
         return _lib
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError as e:
-        _lib_error = str(e)
-        return None
-    if not hasattr(lib, "snpipe_create2"):
-        # a stale pre-rework .so: fall back to Python (rebuildable with
-        # `make -C native` / runtime.build(force=True))
-        _lib_error = (
-            "libsparknet_runtime.so is outdated (missing snpipe_create2); "
-            "rebuild with `make -C native`"
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR], check=True, capture_output=True
         )
+        lib = ctypes.CDLL(_LIB_PATH)
+    except subprocess.CalledProcessError as e:
+        _lib_error = e.stderr.decode("utf-8", "replace") or str(e)
+        return None
+    except OSError as e:  # no make, or an unloadable library
+        _lib_error = str(e)
         return None
     lib.sn_last_error.restype = ctypes.c_char_p
     lib.sndb_open.restype = ctypes.c_void_p
@@ -103,27 +104,26 @@ def _load():
     return lib
 
 
-def build(force: bool = False) -> bool:
-    """Build the native library with make (returns True on success)."""
-    global _lib, _lib_error
-    if os.path.exists(_LIB_PATH) and not force:
-        _lib_error = None
-        if _load() is not None:
-            return True
-        # present but unloadable/stale: fall through and rebuild
-    try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR], check=True, capture_output=True
-        )
-    except (subprocess.CalledProcessError, FileNotFoundError) as e:
-        _lib_error = getattr(e, "stderr", b"") or str(e)
-        return False
-    _lib, _lib_error = None, None
+def build() -> bool:
+    """(Re)try the native build + load; True when the library is active."""
+    global _lib_error
+    _lib_error = None
     return _load() is not None
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def require_native() -> None:
+    """Raise unless the native library is active — for paths that measure
+    it, where the Python fallback would be a different thing timed under
+    the same name."""
+    if _load() is None:
+        raise RuntimeError(
+            "native runtime unavailable (make -C native failed): "
+            f"{_lib_error}"
+        )
 
 
 def _err(lib) -> str:

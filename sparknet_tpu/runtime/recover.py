@@ -586,9 +586,13 @@ def main(argv=None) -> int:
 
     # the virtual mesh must exist before any backend use (same rule as
     # bench.py's multi-device modes)
-    from sparknet_tpu.utils.devices import force_virtual_cpu_devices
+    from sparknet_tpu.utils.devices import (
+        enable_compile_cache,
+        ensure_devices,
+    )
 
-    force_virtual_cpu_devices(max(args.workers, 2))
+    ensure_devices(max(args.workers, 2))
+    enable_compile_cache()
 
     ctx = RecoverContext(
         args.workdir,

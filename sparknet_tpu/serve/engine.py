@@ -127,6 +127,23 @@ class InferenceEngine:
                 feeds.extend(lp.top)
         return len(set(feeds))
 
+    def to_device(self, device) -> None:
+        """Commit the weights to ``device``: every forward then runs
+        there (a program follows its committed operands), whatever the
+        calling thread's default device.  Call before ``warmup()``."""
+        import jax
+
+        self.params = jax.device_put(self.params, device)
+        self.stats = jax.device_put(self.stats, device)
+
+    @property
+    def device(self):
+        """The device the weights live on."""
+        import jax
+
+        (dev,) = jax.tree_util.tree_leaves(self.params)[0].devices()
+        return dev
+
     # ------------------------------------------------------------------
     # Compilation control
     # ------------------------------------------------------------------

@@ -214,8 +214,9 @@ class ReplicaPool:
     engine; the pool warms every engine it builds before the engine sees
     traffic (construction, ``respawn``, ``promote`` — all off the
     serving path).  ``devices`` optionally pins replica i to
-    ``devices[i % len(devices)]`` (per-device fleet; on a 1-device host
-    every replica shares the device and the threads contend — disclosed
+    ``devices[i % len(devices)]`` by committing its engine's arrays
+    there before warm-up (per-device fleet; on a 1-device host every
+    replica shares the device and the threads contend — disclosed
     wherever it matters)."""
 
     def __init__(
@@ -297,15 +298,10 @@ class ReplicaPool:
                     ) -> InferenceEngine:
         """Build + warm one engine for replica ``index`` — always off
         the serving path (construction, respawn, promote)."""
+        eng = self.make_engine(weights=weights)
         dev = self._device_for(index)
         if dev is not None:
-            import jax
-
-            with jax.default_device(dev):
-                eng = self.make_engine(weights=weights)
-                eng.warmup()
-                return eng
-        eng = self.make_engine(weights=weights)
+            eng.to_device(dev)
         eng.warmup()
         return eng
 

@@ -162,6 +162,25 @@ class GenerationEngine:
         self._decode = jax.jit(_decode)
         self._score = jax.jit(_score)
 
+    def to_device(self, device) -> None:
+        """Commit the weights and the KV arena to ``device``: prefill
+        and decode then run there (a program follows its committed
+        operands), whatever the calling thread's default device.  Call
+        before ``warmup()``."""
+        import jax
+
+        self.params = jax.device_put(self.params, device)
+        self.pool.k = jax.device_put(self.pool.k, device)
+        self.pool.v = jax.device_put(self.pool.v, device)
+
+    @property
+    def device(self):
+        """The device the weights live on."""
+        import jax
+
+        (dev,) = jax.tree_util.tree_leaves(self.params)[0].devices()
+        return dev
+
     # ------------------------------------------------------------------
     # Compilation control
     # ------------------------------------------------------------------

@@ -153,41 +153,6 @@ def test_ring_attention_kv_grads_match_reference():
         )
 
 
-def test_ring_attention_check_rep_backport():
-    """Regression for the check_rep backport: on pre-varying jax
-    (no ``lax.pcast``) the module must run its shard_maps with
-    check_rep disabled — the replication checker mis-types the
-    ppermute loop carries under autodiff — and the trainers consume
-    the SAME kwargs via ``seq_shmap_kwargs`` so their sequence-
-    parallel rounds lower on every jax this module does."""
-    import importlib
-
-    from jax import lax
-
-    # the package re-exports the ring_attention FUNCTION; fetch the
-    # module itself for its kwargs helper
-    ra = importlib.import_module("sparknet_tpu.parallel.ring_attention")
-
-    kw = ra.seq_shmap_kwargs()
-    if hasattr(lax, "pcast"):
-        assert kw == {}  # varying-typed jax needs no opt-out
-    else:
-        assert kw == {"check_rep": False}
-    # a fresh dict each call: a caller mutating its copy can't poison
-    # the module's view
-    kw["check_rep"] = "mutated"
-    assert ra.seq_shmap_kwargs() != {"check_rep": "mutated"}
-    # and the backport path actually differentiates: grad through the
-    # ring under jit (this is what check_rep=True rejects on old jax)
-    mesh = make_mesh({"sp": 2}, devices=jax.devices()[:2])
-    q, k, v = _qkv(6)
-    fn = ring_self_attention(mesh, "sp", causal=True)
-    g = jax.jit(
-        jax.grad(lambda q: jnp.sum(jnp.square(fn(q, k, v))))
-    )(q)
-    assert np.all(np.isfinite(np.asarray(g)))
-
-
 # ---------------------------------------------------------------------
 # flash backward (custom_vjp): grads pinned against jax.grad of the
 # dense reference — the training-step default rides this kernel pair

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64 as jax_enable_x64
+from jax import enable_x64 as jax_enable_x64
 
 from sparknet_tpu import config
 from sparknet_tpu.net import JaxNet

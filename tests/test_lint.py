@@ -576,7 +576,7 @@ class TestRegistryAudit:
         assert not audit, [f.message for f in audit]
 
     def test_docs_reference_complete(self):
-        """PERF.md's telemetry reference must name every canonical
+        """ARCHITECTURE.md's telemetry reference must name every canonical
         metric and phase (the docs leg of the audit)."""
         rep = runner.scan_package(_REPO, with_docs=True)
         docs = [

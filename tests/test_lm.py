@@ -50,10 +50,7 @@ def _build(sp, docs_or_none=None, **solver_kw):
         dim=32, depth=2, heads=2, seq_len=T,
         sp_axis="sp" if sp > 1 else None, sp_size=sp,
     )
-    solver = Solver(
-        _solver_param(), net=lm,
-        grad_reduce_axes=("sp",) if sp > 1 else (), **solver_kw,
-    )
+    solver = Solver(_solver_param(), net=lm, **solver_kw)
     return lm, solver
 
 

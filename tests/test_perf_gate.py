@@ -40,7 +40,7 @@ def test_newest_artifact_per_family_wins(tmp_path):
     g = _gate()
     _write(tmp_path, "PIPELINE_r08.json", GOOD_PIPELINE)
     _write(tmp_path, "PIPELINE_r03.json", {"value": 0.2})  # old history
-    _write(tmp_path, "BENCH_r04_googlenet.json", {"value": 50.0})
+    _write(tmp_path, "SCALING_r04_googlenet.json", {"value": 50.0})
     _write(tmp_path, "BASELINE.json", {"value": -1})  # not an artifact
     _write(tmp_path, "notes_r99.json", {"value": -1})  # unknown family
     arts = g.find_artifacts(str(tmp_path))
@@ -48,33 +48,33 @@ def test_newest_artifact_per_family_wins(tmp_path):
     assert [os.path.basename(p) for p in arts["PIPELINE"][1]] == [
         "PIPELINE_r08.json"
     ]
-    assert arts["BENCH"][0] == 4  # suffixed variants count in-family
-    assert set(arts) == {"PIPELINE", "BENCH"}
+    assert arts["SCALING"][0] == 4  # suffixed variants count in-family
+    assert set(arts) == {"PIPELINE", "SCALING"}
     # ALL same-newest-round variants are returned (unsuffixed first) so
     # the gate validates every one, not an arbitrary glob-order pick
-    _write(tmp_path, "BENCH_r04.json", {"value": 60.0})
-    _write(tmp_path, "BENCH_r04_resnet50.json", {"value": 70.0})
+    _write(tmp_path, "SCALING_r04.json", {"value": 60.0})
+    _write(tmp_path, "SCALING_r04_resnet50.json", {"value": 70.0})
     arts = g.find_artifacts(str(tmp_path))
-    assert [os.path.basename(p) for p in arts["BENCH"][1]] == [
-        "BENCH_r04.json", "BENCH_r04_googlenet.json",
-        "BENCH_r04_resnet50.json",
+    assert [os.path.basename(p) for p in arts["SCALING"][1]] == [
+        "SCALING_r04.json", "SCALING_r04_googlenet.json",
+        "SCALING_r04_resnet50.json",
     ]
     # a regression in ANY same-round variant fails --check
-    _write(tmp_path, "BENCH_r04_googlenet.json", {"value": 0})
+    _write(tmp_path, "SCALING_r04_googlenet.json", {"value": 0})
     rc, rows = g.check(str(tmp_path))
     assert rc == 1
     assert any(
-        r["artifact"] == "BENCH_r04_googlenet.json" and not r["ok"]
+        r["artifact"] == "SCALING_r04_googlenet.json" and not r["ok"]
         for r in rows
     )
-    # suffixes with underscores (BENCH_MODEL=cifar10_full) are in-family
+    # suffixes with underscores (a model name like cifar10_full) are in-family
     # too — a newer such artifact must supersede and be validated
-    _write(tmp_path, "BENCH_r06_cifar10_full.json", {"value": 0})
+    _write(tmp_path, "SCALING_r06_cifar10_full.json", {"value": 0})
     arts = g.find_artifacts(str(tmp_path))
-    assert arts["BENCH"][0] == 6
+    assert arts["SCALING"][0] == 6
     rc, rows = g.check(str(tmp_path))
     assert any(
-        r["artifact"] == "BENCH_r06_cifar10_full.json" and not r["ok"]
+        r["artifact"] == "SCALING_r06_cifar10_full.json" and not r["ok"]
         for r in rows
     )
 

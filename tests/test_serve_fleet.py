@@ -104,6 +104,29 @@ def _gate_engines(pool):
 # router: routing, shed consistency, eject/respawn
 
 
+def test_pool_pins_replica_i_to_device_i(netp_deploy):
+    """``devices=`` commits each replica's engine to its own device:
+    the forward runs there from a thread with another default device,
+    and serving after warm-up compiles nothing."""
+    import jax
+
+    devices = jax.devices()[:2]
+    pool = ReplicaPool(
+        _make_engine_factory(netp_deploy), replicas=3, devices=devices
+    )
+    try:
+        for rep in pool.replicas:
+            eng = rep.engine
+            assert eng.device == devices[rep.index % 2]
+            warm = eng.jit_cache_size()
+            out = eng._fwd(eng.params, eng.stats, np.repeat(X, 4, axis=0))
+            assert out.devices() == {eng.device}
+            eng.infer(X)
+            assert eng.jit_cache_size() == warm
+    finally:
+        pool.close()
+
+
 def test_router_routes_and_matches_single_engine(netp_deploy):
     pool, router = _fleet(netp_deploy, replicas=2)
     try:

@@ -382,8 +382,8 @@ def test_bf16_f32_train_curve_equivalence_cifar():
 
 def test_note_losses_is_lazy_bounded_and_exact():
     """smoothed_loss must not pull losses to host until read (the hot
-    loop stays free of device->host syncs — PERF.md 'Relay transfer
-    degradation'), pending retention is bounded by the window size, and
+    loop stays free of device->host syncs), pending retention is
+    bounded by the window size, and
     the drained window equals the eager computation."""
     s = _solver("average_loss: 3")
     assert s._loss_window.maxlen == 3

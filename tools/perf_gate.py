@@ -1,8 +1,7 @@
 """Perf-regression gate over the committed benchmark artifacts.
 
 The repo's perf story is a trajectory of committed one-line JSON
-artifacts (BENCH_*, PIPELINE_*, OBS_*, HEALTH_*, COMM_*, PROFILE_*,
-...).  Each carries pinned bands in its schema tests, but nothing
+artifacts (PIPELINE_*, OBS_*, COMM_*, PROFILE_*, ...).  Each carries pinned bands in its schema tests, but nothing
 checked them *as a set*, and nothing compared a live run against them.
 This gate does both:
 
@@ -84,11 +83,6 @@ class Rule:
 # and test_bench_smoke schema tests enforce, applied to the NEWEST
 # artifact of each family.  Older artifacts are history, not contracts.
 RULES: Dict[str, List[Rule]] = {
-    "BENCH": [Rule("value", ">", 0)],
-    "HOSTFEED": [
-        Rule("value", ">=", 267.0),  # the reference K40 row, measured
-        Rule("vs_baseline", ">=", 1.0),
-    ],
     # MULTICHIP artifacts are pass/fail dryrun records, not rates
     "MULTICHIP": [Rule("ok", "is", True), Rule("rc", "==", 0)],
     "SCALING": [Rule("value", ">", 0)],
@@ -404,7 +398,7 @@ RULES: Dict[str, List[Rule]] = {
 
 def find_artifacts(root: str = _REPO) -> Dict[str, Tuple[int, List[str]]]:
     """Newest committed artifacts per family: ``FAMILY -> (round,
-    [paths])``.  Suffixed variants (BENCH_r04_googlenet) count in their
+    [paths])``.  Suffixed variants (SCALING_r04_googlenet) count in their
     family and ALL same-newest-round variants are returned (sorted, the
     unsuffixed one first) so the gate validates every one of them — a
     single arbitrary glob-order pick would silently skip siblings.
@@ -412,7 +406,7 @@ def find_artifacts(root: str = _REPO) -> Dict[str, Tuple[int, List[str]]]:
     newest: Dict[str, Tuple[int, List[str]]] = {}
     for path in glob.glob(os.path.join(root, "*.json")):
         m = re.match(
-            # suffixes may contain underscores (BENCH_r06_cifar10_full)
+            # suffixes may contain underscores (SCALING_r06_cifar10_full)
             r"([A-Z]+)_r(\d+)(?:_[A-Za-z0-9_]+)?\.json$",
             os.path.basename(path),
         )
@@ -430,16 +424,7 @@ def find_artifacts(root: str = _REPO) -> Dict[str, Tuple[int, List[str]]]:
 
 def _load(path: str) -> dict:
     with open(path) as f:
-        d = json.load(f)
-    # the unsuffixed BENCH_r* artifacts are driver wrapper records
-    # ({n, cmd, rc, tail, parsed: {...}}) with the one-line artifact
-    # nested under "parsed"; the suffixed variants are bare.  Unwrap so
-    # both shapes meet the same rules.
-    if isinstance(d, dict) and "value" not in d and isinstance(
-        d.get("parsed"), dict
-    ):
-        return d["parsed"]
-    return d
+        return json.load(f)
 
 
 def _chaos_survival_rule(art: dict) -> Tuple[bool, str]:
