@@ -5,8 +5,12 @@ The reference preprocesses per image on the host — random/center crop +
 mean subtraction in Scala closures (``ImageNetApp.scala:128-180``) or in
 ``DataTransformer`` C++ (``data_transformer.cpp:19-132``). TPU-first, the
 same math runs *inside* the jitted train step on uint8 device batches:
-elementwise work is free next to the convs, the host stays out of the hot
-path, and host->device transfers shrink 4x (uint8 vs float32).
+the host stays out of the hot path, and host->device transfers shrink 4x
+(uint8 vs float32).  It is not free next to the convs: on the v5e the
+scope ``transform`` takes 2.84 ms of CaffeNet's 16.16 ms step at batch
+256, 17.6% of the device's time (``transform_device_ms``, PERF.md
+section 5, PR 24) -- per-image crop windows as two 256-iteration loops,
+a layout copy, the mirror's ``rev``.
 
 Factories return closures with the reference's semantics:
 

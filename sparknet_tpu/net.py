@@ -53,6 +53,14 @@ class NetOutputs:
     stats: Stats
 
 
+def layer_scope(lp) -> str:
+    """``<Type>:<name>``, the ``jax.named_scope`` of one layer's work in the
+    compiled program (its backward pass reads ``transpose(jvp(<Type>:<name>))``
+    in the device trace).  The name stack is joined with ``/``, so a ``/`` in
+    the layer's name (``inception_3a/1x1``) is written ``.``."""
+    return f"{lp.type}:{lp.name.replace('/', '.')}"
+
+
 class JaxNet:
     """A compiled net for one phase.
 
@@ -450,72 +458,78 @@ class JaxNet:
         cd = self.compute_dtype
         for li, layer in enumerate(self.layers):
             lp = layer.lp
-            if li in self._hconv_skip:
+            if li in self._hconv_skip or li in self._plp_skip:
                 continue
-            if li in self._hconv_groups:
-                self._apply_hconv(
-                    self._hconv_groups[li], blobs[lp.bottom[0]], params,
-                    perturb, blobs,
-                )
-                continue
-            if li in self._plp_skip:
-                continue
-            if li in self._plp_fused:
-                pool_top, fn = self._plp_fused[li]
-                x = blobs[lp.bottom[0]]
-                if cd is not None and jnp.issubdtype(x.dtype, jnp.floating):
-                    x = x.astype(cd)
-                y = fn(x)
-                if perturb is not None and pool_top in perturb:
-                    y = y + perturb[pool_top]
-                blobs[pool_top] = y
-                continue
-            if isinstance(layer, data_layers._HostFed):
-                # host blobs keep their dtype: index-valued blobs (labels)
-                # must never round through bf16; consumers cast as needed
-                tops = [blobs[t] for t in lp.top]
-            else:
-                lblobs = self._gather_blobs(layer.name, params, new_stats)
-                bottoms = [blobs[b] for b in lp.bottom]
-                if cd is not None:
-                    if layer.IS_LOSS:
-                        # losses compute in f32 for stable log/exp; the
-                        # label bottom is f32 already (exact indices)
-                        bottoms = [b.astype(jnp.float32) for b in bottoms]
-                    elif not layer.MIXED_PRECISION_EXEMPT:
-                        lblobs = [b.astype(cd) for b in lblobs]
-                        bottoms = [
-                            b.astype(cd)
-                            if jnp.issubdtype(b.dtype, jnp.floating)
-                            else b
-                            for b in bottoms
-                        ]
-                lrng = jax.random.fold_in(rng, li) if rng is not None else None
-                tops, updated = layer.apply(lblobs, bottoms, lrng, train)
-                if updated is not None:
-                    refs = self._blob_refs[layer.name]
-                    for d, ref, arr in zip(
-                        self._blob_defs[layer.name], refs, updated
+            # everything the layer causes (casts, apply, loss term) under
+            # one scope: the device trace reads time per layer from it
+            with jax.named_scope(layer_scope(lp)):
+                if li in self._hconv_groups:
+                    self._apply_hconv(
+                        self._hconv_groups[li], blobs[lp.bottom[0]], params,
+                        perturb, blobs,
+                    )
+                    continue
+                if li in self._plp_fused:
+                    pool_top, fn = self._plp_fused[li]
+                    x = blobs[lp.bottom[0]]
+                    if cd is not None and jnp.issubdtype(
+                        x.dtype, jnp.floating
                     ):
-                        if ref.collection == "stats":
-                            # keep stat blobs at their master dtype even
-                            # under bf16 compute
-                            cur = new_stats[ref.owner][ref.index]
-                            new_stats[ref.owner][ref.index] = arr.astype(
-                                cur.dtype
-                            )
-            if perturb is not None:
-                tops = [
-                    top + perturb[name] if name in perturb else top
-                    for name, top in zip(lp.top, tops)
-                ]
-            for w, top, name in zip(
-                self._loss_weights[layer.name], tops, lp.top
-            ):
-                if w:
-                    loss = loss + w * jnp.sum(top)
-            for name, top in zip(lp.top, tops):
-                blobs[name] = top
+                        x = x.astype(cd)
+                    y = fn(x)
+                    if perturb is not None and pool_top in perturb:
+                        y = y + perturb[pool_top]
+                    blobs[pool_top] = y
+                    continue
+                if isinstance(layer, data_layers._HostFed):
+                    # host blobs keep their dtype: index-valued blobs (labels)
+                    # must never round through bf16; consumers cast as needed
+                    tops = [blobs[t] for t in lp.top]
+                else:
+                    lblobs = self._gather_blobs(layer.name, params, new_stats)
+                    bottoms = [blobs[b] for b in lp.bottom]
+                    if cd is not None:
+                        if layer.IS_LOSS:
+                            # losses compute in f32 for stable log/exp; the
+                            # label bottom is f32 already (exact indices)
+                            bottoms = [b.astype(jnp.float32) for b in bottoms]
+                        elif not layer.MIXED_PRECISION_EXEMPT:
+                            lblobs = [b.astype(cd) for b in lblobs]
+                            bottoms = [
+                                b.astype(cd)
+                                if jnp.issubdtype(b.dtype, jnp.floating)
+                                else b
+                                for b in bottoms
+                            ]
+                    lrng = (
+                        jax.random.fold_in(rng, li) if rng is not None
+                        else None
+                    )
+                    tops, updated = layer.apply(lblobs, bottoms, lrng, train)
+                    if updated is not None:
+                        refs = self._blob_refs[layer.name]
+                        for d, ref, arr in zip(
+                            self._blob_defs[layer.name], refs, updated
+                        ):
+                            if ref.collection == "stats":
+                                # keep stat blobs at their master dtype even
+                                # under bf16 compute
+                                cur = new_stats[ref.owner][ref.index]
+                                new_stats[ref.owner][ref.index] = arr.astype(
+                                    cur.dtype
+                                )
+                if perturb is not None:
+                    tops = [
+                        top + perturb[name] if name in perturb else top
+                        for name, top in zip(lp.top, tops)
+                    ]
+                for w, top, name in zip(
+                    self._loss_weights[layer.name], tops, lp.top
+                ):
+                    if w:
+                        loss = loss + w * jnp.sum(top)
+                for name, top in zip(lp.top, tops):
+                    blobs[name] = top
         return NetOutputs(blobs=blobs, loss=loss, stats=new_stats)
 
     def forward(

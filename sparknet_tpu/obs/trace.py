@@ -21,6 +21,14 @@ instrumented hot paths pay ~nothing by default; with tracing on, a span
 is two ``perf_counter`` reads and one list append under a lock —
 ``bench.py --mode=obs`` measures the end-to-end round-time overhead
 (<2% acceptance, ``OBS_r09.json``).
+
+On the profiler's clock: a span that any sink is installed for also
+opens a ``jax.profiler.TraceAnnotation(name, **args)``, so while
+``jax.profiler.trace`` runs over a job its ``assemble`` / ``h2d`` /
+``average`` / ``execute`` sit on the host plane of the same
+``.xplane.pb`` as the device's operations, on the thread that ran them
+(ARCHITECTURE.md "Telemetry reference").  Outside a profiler trace the
+annotation is a no-op; with no sink installed none is made.
 """
 
 from __future__ import annotations
@@ -240,17 +248,27 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, name: str, cat: str, args: Optional[dict]):
+        # imported where a span is made, so that importing obs stays free
+        # of jax and the off path (span() -> _NULL_SPAN) never touches it
+        from jax.profiler import TraceAnnotation
+
         self.name, self.cat, self.args = name, cat, args
+        # the same span on the profiler's clock: while jax.profiler traces,
+        # a host event ``name`` (its args as stats) on this thread's line
+        # of the .xplane.pb, beside the device's; otherwise a no-op
+        self._annotation = TraceAnnotation(name, **(args or {}))
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         dur_s = t1 - self._t0
         t = _tracer
         if t is not None:

@@ -375,27 +375,29 @@ class ParameterAveragingTrainer:
                 ok = jnp.where(bad, 0.0, 1.0)
                 alive = alive * ok
                 astats = dict(astats, masked=1.0 - ok)
-            denom0 = jax.lax.psum(alive, axis)
-            denom = jnp.maximum(denom0, 1.0)
+            # the scope the device trace reads the cost of averaging from
+            with jax.named_scope("average"):
+                denom0 = jax.lax.psum(alive, axis)
+                denom = jnp.maximum(denom0, 1.0)
 
-            def wmean(w):
-                contrib = jnp.where(alive > 0, w, jnp.zeros_like(w))
-                m = jax.lax.psum(contrib, axis) / denom.astype(w.dtype)
-                if mask_nf:
-                    # no finite worker at all: keep own params (the
-                    # host sentry escalates) instead of an all-zero
-                    # "average" that would read as healthy
-                    return jnp.where(denom0 > 0, m, w)
-                return m
+                def wmean(w):
+                    contrib = jnp.where(alive > 0, w, jnp.zeros_like(w))
+                    m = jax.lax.psum(contrib, axis) / denom.astype(w.dtype)
+                    if mask_nf:
+                        # no finite worker at all: keep own params (the
+                        # host sentry escalates) instead of an all-zero
+                        # "average" that would read as healthy
+                        return jnp.where(denom0 > 0, m, w)
+                    return m
 
-            avg_params = (
-                tree_map(wmean, st.params) if average_params else st.params
-            )
-            avg_stats = (
-                tree_map(wmean, st.stats)
-                if average_stats and average_params
-                else st.stats
-            )
+                avg_params = (
+                    tree_map(wmean, st.params) if average_params else st.params
+                )
+                avg_stats = (
+                    tree_map(wmean, st.stats)
+                    if average_stats and average_params
+                    else st.stats
+                )
             history = st.history
             if mask_nf and average_params:
                 # the masked slot's params are replaced by the survivor
@@ -488,35 +490,36 @@ class ParameterAveragingTrainer:
                 ).astype(jnp.float32)
                 # per-slice live counts, visible to every worker; each
                 # worker reads its OWN slice's count
-                denom0_all = jax.lax.psum(onehot * alive, axis)
-                denom0 = jnp.take(denom0_all, sid)
-                denom = jnp.maximum(denom0, 1.0)
+                with jax.named_scope("average"):
+                    denom0_all = jax.lax.psum(onehot * alive, axis)
+                    denom0 = jnp.take(denom0_all, sid)
+                    denom = jnp.maximum(denom0, 1.0)
 
-                def smean(w):
-                    contrib = jnp.where(alive > 0, w, jnp.zeros_like(w))
-                    stacked = (
-                        onehot.reshape((num_slices,) + (1,) * w.ndim)
-                        * contrib[None]
+                    def smean(w):
+                        contrib = jnp.where(alive > 0, w, jnp.zeros_like(w))
+                        stacked = (
+                            onehot.reshape((num_slices,) + (1,) * w.ndim)
+                            * contrib[None]
+                        )
+                        sums = jax.lax.psum(stacked, axis)
+                        m = jnp.take(
+                            sums, sid, axis=0
+                        ) / denom.astype(w.dtype)
+                        # a fully-departed slice keeps its own params (its
+                        # slots are stale until readmission broadcasts) —
+                        # unlike the global round there may be NO live
+                        # worker in this group even on a healthy fleet
+                        return jnp.where(denom0 > 0, m, w)
+
+                    avg_params = (
+                        tree_map(smean, st.params)
+                        if average_params else st.params
                     )
-                    sums = jax.lax.psum(stacked, axis)
-                    m = jnp.take(
-                        sums, sid, axis=0
-                    ) / denom.astype(w.dtype)
-                    # a fully-departed slice keeps its own params (its
-                    # slots are stale until readmission broadcasts) —
-                    # unlike the global round there may be NO live
-                    # worker in this group even on a healthy fleet
-                    return jnp.where(denom0 > 0, m, w)
-
-                avg_params = (
-                    tree_map(smean, st.params)
-                    if average_params else st.params
-                )
-                avg_stats = (
-                    tree_map(smean, st.stats)
-                    if average_stats and average_params
-                    else st.stats
-                )
+                    avg_stats = (
+                        tree_map(smean, st.stats)
+                        if average_stats and average_params
+                        else st.stats
+                    )
                 history = st.history
                 if mask_nf and average_params:
                     # audit-masked worker rejoining its slice mean:
@@ -724,7 +727,7 @@ class ParameterAveragingTrainer:
         # the fused XLA program's dispatch/execution.  Span timing stays
         # dispatch-honest: no extra device sync is added here.
         astats = None
-        with obs.span("average"):
+        with obs.span("average", round=r):
             if live_mask is None:
                 live_mask = np.ones((self.num_workers,), np.float32)
             live = self._place_live(live_mask)  # cached per mask value

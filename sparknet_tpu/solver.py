@@ -266,9 +266,10 @@ class Solver:
         grad_fn = jax.value_and_grad(self.net.loss_fn, has_aux=True)
         if self.param.iter_size == 1:
             if self.train_transform is not None:
-                batch = self.train_transform(
-                    batch, jax.random.fold_in(rng, 0x7F)
-                )
+                with jax.named_scope("transform"):
+                    batch = self.train_transform(
+                        batch, jax.random.fold_in(rng, 0x7F)
+                    )
             (loss, (_, new_stats)), g = grad_fn(params, stats, batch, rng, True)
             return g, loss, new_stats
 
@@ -276,7 +277,10 @@ class Solver:
             acc, st, i = carry
             lrng = jax.random.fold_in(rng, i)
             if self.train_transform is not None:
-                mb = self.train_transform(mb, jax.random.fold_in(lrng, 0x7F))
+                with jax.named_scope("transform"):
+                    mb = self.train_transform(
+                        mb, jax.random.fold_in(lrng, 0x7F)
+                    )
             (loss, (_, st2)), g = grad_fn(params, st, mb, lrng, True)
             return (_tree_map(jnp.add, acc, g), st2, i + 1), loss
 
@@ -346,9 +350,10 @@ class Solver:
         readout, fused into the same program)."""
         lrng = jax.random.fold_in(rng, st.iter)
         grads, loss, new_stats = self._grads(st.params, st.stats, batch, lrng)
-        new_params, new_history, grad_norm = self._apply_update(
-            st.params, st.history, grads, st.iter
-        )
+        with jax.named_scope("update"):
+            new_params, new_history, grad_norm = self._apply_update(
+                st.params, st.history, grads, st.iter
+            )
         new_st = TrainState(new_params, new_stats, new_history, st.iter + 1)
         if self.audit:
             stats = _health.audit_iteration(

@@ -36,3 +36,22 @@ REPO_ROOT_TRAINING_LOGS = frozenset(
 from sparknet_tpu.utils.devices import force_virtual_cpu_devices  # noqa: E402
 
 force_virtual_cpu_devices(8)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_span_sink_outlives_its_test_file():
+    """``obs.span``'s sinks are process globals, and a worker runs many test
+    files in an order the scheduler picks: a file that leaves one installed
+    (the process-wide training metrics wire the phase observer and several
+    files never drop them) turns ``span()`` on for whichever file comes next,
+    whose off-path tests (``span() is _NULL_SPAN``) then fail by luck of the
+    draw.  Every file ends with all five sinks off."""
+    yield
+    from sparknet_tpu import obs
+    from sparknet_tpu.obs import trace
+
+    obs._reset_training_metrics_for_tests()  # phase observer, ship, flight, profiler
+    trace.uninstall_tracer()
+    trace.set_span_observer(None)
