@@ -6,11 +6,15 @@ mean subtraction in Scala closures (``ImageNetApp.scala:128-180``) or in
 ``DataTransformer`` C++ (``data_transformer.cpp:19-132``). TPU-first, the
 same math runs *inside* the jitted train step on uint8 device batches:
 the host stays out of the hot path, and host->device transfers shrink 4x
-(uint8 vs float32).  It is not free next to the convs: on the v5e the
-scope ``transform`` takes 2.84 ms of CaffeNet's 16.16 ms step at batch
-256, 17.6% of the device's time (``transform_device_ms``, PERF.md
-section 5, PR 24) -- per-image crop windows as two 256-iteration loops,
-a layout copy, the mirror's ``rev``.
+(uint8 vs float32).  On the v5e the scope ``transform`` takes 0.45 ms of
+CaffeNet's 13.14 ms step at batch 256 (``transform_device_ms``, PERF.md
+section 5, my chip run, PR 25): XLA fuses the pass over the stored frames
+(mean, rounding to the compute dtype) and both one-hot selections into
+one convolution fusion of 0.27 ms and copies the bf16 crops once into
+conv1's layout, 0.17 ms; nothing walks the batch image by image.  Off
+the chip the selections are real arithmetic, 0.17 GFLOP an image: 0.4 s
+for a float32 batch of 256 on the sandbox's 8 CPU cores, a few percent of
+a CaffeNet step there.
 
 Factories return closures with the reference's semantics:
 
@@ -94,27 +98,27 @@ def finish_host_crops(
     return fn
 
 
-def _crop_one(img, mean, h_off, w_off, crop: int, flip, scale: float):
-    """Crop one (C, H, W) image + the mean at the same window, subtract,
-    optionally mirror (reference mirrors after transform: the output is
-    written flipped, data_transformer.cpp:119-130)."""
-    c = img.shape[0]
-    window = jax.lax.dynamic_slice(
-        img, (0, h_off, w_off), (c, crop, crop)
-    ).astype(jnp.float32)
-    if mean is not None:
-        if mean.shape[-2:] == (1, 1):  # per-channel mean: broadcast
-            window = window - mean
-        else:  # full mean image: indexed by the source window
-            mwin = jax.lax.dynamic_slice(
-                mean, (0, h_off, w_off), (c, crop, crop)
-            )
-            window = window - mwin
-    if scale != 1.0:
-        window = window * scale
-    if flip is not None:
-        window = jnp.where(flip, window[:, :, ::-1], window)
-    return window
+def _select_crops(d, h_offs, w_offs, flips, crop: int):
+    """Crop (and mirror) every image of ``d`` (N, C, H, W) at its own
+    offsets as two one-hot selections on the MXU: ``sel_w[n, w, j]`` is 1
+    where source column ``w`` lands in output column ``j`` (the mirror is
+    inside it: ``j`` reads ``crop - 1 - j``), ``sel_h[n, i, h]`` where
+    source row ``h`` lands in output row ``i``.  Every output element is
+    one product ``1 * d`` plus zeros, accumulated in float32, so rounding
+    back to ``d.dtype`` returns ``d``'s own bits; float32 needs
+    ``precision="highest"`` for that on the TPU (bf16 passes otherwise)."""
+    _, _, h, w = d.shape
+    j = jnp.arange(crop)
+    src_w = w_offs[:, None] + jnp.where(flips[:, None], crop - 1 - j, j)
+    src_h = h_offs[:, None] + j
+    sel_w = (jnp.arange(w)[None, :, None] == src_w[:, None, :]).astype(d.dtype)
+    sel_h = (src_h[:, :, None] == jnp.arange(h)[None, None, :]).astype(d.dtype)
+    how = dict(
+        preferred_element_type=jnp.float32,
+        precision="highest" if d.dtype == jnp.float32 else None,
+    )
+    cols = jnp.einsum("nchw,nwj->nchj", d, sel_w, **how).astype(d.dtype)
+    return jnp.einsum("nih,nchj->ncij", sel_h, cols, **how).astype(d.dtype)
 
 
 def train_transform(
@@ -123,15 +127,23 @@ def train_transform(
     mirror: bool = True,
     scale: float = 1.0,
     data_key: str = "data",
-) -> Callable[[Batch, jax.Array], Batch]:
+) -> Callable[..., Batch]:
     """Random crop + mirror + mean-sub closure for TRAIN phase
     (``imageNetTrainPreprocessing``, ImageNetApp.scala:166-180; randomness
-    per image, like DataTransformer's per-datum Rand())."""
+    per image, like DataTransformer's per-datum Rand()).
+
+    Mean and scale are elementwise and crop and mirror pure selections, so
+    they commute: the mean is subtracted (in float32) and the result
+    rounded to ``dtype`` once on the whole stored frame, then
+    ``_select_crops`` picks each image's window.  Called as
+    ``(batch, rng)`` the closure returns float32 crops; ``Solver`` passes
+    its net's compute dtype as ``dtype``, so that the batch is rounded
+    here, once, to the very bits the first layer's cast would give."""
     mean_arr = _host_mean(mean)
 
-    def fn(batch: Batch, rng: jax.Array) -> Batch:
+    def fn(batch: Batch, rng: jax.Array, dtype=jnp.float32) -> Batch:
         imgs = batch[data_key]
-        n, c, h, w = imgs.shape
+        n, _, h, w = imgs.shape
         k_h, k_w, k_f = jax.random.split(rng, 3)
         h_offs = jax.random.randint(k_h, (n,), 0, h - crop + 1)
         w_offs = jax.random.randint(k_w, (n,), 0, w - crop + 1)
@@ -140,13 +152,15 @@ def train_transform(
             if mirror
             else jnp.zeros((n,), bool)
         )
-        out = jax.vmap(
-            lambda im, ho, wo, fl: _crop_one(
-                im, mean_arr, ho, wo, crop, fl, scale
-            )
-        )(imgs, h_offs, w_offs, flips)
+        d = imgs.astype(jnp.float32)
+        if mean_arr is not None:
+            d = d - mean_arr  # (C, H, W) or (C, 1, 1): broadcasts over N
+        if scale != 1.0:
+            d = d * scale
         new = dict(batch)
-        new[data_key] = out
+        new[data_key] = _select_crops(
+            d.astype(dtype), h_offs, w_offs, flips, crop
+        )
         return new
 
     return fn

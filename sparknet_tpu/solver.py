@@ -26,6 +26,7 @@ Semantics matched to the reference (``sgd_solver.cpp``):
 from __future__ import annotations
 
 import collections
+import inspect
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -262,25 +263,32 @@ class Solver:
     # ------------------------------------------------------------------
     # One iteration: iter_size microbatches -> grads -> update
     # ------------------------------------------------------------------
+    def _transform_train(self, batch, rng):
+        """The train transform on one minibatch, under the scope
+        ``transform``.  A closure that declares a ``dtype`` parameter
+        (``data/transforms.train_transform``) is handed the net's compute
+        dtype, so the batch is rounded once, there; any other
+        ``(batch, rng) -> batch`` callable is called as it is."""
+        fn = self.train_transform
+        if fn is None:
+            return batch
+        rng = jax.random.fold_in(rng, 0x7F)
+        with jax.named_scope("transform"):
+            if "dtype" in inspect.signature(fn).parameters:
+                return fn(batch, rng, self.compute_dtype or jnp.float32)
+            return fn(batch, rng)
+
     def _grads(self, params, stats, batch, rng):
         grad_fn = jax.value_and_grad(self.net.loss_fn, has_aux=True)
         if self.param.iter_size == 1:
-            if self.train_transform is not None:
-                with jax.named_scope("transform"):
-                    batch = self.train_transform(
-                        batch, jax.random.fold_in(rng, 0x7F)
-                    )
+            batch = self._transform_train(batch, rng)
             (loss, (_, new_stats)), g = grad_fn(params, stats, batch, rng, True)
             return g, loss, new_stats
 
         def micro(carry, mb):
             acc, st, i = carry
             lrng = jax.random.fold_in(rng, i)
-            if self.train_transform is not None:
-                with jax.named_scope("transform"):
-                    mb = self.train_transform(
-                        mb, jax.random.fold_in(lrng, 0x7F)
-                    )
+            mb = self._transform_train(mb, lrng)
             (loss, (_, st2)), g = grad_fn(params, st, mb, lrng, True)
             return (_tree_map(jnp.add, acc, g), st2, i + 1), loss
 

@@ -13,6 +13,7 @@ import tarfile
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from sparknet_tpu.data import (
     ImageNetLoader,
@@ -145,6 +146,161 @@ def test_train_transform_mirror_and_randomness():
     b = np.asarray(fn({"data": imgs}, jax.random.PRNGKey(1))["data"])
     assert a.shape == (2, 3, 4, 4)
     assert not np.allclose(a, b)  # offsets/flips differ across rngs
+
+
+def _crop_reference(imgs, mean, crop, mirror, scale, key):
+    """The per-image semantics, plainly: window of the frame, the mean at
+    the same window, subtract, scale, mirror — in float32, offsets and
+    flips from the transform's own ``jax.random`` calls."""
+    n, _, h, w = imgs.shape
+    k_h, k_w, k_f = jax.random.split(key, 3)
+    h_offs = np.asarray(jax.random.randint(k_h, (n,), 0, h - crop + 1))
+    w_offs = np.asarray(jax.random.randint(k_w, (n,), 0, w - crop + 1))
+    flips = np.asarray(jax.random.bernoulli(k_f, 0.5, (n,)))
+    out = np.empty((n, imgs.shape[1], crop, crop), np.float32)
+    for i, (ho, wo) in enumerate(zip(h_offs, w_offs)):
+        window = imgs[i, :, ho:ho + crop, wo:wo + crop].astype(np.float32)
+        if mean is not None:
+            full = mean.shape[-2:] != (1, 1)
+            window = window - (
+                mean[:, ho:ho + crop, wo:wo + crop] if full else mean
+            )
+        if scale != 1.0:
+            window = window * np.float32(scale)
+        out[i] = window[:, :, ::-1] if mirror and flips[i] else window
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "shape,crop,mean_kind,mirror,scale",
+    [
+        ((5, 3, 12, 12), 8, "image", True, 1.0),
+        ((5, 3, 12, 12), 8, "channel", True, 1.0),
+        ((5, 3, 12, 12), 8, None, True, 1.0),
+        ((5, 3, 12, 12), 8, "image", False, 1.0),
+        ((5, 3, 12, 12), 8, "image", True, 0.5),
+        ((5, 3, 12, 12), 8, "channel", False, 0.5),
+        ((4, 3, 10, 14), 7, "image", True, 1.0),  # non-square stored frames
+        ((1, 3, 6, 6), 4, "image", True, 1.0),  # batch 1
+        ((3, 3, 9, 9), 9, "image", True, 1.0),  # the crop is the frame
+    ],
+)
+def test_train_transform_equals_per_image_reference_exactly(
+    shape, crop, mean_kind, mirror, scale, dtype
+):
+    """Mean, scale and rounding once on the stored frame, crop and mirror
+    as one-hot selections: every element equals the per-image reference's
+    bits, in float32 and (reference rounded once) in bfloat16."""
+    rng = np.random.RandomState(1)
+    imgs = rng.randint(0, 256, shape).astype(np.uint8)
+    mean = {
+        "image": rng.rand(*shape[1:]).astype(np.float32) * 255,
+        "channel": np.float32([104.3, 116.7, 122.9])[:, None, None],
+        None: None,
+    }[mean_kind]
+    fn = transforms.train_transform(mean, crop, mirror=mirror, scale=scale)
+    for seed in (0, 7):
+        key = jax.random.PRNGKey(seed)
+        want = _crop_reference(imgs, mean, crop, mirror, scale, key)
+        got = jax.jit(lambda b, k: fn(b, k, dtype))({"data": imgs}, key)
+        assert got["data"].dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(
+            np.asarray(got["data"].astype(jnp.float32)),
+            np.asarray(jnp.asarray(want).astype(dtype).astype(jnp.float32)),
+        )
+
+
+_TINY_NET = """
+name: "tiny"
+layer { name: "data" type: "HostData" top: "data" top: "label"
+  java_data_param { shape { dim: 4 dim: 3 dim: 8 dim: 8 } shape { dim: 4 } } }
+layer { name: "conv1" type: "Convolution" bottom: "data" top: "c1"
+  convolution_param { num_output: 4 kernel_size: 3
+    weight_filler { type: "xavier" } } }
+layer { name: "fc" type: "InnerProduct" bottom: "c1" top: "logits"
+  inner_product_param { num_output: 5 weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits" bottom: "label" top: "loss" }
+"""
+
+
+def _tiny_solver(extra="", **kw):
+    """A solver over 12x12 stored frames cropped to 8, whose net's
+    ``loss_fn`` records the ``data`` it is handed."""
+    from sparknet_tpu import config
+    from sparknet_tpu.solver import Solver
+
+    mean = np.random.RandomState(2).rand(3, 12, 12).astype(np.float32) * 255
+    solver = Solver(
+        config.parse_solver_prototxt(f'base_lr: 0.01 lr_policy: "fixed" {extra}'),
+        net_param=config.parse_net_prototxt(_TINY_NET),
+        train_transform=transforms.train_transform(mean, 8),
+        **kw,
+    )
+    seen, loss_fn = [], solver.net.loss_fn
+
+    def recording(params, stats, batch, *rest):
+        seen.append(batch["data"])
+        return loss_fn(params, stats, batch, *rest)
+
+    solver.net.loss_fn = recording
+    return solver, seen
+
+
+def _tiny_batch(lead=()):
+    rng = np.random.RandomState(3)
+    return {
+        "data": rng.randint(0, 256, lead + (4, 3, 12, 12)).astype(np.uint8),
+        "label": rng.randint(0, 5, lead + (4,)).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_solver_hands_the_transform_its_compute_dtype(compute_dtype):
+    """Inside the step the crops arrive in the net's compute dtype (conv1's
+    cast has nothing left to do); the factory's closure called with two
+    arguments, as the benchmark's check calls it, still gives float32."""
+    solver, seen = _tiny_solver(compute_dtype=compute_dtype)
+    state, batch, key = solver.init_state(0), _tiny_batch(), jax.random.PRNGKey(4)
+    solver._grads(state.params, state.stats, batch, key)
+    assert seen[0].dtype == jnp.dtype(compute_dtype or "float32")
+    assert seen[0].shape == (4, 3, 8, 8)
+    plain = solver.train_transform(batch, jax.random.fold_in(key, 0x7F))
+    assert plain["data"].dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(seen[0].astype(jnp.float32)),
+        np.asarray(plain["data"].astype(seen[0].dtype).astype(jnp.float32)),
+    )
+    # any other (batch, rng) -> batch callable is called as it is
+    solver.train_transform = lambda b, rng: {
+        **b, "data": b["data"][..., :8, :8].astype(np.float32)
+    }
+    solver._grads(state.params, state.stats, batch, key)
+    assert seen[1].dtype == jnp.float32
+
+
+def test_solver_iter_size_branch_crops_like_the_plain_branch():
+    """Microbatch ``i`` of the ``iter_size > 1`` scan gets the crops the
+    plain branch gives for ``fold_in(rng, i)``, in the compute dtype."""
+    plain, seen_plain = _tiny_solver(compute_dtype="bfloat16")
+    micro, seen_micro = _tiny_solver("iter_size: 2", compute_dtype="bfloat16")
+    state, batch, key = plain.init_state(0), _tiny_batch((2,)), jax.random.PRNGKey(5)
+    with jax.disable_jit():  # the scan runs as a loop: crops are concrete
+        micro._grads(state.params, state.stats, batch, key)
+    assert len(seen_micro) == 2
+    for i, got in enumerate(seen_micro):
+        plain._grads(
+            state.params, state.stats,
+            {k: v[i] for k, v in batch.items()}, jax.random.fold_in(key, i),
+        )
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(seen_plain[i].astype(jnp.float32)),
+        )
+    assert not np.array_equal(
+        np.asarray(seen_micro[0], np.float32), np.asarray(seen_micro[1], np.float32)
+    )
 
 
 def test_test_transform_center_crop_golden():
