@@ -102,6 +102,9 @@ CANONICAL_METRICS = {
     # parallelism over parallel/ring_attention.py)
     "sparknet_lm_tokens_total": (),
     "sparknet_lm_ring_hop_bytes_total": (),
+    # sparse-expert routing of models/hybrid_lm.py (--model_config)
+    "sparknet_lm_held_assignments_per_token": ("layer",),
+    "sparknet_lm_held_load_skew": ("layer",),
     # autoregressive generation serving (serve/generate.py KV arena +
     # serve/batcher.py StreamBatcher + serve/fleet.py stream routing)
     "sparknet_kv_blocks_total": (),

@@ -41,6 +41,14 @@ TAU = 4
 
 
 def add_lm_model_args(parser) -> None:
+    parser.add_argument(
+        "--model_config", default=None,
+        help="a configuration file (benchmark/configs/*.json: the keys of a "
+        "published config.json plus experts_held and compute_dtype): trains "
+        "models/hybrid_lm.HybridMoELM with ADAM at the file's solver "
+        "settings instead of the byte-level TransformerLM; --dim / --depth / "
+        "--heads and the SGD flags are then unused, --sp must be 1",
+    )
     parser.add_argument("--seq_len", type=int, default=128)
     parser.add_argument("--dim", type=int, default=64)
     parser.add_argument("--depth", type=int, default=2)
@@ -57,13 +65,72 @@ def add_lm_model_args(parser) -> None:
     )
 
 
+def build_hybrid_lm_solver(config: dict):
+    """(HybridMoELM, Solver) from a configuration dict: ADAM at the
+    configuration's ``solver`` settings, the configuration's
+    ``compute_dtype`` handed to the model through the ``Solver``.  Shared
+    with the benchmark's ``lm-train-resident`` kind."""
+    from sparknet_tpu.config import parse_solver_prototxt
+    from sparknet_tpu.models.hybrid_lm import HybridMoELM
+    from sparknet_tpu.solver import Solver
+
+    lm = HybridMoELM(config)
+    s = config.get("solver", {})
+    solver_param = parse_solver_prototxt(
+        'type: "ADAM" lr_policy: "fixed" '
+        f"base_lr: {s.get('base_lr', 3e-4)} "
+        f"momentum: {s.get('momentum', 0.9)} "
+        f"momentum2: {s.get('momentum2', 0.95)} "
+        f"delta: {s.get('delta', 1e-8)} "
+        f"weight_decay: {s.get('weight_decay', 0.0)} "
+        f"iter_size: {s.get('iter_size', 1)} "
+        "average_loss: 20"
+    )
+    solver = Solver(
+        solver_param, net=lm, compute_dtype=config.get("compute_dtype"))
+    return lm, solver
+
+
+def set_routing_gauges(lm, stacked_params, tokens):
+    """Where one batch of ``(B, T)`` tokens goes, per layer: assignments to
+    the held experts a token and the largest held expert's load over the
+    mean (``models/hybrid_lm.routing_gauges``), set as the gauges
+    ``sparknet_lm_held_assignments_per_token`` / ``sparknet_lm_held_load_skew``
+    where training metrics are on, and returned.  Outside the timed loop: it
+    runs a forward pass.  ``stacked_params`` are the trainer's (worker-major);
+    worker 0 is sliced inside the jit, so no copy of the weights is made."""
+    import jax
+
+    from sparknet_tpu import obs
+    from sparknet_tpu.models.hybrid_lm import routing_gauges
+    from sparknet_tpu.parallel import first_worker
+
+    counts = jax.jit(
+        lambda p: lm.routing_counts(first_worker(p), tokens))(stacked_params)
+    gauges = routing_gauges(counts, tokens=int(np.prod(tokens.shape)))
+    tm = obs.training_metrics()
+    if tm is not None:
+        for i, (per_token, skew) in enumerate(zip(
+                gauges["held_assignments_per_token"],
+                gauges["held_load_skew"])):
+            tm.lm_held_assignments.labels(str(i)).set(per_token)
+            tm.lm_held_load_skew.labels(str(i)).set(skew)
+    return gauges
+
+
 def build_lm_solver(args, sp: int):
-    """(TransformerLM, Solver) from parsed args — shared with ``cli
-    train --lm`` and the bench."""
+    """(model, Solver) from parsed args — shared with ``cli train --lm``
+    and the bench.  ``--model_config`` selects the hybrid model."""
     from sparknet_tpu import models
     from sparknet_tpu.config import parse_solver_prototxt
     from sparknet_tpu.solver import Solver
 
+    if getattr(args, "model_config", None):
+        from sparknet_tpu.models.hybrid_lm import load_config
+
+        if sp != 1:
+            raise SystemExit("lm: --model_config trains at --sp 1 only")
+        return build_hybrid_lm_solver(load_config(args.model_config))
     lm = models.build_transformer_lm(
         dim=args.dim,
         depth=args.depth,
@@ -284,10 +351,17 @@ def main(argv=None) -> int:
             f"{sum(len(d) for d in docs)} bytes")
 
     lm, solver = build_lm_solver(args, sp)
-    log.log(
-        f"model: dim={args.dim} depth={args.depth} heads={args.heads} "
-        f"seq_len={args.seq_len} ({lm.num_params()} params)"
-    )
+    if args.model_config:
+        log.log(
+            f"model: {args.model_config} experts_held="
+            f"{list(lm.experts_held)} compute_dtype={lm.compute_dtype} "
+            f"seq_len={args.seq_len} ({lm.num_params()} params)"
+        )
+    else:
+        log.log(
+            f"model: dim={args.dim} depth={args.depth} heads={args.heads} "
+            f"seq_len={args.seq_len} ({lm.num_params()} params)"
+        )
     prefix = args.snapshot_prefix
     sentry = health_mod.sentry_from_args(args, solver, echo=log.log)
     spec = hierarchy.spec_from_args(args, n_workers)
@@ -393,6 +467,12 @@ def main(argv=None) -> int:
             jr.close()
         log.close()
         return 0
+
+    if args.model_config:
+        # where the first round's tokens go, by layer: gauges, set once
+        first = samplers[0].window_for_round(start_round, 1)["tokens"][0]
+        gauges = set_routing_gauges(lm, state.params, first)
+        log.log(f"routing of the first minibatch, by layer: {gauges}")
 
     tokens_per_round = n_workers * args.tau * args.batch * args.seq_len
     ring_bytes_per_round = (

@@ -401,6 +401,19 @@ class TrainingMetrics:
             "parallelism: K+V shards x (sp-1) hops x layers, "
             "forward + transposed backward; zero when sp=1)",
         )
+        self.lm_held_assignments = registry.gauge(
+            "sparknet_lm_held_assignments_per_token",
+            "assignments a token sends to the routed experts HELD on this "
+            "chip (top_k x held / experts expected), by layer; set outside "
+            "the round loop from models/hybrid_lm routing_counts",
+            labels=("layer",),
+        )
+        self.lm_held_load_skew = registry.gauge(
+            "sparknet_lm_held_load_skew",
+            "largest held expert's assignments over the held experts' "
+            "mean (1.0 = even routing), by layer",
+            labels=("layer",),
+        )
         # bounded-staleness averaging series (parallel/stale.py,
         # --stale_bound) — zero on the synchronous round
         self.staleness = registry.gauge(

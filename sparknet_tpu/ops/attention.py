@@ -140,3 +140,66 @@ class Attention(Layer):
         if p.bias_term:
             y = y + blobs[3]
         return [y], None
+
+
+def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
+                         compute_dtype=None):
+    """Causal softmax attention with grouped K/V heads, in query blocks.
+
+    ``q``: ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``, each K/V head
+    serving ``Hq // Hkv`` query heads (never repeated in memory).  The query
+    blocks run one after another (``lax.map`` over ``jax.checkpoint``ed
+    blocks), so no more than ``block_q x T`` scores a head exist at once,
+    forward or backward.  A ``lax.map`` needs one shape for all its blocks:
+    the sequence is cut into ``segments`` runs of blocks, and a run meets
+    only the keys up to its own end, a static slice, so with four runs 5/8
+    of the full score matrix is computed where causality needs 1/2.  Scores
+    and softmax are float32; the two products take their operands in
+    ``compute_dtype``.  Returns ``(B, T, Hq, D)`` float32."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    group = hq // hkv
+    cd = compute_dtype or jnp.float32
+    f32 = jnp.float32
+    pad = (-t) % block_q  # padded keys lie after every real query: masked
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+    blocks = (t + pad) // block_q
+    # heads before positions, the layout batched products are made for: a
+    # block of queries is (B, Hkv, group * block_q, D) against (B, Hkv, S, D)
+    q = (q.astype(f32) * d ** -0.5).astype(cd)
+    q = q.reshape(b, blocks, block_q, hkv, group, d).transpose(1, 0, 3, 4, 2, 5)
+    q = q.reshape(blocks, b, hkv, group * block_q, d)
+    k = k.astype(cd).transpose(0, 2, 1, 3)
+    v = v.astype(cd).transpose(0, 2, 1, 3)
+    row = jnp.tile(jnp.arange(block_q), group)[:, None]
+
+    @jax.checkpoint
+    def block(qi, first, ki, vi):
+        s = jnp.einsum("bkrd,bksd->bkrs", qi, ki, preferred_element_type=f32)
+        # a finite mask and the softmax written out, normalised after the
+        # second product: on the v5e `where(.., -inf)` + `jax.nn.softmax`
+        # in float32 runs 16 x slower than this (49 ms against 3 ms a block
+        # of 4096 x 8192 scores, PERF.md section 6, PR 27).  Every row keeps
+        # its own position, so its maximum is a real score
+        s = jnp.where(first + row >= jnp.arange(ki.shape[2])[None, :], s,
+                      -1e30)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.einsum("bkrs,bksd->bkrd", e.astype(cd), vi,
+                       preferred_element_type=f32)
+        return o / jnp.sum(e, axis=-1, keepdims=True)
+
+    per_run = -(-blocks // segments)
+    out = []
+    for lo in range(0, blocks, per_run):
+        hi = min(lo + per_run, blocks)
+        ki, vi = k[:, :, :hi * block_q], v[:, :, :hi * block_q]
+        out.append(jax.lax.map(
+            lambda x: block(x[0], x[1], ki, vi),
+            (q[lo:hi], jnp.arange(lo, hi) * block_q)))
+    # (blocks, B, Hkv, group * block_q, D) -> (B, T, Hq, D)
+    out = jnp.concatenate(out, axis=0)
+    out = out.reshape(blocks, b, hkv, group, block_q, d)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t + pad, hq, d)
+    return out[:, :t]

@@ -1,0 +1,135 @@
+"""Sparse mixture-of-experts pieces: a top-k router over ALL experts and
+the part of the result that the experts HELD HERE give.
+
+An expert-parallel deployment divides a layer's experts over chips; each
+chip routes every token over the whole published router width, and adds
+only the terms of its own contiguous range ``lo .. lo + n - 1``.  What the
+absent experts would add is left out (on the chips that hold them it is
+their part); on one chip there is no exchange, and nothing here stands in
+for one.
+
+No token is dropped and no expert has a capacity.  The held assignments
+are sorted by expert and go through one grouped matrix product per
+projection (``jax.lax.ragged_dot``), then a weighted scatter-add.  XLA
+needs a static row count: the grouped path takes ``fast_rows`` rows, sized
+at ``slack`` times the expected number of held assignments, and when a
+batch routes more than that to the held experts the same grouped products
+run over chunks of tokens small enough that whatever a chunk routes fits
+(``lax.cond``; exact, slower, rare).  Rows of the grouped path beyond the held assignments carry weight
+0 and ride in the last expert's group, so every row is a real product.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+# rows of the grouped path over the expected held assignments.  Seeded
+# weights on Zipf tokens send the held experts 0.94 to 1.07 times the
+# expectation, and 28 steps of training move a layer's share as far as 1.18
+# times it (or to none of it) (the v5e, PERF.md section 6, PR 27).  The rest
+# is room for a router that goes on drifting, since a step past the rows
+# costs ``tokens * top_k / rows`` times the experts' work
+ROWS_SLACK = 2.0
+
+
+def route(x, w_router, top_k: int):
+    """``x``: ``(N, E)``; ``w_router``: ``(E, experts)``.  Float32 at full
+    precision throughout: a top-k over rounded probabilities picks other
+    experts.  Returns the renormalised weights ``(N, top_k)`` and the
+    expert ids ``(N, top_k)``."""
+    logits = jnp.dot(x.astype(F32), w_router.astype(F32), precision=HIGHEST)
+    weights, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return weights / jnp.sum(weights, axis=-1, keepdims=True), ids
+
+
+def plan(ids, lo: int, n: int):
+    """How the held assignments are laid out for the grouped products: the
+    order that puts them first, by expert (stable, so by token inside one),
+    and how many each held expert receives.  ``ids``: ``(N, top_k)``.
+    Returns ``(order (N * top_k,), counts (n,))``, both int32."""
+    local = ids.reshape(-1) - lo
+    key = jnp.where((local >= 0) & (local < n), local, n)
+    counts = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+    return jnp.argsort(key, stable=True).astype(jnp.int32), counts
+
+
+def fast_rows_for(tokens: int, top_k: int, experts: int, n: int,
+                  slack: float = ROWS_SLACK, multiple: int = 256) -> int:
+    """Rows of the grouped path: ``slack`` x the expected held assignments
+    (``tokens * top_k * n / experts``), rounded up to ``multiple`` and never
+    more than every assignment there is."""
+    expected = tokens * top_k * n / experts
+    rows = -(-int(slack * expected) // multiple) * multiple
+    return max(min(top_k, n), min(rows, tokens * min(top_k, n)))
+
+
+def gated_mlp(x, gate, up, down, compute_dtype=None):
+    """``down(silu(gate(x)) * up(x))``, no bias."""
+    cd = compute_dtype or F32
+    dot = lambda a, w: jnp.dot(  # noqa: E731
+        a.astype(cd), w.astype(cd), preferred_element_type=F32)
+    return dot(jax.nn.silu(dot(x, gate)) * dot(x, up), down)
+
+
+def _expert_mlp_grouped(xs, gate, up, down, sizes, cd):
+    dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
+        a.astype(cd), w.astype(cd), sizes, preferred_element_type=F32)
+    return dot(jax.nn.silu(dot(xs, gate)) * dot(xs, up), down)
+
+
+def _grouped(x, weights, ids, order, counts, gate, up, down, rows, cd):
+    """The held assignments' sum by grouped products over ``rows`` rows;
+    needs ``sum(counts) <= rows``.  Rows beyond the held assignments carry
+    weight 0 and ride in the last expert's group."""
+    tokens, top_k = ids.shape
+    n = gate.shape[0]
+    total = jnp.sum(counts)
+    first = order[:rows]
+    token = first // top_k
+    w = jnp.where(jnp.arange(rows) < total, weights.reshape(-1)[first], 0.0)
+    sizes = counts.at[n - 1].add(rows - total)
+    y = _expert_mlp_grouped(x[token], gate, up, down, sizes, cd)
+    return jnp.zeros((tokens, x.shape[1]), F32).at[token].add(y * w[:, None])
+
+
+def held_experts(x, weights, ids, order, counts, gate, up, down, *, lo: int,
+                 fast_rows: int, compute_dtype=None):
+    """``sum_k weights[:, k] * expert_{ids[:, k]}(x)`` over the assignments
+    whose expert is held here.  ``x``: ``(N, E)``; ``order``, ``counts``:
+    ``plan(ids, lo, n)``; ``gate``, ``up``: ``(n, E, F)``; ``down``:
+    ``(n, F, E)``; expert ``e`` of the model is ``gate[e - lo]``.  Returns
+    ``(N, E)`` float32.
+
+    When the batch routes more than ``fast_rows`` assignments to the held
+    experts, the same grouped products run over chunks of tokens small
+    enough that every assignment a chunk could make fits in ``fast_rows``
+    rows: exact, ``tokens * min(top_k, n) / fast_rows`` times the work."""
+    cd = compute_dtype or F32
+    tokens, top_k = ids.shape
+    n = gate.shape[0]
+    most = min(top_k, n)  # held assignments one token can make
+    rows = max(fast_rows, most)
+    if rows >= tokens * most:
+        return _grouped(x, weights, ids, order, counts, gate, up, down,
+                        tokens * most, cd)
+    chunk = max(c for c in range(1, rows // most + 1) if tokens % c == 0)
+
+    def fast(_):
+        return _grouped(x, weights, ids, order, counts, gate, up, down,
+                        rows, cd)
+
+    @jax.checkpoint
+    def one_chunk(args):
+        xc, wc, ic = args
+        return _grouped(xc, wc, ic, *plan(ic, lo, n), gate, up, down,
+                        chunk * most, cd)
+
+    def chunked(_):
+        split = lambda a: a.reshape(tokens // chunk, chunk, a.shape[1])  # noqa: E731
+        out = jax.lax.map(one_chunk, (split(x), split(weights), split(ids)))
+        return out.reshape(tokens, x.shape[1])
+
+    return jax.lax.cond(jnp.sum(counts) <= rows, fast, chunked, None)

@@ -581,6 +581,17 @@ class ParameterAveragingTrainer:
         st = self.solver.init_state(seed)
         n = self.num_workers
         sharding = leading_sharding(self.mesh, self.axis)
+        if n == 1:
+            # one worker: the stack is the replica itself under a leading
+            # axis of 1.  Donated, so the buffers are the same ones: the
+            # host path below holds the replica AND its stacked copy on the
+            # one device until it returns, twice a state of gigabytes
+            # (PERF.md section 6, PR 27).  Several workers take the host
+            # path as before
+            return jax.jit(
+                lambda tree: tree_map(lambda x: x[None], tree),
+                out_shardings=sharding, donate_argnums=(0,),
+            )(st)
 
         # identical init in every process; each device's shard is cut
         # from a broadcast VIEW of the one host replica, so the n-fold

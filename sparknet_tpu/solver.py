@@ -200,6 +200,18 @@ class Solver:
             # generic and composes unchanged.
             if net_param is not None:
                 raise ValueError("pass net= or net_param=, not both")
+            if compute_dtype is not None:
+                # the precision is stated once, here, as for JaxNet: a net
+                # object that computes in a lower dtype over float32 master
+                # weights takes it through ``set_compute_dtype``
+                # (models/hybrid_lm.py); one with float32 written into its
+                # forward (TransformerLM) cannot honour it
+                if not hasattr(net, "set_compute_dtype"):
+                    raise ValueError(
+                        f"compute_dtype={compute_dtype!r}: "
+                        f"{type(net).__name__} has no set_compute_dtype"
+                    )
+                net.set_compute_dtype(compute_dtype)
             self.net_param = getattr(net, "net_param", None)
             self.net = net
         else:

@@ -1,0 +1,436 @@
+"""The hybrid linear-attention / sparse-expert LM (``models/hybrid_lm.py``,
+``ops/delta_rule.py``, ``ops/moe.py``, ``ops/attention.causal_gqa_attention``)
+against the plain reference ``benchmark/reference/qwen3_next.py``, at a small
+size on the CPU: widths of a few tens, 8 layers (two periods of three
+DeltaNet layers and one attention layer), 16 experts top-4, T of two and a
+half of the delta rule's chunks of 64 so that a ragged last chunk is covered
+(the model takes no chunk or block size: ragged query blocks, runs of blocks
+and small chunks are tested on the ops, which take them as arguments).
+
+Float32 comparisons run under ``default_matmul_precision("highest")``; what
+is left is summation order (chunked against token-by-token, grouped against
+expert-by-expert), so the bounds are a few float32 roundings of sums of tens
+to hundreds of terms: 2e-5 relative, 5e-4 on gradients (they pass through
+eight layers, and the decay's, ``A_log`` / ``dt_bias`` / ``g``, through the
+exponentials of running sums: 1.1e-4 was measured on ``dt_bias``).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import qwen3_next as ref
+from sparknet_tpu.models.hybrid_lm import HybridMoELM, routing_gauges
+from sparknet_tpu.ops import moe
+from sparknet_tpu.ops.attention import causal_gqa_attention
+from sparknet_tpu.ops.delta_rule import gated_delta_rule
+
+SMALL = {
+    "vocab_size": 64, "hidden_size": 32, "num_hidden_layers": 8,
+    "full_attention_interval": 4, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "partial_rotary_factor": 0.25,
+    "rope_theta": 10000000, "rms_norm_eps": 1e-6,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4,
+    "linear_key_head_dim": 8, "linear_value_head_dim": 8,
+    "linear_conv_kernel_dim": 4, "num_experts": 16, "num_experts_per_tok": 4,
+    "moe_intermediate_size": 16, "shared_expert_intermediate_size": 16,
+    "norm_topk_prob": True,
+    # this system's own keys
+    "experts_held": [4, 8],
+}
+T = 160  # two and a half chunks of 64
+T_OP = 40  # on the ops: two and a half chunks of 16
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30))
+
+
+def seeded(model, seed=0, spread=True):
+    """Seeded weights; the zero-initialised norm weights and the one-
+    initialised vectors are moved off their defaults so that a test cannot
+    pass by ignoring them."""
+    params, _ = model.init(seed)
+    if spread:
+        key = jax.random.key(seed + 100)
+        for gi, (group, blobs) in enumerate(sorted(params.items())):
+            for bi, blob in enumerate(blobs):
+                if blob.ndim == 1:
+                    k = jax.random.fold_in(jax.random.fold_in(key, gi), bi)
+                    blobs[bi] = blob + 0.1 * jax.random.normal(k, blob.shape)
+                else:
+                    blobs[bi] = blob * 5.0  # std 0.1: every term matters
+    return params
+
+
+def batch(seed, b=2, t=T, vocab=SMALL["vocab_size"]):
+    tokens = jax.random.randint(jax.random.key(seed), (b, t + 1), 0, vocab)
+    return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
+
+
+@pytest.fixture(scope="module")
+def model():
+    return HybridMoELM(SMALL)
+
+
+@pytest.fixture(scope="module")
+def params(model):
+    return seeded(model)
+
+
+def test_layer_pattern_and_parameter_count(model):
+    assert [model.is_attention_layer(i) for i in range(8)] == [
+        False, False, False, True] * 2
+    published = HybridMoELM({
+        **SMALL, "vocab_size": 18992, "hidden_size": 2048,
+        "num_hidden_layers": 4, "num_attention_heads": 16, "head_dim": 256,
+        "linear_num_key_heads": 16, "linear_num_value_heads": 32,
+        "linear_key_head_dim": 128, "linear_value_head_dim": 128,
+        "num_experts": 512, "num_experts_per_tok": 10,
+        "moe_intermediate_size": 512, "shared_expert_intermediate_size": 512,
+        "experts_held": [0, 32],
+    })
+    sizes = dict(published._group_blobs)
+    count = lambda g: sum(int(np.prod(s)) for s in sizes[g])  # noqa: E731
+    assert count("l0_mixer") == 33_718_464  # Gated DeltaNet
+    assert count("l3_mixer") == 27_263_488  # gated attention
+    assert count("l0_router") == 1_048_576
+    assert count("l0_shared") == 3_147_776
+    assert count("l0_experts") == 100_663_296
+    assert published.num_params() == 625_667_136  # ISSUE 27: 625.7M
+
+
+def test_logits_loss_and_every_gradient_match_the_reference(model, params):
+    data = batch(1)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(model.forward_logits)(params, data["tokens"])
+        want = jax.jit(lambda p, t: ref.logits(p, t, SMALL))(
+            params, data["tokens"])
+        assert rel(got, want) < 2e-5
+        (loss, _), grads = jax.jit(jax.value_and_grad(
+            model.loss_fn, has_aux=True))(params, {}, data)
+        want_loss, want_grads = jax.jit(jax.value_and_grad(
+            lambda p, b: ref.loss(p, b["tokens"], b["targets"], SMALL)))(
+                params, data)
+    assert abs(float(loss) - float(want_loss)) < 2e-5 * float(want_loss)
+    errors = {
+        f"{group}[{i}]": rel(g, w)
+        for group in grads
+        for i, (g, w) in enumerate(zip(grads[group], want_grads[group]))
+    }
+    assert set(errors) == {
+        f"{g}[{i}]" for g, blobs in params.items() for i in range(len(blobs))}
+    # the decay's two vectors (A_log, dt_bias) of a DeltaNet layer have
+    # gradients that all but vanish (most heads forget within a token:
+    # exp(-A) with A up to 16), what is left of them is float32 noise of
+    # terms that cancel: 8.3e-4 at this T, against 1e-4 and less elsewhere
+    decay = {f"l{i}_mixer[{j}]" for i in range(8) for j in (3, 4)
+             if not model.is_attention_layer(i)}
+    print({k: float(f"{v:.2g}") for k, v in errors.items() if k in decay})
+    worst = max(set(errors) - decay, key=errors.get)
+    assert errors[worst] < 5e-4, (worst, errors[worst])
+    assert max(errors[k] for k in decay) < 5e-3
+    # nothing is trivially zero: every blob has a gradient
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for gs in want_grads.values()
+               for g in gs)
+
+
+@pytest.mark.parametrize("decay", ["near_one", "near_zero", "mixed"])
+def test_chunked_delta_rule_matches_the_recurrence(decay):
+    b, t, h, dk, dv = 2, T_OP, 6, 8, 8
+    keys = jax.random.split(jax.random.key(7), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(keys[0], (b, t, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (b, t, h, dk)))
+    v = jax.random.normal(keys[2], (b, t, h, dv))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[3], (b, t, h)))
+    u = jax.random.uniform(keys[4], (b, t, h))
+    g = {"near_one": -1e-4 * u, "near_zero": -8.0 - 8.0 * u,
+         "mixed": -jnp.exp(6.0 * u - 5.0)}[decay]
+
+    def both(fn):
+        def scalar(q, k, v, g, beta):
+            o = fn(q, k, v, g, beta)
+            return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape))), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(q, k, v, g, beta)
+        return o, grads
+
+    with jax.default_matmul_precision("highest"):
+        # two blocks of three heads: the blocked path of the work in chunks
+        o, grads = both(
+            lambda *a: gated_delta_rule(*a, chunk=16, head_block=3))
+        want, want_grads = both(ref.delta_rule_recurrent)
+    assert o.shape == (b, t, h, dv)
+    assert rel(o, want) < 2e-5
+    scale = max(float(jnp.linalg.norm(w)) for w in want_grads)
+    for name, got_g, want_g in zip("qkvgb", grads, want_grads):
+        # against the largest gradient where this one all but vanishes (g's
+        # at decay ~0 is 1e-5 of v's: float32 noise of terms that cancel)
+        floor = max(float(jnp.linalg.norm(want_g)), 1e-3 * scale)
+        assert float(jnp.linalg.norm(got_g - want_g)) < 5e-4 * floor, name
+
+
+def skewed_router(hidden, experts, hot, cold, key):
+    """A router that sends nearly every token to ``hot`` and none to
+    ``cold`` (inputs are positive in their first coordinate)."""
+    w = 0.01 * jax.random.normal(key, (hidden, experts))
+    return w.at[0, hot].set(50.0).at[0, cold].set(-50.0)
+
+
+@pytest.mark.parametrize("slack", [8.0, 0.05])
+def test_held_experts_match_the_dense_loop_under_skew(slack):
+    """One held expert gets most tokens and one gets none; with ``slack``
+    0.05 the grouped path is too short and the exact path over chunks of
+    tokens runs."""
+    n_tok, e, f, experts, top_k = 96, 16, 8, 16, 4
+    lo, n = 4, 8
+    keys = jax.random.split(jax.random.key(3), 6)
+    x = jax.random.normal(keys[0], (n_tok, e)).at[:, 0].set(1.0)
+    router = skewed_router(e, experts, hot=6, cold=9, key=keys[1])
+    gate, up = (0.3 * jax.random.normal(k, (n, e, f)) for k in keys[2:4])
+    down = 0.3 * jax.random.normal(keys[4], (n, f, e))
+    config = {"num_experts_per_tok": top_k, "norm_topk_prob": True}
+
+    def program(x, router, gate, up, down):
+        weights, ids = moe.route(x, router, top_k)
+        order, counts = moe.plan(ids, lo, n)
+        rows = moe.fast_rows_for(n_tok, top_k, experts, n, slack=slack,
+                                 multiple=8)
+        return moe.held_experts(x, weights, ids, order, counts, gate, up,
+                                down, lo=lo, fast_rows=rows), counts
+
+    def plain(x, router, gate, up, down):
+        weights, ids = ref.route(x, router, config)
+        return ref.routed_experts(x, weights, ids, (gate, up, down), (lo, n))
+
+    with jax.default_matmul_precision("highest"):
+        out, counts = jax.jit(program)(x, router, gate, up, down)
+        want = jax.jit(plain)(x, router, gate, up, down)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(program(*a)[0] ** 2),
+            argnums=(0, 1, 2, 3, 4)))(x, router, gate, up, down)
+        want_grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(plain(*a) ** 2),
+            argnums=(0, 1, 2, 3, 4)))(x, router, gate, up, down)
+    counts = np.asarray(counts)
+    assert counts[6 - lo] == n_tok and counts[9 - lo] == 0
+    fast = moe.fast_rows_for(n_tok, top_k, experts, n, slack=slack, multiple=8)
+    assert (counts.sum() <= fast) == (slack > 1)  # which path ran
+    assert rel(out, want) < 2e-5
+    for got_g, want_g in zip(grads, want_grads):
+        assert rel(got_g, want_g) < 5e-4
+
+
+def test_all_shares_add_up_to_the_uncut_layer(model, params):
+    """The guide's share test: the outputs of the shares [0, n), [n, 2n), ...
+    of one MoE layer, the shared expert counted once, sum to the uncut
+    reference's output of the whole layer."""
+    experts, n = SMALL["num_experts"], 4
+    whole = HybridMoELM({**SMALL, "experts_held": [0, experts]})
+    full = seeded(whole, seed=5)
+    x = jax.random.normal(jax.random.key(11), (2 * T, SMALL["hidden_size"]))
+    router, blobs, shared = (full["l0_router"][0], full["l0_experts"],
+                             full["l0_shared"])
+    with jax.default_matmul_precision("highest"):
+        normed = ref.rms_norm0(x, 0.0, SMALL["rms_norm_eps"])
+        want = ref.moe(normed, router, blobs, shared, SMALL, held=(0, experts))
+        total = whole._shared_expert(normed, shared)
+        for lo in range(0, experts, n):
+            share = HybridMoELM({**SMALL, "experts_held": [lo, n]})
+            part = [b[lo:lo + n] for b in blobs]
+            total = total + share._held_experts(
+                normed, *share._route(x, jnp.zeros(x.shape[-1]), router), part)
+            # the program's share is the reference's share
+            assert rel(
+                share._held_experts(
+                    normed, *share._route(x, jnp.zeros(x.shape[-1]), router),
+                    part),
+                ref.routed_experts(normed, *ref.route(normed, router, SMALL),
+                                   part, (lo, n))) < 2e-5
+    assert rel(total, want) < 2e-5
+
+
+def test_bf16_compute_is_near_float32_and_not_float32(model):
+    """bf16 keeps 8 bits (2^-9 = 2e-3 a rounding); through eight layers the
+    logits differ from float32's by a rounding or two: 2.9e-3 to 3.0e-3 on
+    three seeds.  Below 1e-4 the bf16 path would be computing in float32;
+    above 2e-2 it lost more than rounding.  Weights as ``init`` makes them:
+    at five times that, near-ties of a top-4 of 16 flip under rounding and a
+    flipped expert moves a token's logits wholesale (0.10 to 0.13)."""
+    data = batch(2)
+    params = seeded(model, spread=False)
+    low = HybridMoELM({**SMALL, "compute_dtype": "bfloat16"})
+    exact = jax.jit(model.forward_logits)(params, data["tokens"])
+    got = jax.jit(low.forward_logits)(params, data["tokens"])
+    assert got.dtype == jnp.float32
+    assert 1e-4 < rel(got, exact) < 2e-2
+    loss = lambda m: float(jax.jit(m.loss_fn)(params, {}, data)[0])  # noqa: E731
+    assert 0 < abs(loss(low) - loss(model)) < 2e-2 * loss(model)
+
+
+def test_routing_counts_and_gauges(model, params):
+    data = batch(3)
+    counts = np.asarray(jax.jit(model.routing_counts)(params, data["tokens"]))
+    assert counts.shape == (8, 8)
+    _, ids = ref.route(
+        ref.rms_norm0(params["embed"][0][data["tokens"]], 0.0, 1e-6),
+        params["l0_router"][0], SMALL)  # shape only: ids of some routing
+    assert counts.sum(axis=1).max() <= ids.size
+    gauges = routing_gauges(counts, tokens=data["tokens"].size)
+    assert len(gauges["held_assignments_per_token"]) == 8
+    # 8 of 16 experts held, top-4: two assignments a token expected
+    assert 1.0 < np.mean(gauges["held_assignments_per_token"]) < 3.0
+    assert all(s >= 1.0 for s in gauges["held_load_skew"])
+
+
+def test_generation_is_refused(model, params):
+    with pytest.raises(NotImplementedError, match="not supported"):
+        model.prefill_with_kv(params, jnp.zeros((1, 4), jnp.int32))
+    with pytest.raises(NotImplementedError, match="not supported"):
+        model.decode_step_with_kv(params, None, None, None, None)
+
+
+def test_solver_hands_compute_dtype_to_a_net_that_takes_one():
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.config import parse_solver_prototxt
+    from sparknet_tpu.models import build_transformer_lm
+    from sparknet_tpu.solver import Solver
+
+    lm, solver = lm_app.build_hybrid_lm_solver(
+        {**SMALL, "compute_dtype": "bfloat16"})
+    assert solver.net is lm and lm.compute_dtype == jnp.bfloat16
+    assert solver.method == "ADAM" and solver.param.momentum2 == 0.95
+    lm, _ = lm_app.build_hybrid_lm_solver(SMALL)
+    assert lm.compute_dtype is None
+    plain = parse_solver_prototxt('base_lr: 0.1 lr_policy: "fixed"')
+    # TransformerLM has float32 written into its forward: asked for another
+    # precision it says so, and without one it is built as before
+    with pytest.raises(ValueError, match="set_compute_dtype"):
+        Solver(plain, net=build_transformer_lm(), compute_dtype="bfloat16")
+    assert Solver(plain, net=build_transformer_lm()).compute_dtype is None
+
+
+def test_one_adam_round_on_two_workers_is_two_solver_runs_averaged():
+    """``ParameterAveragingTrainer.round`` through ``Solver(net=...)`` with
+    ADAM on two virtual CPU workers: parameters are the mean of two plain
+    solver runs (each on its worker's batch and rng), history is each run's
+    own (never averaged)."""
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.parallel import ParameterAveragingTrainer, make_mesh
+    from sparknet_tpu.utils.rngs import default_train_key
+
+    config = {**SMALL, "num_hidden_layers": 4, "compute_dtype": "bfloat16"}
+    _, solver = lm_app.build_hybrid_lm_solver(config)
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    trainer = ParameterAveragingTrainer(solver, mesh)
+    tau = 2
+    per_worker = [
+        {k: jnp.stack([batch(10 * w + i)[k] for i in range(tau)])
+         for k in ("tokens", "targets")} for w in range(2)]
+    stacked = {k: np.stack([np.asarray(b[k]) for b in per_worker])
+               for k in ("tokens", "targets")}
+    state, losses = trainer.round(trainer.init_state(seed=4), stacked,
+                                  round_index=0)
+    assert losses.shape == (2, tau)
+    runs = []
+    for w in range(2):
+        rng = jax.random.fold_in(default_train_key(0), w)
+        runs.append(solver.step(solver.init_state(seed=4), per_worker[w],
+                                rng=rng)[0])
+    leaves = jax.tree_util.tree_leaves
+    for got, a, b in zip(leaves(state.params), leaves(runs[0].params),
+                         leaves(runs[1].params)):
+        want = (np.asarray(a) + np.asarray(b)) / 2
+        np.testing.assert_allclose(np.asarray(got[0]), want, rtol=2e-5, atol=1e-7)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(got[1]))
+    for got, a, b in zip(leaves(state.history), leaves(runs[0].history),
+                         leaves(runs[1].history)):
+        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(a), rtol=2e-5, atol=1e-9)
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(b), rtol=2e-5, atol=1e-9)
+    # Adam moved every blob, both moments
+    assert all(float(jnp.max(jnp.abs(h))) > 0 for h in leaves(state.history))
+
+
+def test_init_state_stacks_what_the_solver_initialises():
+    """One worker takes the donated reshape on the device, two the host
+    path: both give every worker the solver's own initial state."""
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.parallel import ParameterAveragingTrainer, make_mesh
+
+    _, solver = lm_app.build_hybrid_lm_solver(
+        {**SMALL, "num_hidden_layers": 4})
+    single = solver.init_state(seed=9)
+    for n in (1, 2):
+        mesh = make_mesh({"dp": n}, devices=jax.devices()[:n])
+        state = ParameterAveragingTrainer(solver, mesh).init_state(seed=9)
+        for got, want in zip(jax.tree_util.tree_leaves(state),
+                             jax.tree_util.tree_leaves(single)):
+            assert got.shape == (n,) + want.shape
+            assert len(got.sharding.device_set) == n
+            for w in range(n):
+                np.testing.assert_array_equal(np.asarray(got[w]),
+                                              np.asarray(want))
+
+
+@pytest.mark.parametrize("t,block_q,segments", [(40, 16, 4), (33, 16, 2),
+                                                (64, 64, 1), (48, 8, 4)])
+def test_blockwise_gqa_attention_matches_full_attention(t, block_q, segments):
+    """Ragged last block, runs of blocks that meet only their own keys, one
+    block: all the full-matrix attention of the reference, forward and
+    gradient."""
+    b, hq, hkv, d = 2, 4, 2, 16
+    keys = jax.random.split(jax.random.key(t), 3)
+    q = jax.random.normal(keys[0], (b, t, hq, d))
+    k = jax.random.normal(keys[1], (b, t, hkv, d))
+    v = jax.random.normal(keys[2], (b, t, hkv, d))
+
+    def full(q, k, v):
+        kk, vv = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * d ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+
+    def blockwise(q, k, v):
+        return causal_gqa_attention(q, k, v, block_q=block_q,
+                                    segments=segments)
+
+    weigh = lambda f: (lambda *a: jnp.sum(  # noqa: E731
+        f(*a) * jnp.sin(jnp.arange(b * t * hq * d).reshape(b, t, hq, d))))
+    with jax.default_matmul_precision("highest"):
+        assert rel(jax.jit(blockwise)(q, k, v), jax.jit(full)(q, k, v)) < 2e-6
+        got = jax.jit(jax.grad(weigh(blockwise), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(weigh(full), argnums=(0, 1, 2)))(q, k, v)
+    for g_, w_ in zip(got, want):
+        assert rel(g_, w_) < 2e-5
+
+
+def test_lm_app_trains_the_hybrid_model_from_a_configuration_file(tmp_path):
+    """``lm_app --model_config``: the byte corpus through
+    ``Solver(net=...)`` with ADAM and ``ParameterAveragingTrainer.round`` on
+    two workers, the routing gauges set once before the loop."""
+    import json
+
+    from sparknet_tpu import obs
+    from sparknet_tpu.apps import lm_app
+
+    config = {**SMALL, "vocab_size": 256, "num_hidden_layers": 4,
+              "compute_dtype": "bfloat16"}
+    path = tmp_path / "tiny.json"
+    path.write_text(json.dumps(config))
+    rc = lm_app.main([
+        "--model_config", str(path), "--workers", "2", "--rounds", "3",
+        "--tau", "2", "--batch", "2", "--seq_len", "24", "--log_every", "1",
+        "--obs", "--obs_port", "0",
+    ])
+    assert rc == 0
+    tm = obs.training_metrics()
+    assert tm is not None and tm.lm_tokens.value == 3 * 2 * 2 * 2 * 24
+    per_token = [tm.lm_held_assignments.labels(str(i)).value for i in range(4)]
+    skew = [tm.lm_held_load_skew.labels(str(i)).value for i in range(4)]
+    # 8 of 16 experts held, top-4: two assignments a token expected
+    assert all(0.5 < x < 3.5 for x in per_token) and all(x >= 1 for x in skew)
+    with pytest.raises(SystemExit, match="--sp 1"):
+        lm_app.main(["--model_config", str(path), "--sp", "2"])
