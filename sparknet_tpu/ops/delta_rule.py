@@ -12,22 +12,32 @@ form (Yang et al., "Gated Delta Networks", 2024): inside a chunk the
 with ``L_tj = beta_t exp(gamma_t - gamma_j) (k_t . k_j)`` for ``j < t``
 and ``gamma`` the running sum of ``g`` inside the chunk; across chunks a
 ``lax.scan`` carries ``S``.  Everything that does not need ``S`` (the
-triangular inverse, ``U``, ``W``, the masked ``q k^T``) is batched over all
-chunks, a block of heads at a time.
+triangular inverse, ``U``, ``W``, the masked ``q k^T``) is one stage over all
+chunks: on the TPU, at shapes they take (``pallas_delta_rule.accepts``), two
+Pallas kernels that keep a chunk's matrices in VMEM
+(``ops/pallas_delta_rule.py``: forward, and a backward of its own that
+differentiates the inverse by its identity); elsewhere ``_within_chunks``
+below, the same arithmetic in XLA and the oracle the kernels are tested
+against.  No switch picks between them.
 
 Precision: ``g``, ``gamma``, every ``exp``, the triangular inverse (its
 operands; its products at full precision in float32, at three bf16 passes
 beside a lower ``compute_dtype``) and the carried state are float32 whatever
 ``compute_dtype`` is; the large
 matrix products take their operands in ``compute_dtype`` and accumulate
-in float32.  The backward pass is jax's own, through the scan: it keeps
-one ``S`` a chunk (``T / chunk`` states a head).
+in float32.  The scan's backward pass is jax's own: it keeps one ``S`` a
+chunk (``T / chunk`` states a head); ``_within_chunks``' is too, through
+every product of the inverse.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from sparknet_tpu import obs
+from sparknet_tpu.ops import pallas_delta_rule
+from sparknet_tpu.ops.pallas_attention import lowerable
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -64,8 +74,8 @@ def _unit_lower_inverse(strict_lower, precision=HIGHEST):
 
 def _within_chunks(q, k, v, g, beta, cd):
     """Everything that does not need the carried state, for all chunks at
-    once.  ``q``, ``k``, ``v``: ``(B, H, N, C, d)``; ``g``, ``beta``:
-    ``(B, H, N, C)`` float32.  Returns what the scan over chunks reads:
+    once.  ``q``, ``k``, ``v``: ``(..., C, d)``; ``g``, ``beta``:
+    ``(..., C)`` float32.  Returns what the scan over chunks reads:
     ``u`` float32 (it is added), the rest in ``cd`` (the dtype their
     products take them in; the rounding is the same one, made once)."""
     chunk = q.shape[-2]
@@ -80,14 +90,14 @@ def _within_chunks(q, k, v, g, beta, cd):
     diff = jnp.where(lower, gamma[..., :, None] - gamma[..., None, :], 0.0)
     decay = jnp.where(lower, jnp.exp(diff), 0.0)
     k_beta = k.astype(F32) * beta[..., None]
-    strict = jnp.tril(mm("bhnid,bhnjd->bhnij", k_beta, k) * decay, -1)
+    strict = jnp.tril(mm("...id,...jd->...ij", k_beta, k) * decay, -1)
     # the inverse's consumers round it to ``cd``: beside bfloat16 three
     # bf16 passes (about 2^-16) are exact enough, and half the time of six
     inv = _unit_lower_inverse(
         strict, HIGHEST if jnp.dtype(cd) == F32 else jax.lax.Precision.HIGH)
-    u = mm("bhnij,bhnjd->bhnid", inv, v.astype(F32) * beta[..., None])
-    w = mm("bhnij,bhnjd->bhnid", inv, k_beta * jnp.exp(gamma)[..., None])
-    qk = mm("bhnid,bhnjd->bhnij", q, k) * decay
+    u = mm("...ij,...jd->...id", inv, v.astype(F32) * beta[..., None])
+    w = mm("...ij,...jd->...id", inv, k_beta * jnp.exp(gamma)[..., None])
+    qk = mm("...id,...jd->...ij", q, k) * decay
     q_in = q.astype(F32) * jnp.exp(gamma)[..., None]
     last = gamma[..., -1:]
     k_out = k.astype(F32) * jnp.exp(last - gamma)[..., None]
@@ -96,42 +106,48 @@ def _within_chunks(q, k, v, g, beta, cd):
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
-                     compute_dtype=None, head_block: int = 8):
+                     compute_dtype=None):
     """``q``, ``k``: ``(B, T, H, dk)`` (normalised and scaled by the
     caller); ``v``: ``(B, T, H, dv)``; ``g`` (log decay, <= 0) and ``beta``:
     ``(B, T, H)``.  Returns ``o``: ``(B, T, H, dv)`` float32.  ``T`` need
     not divide by ``chunk``: the tail is padded with tokens that write
-    nothing (``k = v = beta = g = 0``).  The work inside chunks runs
-    ``head_block`` heads at a time (where ``H`` divides by it), each block a
-    ``jax.checkpoint``: its float32 ``(C, C)`` temporaries, a dozen a level
-    of the inverse, then exist for one block of heads only."""
+    nothing (``k = v = beta = g = 0``).  The work inside chunks is the
+    Pallas kernels' where they lower and take the shapes, else
+    ``_within_chunks``; an ``obs`` instant names the path at each trace."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
-    cd = compute_dtype or F32
-    pad = (-t) % chunk
+    cd = jnp.dtype(compute_dtype or F32)
+    backend = jax.default_backend()
+    if not lowerable():
+        why = f"no Pallas lowering on {backend}"
+    elif not pallas_delta_rule.accepts(chunk, dk, dv):
+        why = "chunk must tile 128 tokens by 16s, dk and dv whole lanes"
+    else:
+        why = ""
+    obs.instant("delta_rule_path", cat="kernel",
+                path="xla" if why else "pallas", why=why, backend=backend,
+                chunk=chunk, dk=dk, dv=dv, dtype=cd.name)
+    pad = (-t % chunk) if why else pallas_delta_rule.padded_length(t) - t
     if pad:
         widths = ((0, 0), (0, pad), (0, 0))
         q, k, v = (jnp.pad(x, widths + ((0, 0),)) for x in (q, k, v))
         g, beta = jnp.pad(g, widths), jnp.pad(beta, widths)
     n = (t + pad) // chunk
+    g, beta = g.astype(F32), beta.astype(F32)
 
-    def blocks(x):  # (B, T, H, ...) -> (B, H, N, C, ...)
+    def blocks(x):  # (B, T, H, ...) -> (N, B, H, C, ...)
         x = x.reshape(b, n, chunk, h, *x.shape[3:])
-        return jnp.moveaxis(x, 3, 1)
+        return jnp.moveaxis(x, (1, 3), (0, 2))
 
-    inputs = (blocks(q), blocks(k), blocks(v),
-              blocks(g.astype(F32)), blocks(beta.astype(F32)))
-    within = jax.checkpoint(lambda *xs: _within_chunks(*xs, cd))
-    if h > head_block and h % head_block == 0:
-        groups = h // head_block
-        split = lambda x: jnp.moveaxis(  # noqa: E731
-            x.reshape(b, groups, head_block, *x.shape[2:]), 1, 0)
-        merge = lambda x: jnp.moveaxis(x, 0, 1).reshape(  # noqa: E731
-            b, h, *x.shape[3:])
-        per_chunk = tuple(merge(x) for x in jax.lax.map(
-            lambda xs: within(*xs), tuple(split(x) for x in inputs)))
+    # chunk-major, as the scan reads them: u, w, qk, q_in, k_out, the decay
+    if why:
+        per_chunk = _within_chunks(
+            *(blocks(x) for x in (q, k, v, g, beta)), cd)
     else:
-        per_chunk = within(*inputs)
+        per_chunk = (
+            *pallas_delta_rule.within_chunks(
+                *(x.astype(F32) for x in (q, k, v)), g, beta, chunk, cd),
+            jnp.exp(jnp.sum(blocks(g), axis=-1)))
     u = per_chunk[0]
 
     def mm(eq, x, y):
@@ -151,8 +167,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
     vma = tuple(sorted(jax.typeof(u).vma))
     if vma:
         s0 = jax.lax.pcast(s0, vma, to="varying")
-    _, o = jax.lax.scan(
-        step, s0, tuple(jnp.moveaxis(x, 2, 0) for x in per_chunk))
+    _, o = jax.lax.scan(step, s0, per_chunk)
     # (N, B, H, C, dv) -> (B, T, H, dv)
     o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * chunk, h, dv)
     return o[:, :t]

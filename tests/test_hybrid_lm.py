@@ -22,7 +22,7 @@ import pytest
 
 from benchmark.reference import qwen3_next as ref
 from sparknet_tpu.models.hybrid_lm import HybridMoELM, routing_gauges
-from sparknet_tpu.ops import moe
+from sparknet_tpu.ops import delta_rule, moe, pallas_delta_rule
 from sparknet_tpu.ops.attention import causal_gqa_attention
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
 
@@ -137,10 +137,13 @@ def test_logits_loss_and_every_gradient_match_the_reference(model, params):
                for g in gs)
 
 
-@pytest.mark.parametrize("decay", ["near_one", "near_zero", "mixed"])
-def test_chunked_delta_rule_matches_the_recurrence(decay):
-    b, t, h, dk, dv = 2, T_OP, 6, 8, 8
-    keys = jax.random.split(jax.random.key(7), 6)
+DECAYS = ["near_one", "near_zero", "mixed"]
+
+
+def rule_inputs(b, t, h, dk, dv, decay, seed=7):
+    """Unit keys, scaled unit queries; a decay that forgets nothing, forgets
+    within a token, or spans both."""
+    keys = jax.random.split(jax.random.key(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = unit(jax.random.normal(keys[0], (b, t, h, dk))) * dk ** -0.5
     k = unit(jax.random.normal(keys[1], (b, t, h, dk)))
@@ -149,28 +152,178 @@ def test_chunked_delta_rule_matches_the_recurrence(decay):
     u = jax.random.uniform(keys[4], (b, t, h))
     g = {"near_one": -1e-4 * u, "near_zero": -8.0 - 8.0 * u,
          "mixed": -jnp.exp(6.0 * u - 5.0)}[decay]
+    return q, k, v, g, beta
 
-    def both(fn):
-        def scalar(q, k, v, g, beta):
-            o = fn(q, k, v, g, beta)
-            return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape))), o
-        (_, o), grads = jax.jit(jax.value_and_grad(
-            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(q, k, v, g, beta)
-        return o, grads
 
-    with jax.default_matmul_precision("highest"):
-        # two blocks of three heads: the blocked path of the work in chunks
-        o, grads = both(
-            lambda *a: gated_delta_rule(*a, chunk=16, head_block=3))
-        want, want_grads = both(ref.delta_rule_recurrent)
-    assert o.shape == (b, t, h, dv)
-    assert rel(o, want) < 2e-5
+def outputs_and_gradients(fn, inputs):
+    """``fn``'s outputs (one or a tuple) and the gradients of a fixed
+    weighting of them, in all five inputs."""
+    def scalar(*xs):
+        outs = fn(*xs)
+        outs = outs if isinstance(outs, tuple) else (outs,)
+        total = sum(
+            jnp.sum(o.astype(jnp.float32)
+                    * jnp.cos(jnp.arange(o.size).reshape(o.shape)))
+            for o in outs)
+        return total, outs
+    (_, outs), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*inputs)
+    return outs, grads
+
+
+def assert_gradients_close(grads, want_grads, tol):
     scale = max(float(jnp.linalg.norm(w)) for w in want_grads)
     for name, got_g, want_g in zip("qkvgb", grads, want_grads):
         # against the largest gradient where this one all but vanishes (g's
         # at decay ~0 is 1e-5 of v's: float32 noise of terms that cancel)
         floor = max(float(jnp.linalg.norm(want_g)), 1e-3 * scale)
-        assert float(jnp.linalg.norm(got_g - want_g)) < 5e-4 * floor, name
+        assert float(jnp.linalg.norm(got_g - want_g)) < tol * floor, name
+
+
+@pytest.mark.parametrize("decay", DECAYS)
+def test_chunked_delta_rule_matches_the_recurrence(decay):
+    b, t, h, dk, dv = 2, T_OP, 6, 8, 8
+    inputs = rule_inputs(b, t, h, dk, dv, decay)
+    with jax.default_matmul_precision("highest"):
+        (o,), grads = outputs_and_gradients(
+            lambda *a: gated_delta_rule(*a, chunk=16), inputs)
+        (want,), want_grads = outputs_and_gradients(
+            ref.delta_rule_recurrent, inputs)
+    assert o.shape == (b, t, h, dv)
+    assert rel(o, want) < 2e-5
+    assert_gradients_close(grads, want_grads, 5e-4)
+
+
+# the shapes the kernels take: heads of 128, chunks of 64, and a ragged tail
+# (200 tokens: three chunks and 8 tokens, padded to two groups of 128)
+KERNEL_SHAPE = (1, 200, 2, 128, 128)
+# float32: the existing bounds; bfloat16: the configuration's
+# ``delta_rule_rel_tol`` (benchmark/configs/qwen3-next-80b-a3b.json)
+KERNEL_BOUNDS = {"float32": (2e-5, 5e-4), "bfloat16": (0.02, 0.02)}
+
+
+@pytest.fixture
+def kernels_lower(monkeypatch):
+    """The selection sees a backend that lowers the kernels; the kernels
+    themselves still see the CPU and run in interpreter mode."""
+    monkeypatch.setattr(delta_rule, "lowerable", lambda: True)
+
+
+@pytest.mark.parametrize("dtype", sorted(KERNEL_BOUNDS))
+@pytest.mark.parametrize("decay", DECAYS)
+def test_delta_rule_kernels_match_the_recurrence(kernels_lower, decay, dtype):
+    """Forward and backward kernel (interpreter mode) inside the whole
+    rule, against the token-by-token recurrence."""
+    inputs = rule_inputs(*KERNEL_SHAPE, decay)
+    out_tol, grad_tol = KERNEL_BOUNDS[dtype]
+    with jax.default_matmul_precision("highest"):
+        (o,), grads = outputs_and_gradients(
+            lambda *a: gated_delta_rule(*a, compute_dtype=jnp.dtype(dtype)),
+            inputs)
+        (want,), want_grads = outputs_and_gradients(
+            ref.delta_rule_recurrent, inputs)
+    assert o.shape == want.shape
+    assert rel(o, want) < out_tol
+    assert_gradients_close(grads, want_grads, grad_tol)
+
+
+# every decay regime in both dtypes at the benchmark's shape, then the other
+# shapes ``pallas_delta_rule.accepts`` lets in: four, two and one chunk a
+# group of 128 tokens, a key head wider than the value head and the reverse
+@pytest.mark.parametrize("decay, dtype, chunk, dk, dv", [
+    *((decay, dtype, 64, 128, 128)
+      for decay in DECAYS for dtype in sorted(KERNEL_BOUNDS)),
+    ("mixed", "float32", 32, 128, 128),
+    ("mixed", "float32", 128, 128, 256),
+    ("mixed", "bfloat16", 16, 256, 128),
+])
+def test_delta_rule_kernels_match_the_xla_stage(decay, dtype, chunk, dk, dv):
+    """The kernels against ``_within_chunks``, the oracle and the fallback:
+    the five outputs and, through them, the five gradients."""
+    b, t, h, cd = 1, 256, 2, jnp.dtype(dtype)
+    assert pallas_delta_rule.accepts(chunk, dk, dv)
+    inputs = rule_inputs(b, t, h, dk, dv, decay)
+
+    def blocks(x):  # (B, T, H, ...) -> (N, B, H, C, ...)
+        x = x.reshape(b, t // chunk, chunk, h, *x.shape[3:])
+        return jnp.moveaxis(x, (1, 3), (0, 2))
+
+    out_tol, grad_tol = KERNEL_BOUNDS[dtype]
+    with jax.default_matmul_precision("highest"):
+        got, grads = outputs_and_gradients(
+            lambda *xs: tuple(
+                pallas_delta_rule.within_chunks(*xs, chunk, cd)), inputs)
+        want, want_grads = outputs_and_gradients(
+            lambda *xs: delta_rule._within_chunks(
+                *(blocks(x) for x in xs), cd)[:5], inputs)
+    for name, g, w in zip(("u", "w", "qk", "q_in", "k_out"), got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert rel(g.astype(jnp.float32), w.astype(jnp.float32)) < out_tol, name
+    assert_gradients_close(grads, want_grads, grad_tol)
+
+
+@pytest.mark.parametrize("lowers, dk, path, why", [
+    (False, 128, "xla", "no Pallas lowering on cpu"),
+    (True, 8, "xla", "dk and dv whole lanes"),
+    (True, 128, "pallas", ""),
+])
+def test_delta_rule_selects_by_backend_and_shape(
+        monkeypatch, lowers, dk, path, why):
+    """No switch: the backend and the shapes decide, and each trace says so
+    in one ``obs`` instant."""
+    from sparknet_tpu import obs
+    from sparknet_tpu.obs.trace import Tracer
+
+    monkeypatch.setattr(delta_rule, "lowerable", lambda: lowers)
+    calls = []
+    real = pallas_delta_rule.within_chunks
+    monkeypatch.setattr(pallas_delta_rule, "within_chunks",
+                        lambda *a: calls.append(a[5:]) or real(*a))
+    inputs = rule_inputs(1, 70, 2, dk, dk, "mixed")
+    tracer = obs.install_tracer(Tracer())
+    try:
+        o = jax.jit(lambda *a: gated_delta_rule(
+            *a, compute_dtype=jnp.bfloat16))(*inputs)
+    finally:
+        obs.uninstall_tracer()
+    assert o.shape == inputs[2].shape
+    assert len(calls) == (path == "pallas")
+    events = [e for e in tracer.events() if e["name"] == "delta_rule_path"]
+    assert len(events) == 1  # one a trace
+    args = events[0]["args"]
+    assert args["path"] == path and why in args["why"]
+    assert (args["why"] == "") == (path == "pallas")
+    assert (args["backend"], args["chunk"], args["dk"], args["dv"],
+            args["dtype"]) == ("cpu", 64, dk, dk, "bfloat16")
+
+
+def test_inverse_cotangent_by_its_identity_matches_autodiff():
+    """``dL = -tril(T^T dT T^T, -1)`` against ``jax.vjp`` through the
+    doubling's twelve products, on correlated keys (where powers of ``L``
+    would cancel), two chunks of 64 as the kernel's group of 128 holds
+    them."""
+    chunk, d = 64, 16
+    keys = jax.random.split(jax.random.key(5), 4)
+    common = jax.random.normal(keys[0], (1, 1, d))
+    k = common + 0.1 * jax.random.normal(keys[1], (2, chunk, d))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[2], (2, chunk, 1)))
+    strict = jnp.tril(jnp.einsum("nid,njd->nij", k * beta, k), -1)
+    d_inv = jax.random.normal(keys[3], (2, chunk, chunk))
+    with jax.default_matmul_precision("highest"):
+        inv, vjp = jax.vjp(delta_rule._unit_lower_inverse, strict)
+        (want,) = vjp(d_inv)
+        m = pallas_delta_rule._masks(chunk)
+        group = lambda x: jax.scipy.linalg.block_diag(*x)  # noqa: E731
+        f32 = jnp.dtype("float32")
+        (got_inv,) = pallas_delta_rule._unit_lower_inverses(
+            [group(strict)], m, chunk, f32)
+        (got,) = pallas_delta_rule._inverse_cotangents(
+            [got_inv], [group(d_inv)], m, f32)
+    # the keys are correlated: L's powers grow, a series in them cancels
+    assert float(jnp.max(jnp.sum(jnp.abs(strict), -1))) > 10
+    assert rel(got_inv, group(inv)) < 1e-5
+    assert rel(got, group(want)) < 1e-5
 
 
 def skewed_router(hidden, experts, hot, cold, key):
