@@ -9,9 +9,9 @@ producer/comm/watchdog/server threads, and emitter/folder agreement on
 every metric and span name.  This package enforces them statically —
 each checker is a small AST visitor emitting the shared
 :class:`findings.Finding` shape — and ``tools/lint.py --check`` runs
-the set against a committed allowlist as a tier-1 guard (the static
-sibling of ``tools/perf_gate.py --check``; the dynamic half is
-``bench.py --mode=sanitize``).
+the set against a committed allowlist as a tier-1 guard (the dynamic
+half is ``tests/test_parallel.py``'s steady rounds under an armed
+transfer guard).
 
 Checkers
 --------
@@ -31,8 +31,7 @@ Checkers
 - ``registry_audit`` — trace/metrics registry drift: every emitted
   ``sparknet_*`` metric name and phase-cat ``span(...)`` literal must
   appear in the canonical sets (``analysis.registry``) consumed by
-  ``tools/trace_report.py``/``tools/perf_gate.py``/PERF.md, and vice
-  versa.
+  ``tools/trace_report.py`` and ARCHITECTURE.md, and vice versa.
 
 Suppression marker grammar (see ARCHITECTURE.md "Static analysis &
 sanitizers"): an inline ``# sparknet: <rule>-ok(<reason>)`` comment on
@@ -40,8 +39,7 @@ any line of the flagged statement suppresses that checker's finding
 there — ``sync-ok``, ``donation-ok``, ``thread-ok``, ``join-ok``,
 ``except-ok``, ``lock-ok``.  The reason is mandatory; an empty one is
 itself a finding.  Suppressed sites stay enumerable
-(``Report.suppressed``) — ``bench.py --mode=sanitize`` lists every
-annotated deliberate sync in its artifact.
+(``Report.suppressed``).
 """
 
 from sparknet_tpu.analysis.findings import Finding, Report  # noqa: F401

@@ -3,9 +3,9 @@
 One authoritative inventory of every ``sparknet_*`` metric the
 framework emits (``obs/__init__.py`` TrainingMetrics) and every
 ``span(...)`` name by category — the sets the folding side consumes:
-``tools/trace_report.py`` (comm-span folding), ``tools/perf_gate.py``
-(live-profile fields), the ARCHITECTURE.md "Telemetry reference" tables, and
-the ``/metrics`` scrapers people build dashboards on.
+``tools/trace_report.py`` (comm-span folding), the ARCHITECTURE.md
+"Telemetry reference" tables, and the ``/metrics`` scrapers people build
+dashboards on.
 
 ``analysis/registry_audit.py`` cross-checks this module against the
 code, both directions: an emitter whose name is missing here fails the

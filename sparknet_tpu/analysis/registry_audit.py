@@ -11,7 +11,7 @@ and cross-checks them against ``analysis.registry``'s canonical sets,
 both directions, plus the docs:
 
 - emitted-but-uncanonical: the folding side (``trace_report``,
-  ``perf_gate`` fields, dashboards) won't know the name exists;
+  dashboards) won't know the name exists;
 - canonical-but-never-emitted: the registry documents a ghost;
 - label drift: same name, different label tuple;
 - docs drift (PERF.md / ARCHITECTURE.md / README.md): every canonical

@@ -13,9 +13,8 @@ thread-target function):
   barrier
 
 Every deliberate site carries ``# sparknet: sync-ok(<reason>)`` on a
-line of the flagged statement; the suppressed list stays enumerable so
-``bench.py --mode=sanitize`` can pin the complete deliberate-sync
-inventory in its artifact.  The checker is intentionally type-blind
+line of the flagged statement; the suppressed list stays enumerable
+(``Report.suppressed``).  The checker is intentionally type-blind
 (``np.asarray`` on a host array is cheap but still gets annotated —
 the annotation IS the documentation that someone checked).
 """

@@ -14,7 +14,8 @@ the ``parallel/ring_attention.py`` construction inside the round's
 ``shard_map`` (KV rotating one ICI hop per ring step), the gradients
 of the sp-replicated params are summed over the ring by ``shard_map``'s
 varying-axes typing, and the trajectory matches the sp=1 run up to
-float associativity (pinned by ``bench.py --mode=lm``).
+float associativity
+(``tests/test_lm.py::test_sp_trajectory_matches_dense``).
 
 Data: documents fetched through ``object_store`` + ``ChunkCache``
 (``data/text.py``), windows drawn by absolute-iteration cursor — the
@@ -61,7 +62,7 @@ def add_lm_model_args(parser) -> None:
         help="train with the dense XLA attention reference instead of "
         "the Pallas flash kernel (the kernel is the default wherever it "
         "lowers natively — ops/pallas_attention.lowerable(); this flag "
-        "is the explicit fallback, and the A/B lever for KERNELS_r21)",
+        "is the explicit fallback)",
     )
 
 
@@ -119,8 +120,8 @@ def set_routing_gauges(lm, stacked_params, tokens):
 
 
 def build_lm_solver(args, sp: int):
-    """(model, Solver) from parsed args — shared with ``cli train --lm``
-    and the bench.  ``--model_config`` selects the hybrid model."""
+    """(model, Solver) from parsed args — shared with ``cli train --lm``.
+    ``--model_config`` selects the hybrid model."""
     from sparknet_tpu import models
     from sparknet_tpu.config import parse_solver_prototxt
     from sparknet_tpu.solver import Solver
@@ -140,7 +141,7 @@ def build_lm_solver(args, sp: int):
         sp_size=sp,
         # --dense_attention is the explicit fallback; the default
         # ("auto") rides the Pallas flash kernel wherever it lowers
-        # natively (getattr: bench Namespaces predate the flag)
+        # natively
         attention=(
             "dense" if getattr(args, "dense_attention", False) else "auto"
         ),

@@ -8,8 +8,7 @@ is ``jax.device_put`` (which on TPU overlaps with compute because transfers
 are async until the buffer is used).
 
 ``device_put=False`` makes the producer deliver host batches only; the
-consumer then issues ``jax.device_put`` itself between steps, as
-``bench.py bench_hostfeed`` does.
+consumer then issues ``jax.device_put`` itself between steps.
 
 Fault tolerance: ``stall_timeout_s`` arms a consumer-side watchdog — if
 the producer delivers nothing for that long (storage wedged past the

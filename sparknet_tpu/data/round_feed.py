@@ -2,8 +2,7 @@
 
 The SparkNet reference keeps PREFETCH_COUNT=3 batches in flight on an
 InternalThread precisely so the data plane never serializes with the
-solver (``base_data_layer.cpp:70-101``); until round 8 only
-``bench.py bench_hostfeed`` reproduced that overlap — every app and
+solver (``base_data_layer.cpp:70-101``); until round 8 every app and
 ``cli train`` did per-round host ``np.stack`` assembly -> blocking
 sharded ``device_put`` -> ``trainer.round``, fully serial, so on a
 machine with a spare core the host work was pure added wall-clock per

@@ -38,8 +38,8 @@ in the same CRC manifest (``load_job_state`` reads it back).
 COMMITTED round boundary — a snapshot published for a round whose
 commit never landed is ignored, so restart never re-executes a
 committed round nor skips an uncommitted one.  Proven bit-identical
-under SIGKILL at every phase boundary by ``bench.py --mode=recover``
-(``runtime/recover.py``).
+at every phase boundary of ``runtime/recover.py``'s driver loop by
+``tests/test_recover.py``.
 """
 
 from __future__ import annotations

@@ -37,8 +37,8 @@ throwaway runs).
 
 ``io/checkpoint.restore_newest_valid_journaled`` reconciles this ledger
 against the on-disk snapshots; ``runtime/recover.py`` is the journaled
-driver loop the kill-anywhere sweep (``bench.py --mode=recover``)
-proves bit-identical recovery on.
+driver loop ``tests/test_recover.py`` kills at every phase boundary and
+holds to bit-identical recovery.
 """
 
 from __future__ import annotations
@@ -282,8 +282,7 @@ def add_cli_args(parser) -> None:
         "--no_journal", dest="journal", action="store_false",
         help="disable the run journal even on resume (the resumed "
         "trajectory may silently diverge from an uninterrupted one: "
-        "EF residuals / sentry state reset — bench.py --mode=recover "
-        "measures exactly this)",
+        "EF residuals / sentry state reset)",
     )
     parser.add_argument(
         "--journal_path", default=None,

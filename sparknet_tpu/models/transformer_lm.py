@@ -8,7 +8,7 @@ decoder-only transformer whose **sequence dimension shards over the
 tau-round parameter averaging every CNN app uses.
 
 Two attention paths, one function (pinned up to float associativity by
-``bench.py --mode=lm`` and ``tests/test_lm.py``):
+``tests/test_lm.py::test_sp_trajectory_matches_dense``):
 
 - ``sp_axis=None`` (sp=1): single-shard causal attention — the Pallas
   flash kernel (``ops.pallas_attention.flash_attention``, fused

@@ -22,9 +22,7 @@ serving (ARCHITECTURE.md "Observability"):
   folded by ``tools/health_report.py``).
 - ``obs.profile``  — the round-anatomy profiler (``--profile``): live
   per-phase breakdown, measured H2D/collective hidden fractions,
-  per-worker skew + straggler verdicts, MFU/roofline gauges; the live
-  counterpart of the offline PIPELINE/OBS artifacts, gated by
-  ``tools/perf_gate.py``.
+  per-worker skew + straggler verdicts, MFU/roofline gauges.
 
 Instrumented code calls the module-level hooks (``obs.span``,
 ``obs.instant``, ``obs.training_metrics()``, ``obs.fault``), which are
@@ -627,8 +625,7 @@ def add_cli_args(parser) -> None:
     parser.add_argument(
         "--profile_out", default=None, metavar="SUMMARY.json",
         help="write the end-of-run RoundProfiler.summary() as JSON "
-        "(implies --profile); feed it to tools/perf_gate.py --live to "
-        "compare this run against the committed baselines",
+        "(implies --profile)",
     )
     parser.add_argument(
         "--slo", action="store_true",
@@ -680,8 +677,7 @@ class ObsRun:
     lifetime and survive run boundaries — ``rate()`` handles restarts;
     a later ``--obs`` run in the same process scrapes continuing
     totals, not zeros).  The residual cost of the observer once metrics
-    have ever been enabled is one histogram observe per phase span —
-    microseconds per round (measured in ``OBS_r09.json``)."""
+    have ever been enabled is one histogram observe per phase span."""
 
     def __init__(self, exporter=None, tracer=None, trace_out=None,
                  metrics: Optional[TrainingMetrics] = None,
@@ -726,8 +722,7 @@ class ObsRun:
                         json.dump(self.profiler.summary(), f, indent=1)
                     if self._echo is not None:
                         self._echo(
-                            "obs: profile summary -> %s (fold with "
-                            "tools/perf_gate.py --live)" % self.profile_out
+                            "obs: profile summary -> %s" % self.profile_out
                         )
                 except Exception:  # noqa: BLE001 — teardown must not die
                     pass

@@ -38,7 +38,8 @@ module closes the loop — detect, record, recover:
                 sentry escalates to halt.
 
 Cost: the audit itself is a handful of fused reductions inside the
-existing program (``bench.py --mode=health`` A/Bs it);
+existing program, bit-neutral to the trajectory
+(``tests/test_health.py::test_audit_bit_identity_and_in_graph_mask``);
 the sentry adds one small per-round device_get of scalar stats — a host
 sync in the round loop — so ``--health`` is opt-in.
 """

@@ -1,21 +1,17 @@
 """Round anatomy: live per-phase / per-worker time attribution.
 
-The offline artifacts (PIPELINE_r08, OBS_r09, COMM_r11) prove the
-RoundFeed H2D overlap and the CommPlane chunk overlap in ``bench.py``
-A/Bs — but a *running* job had no live counterpart: the only runtime
-overlap evidence was a boolean in ``tools/trace_report.py``, per-worker
-time was invisible (the synchronous averaging round is gated by its
-slowest worker — SparkNet §4 assumes homogeneous workers), and nothing
-compared a live run against the committed trajectory.  ``RoundProfiler``
-closes that gap, per round and as rolling percentiles:
+A *running* job's only overlap evidence was a boolean in
+``tools/trace_report.py``, and per-worker time was invisible (the
+synchronous averaging round is gated by its slowest worker — SparkNet §4
+assumes homogeneous workers).  ``RoundProfiler`` closes that gap, per
+round and as rolling percentiles:
 
 - **phase breakdown** — assemble / h2d / execute / quantize / allreduce
   / dequantize / average / snapshot, folded live from the span stream
   (``obs/trace.py`` ``set_span_observer``; no Tracer required);
 - **measured hidden-fraction** — how much of the producer's
   assemble+h2d time (PR 3) and of the comm thread's chunked allreduce
-  time (PR 6) actually ran *under* consumer execute spans: the live
-  counterpart of PIPELINE_r08's 0.97 offline overlap efficiency;
+  time (PR 6) actually ran *under* consumer execute spans;
 - **per-worker skew + straggler verdict** — per-worker times arrive
   from two hooks: host-side per-worker assembly timing
   (``note_worker_phase`` / ``worker_timer`` / ``timed_worker_windows``
@@ -30,8 +26,7 @@ closes that gap, per round and as rolling percentiles:
   harness's seeded ``straggler_injection`` fault must be attributed to
   exactly the injected worker (tier-1 smoke);
 - **MFU / roofline gauges** — achieved FLOP/s from the analytic
-  ``utils/flops.py`` count (``bench.py --mode=profile`` cross-checks it
-  against ``compiled.cost_analysis()``), modeled collective payload
+  ``utils/flops.py`` count, modeled collective payload
   bytes from the comm plane, arithmetic intensity, and a
   compute-vs-bandwidth-bound classification per phase.
 
@@ -39,8 +34,6 @@ Cost discipline: inactive, every hook is one module-global read (the
 ``span()`` fast path is untouched); active, a span costs a few dict/
 deque operations under a lock and the execute probe piggybacks on the
 per-round sync the driver loops already pay (``smoothed_loss``).
-``bench.py --mode=profile`` pins the end-to-end overhead under the
-PR-4/PR-5 noise-floor contract (PROFILE_r11.json).
 """
 
 from __future__ import annotations
@@ -268,8 +261,8 @@ class RoundProfiler:
         per-worker shards.  Polls ``is_ready`` so a fast worker's
         completion is stamped while a straggler still runs (on a real
         multi-device queue; the single-program virtual CPU mesh lands
-        all shards together — disclosed in PROFILE_r11).  The probe is
-        the profiler's one deliberate per-round sync — the driver loops
+        all shards together).  The probe is the profiler's one
+        deliberate per-round sync — the driver loops
         already sync each round (``smoothed_loss``), so it mostly moves
         the wait rather than adding one."""
         import jax
@@ -302,7 +295,7 @@ class RoundProfiler:
             done = []
             for w, d in pending.items():
                 if not can_poll:
-                    # sparknet: sync-ok(the execute probe IS the profiler's one deliberate per-round sync — disclosed in PROFILE_r11)
+                    # sparknet: sync-ok(the execute probe IS the profiler's one deliberate per-round sync)
                     jax.block_until_ready(d)
                 if not can_poll or d.is_ready():
                     times[w] = time.perf_counter() - t0

@@ -41,9 +41,9 @@ counterpart of the round profiler, the same recipe applied per request:
   profiler.
 
 Cost discipline: with no sinks installed the serve plane pays one
-module-global read per hook (``bench.py --mode=servetrace`` pins the
-traced-vs-untraced overhead inside the PR-4/PR-5 noise-floor contract,
-SERVEOBS_r22.json); with tracing on, a span costs the usual two
+module-global read per hook
+(``tests/test_reqtrace.py::test_noop_path_when_tracing_off``); with
+tracing on, a span costs the usual two
 ``perf_counter`` reads and ``on_span`` a few dict ops under a lock.
 """
 
@@ -431,8 +431,8 @@ class RequestProfiler:
 
     # ------------------------------------------------------------------
     def summary(self) -> dict:
-        """Rolling window percentiles + verdicts (the servetrace bench
-        artifact and the offline report read this)."""
+        """Rolling window percentiles + verdicts (``/healthz`` and the
+        offline report read this)."""
         with self._lock:
             recs = list(self._done)
             shed_win = list(self._shed_win)

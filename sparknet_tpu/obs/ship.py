@@ -35,10 +35,7 @@ Degradation contract (the part that keeps training safe):
   honest loss count instead of an OOM.
 
 Test/chaos seams (documented, like the object-store fault hook):
-``SPARKNET_SHIP_INTERVAL_S`` overrides the flush cadence and
-``SPARKNET_SHIP_CLOCK_SKEW_S`` skews this host's reported wall clock —
-the seam ``bench.py --mode=fleet`` uses to prove the collector's clock
-alignment recovers a known offset.
+``SPARKNET_SHIP_INTERVAL_S`` overrides the flush cadence.
 """
 
 from __future__ import annotations
@@ -93,11 +90,6 @@ class Shipper:
         self.interval_s = float(
             interval_s if interval_s is not None
             else (env_iv or DEFAULT_INTERVAL_S)
-        )
-        # test/bench seam: a skewed host clock (the whole host's wall
-        # clock reads shifted) — collector alignment must recover it
-        self.clock_skew_s = float(
-            os.environ.get("SPARKNET_SHIP_CLOCK_SKEW_S", "0") or 0.0
         )
         self.capacity = int(capacity)
         self.max_batch = int(max_batch)
@@ -251,20 +243,6 @@ class Shipper:
             # than one batch must not read as loss)
             events_total = self.events_total - len(self._buf)
             dropped_total = self.dropped_total
-        if self.clock_skew_s:
-            # the skewed-clock seam covers the whole host clock: event
-            # stamps ship as this host's (skewed) wall time too, so the
-            # collector's alignment is what un-skews them (copies —
-            # the buffered originals stay true for a failed-push requeue)
-            skewed = []
-            for rec in pending:
-                t = rec.get("t_s")
-                if isinstance(t, (int, float)):
-                    rec = dict(rec, t_s=t + self.clock_skew_s)
-                skewed.append(rec)
-            ship_events = skewed
-        else:
-            ship_events = pending
         snap = self._snapshot()
         deltas, resets = counter_deltas(
             self._prev_counters, snap["counters"]
@@ -274,11 +252,11 @@ class Shipper:
             "host": self.host,
             "boot_id": self.boot_id,
             "seq": self._seq,
-            "t_send": time.time() + self.clock_skew_s,
+            "t_send": time.time(),
             "round": max_round,
             "counters": deltas,
             "gauges": snap["gauges"],
-            "events": ship_events,
+            "events": pending,
             "events_total": events_total,
             "dropped_total": dropped_total,
             "resets": resets,

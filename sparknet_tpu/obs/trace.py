@@ -18,9 +18,7 @@ flat ``training_log_<ts>.txt``).
 Cost discipline: the module-level ``span()``/``instant()`` fast path is
 a shared no-op when no tracer is installed (one global read), so
 instrumented hot paths pay ~nothing by default; with tracing on, a span
-is two ``perf_counter`` reads and one list append under a lock —
-``bench.py --mode=obs`` measures the end-to-end round-time overhead
-(<2% acceptance, ``OBS_r09.json``).
+is two ``perf_counter`` reads and one list append under a lock.
 
 On the profiler's clock: a span that any sink is installed for also
 opens a ``jax.profiler.TraceAnnotation(name, **args)``, so while

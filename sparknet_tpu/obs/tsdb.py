@@ -26,8 +26,9 @@ Design points:
 - **downsampling is exact**, not resampled: every record lands in ALL
   stages at once, so a 60 s bucket's ``sum``/``count``/``min``/``max``
   are the fold of exactly the raw samples in its span — the
-  raw-vs-rollup agreement ``bench.py --mode=slo`` pins is an identity,
-  not an approximation.
+  raw-vs-rollup agreement
+  (``tests/test_tsdb.py::test_all_stages_record_the_same_samples``) is an
+  identity, not an approximation.
 - **queries are served sparse**: empty buckets are skipped, the stage
   is chosen as the finest one that covers the requested range at (or
   above) the requested step, and the response declares the step it

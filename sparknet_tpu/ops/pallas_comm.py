@@ -13,13 +13,12 @@ grid over the worker dim, every leaf of the chunk rides in as its own
 ref (no packing copies), and a static Python loop inside the cell walks
 the leaves — read x/a/r once, write q/scale/residual once.
 
-Numerical contract (pinned by ``tests/test_pallas_comm.py`` and
-``bench.py --mode=kernels``): the fused kernels are BIT-IDENTICAL to
-the unfused closures in interpret mode — same op order per element
+Numerical contract (pinned by ``tests/test_pallas_comm.py``): the fused
+kernels are BIT-IDENTICAL to the unfused closures in interpret mode — same op order per element
 (delta = (x - a) + r; amax/127 int8 grid with rint+clip; bf16 cast;
 err = delta - dequant), so the compress=none/fp32 legs match the
-unfused trainer exactly and the compressed legs inherit COMM_r11's
-pinned loss bands unchanged.
+unfused trainer exactly and the compressed legs inherit
+``comm.LOSS_BAND`` unchanged.
 
 Routing mirrors every other kernel in ``ops/``: native where
 ``pallas_attention.lowerable()`` holds, interpreter mode as the

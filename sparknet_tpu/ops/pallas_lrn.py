@@ -1,7 +1,7 @@
 """Fused cross-channel LRN kernel in Pallas — the AlexNet hot op.
 
-Ablation on the headline bench (bench.py, v5e) put LRN at ~23% of the
-fused AlexNet training step: autodiff through ``reduce_window`` + ``pow``
+An ablation of the fused AlexNet training step (another machine, before
+the ledger) put LRN at ~23% of it: autodiff through ``reduce_window`` + ``pow``
 materializes the squared/summed/scale intermediates in HBM both ways.
 This kernel keeps the whole channel window resident in VMEM per
 (image, spatial-tile) grid cell and writes only ``y`` forward / ``dx``

@@ -401,7 +401,7 @@ def _fast_negpow(s, beta: float):
     small integer (every Caffe model zoo LRN uses beta=0.75): composed from
     sqrt/rsqrt/multiplies, which the TPU VPU executes natively.  LRN is the
     headline AlexNet step's biggest non-matmul cost — pow = exp(log) on a
-    ~75M-element tensor dominated the ablation (see bench.py)."""
+    ~75M-element tensor dominated the step's ablation."""
     q = round(4 * beta)
     if not math.isclose(4 * beta, q) or not 1 <= q <= 8:
         return jnp.power(s, -beta)

@@ -1,9 +1,8 @@
 """Communication-efficient parameter averaging: the comm plane.
 
-SCALING_r05 measured the regime SparkNet's tau exists to amortize: on
-the 2-proc mesh the averaging collective costs 25.4 ms against 7.4 ms
-of local compute per round — the round is bandwidth-bound.  This module
-attacks the wire directly, three ways:
+The regime SparkNet's tau exists to amortize is a round whose averaging
+collective costs more than its local compute.  This module attacks the
+wire directly, three ways:
 
 1. **Delta quantization.**  Workers average bf16/int8-quantized
    *deltas from the round-start broadcast params* (``theta_end -
@@ -51,16 +50,12 @@ representation (int8 = 1 B/elem + one f32 max-abs scale per tensor,
 bf16 = 2 B/elem, fp32 = 4 B/elem).  On the virtual CPU mesh
 collectives are shared-memory copies — the counter models what a
 bandwidth-bound interconnect would carry, which is exactly the
-quantity compression changes; ``bench.py --mode=scaling`` A/Bs the
-wall-clock against a configurable interconnect cost model
-(``SPARKNET_COMM_COST_MS_PER_MB``).
+quantity compression changes.
 """
 
 from __future__ import annotations
 
-import os
 import threading
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -86,8 +81,7 @@ DEFAULT_OVERLAP_STEPS = 1
 # A/B protocol (same seed, same data, cifar10_quick-class model, tens
 # of rounds), the final smoothed loss of a bf16/int8 delta-averaged
 # run must land within this absolute band of the fp32 collective's.
-# Pinned here, proven by ``bench.py --mode=scaling`` (COMM_r11.json:
-# loss_band_ok) and by the tier-1 smoke in tests/test_comm.py.
+# Pinned here, held by tests/test_comm.py.
 LOSS_BAND = 0.08
 
 _ELEM_NBYTES = {"fp32": 4, "none": 4, "bf16": 2, "int8": 1}
@@ -120,13 +114,6 @@ def comm_kwargs_from_args(args) -> Dict[str, object]:
         "compress": getattr(args, "compress", "none"),
         "overlap_avg": bool(getattr(args, "overlap_avg", False)),
     }
-
-
-def _cost_ms_per_mb_default() -> float:
-    try:
-        return float(os.environ.get("SPARKNET_COMM_COST_MS_PER_MB", "0"))
-    except ValueError:
-        return 0.0
 
 
 def _per_worker_nbytes(leaf, mode: str) -> int:
@@ -165,7 +152,6 @@ class CommPlane:
         overlap: bool = False,
         chunks: int = DEFAULT_CHUNKS,
         overlap_steps: int = DEFAULT_OVERLAP_STEPS,
-        cost_ms_per_mb: Optional[float] = None,
         average_stats: bool = True,
         mask_nonfinite: bool = True,
         batch_spec=None,
@@ -193,11 +179,6 @@ class CommPlane:
         self.overlap = bool(overlap)
         self.chunks = max(1, int(chunks))
         self.overlap_steps = max(1, int(overlap_steps))
-        self.cost_ms_per_mb = (
-            _cost_ms_per_mb_default()
-            if cost_ms_per_mb is None
-            else float(cost_ms_per_mb)
-        )
         self.average_stats = bool(average_stats)
         self.audit = bool(getattr(solver, "audit", False))
         self.mask_nonfinite = bool(mask_nonfinite) and self.audit
@@ -602,9 +583,10 @@ class CommPlane:
         comm-plane half of a full-job-state snapshot (``io/checkpoint``
         ``extra_state``).  A resumed run that does NOT restore this
         silently resets the EF bias correction and diverges from the
-        uninterrupted trajectory (measured: ``bench.py --mode=recover``
-        ``--no_journal`` leg).  Call at a round boundary with no
-        in-flight overlapped collective (``finalize()`` first)."""
+        uninterrupted trajectory
+        (``tests/test_recover.py::test_no_journal_resume_diverges``).
+        Call at a round boundary with no in-flight overlapped
+        collective (``finalize()`` first)."""
         if self._resid is None:
             return None
         if self._pending is not None:
@@ -673,10 +655,6 @@ class CommPlane:
         return self._pending is not None
 
     # ------------------------------------------------------------------
-    def _sleep_cost(self, chunk_bytes: int) -> None:
-        if self.cost_ms_per_mb > 0:
-            time.sleep(self.cost_ms_per_mb * (chunk_bytes / (1 << 20)) / 1e3)
-
     def _dispatch_chunks(self, q, scales, alive):
         """Dispatch every chunk's collective from the CALLING thread —
         the device queue executes programs in dispatch order, so the
@@ -702,10 +680,9 @@ class CommPlane:
         return outs, denom0
 
     def _pace_chunks(self, q, outs, denom0, holder) -> None:
-        """Pace the modeled wire over the already-dispatched chunks
-        (comm thread in overlap mode, inline in barriered mode).  Each
-        chunk's span covers the optional interconnect cost-model sleep
-        plus the block on its mean — the span times the wire, not the
+        """Wait for the already-dispatched chunks in order (comm thread
+        in overlap mode, inline in barriered mode).  Each chunk's span
+        covers the block on its mean — the span times the wire, not the
         dispatch."""
         try:
             # the wire cannot carry a delta before it exists: wait for
@@ -718,7 +695,6 @@ class CommPlane:
             means: list = [None] * len(q)
             for sl, m, nbytes in outs:
                 with obs.span("allreduce", chunk=sl.start, nbytes=nbytes):
-                    self._sleep_cost(nbytes)
                     # sparknet: sync-ok(chunk landing: the span times the wire, not the dispatch — comm-thread side of the overlap)
                     jax.block_until_ready(m)
                 means[sl] = list(m)

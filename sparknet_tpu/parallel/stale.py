@@ -61,9 +61,7 @@ for wall-clock) and the trainer executes one fused program per
 boundary in which non-arrived workers' speculative windows are
 discarded in-graph.  The arrival/weight/ledger semantics, the journal
 versioning, and the recovery contract are the real ones; only the
-overlap of straggler compute with the boundary is simulated
-(``bench.py --mode=stale`` measures the wall-clock consequences with
-real sleeps).
+overlap of straggler compute with the boundary is simulated.
 """
 
 from __future__ import annotations
@@ -182,7 +180,6 @@ class BoundedStalenessTrainer:
         stale_bound: int = 0,
         discount: float = 0.5,
         average_stats: bool = True,
-        average_params: bool = True,
         mask_nonfinite: bool = True,
         compress: str = "none",
         overlap_avg: bool = False,
@@ -207,7 +204,6 @@ class BoundedStalenessTrainer:
         self.base = ParameterAveragingTrainer(
             solver, mesh, axis,
             average_stats=average_stats,
-            average_params=average_params,
             mask_nonfinite=mask_nonfinite,
             compress=compress,
             overlap_avg=overlap_avg,
@@ -286,15 +282,9 @@ class BoundedStalenessTrainer:
 
         def finish(params, stats, history, it, losses, astats,
                    keep, bad, swmean, any_arr):
-            avg_params = (
-                tree_map(swmean, params) if average_params else params
-            )
-            avg_stats = (
-                tree_map(swmean, stats)
-                if average_stats and average_params
-                else stats
-            )
-            if mask_nf and average_params:
+            avg_params = tree_map(swmean, params)
+            avg_stats = tree_map(swmean, stats) if average_stats else stats
+            if mask_nf:
                 # an audit-masked arrival adopts the survivor mean but
                 # its momentum still holds the poisoned window — zero
                 # it (the sync round's rejoin contract); absent workers
@@ -627,7 +617,7 @@ class BoundedStalenessTrainer:
         if tm is not None:
             tm.rounds.inc()
             tm.iters.inc(losses.shape[-1])
-            if self.hierarchy is not None and self.base.average_params:
+            if self.hierarchy is not None:
                 tm.hierarchy_rounds.labels(tier).inc()
                 tm.hierarchy_bytes.labels(tier).inc(
                     self.base._payload_bytes(state)

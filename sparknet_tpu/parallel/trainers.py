@@ -220,23 +220,16 @@ class ParameterAveragingTrainer:
         mesh: Mesh,
         axis: str = "dp",
         average_stats: bool = True,
-        average_params: bool = True,
         mask_nonfinite: bool = True,
         compress: str = "none",
         overlap_avg: bool = False,
         comm_chunks: Optional[int] = None,
         overlap_steps: Optional[int] = None,
-        comm_cost_ms_per_mb: Optional[float] = None,
         comm_fused: Optional[bool] = None,
         hierarchy: Optional[HierarchySpec] = None,
         batch_spec=None,
     ):
-        """``average_params=False`` skips the cross-worker pmean — a
-        DIAGNOSTIC mode (workers then train fully independently): the
-        scaling bench A/Bs it against the real round to attribute round
-        time to compute vs collective.
-
-        ``compress``/``overlap_avg`` engage the comm plane
+        """``compress``/``overlap_avg`` engage the comm plane
         (``parallel/comm.py``): delta-quantized (bf16/int8) chunked
         collectives, optionally overlapped with the next round's first
         local steps.  The default (``compress='none'``,
@@ -281,7 +274,6 @@ class ParameterAveragingTrainer:
         self.num_workers = mesh.shape[axis]
         self.audit = bool(getattr(solver, "audit", False))
         self.mask_nonfinite = bool(mask_nonfinite) and self.audit
-        self.average_params = bool(average_params)
         self.average_stats = bool(average_stats)
         # batch pytree partitioning: P(axis) (worker-major, the CNN
         # apps) unless the caller declares per-leaf specs (sequence
@@ -302,7 +294,7 @@ class ParameterAveragingTrainer:
             )
         self.compress = compress
         self._comm = None
-        if (compress != "none" or overlap_avg) and average_params:
+        if compress != "none" or overlap_avg:
             self._comm = _comm.CommPlane(
                 solver, mesh, axis,
                 compress=compress,
@@ -315,13 +307,12 @@ class ParameterAveragingTrainer:
                     _comm.DEFAULT_OVERLAP_STEPS
                     if overlap_steps is None else overlap_steps
                 ),
-                cost_ms_per_mb=comm_cost_ms_per_mb,
                 average_stats=average_stats,
                 mask_nonfinite=mask_nonfinite,
                 batch_spec=batch_spec,
                 # fused Pallas epilogue routing (None = the shared
-                # lowerable() gate; True forces the kernels, the
-                # KERNELS_r21 A/B lever)
+                # lowerable() gate; True forces the kernels, as
+                # tests/test_pallas_comm.py does)
                 fused=comm_fused,
             )
         self._fused_payload_bytes: Optional[int] = None
@@ -390,16 +381,12 @@ class ParameterAveragingTrainer:
                         return jnp.where(denom0 > 0, m, w)
                     return m
 
-                avg_params = (
-                    tree_map(wmean, st.params) if average_params else st.params
-                )
+                avg_params = tree_map(wmean, st.params)
                 avg_stats = (
-                    tree_map(wmean, st.stats)
-                    if average_stats and average_params
-                    else st.stats
+                    tree_map(wmean, st.stats) if average_stats else st.stats
                 )
             history = st.history
-            if mask_nf and average_params:
+            if mask_nf:
                 # the masked slot's params are replaced by the survivor
                 # mean, but its momentum history still holds the
                 # poisoned window — zero it too, or momentum replays the
@@ -511,17 +498,13 @@ class ParameterAveragingTrainer:
                         # worker in this group even on a healthy fleet
                         return jnp.where(denom0 > 0, m, w)
 
-                    avg_params = (
-                        tree_map(smean, st.params)
-                        if average_params else st.params
-                    )
+                    avg_params = tree_map(smean, st.params)
                     avg_stats = (
                         tree_map(smean, st.stats)
-                        if average_stats and average_params
-                        else st.stats
+                        if average_stats else st.stats
                     )
                 history = st.history
-                if mask_nf and average_params:
+                if mask_nf:
                     # audit-masked worker rejoining its slice mean:
                     # zero its momentum (the fused round's contract)
                     rejoined = jnp.logical_and(bad, denom0 > 0)
@@ -760,7 +743,7 @@ class ParameterAveragingTrainer:
                             state, batches, rng, live
                         )
                 tm = obs.training_metrics()
-                if tm is not None and self.average_params:
+                if tm is not None:
                     tm.collective_bytes.labels("none").inc(
                         self._payload_bytes(state)
                     )
@@ -785,7 +768,7 @@ class ParameterAveragingTrainer:
                             state, batches, rng, live
                         )
                 tm = obs.training_metrics()
-                if tm is not None and self.average_params:
+                if tm is not None:
                     # the fused fp32 collective's modeled wire bytes
                     # (ring factor x params+stats payload) — computed
                     # once, charged per round
@@ -795,13 +778,10 @@ class ParameterAveragingTrainer:
             # tier-split byte/round accounting for hierarchy runs: the
             # intra series models the ICI (in-slice) fabric, the cross
             # series the DCN — the quantity the two-tier schedule
-            # divides by K (bench.py --mode=elastic pins the ratio)
+            # divides by K
+            # (tests/test_membership.py::test_hierarchy_tier_metrics_charged)
             tm = obs.training_metrics()
-            if (
-                tm is not None
-                and self.hierarchy is not None
-                and self.average_params
-            ):
+            if tm is not None and self.hierarchy is not None:
                 tier = "intra" if intra else "cross"
                 payload = self._payload_bytes(state)
                 if not intra and self._comm is not None:
@@ -863,8 +843,7 @@ class ParameterAveragingTrainer:
             payload = self._comm.payload_bytes_per_round or None
             compress = self._comm.compress
         else:
-            if self.average_params:
-                self._payload_bytes(state)
+            self._payload_bytes(state)
             payload = self._fused_payload_bytes
             compress = "none"
         prof.note_round_work(

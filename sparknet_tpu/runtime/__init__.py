@@ -6,8 +6,7 @@ DataReader's reader thread and DataTransformer.  The library is built
 from the tracked sources at first use (``make -C native``; make's
 timestamp rule replaces one older than ``runtime.cpp``).  A pure-Python
 fallback keeps the record DB and pipeline working where it cannot be
-built; ``native_available()`` reports which path is active and
-``require_native()`` is for paths that measure the native one.
+built; ``native_available()`` reports which path is active.
 """
 
 from __future__ import annotations
@@ -113,17 +112,6 @@ def build() -> bool:
 
 def native_available() -> bool:
     return _load() is not None
-
-
-def require_native() -> None:
-    """Raise unless the native library is active — for paths that measure
-    it, where the Python fallback would be a different thing timed under
-    the same name."""
-    if _load() is None:
-        raise RuntimeError(
-            "native runtime unavailable (make -C native failed): "
-            f"{_lib_error}"
-        )
 
 
 def _err(lib) -> str:

@@ -57,10 +57,10 @@ real TPU pod into a small cifar10_quick run on the virtual mesh —
   connection.
 
 Every fault is counted as injected and (when the run recovers) survived;
-``bench.py --mode=chaos`` emits the ``CHAOS_r07.json`` artifact
-(faults_injected, faults_survived, recovery latency, loss-band check
-against the no-fault baseline) and the tier-1 chaos smoke
-(``tests/test_chaos.py``) runs the same default plan.
+``run_chaos`` returns the report (faults_injected, faults_survived,
+recovery latency, loss-band check against the no-fault baseline) and the
+tier-1 chaos smoke (``tests/test_chaos.py::test_chaos_smoke_default_plan``)
+runs the default plan and holds every fault class to it.
 """
 
 from __future__ import annotations
@@ -211,8 +211,8 @@ class FaultPlan:
     # resume rewound to the last committed boundary, re-executed at
     # most ONE round, and the final state digest is BIT-IDENTICAL to
     # an uninterrupted control.  (The in-process stand-in for the
-    # SIGKILL sweep; the real kill-anywhere proof is ``bench.py
-    # --mode=recover`` / RECOVER_r17.)
+    # SIGKILL sweep, ``run_kill_sweep`` below; every kill point runs in
+    # process in ``tests/test_recover.py``.)
     driver_kill_round: Optional[int] = 5
     # slow_slice: at the END of this round, a bounded A/B sub-scenario
     # (parallel/stale.py): one whole slice of a two-tier job runs
@@ -940,7 +940,8 @@ def run_kill_sweep(
     timeout_s: float = 900.0,
     echo=None,
 ) -> Dict:
-    """The kill-anywhere chaos sweep (``bench.py --mode=recover``):
+    """The kill-anywhere chaos sweep
+    (``tests/test_recover.py::test_subprocess_kill_sweep_smoke``):
     for every phase boundary of the journaled driver loop
     (``runtime/recover.py``), a REAL ``SIGKILL`` is delivered at that
     exact point of a subprocess run, the process is relaunched with

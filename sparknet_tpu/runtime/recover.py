@@ -584,8 +584,7 @@ def main(argv=None) -> int:
     p.add_argument("--fsync", default="commit")
     args = p.parse_args(argv)
 
-    # the virtual mesh must exist before any backend use (same rule as
-    # bench.py's multi-device modes)
+    # the virtual mesh must exist before any backend use
     from sparknet_tpu.utils.devices import (
         enable_compile_cache,
         ensure_devices,

@@ -28,7 +28,8 @@ Contracts:
   runs the new one.  ``ReplicaPool.promote`` builds + warms one fresh
   engine per replica OFF the serving path (no jit-cache churn where
   requests run) and swaps them in — zero dropped in-flight requests
-  across a promote (tested, and pinned in ``DELIVERY_r15.json``).
+  across a promote
+  (``tests/test_serve_fleet.py::test_inflight_requests_survive_promote``).
 - **Canary mirroring.**  With a canary installed (``serve/delivery.py``)
   the router duplicates every k-th request to the canary engine from a
   dedicated mirror thread: the client is always answered by an

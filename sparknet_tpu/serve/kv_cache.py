@@ -14,7 +14,8 @@ of the batcher's ``QueueFull``, i.e. the same HTTP 429 load-shedding
 contract — never a mid-stream OOM.  Because reservation is worst-case
 and release is all-at-once (finish/evict), the accounting is exact by
 construction: ``allocated_total == freed_total`` whenever the engine is
-drained, and ``bench.py --mode=genserve`` pins exactly that.
+drained (``tests/test_generate.py`` holds every engine it drains to that,
+the admission storm included).
 
 Block 0 is the TRASH block: it is never handed to a sequence, and the
 engine points every inactive decode slot's index row at it so the fixed

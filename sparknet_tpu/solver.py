@@ -404,7 +404,7 @@ class Solver:
     def step_repeat(self, state: TrainState, batch, tau: int, rng=None):
         """Run ``tau`` iterations on the SAME device-resident batch inside
         one jitted program.  One dispatch for the whole window — use for
-        throughput measurement (bench.py) or single-batch overfit tests."""
+        probes (``tools/perf_probe.py``) or single-batch overfit tests."""
         rng = rng if rng is not None else default_train_key(0)
         if not hasattr(self, "_jit_step_repeat"):
             self._jit_step_repeat = jax.jit(

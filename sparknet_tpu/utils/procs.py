@@ -104,128 +104,16 @@ def toy_averaging_worker(marker: str) -> str:
     return _TOY_AVERAGING_WORKER.replace("@MARKER@", marker)
 
 
-# Timed variant: measures the pmean(θ) collective's wall-clock share of
-# an averaging round ACROSS A REAL PROCESS BOUNDARY (jax.distributed over
-# loopback TCP) via the same average_params=True/False A/B bench_scaling
-# uses on the virtual mesh.  Model sized so θ is ~0.5 MB — big enough
-# for the collective to be measurable, small enough for CPU workers.
-_TIMED_AVERAGING_WORKER = r"""
-import sys
-import time
-import numpy as np
-
-pid, port = int(sys.argv[1]), sys.argv[2]
-
-import jax
-
-from sparknet_tpu import config
-from sparknet_tpu.parallel import ParameterAveragingTrainer
-from sparknet_tpu.parallel.mesh import initialize_distributed, make_mesh
-from sparknet_tpu.solver import Solver
-
-initialize_distributed(
-    coordinator_address=f"127.0.0.1:{port}", num_processes=2, process_id=pid
-)
-
-# fleet-plane wiring: with SPARKNET_SHIP_TO set (tools/launch.py
-# --fleet_collector, or the e2e fleet test) each worker ships its
-# metric deltas + round spans to the one collector
-import os as _os
-
-_run_obs = None
-if _os.environ.get("SPARKNET_SHIP_TO"):
-    from sparknet_tpu import obs as _obs
-
-    _run_obs = _obs.start(
-        ship_to=_os.environ["SPARKNET_SHIP_TO"],
-        host_id=_os.environ.get("SPARKNET_HOST_ID", f"proc{pid}"),
-        echo=None,
-    )
-
-NET = '''
-name: "timed"
-layer { name: "data" type: "HostData" top: "x" top: "label"
-  java_data_param { shape { dim: 16 dim: 256 } shape { dim: 16 } } }
-layer { name: "ip1" type: "InnerProduct" bottom: "x" top: "h"
-  inner_product_param { num_output: 256 weight_filler { type: "xavier" } } }
-layer { name: "relu1" type: "ReLU" bottom: "h" top: "h" }
-layer { name: "ip2" type: "InnerProduct" bottom: "h" top: "logits"
-  inner_product_param { num_output: 128 weight_filler { type: "xavier" } } }
-layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
-  bottom: "label" top: "loss" }
-'''
-sp = config.parse_solver_prototxt(
-    'base_lr: 0.01 lr_policy: "fixed" momentum: 0.9'
-)
-mesh = make_mesh({"dp": 4})
-TAU, ROUNDS = 10, 10
-
-rng = np.random.RandomState(0)
-from jax.sharding import NamedSharding, PartitionSpec as P
-sharding = NamedSharding(mesh, P("dp"))
-full = {
-    "x": rng.randn(4, TAU, 16, 256).astype(np.float32),
-    "label": rng.randint(0, 128, (4, TAU, 16)).astype(np.float32),
-}
-# the round DONATES its batch argument (the consumed buffers are
-# recycled on device), so a placed batch is single-use: re-place per
-# round.  The placement cost is identical in both A/B legs, so the
-# avg-minus-local subtraction still isolates the collective.
-def make_batches():
-    return {
-        k: jax.make_array_from_callback(
-            v.shape, sharding, lambda idx, v=v: v[idx]
-        )
-        for k, v in full.items()
-    }
-
-
-def timed(average_params):
-    solver = Solver(sp, net_param=config.parse_net_prototxt(NET))
-    trainer = ParameterAveragingTrainer(
-        solver, mesh, average_params=average_params
-    )
-    state = trainer.init_state(seed=0)
-    state, losses = trainer.round(state, make_batches())  # compile + warm
-    # sparknet: sync-ok(A/B timing harness: the sync closes the clock, identical in both legs)
-    jax.block_until_ready(losses)
-    t0 = time.perf_counter()
-    for _ in range(ROUNDS):
-        state, losses = trainer.round(state, make_batches())
-    # sparknet: sync-ok(A/B timing harness: the sync closes the clock, identical in both legs)
-    jax.block_until_ready(losses)
-    return (time.perf_counter() - t0) / ROUNDS
-
-
-avg = timed(True)
-local = timed(False)
-coll_ms = max(0.0, (avg - local) * 1e3)
-if _run_obs is not None:
-    _run_obs.close()  # final flush ships the run's tail
-print(
-    f"@MARKER@ p{pid} avg_ms={avg * 1e3:.3f} local_ms={local * 1e3:.3f} "
-    f"collective_ms={coll_ms:.3f} tau={TAU}"
-)
-"""
-
-
-def timed_averaging_worker(marker: str) -> str:
-    return _TIMED_AVERAGING_WORKER.replace("@MARKER@", marker)
-
-
 # Fleet-shipping worker: a real single-device training loop (tiny
 # InnerProduct net, per-round ``execute`` spans carrying the absolute
 # round) that ships its metric deltas + run-log events to the collector
 # named by SPARKNET_SHIP_TO — the per-process half of the fleet e2e
-# proof (tests/test_fleet.py) and of ``bench.py --mode=fleet``.  Env
-# knobs (all optional) shape the fleet scenario WITHOUT touching the
-# harness: SPARKNET_FLEET_ROUNDS / _ROUND_S (clock-paced rounds),
-# _STRAGGLE_FROM + _STRAGGLE_S (a slow host: extra per-round sleep from
-# an absolute round on), _LINGER_S (keep the shipper heartbeating after
-# the loop so a peer's lag verdict can be observed against a live
-# fleet), SPARKNET_SHIP_CLOCK_SKEW_S (a skewed host clock the
-# collector's alignment must recover).  Needs no cross-process
-# collectives, so it runs on any CPU jax build.
+# proof (tests/test_fleet.py, tests/test_membership.py).  Env knobs (all
+# optional) shape the fleet scenario WITHOUT touching the harness:
+# SPARKNET_FLEET_ROUNDS / _ROUND_S (clock-paced rounds), _LINGER_S (keep
+# the shipper heartbeating after the loop so a peer's lag verdict can be
+# observed against a live fleet).  Needs no cross-process collectives, so
+# it runs on any CPU jax build.
 _FLEET_SHIP_WORKER = r"""
 import os
 import sys
@@ -250,8 +138,6 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits"
 
 rounds = int(os.environ.get("SPARKNET_FLEET_ROUNDS", "5"))
 round_s = float(os.environ.get("SPARKNET_FLEET_ROUND_S", "0.02"))
-straggle_from = int(os.environ.get("SPARKNET_FLEET_STRAGGLE_FROM", "-1"))
-straggle_s = float(os.environ.get("SPARKNET_FLEET_STRAGGLE_S", "0"))
 linger_s = float(os.environ.get("SPARKNET_FLEET_LINGER_S", "0"))
 
 run = obs.start(
@@ -278,7 +164,7 @@ for r in range(rounds):
     with obs.span("execute", round=r):
         state, losses = solver.step(state, window())
     run.shipper.note_round(r)
-    time.sleep(round_s + (straggle_s if 0 <= straggle_from <= r else 0.0))
+    time.sleep(round_s)
 print(f"@MARKER@ p{pid} rounds={rounds} loss={solver.smoothed_loss:.4f}")
 sys.stdout.flush()
 if linger_s:

@@ -41,9 +41,9 @@ def default_train_key(seed: int = 0) -> jax.Array:
     """``train_key`` for the hot-loop *default-rng* paths
     (``trainer.round(..., rng=None)`` every round): the key is cached
     per (seed, impl), so the per-round scalar host->device transfer a
-    fresh ``jax.random.key`` pays disappears — ``bench.py
-    --mode=sanitize`` runs the round loop under
-    ``jax.transfer_guard("disallow")`` and a fresh key per round is
+    fresh ``jax.random.key`` pays disappears —
+    ``tests/test_parallel.py`` runs the round loops under
+    ``jax_transfer_guard`` at ``disallow`` and a fresh key per round is
     exactly the class of silent implicit transfer it exists to catch.
     (Keys are never consumed in place — reusing the cached array is
     semantically identical to rebuilding it.)"""

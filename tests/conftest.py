@@ -25,7 +25,7 @@ os.environ.setdefault(
 
 # repo-hygiene baseline, captured BEFORE any test runs: tier-1 must not
 # add training_log_*.txt at the repo root (the PR-4 tmpdir-routing
-# regression guard in test_bench_smoke.py compares against this set)
+# regression guard in test_io_and_utils.py compares against this set)
 import glob as _glob  # noqa: E402
 
 REPO_ROOT_TRAINING_LOGS = frozenset(

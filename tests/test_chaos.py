@@ -4,8 +4,7 @@ a producer stall through the prefetch watchdog, a real SIGHUP
 preemption with simulated process death, newest-snapshot corruption
 quarantined + fallback restore, and a dead dp worker masked out of the
 average — and requires every injected fault survived plus a final loss
-inside the no-fault baseline's band (the acceptance bar for
-``CHAOS_r17.json``)."""
+inside the no-fault baseline's band."""
 
 import dataclasses
 import os

@@ -12,7 +12,7 @@ Key contracts:
   consensus, and its error-feedback residual resets on rejoin
   (mirroring the momentum-zeroing rejoin contract),
 - the int8 leg's final loss lands inside the pinned band
-  (``comm.LOSS_BAND`` — the COMM_r11 acceptance, run in-process here).
+  (``comm.LOSS_BAND``).
 """
 
 import numpy as np
@@ -212,6 +212,24 @@ def test_audit_masked_worker_momentum_and_residual_zeroed():
     assert all((r[2] == 0).all() for r in res)
 
 
+@pytest.mark.parametrize("compress", ["bf16", "int8"])
+def test_audit_is_a_pure_readout_under_compression(compress):
+    """``--health`` beside ``--compress``: the comm plane's audited local
+    program only reads, so the audited trajectory (params, per-worker
+    momentum, iteration) is BITWISE the unaudited one, and a healthy
+    fleet masks nobody."""
+    mesh = _mesh(4)
+    data = _data(4, 3, seed=5)
+    _, off, _ = _run_rounds(mesh, data, compress=compress)
+    _, on, out = _run_rounds(mesh, data, audit=True, compress=compress)
+    la = jax.tree_util.tree_leaves(off)
+    lb = jax.tree_util.tree_leaves(on)
+    assert len(la) == len(lb)
+    for a, b in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert not np.asarray(out[2]["masked"]).any()
+
+
 def test_overlap_degrades_to_barriered_on_masked_round():
     """An overlapped round with a dead worker falls back to the strict
     barriered apply (identical result, nothing left in flight)."""
@@ -292,7 +310,7 @@ def test_collective_bytes_counter_ratios():
     ring-model payload; bf16 charges exactly 2x less and int8 ~4x less
     — minus the per-tensor f32 scale int8 honestly carries, which is
     VISIBLE on this toy model's tiny tensors (and negligible at
-    cifar10_quick scale, where COMM_r11 pins the >=4x).  The charged
+    cifar10_quick scale).  The charged
     value must equal the comm plane's own payload model exactly."""
     mesh = _mesh(2)
     data = _data(2, 2, seed=3)
@@ -445,8 +463,7 @@ def _quick_trainer(batch, workers, audit=False, **kw):
 def test_int8_final_loss_inside_pinned_band(tmp_path):
     """Tier-1 acceptance smoke: on the cifar10_quick protocol the int8
     delta-averaged leg's final smoothed loss lands inside the pinned
-    band (comm.LOSS_BAND) of the fp32 fused collective — the same
-    contract COMM_r11.json pins at bench scale."""
+    band (comm.LOSS_BAND) of the fp32 fused collective."""
     from sparknet_tpu.data import CifarLoader
 
     workers, tau, batch, rounds = 2, 2, 8, 5

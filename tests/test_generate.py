@@ -394,7 +394,6 @@ def test_router_stream_resume_after_replica_kill(lm):
         assert rep.engine.pool.used() == 0
 
 
-@pytest.mark.slow
 def test_stream_delivery_promote_and_rollback(lm, tmp_path):
     """The full gauntlet on streams: a good publish (same weights)
     promotes with a token-identical probe and zero stream errors; a

@@ -669,3 +669,92 @@ def test_audit_bit_identity_and_in_graph_mask():
     assert nf.tolist() == [0, 0]
     for leaf in jax.tree_util.tree_leaves(st_rj):
         assert np.isfinite(np.asarray(leaf)).all()
+
+
+def test_all_worker_nan_is_detected_in_its_round_and_rolled_back(tmp_path):
+    """The sentry across the live loop: the chaos feed poisons EVERY dp
+    worker's batch at one round (so the in-graph mask cannot absorb it),
+    the ``rollback`` policy restores the newest verified snapshot and the
+    run goes on inside the no-fault run's loss band, and the flight
+    bundle dumped at the rollback folds to a report that names the
+    round."""
+    import dataclasses
+
+    import jax
+
+    from sparknet_tpu import config as cfg, models
+    from sparknet_tpu.data import CifarLoader
+    from sparknet_tpu.io import checkpoint
+    from sparknet_tpu.obs.health import make_restore_fn
+    from sparknet_tpu.parallel import (
+        ParameterAveragingTrainer,
+        first_worker,
+        make_mesh,
+    )
+    from sparknet_tpu.runtime import chaos
+    from sparknet_tpu.solver import Solver
+
+    workers, tau, batch, rounds, nan_round = 2, 1, 4, 6, 3
+    data_dir = str(tmp_path / "data")
+    CifarLoader.write_synthetic(data_dir, num_train=64, num_test=8, seed=10)
+    xs, ys = CifarLoader(data_dir).minibatches(batch, train=True)
+    netp = cfg.replace_data_layers(
+        models.load_model("cifar10_quick"),
+        [(batch, 3, 32, 32), (batch,)],
+        [(batch, 3, 32, 32), (batch,)],
+    )
+    mesh = make_mesh({"dp": workers}, devices=jax.devices()[:workers])
+    solver = Solver(
+        models.load_model_solver("cifar10_quick"), net_param=netp, audit=True
+    )
+    trainer = ParameterAveragingTrainer(solver, mesh)
+    plan = dataclasses.replace(
+        chaos.FaultPlan.default(),
+        seed=10, workers=workers, rounds=rounds, tau=tau, batch=batch,
+        storage_faults=(), stall_rounds=(), preempt_round=None,
+        corrupt_newest=False, dead_worker=None, straggler_round=None,
+        nan_round=nan_round, nan_workers=tuple(range(workers)),
+    )
+
+    def run(p, sentry, prefix):
+        counters = dict.fromkeys(
+            ("storage_injected", "storage_survived", "stalls_injected",
+             "stalls_survived"), 0,
+        )
+        sentry.restore_fn = make_restore_fn(solver, prefix, trainer=trainer)
+        feed = chaos._Feed(p, xs, ys, counters, [], mesh)
+        state = trainer.init_state(seed=0)
+        try:
+            for r in range(p.rounds):
+                state, losses = sentry.guarded_round(
+                    trainer, state, feed.next_round(r), round_index=r
+                )
+                if (r + 1) % 2 == 0:
+                    checkpoint.snapshot(
+                        solver, first_worker(jax.device_get(state)), prefix
+                    )
+        finally:
+            feed.close()
+        return float(np.mean(np.asarray(jax.device_get(losses))))
+
+    clean = HealthSentry(policy="rollback", echo=None)
+    no_fault_loss = run(plan.no_fault_view(), clean, str(tmp_path / "clean"))
+    assert clean.rollbacks == 0 and clean.last_anomaly_round is None
+
+    bundle = str(tmp_path / "flight_postmortem.json")
+    recorder = flight.install(flight.FlightRecorder(path=bundle))
+    sentry = HealthSentry(policy="rollback", echo=None)
+    obs.set_sentry(sentry)
+    try:
+        final_loss = run(plan, sentry, str(tmp_path / "faulted"))
+    finally:
+        flight.uninstall(recorder)
+        obs.set_sentry(None)
+    assert sentry.last_anomaly_round == nan_round
+    assert sentry.rollbacks >= 1
+    assert math.isfinite(final_loss)
+    assert abs(final_loss - no_fault_loss) <= max(0.25, 0.25 * abs(no_fault_loss))
+    hr = _load_health_report()
+    rep = hr.fold(hr.load_records(bundle))
+    assert rep["first_poisoned_round"] == nan_round
+    assert flight.load_bundle(bundle)["reason"].startswith("sentry")

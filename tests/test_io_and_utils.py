@@ -17,6 +17,8 @@ from sparknet_tpu import config
 from sparknet_tpu.io import caffemodel, checkpoint, wire
 from sparknet_tpu.solver import Solver
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 NET = """
 name: "ckpt_net"
 layer { name: "data" type: "HostData" top: "x" top: "label"
@@ -364,3 +366,31 @@ def test_device_timer_syncs_on_given_arrays(monkeypatch):
     t3.start()
     t3.stop()
     assert t3.has_run_at_least_once
+
+
+def test_repo_root_log_hygiene():
+    """Tier-1 runs must not litter the repo root with training_log_*.txt
+    (regression guard for the PR-4 conftest tmpdir routing): the current
+    repo-root log set must equal the session-start baseline, and a
+    default TrainingLog must route into $SPARKNET_LOG_DIR, not the CWD."""
+    import glob
+
+    import conftest
+    from sparknet_tpu.utils import TrainingLog
+
+    assert os.environ.get("SPARKNET_LOG_DIR"), "conftest routing missing"
+    now = frozenset(
+        os.path.basename(p)
+        for p in glob.glob(os.path.join(_REPO, "training_log_*.txt"))
+    )
+    new = now - conftest.REPO_ROOT_TRAINING_LOGS
+    assert not new, f"tests wrote logs into the repo root: {sorted(new)}"
+    log = TrainingLog(tag="hygiene_probe")
+    try:
+        assert os.path.dirname(os.path.abspath(log.path)) == (
+            os.path.abspath(os.environ["SPARKNET_LOG_DIR"])
+        )
+        assert not os.path.abspath(log.path).startswith(_REPO + os.sep)
+    finally:
+        log.close()
+        os.unlink(log.path)

@@ -1,6 +1,6 @@
 """Run journal (``io/journal.py``) + full-job-state snapshots
 (``io/checkpoint.py`` extra_state / journal-guided restore): the
-crash-consistency primitives behind ``bench.py --mode=recover``.
+crash-consistency primitives behind ``runtime/recover.py``.
 
 Unit-level proofs: CRC framing + torn-tail truncation, fsync policy,
 intent/commit reconciliation (exactly-once rules), the jobstate

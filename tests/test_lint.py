@@ -570,7 +570,7 @@ class TestRegistryAudit:
         """The real repo: every emitted sparknet_* metric and span
         literal is canonical AND every canonical name is emitted —
         drift in either direction fails (this is the audit that keeps
-        trace_report/perf_gate/docs and the emitters in one world)."""
+        trace_report/docs and the emitters in one world)."""
         rep = runner.scan_package(_REPO, with_docs=False)
         audit = [f for f in rep.findings if f.checker == "registry-audit"]
         assert not audit, [f.message for f in audit]

@@ -569,3 +569,77 @@ def test_trace_report_reads_tracer_output_both_formats(tmp_path):
     # the CLI entry point renders without error
     assert tr.main([chrome]) == 0
     assert tr.main([jl, "--json"]) == 0
+
+
+def test_obs_traced_run_tier1_smoke(tmp_path):
+    """Tier-1 telemetry smoke (in-process, small): a short traced
+    cifar10_quick run on the virtual mesh produces a Perfetto-loadable
+    trace whose assemble/h2d/execute/average spans exist, nest sanely,
+    and attribute the producer phases to the feed thread."""
+    import jax
+
+    from sparknet_tpu import config as cfg, models, obs
+    from sparknet_tpu.data import CifarLoader, RoundFeed
+    from sparknet_tpu.obs.trace import Tracer
+    from sparknet_tpu.parallel import ParameterAveragingTrainer, make_mesh
+    from sparknet_tpu.solver import Solver
+
+    workers, tau, batch, rounds = 2, 1, 4, 3
+    data_dir = str(tmp_path / "data")
+    CifarLoader.write_synthetic(data_dir, num_train=32, num_test=8, seed=3)
+    xs, ys = CifarLoader(data_dir).minibatches(batch, train=True)
+
+    def window(r):
+        import numpy as np
+
+        data = np.stack([xs[(r * workers + w) % len(xs)] for w in range(workers)])
+        label = np.stack([ys[(r * workers + w) % len(ys)] for w in range(workers)])
+        return {"data": data[:, None], "label": label[:, None]}
+
+    netp = cfg.replace_data_layers(
+        models.load_model("cifar10_quick"),
+        [(batch, 3, 32, 32), (batch,)],
+        [(batch, 3, 32, 32), (batch,)],
+    )
+    solver = Solver(models.load_model_solver("cifar10_quick"), net_param=netp)
+    mesh = make_mesh({"dp": workers}, devices=jax.devices()[:workers])
+    trainer = ParameterAveragingTrainer(solver, mesh)
+    tracer = obs.install_tracer(Tracer())
+    feed = RoundFeed(lambda r, out: window(r), mesh=mesh, num_rounds=rounds)
+    try:
+        state = trainer.init_state(seed=0)
+        for r in range(rounds):
+            state, losses = trainer.round(state, feed.next_round(r))
+        jax.block_until_ready(losses)
+    finally:
+        feed.stop()
+        obs.uninstall_tracer()
+    path = str(tmp_path / "run.trace.json")
+    tracer.save(path)
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    spans = [e for e in events if e.get("ph") == "X"]
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e["name"], []).append(e)
+    for name in ("assemble", "h2d", "execute", "average"):
+        assert len(by_name.get(name, [])) == rounds, (name, by_name.keys())
+    # nesting: every execute sits inside exactly one average span on
+    # the SAME thread; assemble/h2d live on the producer thread
+    for exe in by_name["execute"]:
+        parents = [
+            a for a in by_name["average"]
+            if a["tid"] == exe["tid"]
+            and a["ts"] <= exe["ts"]
+            and exe["ts"] + exe["dur"] <= a["ts"] + a["dur"] + 1.0
+        ]
+        assert len(parents) == 1, exe
+    exec_tids = {e["tid"] for e in by_name["execute"]}
+    feed_tids = {e["tid"] for e in by_name["assemble"] + by_name["h2d"]}
+    assert exec_tids and feed_tids and not (exec_tids & feed_tids)
+    # per-round h2d follows its round's assemble on the producer
+    asm = sorted(by_name["assemble"], key=lambda e: e["ts"])
+    h2d = sorted(by_name["h2d"], key=lambda e: e["ts"])
+    for a, h in zip(asm, h2d):
+        assert a["args"]["round"] == h["args"]["round"]
+        assert a["ts"] + a["dur"] <= h["ts"] + 1.0

@@ -38,14 +38,14 @@ layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits" bottom: "label" to
 """
 
 
-def _solver(batch_dim=8, momentum=0.9):
+def _solver(batch_dim=8, momentum=0.9, **solver_kw):
     sp = config.parse_solver_prototxt(
         f'base_lr: 0.05 lr_policy: "fixed" momentum: {momentum}'
     )
     netp = config.parse_net_prototxt(NET.replace("dim: 8", f"dim: {batch_dim}", 1))
     # fix label dim too
     netp.layer[0].java_data_param.shape[1].dim = [batch_dim]
-    return Solver(sp, net_param=netp)
+    return Solver(sp, net_param=netp, **solver_kw)
 
 
 def _data(n_workers, tau, batch=8, seed=0, identical=False):
@@ -323,8 +323,7 @@ def test_heterogeneous_train_partitions_window_sampling():
 
 
 def test_scaling_sweep_round_invariants():
-    """CI guard for the BENCH_MODE=scaling sweep (SCALING_r03.json): at
-    every dp in 1..8 a round must compile, produce finite losses, and
+    """At every dp in 1..8 a round must compile, produce finite losses, and
     leave all workers' params bitwise identical post-pmean — the
     structural invariants a collective-shape regression would break
     (reference scaling protocol: caffe/docs/multigpu.md:23-27)."""
@@ -413,3 +412,224 @@ def test_tp_policy_actually_partitions_matmuls():
     # and the round still runs + stays finite with tp placement live
     state, losses = trainer.step(state, batches)
     assert np.isfinite(np.asarray(losses)).all()
+
+
+# ---------------------------------------------------------------------------
+# the hot-path sanitizer: steady pipelined rounds under an armed transfer
+# guard, a flat jit cache, no leaked tracer.  The static half is
+# tools/lint.py (tests/test_lint.py); on the CPU the device->host lane is
+# zero-copy and never fires, so that class stays the linter's.
+# ---------------------------------------------------------------------------
+
+
+def _toy_window(n_workers, tau):
+    def window(r, out=None):
+        return _data(n_workers, tau, seed=r)
+
+    return window
+
+
+def _pa_case(n_workers, cls=ParameterAveragingTrainer, solver_kw=None,
+             round_kw=None, **trainer_kw):
+    def build():
+        mesh = make_mesh({"dp": n_workers}, devices=jax.devices()[:n_workers])
+        trainer = cls(_solver(**(solver_kw or {})), mesh, **trainer_kw)
+
+        def run(state, batch, r):
+            kw = round_kw(r) if round_kw else {}
+            return trainer.round(state, batch, round_index=r, **kw)[:2]
+
+        return trainer, dict(mesh=mesh), _toy_window(n_workers, 2), run
+
+    return build
+
+
+def _two_tier_case(**trainer_kw):
+    from sparknet_tpu.parallel.hierarchy import HierarchySpec
+
+    return _pa_case(
+        4, hierarchy=HierarchySpec.grouped(4, 2, 2), **trainer_kw
+    )
+
+
+def _stale_case(bound):
+    from sparknet_tpu.parallel.stale import BoundedStalenessTrainer
+
+    # B > 0: the second worker misses every other boundary, so both the
+    # partial and the full arrival set run inside the guarded window
+    round_kw = (lambda r: {"arrived": [True, r % 2 == 0]}) if bound else None
+    return _pa_case(
+        2, cls=BoundedStalenessTrainer, stale_bound=bound, round_kw=round_kw
+    )
+
+
+def _allreduce_case():
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    trainer = AllReduceTrainer(_solver(batch_dim=16), mesh)
+
+    def window(r, out=None):
+        one = _data(1, 2, batch=16, seed=r)
+        return {k: v[0] for k, v in one.items()}
+
+    def run(state, batch, r):
+        return trainer.step(state, batch)[:2]
+
+    feed_kw = dict(sharding=trainer.batch_sharding)
+    return trainer, feed_kw, window, run
+
+
+def _lm_case(sp):
+    def build():
+        from sparknet_tpu.apps.lm_app import lm_batch_sharding, lm_batch_spec
+        from sparknet_tpu.data import stack_windows
+        from sparknet_tpu.data.text import TextWindowSampler
+        from sparknet_tpu.models.transformer_lm import TransformerLM
+
+        seq, batch, tau, dp = 16, 2, 2, 2
+        lm = TransformerLM(
+            dim=16, depth=1, heads=2, seq_len=seq,
+            sp_axis="sp" if sp > 1 else None, sp_size=sp,
+        )
+        solver_param = config.parse_solver_prototxt(
+            'base_lr: 0.1 lr_policy: "fixed" momentum: 0.9 average_loss: 20'
+        )
+        axes = {"dp": dp, "sp": sp} if sp > 1 else {"dp": dp}
+        mesh = make_mesh(axes, devices=jax.devices()[: dp * sp])
+        trainer = ParameterAveragingTrainer(
+            Solver(solver_param, net=lm), mesh, batch_spec=lm_batch_spec(sp)
+        )
+        docs = [
+            np.random.RandomState(d).randint(0, 256, 400).astype(np.uint8)
+            for d in range(3)
+        ]
+        samplers = [
+            TextWindowSampler(docs, seq, batch, seed=0, worker=w)
+            for w in range(dp)
+        ]
+
+        def window(r, out=None):
+            return stack_windows(
+                [s.window_for_round(r, tau) for s in samplers], out
+            )
+
+        def run(state, batch, r):
+            return trainer.round(state, batch, round_index=r)[:2]
+
+        return trainer, dict(sharding=lm_batch_sharding(mesh, sp)), window, run
+
+    return build
+
+
+# the round programs users run, by the options that select them
+_SANITIZER_CASES = {
+    "sync": _pa_case(2),
+    "audit": _pa_case(2, solver_kw={"audit": True}),
+    "bf16": _pa_case(2, compress="bf16"),
+    "int8": _pa_case(2, compress="int8"),
+    "int8_overlap": _pa_case(2, compress="int8", overlap_avg=True),
+    "two_tier": _two_tier_case(),
+    "two_tier_int8": _two_tier_case(compress="int8"),
+    "stale_b0": _stale_case(0),
+    "stale_b1": _stale_case(1),
+    "allreduce": _allreduce_case,
+    "lm": _lm_case(1),
+    "lm_sp2": _lm_case(2),
+}
+
+
+def _jit_cache_sizes(trainer):
+    """Cache size of every jitted program the trainer holds, by name: its
+    own, its comm plane's, and the synchronous trainer a wrapper delegates
+    to."""
+    base = getattr(trainer, "base", None)
+    owners = (trainer, getattr(trainer, "_comm", None),
+              base, getattr(base, "_comm", None))
+    return {
+        type(owner).__name__ + "." + name: value._cache_size()
+        for owner in owners if owner is not None
+        for name, value in vars(owner).items()
+        if hasattr(value, "_cache_size")
+    }
+
+
+def _guarded_rounds(case, plant=None, warm=2, steady=5):
+    """``warm`` rounds of the pipelined loop (RoundFeed producer + trainer),
+    then ``steady`` rounds with the process-wide transfer guard at
+    ``disallow``.  Returns the jit cache sizes before and after the steady
+    window.  ``plant`` puts the implicit host->device transfer the guard
+    exists to catch into every round: ``"batch"`` hands the round the host
+    window instead of the batch the feed placed, ``"key"`` a key built from
+    the round's number, ``"producer"`` has the feed's thread scale a late
+    window on the device before it is placed."""
+    from sparknet_tpu.data import RoundFeed
+
+    trainer, feed_kw, window, run = case()
+
+    def assemble(r, out=None):
+        host = window(r, out)
+        if plant == "producer" and r >= warm + steady - 2:
+            host = {k: np.asarray(jnp.multiply(v, 1)) for k, v in host.items()}
+        return host
+
+    feed = RoundFeed(assemble, num_rounds=warm + steady, **feed_kw)
+    guard = jax.config.jax_transfer_guard
+    try:
+        state = trainer.init_state(seed=0)
+        for r in range(warm):
+            state, losses = run(state, feed.next_round(r), r)
+        jax.block_until_ready(losses)
+        before = _jit_cache_sizes(trainer)
+        jax.config.update("jax_transfer_guard", "disallow")
+        # the control: the guard is armed, so a clean window below means
+        # "no transfer", not "no guard"
+        with pytest.raises(Exception, match="(?i)transfer"):
+            jnp.sum(np.ones((8,), np.float32)).block_until_ready()
+        for r in range(warm, warm + steady):
+            batch = feed.next_round(r)
+            if plant == "key":
+                state, losses = trainer.round(
+                    state, batch, rng=jax.random.key(r), round_index=r
+                )[:2]
+            else:
+                state, losses = run(
+                    state, window(r) if plant == "batch" else batch, r
+                )
+            jax.block_until_ready(losses)  # the apps' per-round sync
+        return before, _jit_cache_sizes(trainer)
+    finally:
+        jax.config.update("jax_transfer_guard", guard)
+        feed.stop()
+
+
+# A fault found, not repaired here (ROADMAP): with ``overlap_avg`` the comm
+# plane cuts the placed window in two eagerly (``comm.py`` ``x[:, :s]``), and
+# the slice's start indices reach the device as implicit transfers, one per
+# leaf and round.  Its round still has to leak no tracer.
+_TRANSFERS_TODAY = {"int8_overlap"}
+
+
+@pytest.mark.parametrize("name", sorted(set(_SANITIZER_CASES) - _TRANSFERS_TODAY))
+def test_steady_rounds_make_no_implicit_transfer_and_no_recompile(name):
+    before, after = _guarded_rounds(_SANITIZER_CASES[name])
+    assert before == after and sum(before.values()) >= 1, (before, after)
+
+
+@pytest.mark.parametrize("plant", ["batch", "key", "producer"])
+def test_transfer_guard_catches_a_planted_transfer(plant):
+    with pytest.raises(Exception, match="(?i)disallowed host-to-device transfer"):
+        _guarded_rounds(_SANITIZER_CASES["sync"], plant=plant)
+
+
+@pytest.mark.parametrize("name", sorted(_SANITIZER_CASES))
+def test_round_program_leaks_no_tracer(name):
+    # a fresh trainer, so the round is traced inside the checker (a cached
+    # executable would skip tracing and check nothing)
+    with jax.checking_leaks():
+        trainer, feed_kw, window, run = _SANITIZER_CASES[name]()
+        state = trainer.init_state(seed=0)
+        if "mesh" in feed_kw:
+            batch = shard_leading(window(0), feed_kw["mesh"])
+        else:
+            batch = jax.device_put(window(0), feed_kw["sharding"])
+        state, losses = run(state, batch, 0)
+        jax.block_until_ready(losses)

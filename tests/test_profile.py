@@ -327,8 +327,7 @@ def test_obs_start_wires_profiler_and_prints_summary(capsys):
 
 def test_profile_out_dumps_summary_json(tmp_path):
     """``--profile_out`` (obs.start(profile_out=...)): the end-of-run
-    RoundProfiler.summary() lands as JSON — the file perf_gate --live
-    folds against the committed baselines.  Implies profiling."""
+    RoundProfiler.summary() lands as JSON.  Implies profiling."""
     import json
 
     out = tmp_path / "anatomy.json"

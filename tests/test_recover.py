@@ -5,8 +5,8 @@ AllReduceTrainer resume bit-equivalence, and the async-checkpointer
 preemption drain (SIGTERM flush; SIGKILL mid-write never loses the
 previous snapshot).
 
-The real-SIGKILL sweep lives in ``bench.py --mode=recover``
-(RECOVER_r17.json) and the ``@slow`` subprocess smoke below."""
+The real-SIGKILL sweep (``chaos.run_kill_sweep``) is the ``@slow``
+subprocess smoke below."""
 
 import os
 import signal
@@ -140,6 +140,44 @@ def test_kill_before_round_executes_replays_nothing(ctx, control):
     assert rec["start_round"] == 1
     assert rec["rounds_executed"] == [1, 2]
     assert rec["final_digest"] == control["final_digest"]
+
+
+@pytest.mark.parametrize("phase", ["h2d", "average"])
+def test_kill_at_the_other_phase_boundaries_resumes_bit_identical(
+    ctx, control, phase
+):
+    """The kill points the named cases above leave: the batch placed but
+    the round not run, and the round averaged but its boundary not yet
+    durable.  Both replay exactly the round that was in flight."""
+    rec = _crash_then_resume(ctx, (phase, 1), "kill_" + phase)
+    assert rec["start_round"] == 1
+    assert rec["rounds_executed"] == [1, 2]
+    assert rec["final_digest"] == control["final_digest"]
+
+
+def test_kill_at_stale_boundary_replays_within_the_bound(tmp_path):
+    """Bounded staleness: the arrival set folded and the ledger advanced
+    in memory, but neither snapshot nor commit landed.  The resume
+    replays the boundary from the journaled worker-round vector, at most
+    ``stale_bound`` rounds, to the uninterrupted run's digest."""
+    bound, rounds, kill_round = 2, 4, 2
+    sctx = recover.RecoverContext(
+        str(tmp_path), workers=2, tau=1, batch=8, stale_bound=bound
+    )
+    control = recover.run_driver(
+        sctx, rounds, run_dir=str(tmp_path / "control")
+    )
+    d = str(tmp_path / "kill_stale")
+    with pytest.raises(recover.SimulatedKill):
+        recover.run_driver(
+            sctx, rounds, kill_at=("stale_boundary", kill_round), kill=_boom,
+            run_dir=d,
+        )
+    rec = recover.run_driver(sctx, rounds, resume=True, run_dir=d)
+    assert rec["final_digest"] == control["final_digest"]
+    replayed = [r for r in rec["rounds_executed"] if r <= kill_round]
+    assert 1 <= len(replayed) <= bound
+    assert rec["rounds_executed"][-1] == rounds - 1
 
 
 def test_no_journal_resume_diverges(ctx, control):
@@ -337,7 +375,7 @@ print("UNREACHABLE", flush=True)
 
 # ---------------------------------------------------------------------------
 # the real-SIGKILL sweep, one point (tier-1 runs the in-process legs
-# above; the full sweep is bench.py --mode=recover / RECOVER_r17.json)
+# above)
 
 
 @pytest.mark.slow

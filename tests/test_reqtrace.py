@@ -240,6 +240,93 @@ def test_slow_replica_named_from_synthetic_skew():
     assert set(s["replicas"]) == {"0", "1"}
 
 
+def test_live_kv_squeeze_is_read_kv_bound(lm):
+    """The verdict across the live loop: a storm against a small arena
+    behind a queue too large to fill, so every shed is the KV budget's.
+    Time shares alone cannot see a squeeze that sheds instead of queuing;
+    the window must still read ``kv``, with no stream lost, no block left
+    in use and no recompile."""
+    import threading
+
+    eng = GenerationEngine(
+        lm, prefill_buckets=(8,), max_streams=2, kv_blocks=8,
+        kv_block_size=4, seed=0,
+    )
+    pinned = eng.warmup()
+    prof = reqtrace.install(RequestProfiler())
+    sb = StreamBatcher(eng, max_queue=256)
+    tally = {"ok": 0, "shed": 0, "errors": 0}
+    lock = threading.Lock()
+
+    def client(i):
+        for k in range(2):
+            try:
+                # 4 + 12 positions = 4 of the arena's 8 blocks
+                st = sb.submit_stream([1 + i % 5, 7, 3, k + 1], 12)
+            except QueueFull:
+                with lock:
+                    tally["shed"] += 1
+                continue
+            ev = st.result(timeout=120.0)
+            with lock:
+                tally["ok" if ev["event"] == "done" else "errors"] += 1
+
+    threads = [
+        threading.Thread(target=client, args=(i,), daemon=True)
+        for i in range(8)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(120)
+    sb.stop(drain=True, timeout=30.0)
+    summary = prof.summary()
+    assert tally["errors"] == 0 and tally["ok"] >= 1, tally
+    assert tally["shed"] >= 1, tally
+    assert summary["sheds"] == {"kv_reserve": tally["shed"]}
+    assert summary["verdict"] == "kv", summary
+    assert eng.pool.used() == 0
+    assert eng.pool.allocated_total == eng.pool.freed_total > 0
+    assert eng.jit_cache_size() == pinned
+
+
+def test_live_slow_replica_is_named(lm):
+    """Two stream replicas behind the router, the second one's decode
+    step seeded slow: the per-replica skew must name exactly that one."""
+    import time
+
+    from sparknet_tpu.serve import ReplicaPool, Router
+
+    def make_engine(weights=None):
+        return GenerationEngine(
+            lm, prefill_buckets=(8,), max_streams=2, kv_blocks=30,
+            kv_block_size=4, seed=0,
+        )
+
+    pool = ReplicaPool(make_engine, replicas=2, max_queue=16, stream=True)
+    router = Router(pool, max_inflight=16)
+    slow = pool.replicas[1].engine
+    step = slow.step
+
+    def slow_step():
+        time.sleep(0.02)
+        return step()
+
+    slow.step = slow_step
+    prof = reqtrace.install(RequestProfiler())
+    try:
+        for i in range(8):
+            evs = list(router.submit_stream([1 + i % 5, 7, 3], 6, timeout=60.0))
+            assert evs[-1]["event"] == "done", evs[-1]
+        summary = prof.summary()
+    finally:
+        slow.step = step
+        router.close()
+    assert set(summary["replicas"]) == {"0", "1"}, summary
+    assert summary["slow_replica"] == 1, summary
+    assert summary["skew"] >= 1.5
+
+
 # ----------------------------------------------------------------------
 # shed causes: counter labels + exceptions per cause
 def test_shed_cause_labels_on_counter(lm, engine):

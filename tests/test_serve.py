@@ -188,6 +188,46 @@ def test_batcher_demux_matches_single_shot(engine):
         mb.stop()
 
 
+def test_closed_loop_clients_never_recompile(engine):
+    """The serving contract across the live loop: closed-loop client
+    threads firing single-image requests back to back (concurrency, not
+    the client, fills the buckets) leave the jit cache where warm-up put
+    it, every request is answered, and occupancy is a share."""
+    clients, per_client = 6, 8
+    mb = MicroBatcher(engine, max_queue=64, max_wait_ms=2.0)
+    before = engine.jit_cache_size()
+    x = np.random.RandomState(2).randn(3, 8, 8).astype(np.float32)
+    ref = engine.infer(x[None])[0]
+    errors = []
+
+    def client():
+        try:
+            for _ in range(per_client):
+                # whichever bucket the request rode in: same answer up to
+                # the bucket program's float order
+                np.testing.assert_allclose(
+                    mb.submit(x, timeout=60.0)[0], ref, rtol=1e-5, atol=1e-6
+                )
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errors.append(repr(e))
+
+    try:
+        threads = [threading.Thread(target=client) for _ in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not errors, errors[:3]
+        assert engine.jit_cache_size() == before == len(engine.buckets)
+        assert mb.m_images.value == clients * per_client
+        assert mb.m_latency.count == clients * per_client
+        assert 0 < mb.m_occupancy.mean() <= 1.0
+        lat = [mb.m_latency.quantile(q) for q in (0.50, 0.95, 0.99)]
+        assert 0 < lat[0] <= lat[1] <= lat[2]
+    finally:
+        mb.stop()
+
+
 def test_batcher_multi_item_requests(engine):
     mb = MicroBatcher(engine, max_queue=32, max_wait_ms=1.0)
     try:

@@ -519,6 +519,71 @@ def test_delivery_promotes_good_publish(netp_deploy, toy_solver, tmp_path):
         router.close()
 
 
+def test_watcher_promotes_under_client_traffic_dropping_nothing(
+    netp_deploy, toy_solver, tmp_path
+):
+    """The delivery loop as it runs: client threads keep the two-replica
+    fleet busy while the watcher verifies, warms off the serving path,
+    canaries and promotes.  No client sees an error across the swap and
+    the promoted fleet answers bit-identically to a fresh engine loaded
+    from the published snapshot."""
+    import jax
+
+    solver, state = toy_solver
+    state = state._replace(
+        params=jax.tree_util.tree_map(
+            lambda a: a * np.float32(1.01), state.params
+        ),
+        iter=np.asarray(5, np.int32),
+    )
+    paths = publish_snapshot(
+        solver, state, str(tmp_path), {"passing": True, "reason": "ok"}
+    )
+    pool, router = _fleet(netp_deploy, replicas=2, canary_frac=0.5)
+    stop = threading.Event()
+    errors, answered = [], []
+    lock = threading.Lock()
+
+    def client():
+        try:
+            while not stop.is_set():
+                out = router.submit(X, timeout=60.0)
+                with lock:
+                    answered.append(out.shape)
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            with lock:
+                errors.append(repr(e))
+
+    threads = [
+        threading.Thread(target=client, name=f"dl-{i}", daemon=True)
+        for i in range(3)
+    ]
+    try:
+        ctl = DeliveryController(
+            pool, router, str(tmp_path),
+            cache_dir=str(tmp_path / "cache"),
+            decision_requests=4, divergence_max=10.0,
+        )
+        for t in threads:
+            t.start()
+        _drive(ctl, router, lambda: ctl.promotions == 1)
+        served_at_promote = len(answered)
+        _drive(ctl, router, lambda: len(answered) >= served_at_promote + 6)
+        stop.set()
+        for t in threads:
+            t.join(60)
+        assert not errors, errors[:3]
+        assert answered and set(answered) == {(1, 5)}
+        assert pool.incumbent_id == "published_iter_5"
+        assert ctl.rollbacks == 0
+        fresh = InferenceEngine(netp_deploy, weights=paths[0], buckets=(1, 4))
+        fresh.warmup()
+        assert np.array_equal(router.submit(X), fresh.infer(X))
+    finally:
+        stop.set()
+        router.close()
+
+
 # ----------------------------------------------------------------------
 # the fleet /healthz contract
 

@@ -329,7 +329,7 @@ def test_bfloat16_compute_keeps_f32_masters():
 def test_bf16_f32_train_curve_equivalence_cifar():
     """bf16-compute-with-f32-masters must track the f32 loss curve on a real
     zoo model (cifar10_full) over 200 iterations — the evidence behind
-    bench.py's bfloat16 default.  Bound: the tail-window mean losses agree
+    the benchmark cells' bfloat16.  Bound: the tail-window mean losses agree
     within 5% and both runs learn (tail < 80% of head)."""
     import tempfile
 

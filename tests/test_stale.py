@@ -111,15 +111,15 @@ def test_constructor_validation():
 # the degenerate-path pin: B = 0 is sync averaging, bitwise
 
 
-@pytest.mark.parametrize("hier", [None, "two_tier"])
+@pytest.mark.parametrize("hier", [None, "flat", "two_tier"])
 def test_b0_bit_identical_to_sync(hier):
     n, tau = 4, 3
     mesh = make_mesh({"dp": n}, devices=jax.devices()[:n])
-    spec = (
-        HierarchySpec.grouped(n, 2, cross_slice_every=2)
-        if hier == "two_tier"
-        else None
-    )
+    spec = {
+        None: None,
+        "flat": HierarchySpec.flat(n),
+        "two_tier": HierarchySpec.grouped(n, 2, cross_slice_every=2),
+    }[hier]
     sync = ParameterAveragingTrainer(_solver(), mesh, hierarchy=spec)
     stale = BoundedStalenessTrainer(
         _solver(), mesh, stale_bound=0, hierarchy=spec

@@ -1,5 +1,6 @@
 """Hot-path invariant linter CLI — the static half of the sanitizer
-gate (``bench.py --mode=sanitize`` is the dynamic half).
+gate (the dynamic half is ``tests/test_parallel.py``'s steady rounds
+under an armed transfer guard).
 
 Runs the ``sparknet_tpu/analysis`` checkers over the package:
 sync-in-hot-path, donation discipline, thread hygiene (incl. lock

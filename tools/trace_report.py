@@ -254,7 +254,7 @@ def fold(events: List[dict]) -> Dict[str, object]:
             })
     rep["stragglers"] = stragglers
     rep.update(_hidden_fraction(by_name))
-    # back-compat boolean (OBS_r09 schema): derived from the measured
+    # back-compat boolean: derived from the measured
     # fraction instead of a separate any-overlap scan
     hf = rep["producer_hidden_fraction"]
     rep["producer_overlap_observed"] = bool(hf is not None and hf > 0)
