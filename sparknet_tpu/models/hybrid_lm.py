@@ -25,8 +25,9 @@ Solver protocol as ``TransformerLM``: ``init`` / ``loss_fn`` /
 (``layers`` + ``_blob_refs``).  Every sublayer runs under one
 ``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md "Telemetry
 reference") with a ``jax.checkpoint`` INSIDE the scope: between sublayers
-only the residual stream and the normed input are kept, each sublayer's
-forward is recomputed in its backward, and autodiff names both
+only the residual stream and the normed input are kept (and, of an
+attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
+sublayer's forward is recomputed in its backward, and autodiff names both
 ``transpose(jvp(<Type>:<name>))``, i.e. backward.
 
 Layout of ``in_proj_qkvz`` / ``in_proj_ba`` columns: ``[q | k | v | z]`` and
@@ -47,8 +48,13 @@ from sparknet_tpu.models.transformer_lm import _Group, _Ref
 from sparknet_tpu.ops import moe
 from sparknet_tpu.ops.attention import causal_gqa_attention
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
+from sparknet_tpu.ops.pallas_attention import SAVED as FLASH_SAVED
 
 F32 = jnp.float32
+# a mixer's recomputation keeps what the flash kernels name, their output and
+# its row log-sum-exp (269 MB an attention layer at 2 x 8,192 tokens), and so
+# does not run the forward kernel a second time; nothing else is kept
+MIXER_KEEPS = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
 
 # the keys of config.json that decide a shape or an equation
 CONFIG_KEYS = (
@@ -219,8 +225,13 @@ class HybridMoELM:
         q = rotary(rms_norm0(q, q_norm, eps), theta, rotary_dim)
         k = rotary(rms_norm0(k, k_norm, eps), theta, rotary_dim)
         attn = causal_gqa_attention(q, k, v, compute_dtype=self.compute_dtype)
-        attn = attn * jax.nn.sigmoid(gate.astype(F32))
-        return self._dot(attn.reshape(b, t, hq * d), o_proj, F32)
+        # gated with heads side by side, (B, T, Hq D), as the kernels write
+        # the output and o_proj reads it: heads apart, (.., Hq, D) tiles
+        # otherwise, and the float32 output and its cotangent are each
+        # copied from one tiling to the other (0.8 ms each on the v5e)
+        attn = attn.reshape(b, t, hq * d) * jax.nn.sigmoid(
+            gate.reshape(b, t, hq * d).astype(F32))
+        return self._dot(attn, o_proj, F32)
 
     def _gated_delta_net(self, x, blobs):
         in_qkvz, in_ba, conv, a_log, dt_bias, norm, out_proj = blobs
@@ -287,7 +298,8 @@ class HybridMoELM:
         kind = "GatedAttention" if attention else "GatedDeltaNet"
         mixer = self._gated_attention if attention else self._gated_delta_net
         with jax.named_scope(f"{kind}:l{i}_mixer"):
-            h = x + jax.checkpoint(mixer)(normed, params[f"l{i}_mixer"])
+            h = x + jax.checkpoint(mixer, policy=MIXER_KEEPS)(
+                normed, params[f"l{i}_mixer"])
         b, t, e = h.shape
         with jax.named_scope(f"RMSNorm:l{i}_n2"):
             normed = rms_norm0(h, params[f"l{i}_n2"][0], eps).astype(cd)

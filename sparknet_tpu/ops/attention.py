@@ -20,6 +20,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from sparknet_tpu import obs
 from sparknet_tpu.config.schema import AttentionParameter, FillerParameter
 from sparknet_tpu.ops.base import BlobDef, Layer, register
 
@@ -142,24 +143,89 @@ class Attention(Layer):
         return [y], None
 
 
+# The kernels' blocks: a MiB of queries (a K/V head's group of heads, stacked
+# as rows: 2,048 rows of 256 in bfloat16) against KERNEL_BLOCK_K keys.  On the
+# v5e at T = 8,192, 16 / 2 heads of 256, forward + backward: 35.5 ms; twice
+# the rows 34.6 ms and more than twice the compile time, twice the keys 36.2
+# (PERF.md section 6, PR 30).
+KERNEL_Q_BYTES = 1 << 20
+KERNEL_BLOCK_K = 512
+
+
+def lowerable() -> bool:
+    """``pallas_attention.lowerable``, imported when asked: ``ops/`` imports
+    this module for every net, and Pallas takes 0.9 s that the image nets'
+    set-up has no use for."""
+    from sparknet_tpu.ops import pallas_attention
+
+    return pallas_attention.lowerable()
+
+
 def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
                          compute_dtype=None):
-    """Causal softmax attention with grouped K/V heads, in query blocks.
+    """Causal softmax attention with grouped K/V heads.
 
     ``q``: ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``, each K/V head
-    serving ``Hq // Hkv`` query heads (never repeated in memory).  The query
-    blocks run one after another (``lax.map`` over ``jax.checkpoint``ed
-    blocks), so no more than ``block_q x T`` scores a head exist at once,
-    forward or backward.  A ``lax.map`` needs one shape for all its blocks:
-    the sequence is cut into ``segments`` runs of blocks, and a run meets
-    only the keys up to its own end, a static slice, so with four runs 5/8
-    of the full score matrix is computed where causality needs 1/2.  Scores
-    and softmax are float32; the two products take their operands in
-    ``compute_dtype``.  Returns ``(B, T, Hq, D)`` float32."""
+    serving ``Hq // Hkv`` query heads (never repeated in memory).  Scores
+    and softmax are float32; the products take their operands in
+    ``compute_dtype``, ``q`` scaled by ``D ** -0.5`` before it is cast.
+    Returns ``(B, T, Hq, D)`` float32.
+
+    Where Pallas lowers and ``pallas_attention.accepts`` the shapes, the
+    K/V-blocked flash kernels (``ops/pallas_attention.py``): no score leaves
+    VMEM, the key blocks above the diagonal are neither computed nor fetched
+    (``(n + 1) / 2n`` of the score matrix at ``n`` key blocks), and the
+    backward is the kernels' own.  Elsewhere ``_blockwise_gqa``, the same
+    arithmetic in XLA, the fallback and the oracle the kernels are tested
+    against; ``block_q`` and ``segments`` are its.  No switch picks between
+    them: an ``obs`` instant names the path at each trace."""
+    from sparknet_tpu.ops import pallas_attention  # see lowerable
+
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    cd = jnp.dtype(compute_dtype or jnp.float32)
+    backend = jax.default_backend()
+    if not lowerable():
+        why = f"no Pallas lowering on {backend}"
+    elif not pallas_attention.accepts(hq, hkv, d, cd):
+        why = "heads of whole lanes in whole groups, bfloat16 or float32"
+    else:
+        why = ""
+    if why:  # a run's query blocks each meet the key blocks up to its end
+        block_k = block_q
+        blocks = -(-t // block_q)
+        ends = [min(lo + -(-blocks // segments), blocks)
+                for lo in range(0, blocks, -(-blocks // segments))]
+        met = (sum(hi * (hi - lo) for lo, hi in zip([0] + ends, ends)),
+               blocks * blocks)
+    else:
+        block_k = min(KERNEL_BLOCK_K, t)
+        rows = KERNEL_Q_BYTES // (hq // hkv * d * cd.itemsize)
+        block_q = min(max(rows, pallas_attention.LANES), block_k)
+        met = pallas_attention.blocks_met(t, t, block_q, block_k)
+    obs.instant("attention_path", cat="kernel",
+                path="xla" if why else "pallas", why=why, backend=backend,
+                t=t, hq=hq, hkv=hkv, d=d, dtype=cd.name, block_q=block_q,
+                block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
+    if why:
+        return _blockwise_gqa(q, k, v, block_q, segments, cd)
+    q = (q.astype(jnp.float32) * d ** -0.5).astype(cd)
+    return pallas_attention.flash_attention(
+        q, k.astype(cd), v.astype(cd), causal=True, block_q=block_q,
+        block_k=block_k, scale=1.0, out_dtype=jnp.float32)
+
+
+def _blockwise_gqa(q, k, v, block_q, segments, cd):
+    """``causal_gqa_attention`` in XLA, in query blocks.  The query blocks
+    run one after another (``lax.map`` over ``jax.checkpoint``ed blocks), so
+    no more than ``block_q x T`` scores a head exist at once, forward or
+    backward.  A ``lax.map`` needs one shape for all its blocks: the sequence
+    is cut into ``segments`` runs of blocks, and a run meets only the keys up
+    to its own end, a static slice, so with four runs 5/8 of the full score
+    matrix is computed where causality needs 1/2."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
-    cd = compute_dtype or jnp.float32
     f32 = jnp.float32
     pad = (-t) % block_q  # padded keys lie after every real query: masked
     if pad:

@@ -1,29 +1,47 @@
 """Fused attention kernels in Pallas — the hot-op custom kernel path.
 
-Forward, per-(batch*head, q-block) grid cell: one MXU matmul Q.K^T,
-masked softmax on the VPU, one MXU matmul P.V — all in VMEM, no HBM
-round-trip for the scores matrix (the thing that makes naive attention
-bandwidth-bound).  K/V live whole in VMEM per cell, which is fine for
-the single-chip sequence lengths this framework targets; beyond that
-the ring path (``parallel.ring_attention``) shards the sequence first
-and each shard's local attention goes through this kernel.
+One family of three K/V-blocked flash kernels (forward, dq, dk/dv) behind one
+``jax.custom_vjp`` (``_flash_core``) serves the dense path
+(``flash_attention``: ``models/transformer_lm.py`` and, with grouped K/V
+heads, ``ops/attention.causal_gqa_attention``) and the ring's per-shard step
+(``flash_attention_step``).  No score leaves VMEM, forward or backward, and
+neither K/V nor a score matrix is ever held whole: the grid walks (batch, K/V
+head, query block, key block) with the key block innermost, and the running
+maximum, the running sum and the float32 accumulator live in VMEM scratch.
 
-Backward (``jax.custom_vjp``): the FlashAttention recipe — RECOMPUTE
-the scores from the saved ``(q, k, v, o, lse)`` residuals instead of
-ever writing the (T_q, T_k) probability matrix to HBM.  Two kernels:
-a dq pass gridded like the forward (per q-block, scores live only in
-VMEM) and a dk/dv pass per (batch*head) cell.  Both use the identity
-``ds = p * (dp - (rowsum(do*o) - dlse))`` where ``p = exp(s - lse)``
-is rebuilt in-cell; the ``dlse`` term makes the (o, lse) pair an
-honest differentiable output, which is what lets the ring path merge
-per-step partial attentions and still get exact gradients.
+Grouped heads: the ``group = Hq // Hkv`` query heads of a K/V head meet the
+same K/V block in VMEM, stacked as rows — a block of ``block_q`` tokens is
+``(group * block_q, D)`` against ``(block_k, D)`` — so K and V are read once
+a group and never repeated in memory, and the dk/dv pass sums over the group
+in its contraction.  Where a head is whole lanes (``D % 128 == 0``) the
+kernels read ``q``, ``k``, ``v`` where they lie, ``(B, T, H * D)`` with a
+head as a block of lanes, and write ``o`` the same way; other widths go
+heads-first, a K/V head's group side by side.
 
-Position bookkeeping is absolute: kernels take a (q_offset, k_offset)
-pair so the same code serves the end-aligned dense convention
-(``mha_reference``'s ``tril(k=tk-tq)`` — offset ``(tk - tq, 0)``) and
-the ring's per-shard global positions.  A T_q that does not divide
-``block_q`` is end-padded (padded rows attend unmasked, stay finite,
-and are sliced off; their cotangents are zero) — only T_q=0 errors.
+Causality by blocks: a key block wholly above the diagonal is neither
+computed (``pl.when``) nor fetched (its index map stays on the last block
+needed); the mask is applied on the blocks the diagonal crosses only.
+
+Backward: the FlashAttention recipe from the residuals ``(q, k, v, o, lse)``.
+The dq pass (query block outer) forms ``delta = rowsum(do * o) - dlse`` once a
+query block and hands it on; the dk/dv pass (key block outer) works on the
+transposed scores, ``k q^T``, so that no product needs a transposed left
+operand.  Both rebuild ``p = exp(s - lse)`` in VMEM and skip the blocks above
+the diagonal.  The ``dlse`` term makes ``(o, lse)`` an honest differentiable
+pair, which is what lets the ring merge per-step partial attentions.
+
+Arithmetic: products take their operands in the compute dtype (the inputs')
+and accumulate in float32 — float32 operands at ``Precision.HIGHEST`` —;
+scores, mask, maximum, exponent and sums are float32.  The mask is finite
+(-1e30: PERF.md section 6, PR 27); a row that sees no key at all (a ring step
+ahead of the causal frontier) comes out ``(o = 0, lse = -inf)``.
+
+Position bookkeeping is absolute: a traced ``(q_offset, k_offset)`` pair
+rides in as scalar prefetch, so the same code serves the end-aligned dense
+convention (``mha_reference``'s ``tril(k=tk-tq)`` — offset ``(tk - tq, 0)``)
+and the ring's per-shard global positions.  Ragged lengths are end-padded to
+whole blocks: padded keys are masked, padded query rows are sliced off (their
+cotangents are zero) — only T_q=0 errors.
 
 On non-TPU backends the kernels run in interpreter mode so tests pin
 forward AND backward against ``mha_reference`` / ``jax.grad`` of it
@@ -34,9 +52,12 @@ from __future__ import annotations
 
 import math
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,98 +73,272 @@ def lowerable() -> bool:
     return jax.default_backend() in ("tpu",)
 
 
+F32 = jnp.float32
+LANES = 128
+MASK = -1e30  # finite: see the module docstring
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T without materializing b.T
-_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _dot(a, b, dims):
+def accepts(hq: int, hkv: int, d: int, dtype) -> bool:
+    """What ``flash_attention`` takes in place, without a transpose: heads
+    of whole lanes, query heads in whole groups, a dtype the MXU takes."""
+    return (d % LANES == 0 and hq % hkv == 0
+            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)))
+
+
+class _Shape(NamedTuple):
+    """What a kernel is specialised on.  ``q``, ``o``: ``(B, Tq, Hkv *
+    group * d)``, ``k``, ``v``: ``(B, Tk, Hkv * d)``, both lengths whole
+    blocks; ``tk`` counts the real keys."""
+    causal: bool
+    scale: float
+    group: int
+    d: int
+    tk: int
+    block_q: int
+    block_k: int
+    out_dtype: np.dtype
+    interpret: bool
+
+
+def _mm(a, b, dims):
+    """A product as the docstring states it: the operands as they come,
+    float32 ones at full precision, accumulated in float32."""
+    full = a.dtype == F32 and b.dtype == F32
     return jax.lax.dot_general(
-        a, b, dims, preferred_element_type=jnp.float32
-    )
+        a, b, dims, preferred_element_type=F32,
+        precision=jax.lax.Precision.HIGHEST if full else None)
 
 
-def _causal_mask(offs_ref, rows, tk, row0):
-    """(rows, tk) bool mask from ABSOLUTE positions: query row r of
-    this block sits at ``q_offset + row0 + r``, key column c at
-    ``k_offset + c``.  Offsets ride in as two int32 scalars in SMEM
-    (traced — the ring's ``axis_index`` arithmetic — so they can't be
-    static kernel params)."""
-    q_pos = offs_ref[0] + row0 + jax.lax.broadcasted_iota(
-        jnp.int32, (rows, tk), 0
-    )
-    k_pos = offs_ref[1] + jax.lax.broadcasted_iota(jnp.int32, (rows, tk), 1)
-    return k_pos <= q_pos
+# -- which blocks a pass meets ------------------------------------------------
+# Block (i, j) holds the queries q_off + i block_q + [0, block_q) and the keys
+# k_off + j block_k + [0, block_k).  These take ints, numpy arrays and traced
+# scalars alike: the kernels, their index maps and ``blocks_met`` share them.
+def _needed(i, j, q_off, k_off, block_q, block_k):
+    """Some key of the block is visible to some query of it."""
+    return k_off + j * block_k <= q_off + (i + 1) * block_q - 1
 
 
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                scale, causal, block_q):
-    j = pl.program_id(1)
-    q = q_ref[0]  # (block_q, d)
-    k = k_ref[0]  # (tk, d)
-    v = v_ref[0]
-    tk = k.shape[0]
-    s = _dot(q, k, _NT) * scale
-    if causal:
-        mask = _causal_mask(offs_ref, q.shape[0], tk, j * block_q)
-        s = jnp.where(mask, s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    # fully-masked rows (ring steps ahead of the causal frontier) must
-    # come out (o=0, lse=-inf), not NaN — guard the exp and the divide
-    m_safe = jnp.where(m == -jnp.inf, 0.0, m)
-    p = jnp.exp(s - m_safe)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.dot(
-        p, v.astype(jnp.float32), preferred_element_type=jnp.float32
-    )
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(l > 0, m_safe + jnp.log(l), -jnp.inf)
+def _whole(i, j, q_off, k_off, block_q, block_k):
+    """Every key of the block is visible to every query of it."""
+    return k_off + (j + 1) * block_k - 1 <= q_off + i * block_q
 
 
-def _recompute_p(offs_ref, q, k, lse, scale, causal, row0):
-    """Normalized probabilities rebuilt from the lse residual — the
-    flash backward's recompute.  A fully-masked row has lse=-inf and
-    s=-inf: substitute lse=0 so exp(-inf - 0) = 0 instead of exp(nan)."""
-    s = _dot(q, k, _NT) * scale
-    if causal:
-        mask = _causal_mask(offs_ref, q.shape[0], k.shape[0], row0)
-        s = jnp.where(mask, s, -jnp.inf)
-    return jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0))
+def blocks_met(tq: int, tk: int, block_q: int, block_k: int):
+    """``(computed, total)`` key blocks of causal attention over ``tq``
+    queries end-aligned to ``tk`` keys: what the kernels' grid runs of what
+    it spans."""
+    i = np.arange(-(-tq // block_q))[:, None]
+    j = np.arange(-(-tk // block_k))[None, :]
+    met = _needed(i, j, tk - tq, 0, block_q, block_k)
+    return int(np.sum(met)), met.size
 
 
-def _bwd_dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
-                   lse_ref, dlse_ref, dq_ref, *, scale, causal, block_q):
-    j = pl.program_id(1)
-    k = k_ref[0]
-    do = do_ref[0].astype(jnp.float32)
-    o = o_ref[0].astype(jnp.float32)
-    p = _recompute_p(
-        offs_ref, q_ref[0], k, lse_ref[0], scale, causal, j * block_q
-    )
-    delta = jnp.sum(do * o, axis=-1, keepdims=True) - dlse_ref[0]
-    dp = _dot(do, v_ref[0].astype(jnp.float32), _NT)
-    ds = p * (dp - delta) * scale
-    dq_ref[0] = jnp.dot(
-        ds, k.astype(jnp.float32), preferred_element_type=jnp.float32
-    ).astype(dq_ref.dtype)
+def _last_key_block(i, offs, nk, c: _Shape):
+    """The last key block query block ``i`` needs (block 0 if none)."""
+    reach = offs[0] - offs[1] + (i + 1) * c.block_q - 1
+    return jnp.minimum(jax.lax.div(jnp.maximum(reach, 0), c.block_k), nk - 1)
 
 
-def _bwd_dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref,
-                    lse_ref, dlse_ref, dk_ref, dv_ref, *, scale, causal):
-    q = q_ref[0]  # (tq, d) — whole padded T_q per (batch*head) cell
-    do = do_ref[0].astype(jnp.float32)
-    o = o_ref[0].astype(jnp.float32)
-    p = _recompute_p(offs_ref, q, k_ref[0], lse_ref[0], scale, causal, 0)
-    dv_ref[0] = _dot(p, do, _TN).astype(dv_ref.dtype)
-    delta = jnp.sum(do * o, axis=-1, keepdims=True) - dlse_ref[0]
-    dp = _dot(do, v_ref[0].astype(jnp.float32), _NT)
-    ds = p * (dp - delta) * scale
-    dk_ref[0] = _dot(ds, q.astype(jnp.float32), _TN).astype(dk_ref.dtype)
+def _first_query_block(j, offs, nq, c: _Shape):
+    """The first query block that sees key block ``j`` (the last if none)."""
+    reach = offs[1] - offs[0] + j * c.block_k
+    return jnp.minimum(jax.lax.div(jnp.maximum(reach, 0), c.block_q), nq - 1)
 
 
-# Scoped-VMEM ceiling handed to Mosaic.  The default (16 MiB on v5e)
-# refuses the dk/dv pass — whole q/k/v/do/o plus four (T_q, T_k) f32
-# temporaries per cell — well before the chip's 128 MiB runs out.
+def _walk(offs_ref, i, j, nk, c: _Shape, step):
+    """``step(masked)`` on block ``(i, j)``: not at all above the diagonal,
+    with the mask where the diagonal or the end of the real keys crosses the
+    block, without it elsewhere."""
+    ragged = c.tk % c.block_k != 0  # the last key block holds padding
+    if not (c.causal or ragged):
+        step(False)
+        return
+    whole = j < nk - 1 if ragged else True
+    if not c.causal:
+        pl.when(whole)(lambda: step(False))
+        pl.when(jnp.logical_not(whole))(lambda: step(True))
+        return
+    where = (i, j, offs_ref[0], offs_ref[1], c.block_q, c.block_k)
+    needed = _needed(*where)
+    whole = jnp.logical_and(whole, _whole(*where))
+    pl.when(jnp.logical_and(needed, whole))(lambda: step(False))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(whole)))(
+        lambda: step(True))
+
+
+def _keep(offs_ref, i, j, c: _Shape, transposed: bool):
+    """The block's mask, ``(group * block_q, block_k)`` (``transposed``: keys
+    on rows): a real key, at or before the query's position."""
+    one = (c.block_k, c.block_q) if transposed else (c.block_q, c.block_k)
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
+    key = jax.lax.broadcasted_iota(jnp.int32, one, k_dim)
+    ahead = key - jax.lax.broadcasted_iota(jnp.int32, one, q_dim)
+    keep = None
+    if c.causal:
+        keep = ahead <= (offs_ref[0] - offs_ref[1]
+                         + i * c.block_q - j * c.block_k)
+    if c.tk % c.block_k:
+        real = key < c.tk - j * c.block_k
+        keep = real if keep is None else jnp.logical_and(keep, real)
+    return jnp.concatenate([keep] * c.group, axis=q_dim)
+
+
+# -- a K/V head's group, stacked as rows --------------------------------------
+def _stacked(ref, c: _Shape):
+    """``(block_q, group * d)`` -> ``(group * block_q, d)``, head after head."""
+    if c.group == 1:
+        return ref[...]
+    return jnp.concatenate(
+        [ref[:, g * c.d:(g + 1) * c.d] for g in range(c.group)], axis=0)
+
+
+def _unstack_to(ref, x, c: _Shape):
+    for g in range(c.group):
+        ref[:, g * c.d:(g + 1) * c.d] = (
+            x[g * c.block_q:(g + 1) * c.block_q].astype(ref.dtype))
+
+
+# A per-row scalar lives in HBM with tokens on lanes, ``(group, block_q)`` a
+# block; the forward and dq passes want it as a column beside the scores'
+# rows, the dk/dv pass as a row above the transposed scores' columns.
+def _row(ref, c: _Shape):
+    """``(group, block_q)`` -> ``(1, group * block_q)``."""
+    if c.group == 1:
+        return ref[...]
+    return jnp.concatenate([ref[g:g + 1, :] for g in range(c.group)], axis=1)
+
+
+def _column(ref, c: _Shape):
+    """``(group, block_q)`` -> ``(group * block_q, 1)``."""
+    row = _row(ref, c)
+    return jnp.transpose(jnp.broadcast_to(row, (LANES, row.shape[1])))[:, :1]
+
+
+def _column_to(ref, col, c: _Shape):
+    row = jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+    for g in range(c.group):
+        ref[g:g + 1, :] = row[:, g * c.block_q:(g + 1) * c.block_q]
+
+
+# -- the kernels ----------------------------------------------------------------
+def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref, *, c: _Shape):
+    i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, MASK, F32)
+        l_ref[...] = jnp.zeros(l_ref.shape, F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
+
+    def step(masked):
+        s = _mm(_stacked(q_ref, c), k_ref[...], _NT)
+        if c.scale != 1.0:
+            s = s * c.scale
+        if masked:
+            keep = _keep(offs_ref, i, j, c, False)
+            s = jnp.where(keep, s, MASK)
+        m_prev = m_ref[...]
+        m = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m)
+        if masked:  # a row that has met no key yet has m == MASK
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp(m_prev - m)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + _mm(
+            p.astype(v_ref.dtype), v_ref[...], _NN)
+        m_ref[...] = m
+
+    _walk(offs_ref, i, j, nk, c, step)
+
+    @pl.when(j == nk - 1)
+    def _():
+        l = l_ref[...]
+        seen = l > 0.0
+        l = jnp.where(seen, l, 1.0)
+        _unstack_to(o_ref, acc_ref[...] * (1.0 / l), c)
+        _column_to(lse_ref,
+                   jnp.where(seen, m_ref[...] + jnp.log(l), -jnp.inf), c)
+
+
+def _probabilities(s, lse, keep, c: _Shape):
+    """``p = exp(s - lse)`` from the scores as the product left them; a
+    masked entry is 0 whatever its row's ``lse`` (``-inf`` where the row saw
+    no key)."""
+    if c.scale != 1.0:
+        s = s * c.scale
+    p = jnp.exp(s - lse)
+    return p if keep is None else jnp.where(keep, p, 0.0)
+
+
+def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+               dlse_ref, dq_ref, delta_ref,
+               do_scr, lse_scr, delta_scr, dq_scr, *, c: _Shape):
+    i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+
+    @pl.when(j == 0)
+    def _():
+        do = _stacked(do_ref, c)
+        delta = jnp.sum(do.astype(F32) * _stacked(o_ref, c).astype(F32),
+                        axis=1, keepdims=True) - _column(dlse_ref, c)
+        _column_to(delta_ref, delta, c)
+        delta_scr[...] = delta
+        lse_scr[...] = _column(lse_ref, c)
+        do_scr[...] = do.astype(do_scr.dtype)
+        dq_scr[...] = jnp.zeros(dq_scr.shape, F32)
+
+    def step(masked):
+        k = k_ref[...]
+        keep = _keep(offs_ref, i, j, c, False) if masked else None
+        p = _probabilities(_mm(_stacked(q_ref, c), k, _NT), lse_scr[...],
+                           keep, c)
+        ds = p * (_mm(do_scr[...], v_ref[...], _NT) - delta_scr[...])
+        if c.scale != 1.0:
+            ds = ds * c.scale
+        dq_scr[...] += _mm(ds.astype(k.dtype), k, _NN)
+
+    _walk(offs_ref, i, j, nk, c, step)
+
+    @pl.when(j == nk - 1)
+    def _():
+        _unstack_to(dq_ref, dq_scr[...], c)
+
+
+def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, c: _Shape):
+    # key block outer, query blocks inner; the scores transposed, keys on rows
+    j, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+
+    @pl.when(i == 0)
+    def _():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, F32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, F32)
+
+    def step(masked):
+        q, do = _stacked(q_ref, c), _stacked(do_ref, c)
+        keep = _keep(offs_ref, i, j, c, True) if masked else None
+        p = _probabilities(_mm(k_ref[...], q, _NT), _row(lse_ref, c), keep, c)
+        dv_scr[...] += _mm(p.astype(do.dtype), do, _NN)
+        ds = p * (_mm(v_ref[...], do, _NT) - _row(delta_ref, c))
+        if c.scale != 1.0:
+            ds = ds * c.scale
+        dk_scr[...] += _mm(ds.astype(q.dtype), q, _NN)  # sums over the group
+
+    _walk(offs_ref, i, j, pl.num_programs(2), c, step)
+
+    @pl.when(i == nq - 1)
+    def _():
+        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+
+# Scoped-VMEM ceiling handed to Mosaic.  The default (16 MiB on v5e) is
+# less than a block of grouped heads with its float32 scores takes, well
+# before the chip's 128 MiB runs out.
 VMEM_LIMIT_BYTES = 100 << 20
+BLOCK_K = 512  # keys a block, where the caller does not say
 
 
 def _compiler_params(*semantics):
@@ -164,142 +359,186 @@ def _out_struct(shape, dtype, *operands):
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _fwd_call(qf, kf, vf, offs, causal, block_q, interpret):
-    n, tq, d = qf.shape
-    tk = kf.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    kernel = partial(_fwd_kernel, scale=scale, causal=causal,
-                     block_q=block_q)
+# -- the calls ------------------------------------------------------------------
+def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs,
+          *operands):
+    """One kernel over (batch, K/V head, outer block, inner block), the inner
+    blocks one after another.  ``ins`` / ``outs``: a letter an operand —
+    ``q`` a block of queries' rows ``(block_q, group * d)``, ``k`` a block of
+    keys' ``(block_k, d)``, ``r`` a per-row scalar ``(group, block_q)`` —
+    and for an output its dtype."""
+    b, tq = operands[0].shape[:2]
+    tk = operands[1].shape[1]
+    hkv = operands[1].shape[2] // c.d
+    nq, nk = tq // c.block_q, tk // c.block_k
+
+    def blocks(x, y):  # the grid's last two axes -> (query block, key block)
+        i, j = (y, x) if key_outer else (x, y)
+        return i, j
+
+    def q_block(bi, hi, x, y, offs_ref):
+        i, j = blocks(x, y)
+        if c.causal and key_outer:  # query blocks before the first: not fetched
+            i = jnp.maximum(i, _first_query_block(j, offs_ref, nq, c))
+        return i
+
+    def k_block(bi, hi, x, y, offs_ref):
+        i, j = blocks(x, y)
+        if c.causal and not key_outer:  # key blocks after the last: not fetched
+            j = jnp.minimum(j, _last_key_block(i, offs_ref, nk, c))
+        return j
+
+    specs = {
+        "q": (pl.BlockSpec((None, c.block_q, c.group * c.d),
+                           lambda *g: (g[0], q_block(*g), g[1])),
+              (b, tq, hkv * c.group * c.d)),
+        "k": (pl.BlockSpec((None, c.block_k, c.d),
+                           lambda *g: (g[0], k_block(*g), g[1])),
+              (b, tk, hkv * c.d)),
+        "r": (pl.BlockSpec((None, None, c.group, c.block_q),
+                           lambda *g: (g[0], g[1], 0, q_block(*g))),
+              (b, hkv, c.group, tq)),
+    }
+    everything = (offs, *operands)
     return pl.pallas_call(
-        kernel,
-        grid=(n, tq // block_q),
-        in_specs=[
-            _SMEM,
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            _out_struct((n, tq, d), qf.dtype, qf, kf, vf, offs),
-            _out_struct((n, tq, 1), jnp.float32, qf, kf, vf, offs),
-        ],
-        compiler_params=_compiler_params("parallel", "parallel"),
-        interpret=interpret,
-    )(offs, qf, kf, vf)
+        partial(kernel, c=c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, nk, nq) if key_outer else (b, hkv, nq, nk),
+            in_specs=[specs[x][0] for x in ins],
+            out_specs=[specs[x][0] for x, _ in outs],
+            scratch_shapes=scratch),
+        out_shape=[_out_struct(specs[x][1], dtype, *everything)
+                   for x, dtype in outs],
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
+        interpret=c.interpret,
+        name=name,
+    )(offs, *operands)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_core(qf, kf, vf, offs, causal, block_q, interpret):
-    """(o, lse) over flattened (B*H, T, D) inputs; T_q already padded
-    to a ``block_q`` multiple.  ``offs`` is the int32 (2,) absolute
-    (q_offset, k_offset) pair; ``lse`` is (B*H, T_q, 1) — rows on the
-    sublane axis, the layout every kernel consumes it in.
-    Differentiable in q/k/v AND honest in the lse output (nonzero dlse
-    cotangents — the ring merge — feed the backward's delta term)."""
-    return _fwd_call(qf, kf, vf, offs, causal, block_q, interpret)
+def _forward(q, k, v, offs, c: _Shape):
+    rows = c.group * c.block_q
+    return _call(
+        _fwd_kernel, "flash_attention_forward", c, False, "qkk",
+        [("q", c.out_dtype), ("r", F32)],
+        [pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, 1), F32),
+         pltpu.VMEM((rows, c.d), F32)],
+        offs, q, k, v)
 
 
-def _flash_core_fwd(qf, kf, vf, offs, causal, block_q, interpret):
-    o, lse = _fwd_call(qf, kf, vf, offs, causal, block_q, interpret)
-    return (o, lse), (qf, kf, vf, offs, o, lse)
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_core(q, k, v, offs, c: _Shape):
+    """``(o, lse)`` from ``q`` ``(B, Tq, Hkv * group * d)`` and ``k``, ``v``
+    ``(B, Tk, Hkv * d)``, both lengths whole blocks.  ``offs`` is the int32
+    (2,) absolute (q_offset, k_offset) pair; ``lse`` is ``(B, Hkv, group,
+    Tq)`` float32, tokens on lanes.  Differentiable in q/k/v AND honest in
+    the lse output (nonzero dlse cotangents — the ring merge — feed the
+    backward's delta term)."""
+    return _forward(q, k, v, offs, c)
 
 
-def _flash_core_bwd(causal, block_q, interpret, res, cts):
-    qf, kf, vf, offs, o, lse = res
+# What a ``jax.checkpoint`` around the caller may keep (``policy=jax.
+# checkpoint_policies.save_only_these_names(*SAVED)``) so that its
+# recomputation does not run the forward kernel a second time.
+SAVED = ("flash_attention_o", "flash_attention_lse")
+
+
+def _flash_core_fwd(q, k, v, offs, c):
+    o, lse = _forward(q, k, v, offs, c)
+    o, lse = (checkpoint_name(x, name) for x, name in zip((o, lse), SAVED))
+    return (o, lse), (q, k, v, offs, o, lse)
+
+
+def _flash_core_bwd(c, res, cts):
+    q, k, v, offs, o, lse = res
     do, dlse = cts
-    n, tq, d = qf.shape
-    tk = kf.shape[1]
-    scale = 1.0 / math.sqrt(d)
-    dlse = dlse.astype(jnp.float32)
-    operands = (offs, qf, kf, vf, do, o, lse, dlse)
-    dq_kernel = partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                        block_q=block_q)
-    block_qd = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
-    block_q1 = pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0))
-    all_k = pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0))
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(n, tq // block_q),
-        in_specs=[
-            _SMEM, block_qd, all_k, all_k, block_qd, block_qd,
-            block_q1, block_q1,
-        ],
-        out_specs=block_qd,
-        out_shape=_out_struct((n, tq, d), qf.dtype, *operands),
-        compiler_params=_compiler_params("parallel", "parallel"),
-        interpret=interpret,
-    )(*operands)
-    dkv_kernel = partial(_bwd_dkv_kernel, scale=scale, causal=causal)
-    whole_q = pl.BlockSpec((1, tq, d), lambda i: (i, 0, 0))
-    whole_k = pl.BlockSpec((1, tk, d), lambda i: (i, 0, 0))
-    col_q = pl.BlockSpec((1, tq, 1), lambda i: (i, 0, 0))
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(n,),
-        in_specs=[
-            _SMEM,
-            whole_q, whole_k, whole_k, whole_q, whole_q, col_q, col_q,
-        ],
-        out_specs=[whole_k, whole_k],
-        out_shape=[
-            _out_struct((n, tk, d), kf.dtype, *operands),
-            _out_struct((n, tk, d), vf.dtype, *operands),
-        ],
-        compiler_params=_compiler_params("parallel"),
-        interpret=interpret,
-    )(*operands)
+    rows = c.group * c.block_q
+    dq, delta = _call(
+        _dq_kernel, "flash_attention_dq", c, False, "qkkqqrr",
+        [("q", q.dtype), ("r", F32)],
+        [pltpu.VMEM((rows, c.d), q.dtype), pltpu.VMEM((rows, 1), F32),
+         pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, c.d), F32)],
+        offs, q, k, v, do, o, lse, dlse.astype(F32))
+    dk, dv = _call(
+        _dkv_kernel, "flash_attention_dkv", c, True, "qkkqrr",
+        [("k", k.dtype), ("k", v.dtype)],
+        [pltpu.VMEM((c.block_k, c.d), F32)] * 2,
+        offs, q, k, v, do.astype(q.dtype), lse, delta)
     return dq, dk, dv, None  # integer offsets carry no cotangent
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-def _flatten_heads(x):
+# -- layouts ----------------------------------------------------------------------
+def _heads_first(x, hkv):
+    """``(B, T, H, D)`` -> ``(B * hkv, T, (H // hkv) * D)``: a K/V head's
+    group side by side, for head widths that are not whole lanes."""
     b, t, h, d = x.shape
-    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+    x = x.reshape(b, t, hkv, h // hkv, d)
+    return jnp.transpose(x, (0, 2, 1, 3, 4)).reshape(b * hkv, t, -1)
 
 
-def _pad_to_block(qf, block_q):
-    """End-pad the flattened query rows to a block_q multiple; real
-    rows keep their original absolute positions (the offset is derived
-    from the UNPADDED T_q), padded rows attend unmasked (finite, no
-    NaN) and are sliced off by the caller."""
-    tq = qf.shape[1]
-    pad = (-tq) % block_q
+def _pad_rows(x, block):
+    """End-pad ``(B, T, ...)`` to whole blocks of rows."""
+    pad = (-x.shape[1]) % block
     if pad:
-        qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0)))
-    return qf, pad
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    return x
+
+
+def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
+            out_dtype):
+    """``(o, lse)`` of ``(B, Tq, Hq, D)`` against ``(B, Tk, Hkv, D)``, as
+    ``(B, Tq, Hq, D)`` and ``(B, Hq, Tq)``."""
+    if interpret is None:
+        interpret = not lowerable()
+    b, tq, hq, d = q.shape
+    tk, hkv = k.shape[1:3]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not divide by {hkv} K/V heads")
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    in_place = d % LANES == 0
+    if in_place:  # a head is a block of lanes
+        flat = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
+    else:
+        flat = partial(_heads_first, hkv=hkv)
+    c = _Shape(bool(causal), float(d ** -0.5 if scale is None else scale),
+               hq // hkv, d, tk, block_q, block_k,
+               np.dtype(out_dtype or q.dtype), bool(interpret))
+    o, lse = _flash_core(
+        _pad_rows(flat(q), block_q), _pad_rows(flat(k), block_k),
+        _pad_rows(flat(v), block_k), offs, c)
+    o, lse = o[:, :tq], lse[..., :tq]
+    if in_place:
+        return o.reshape(b, tq, hq, d), lse.reshape(b, hq, tq)
+    o = o.reshape(b, hkv, tq, hq // hkv, d)
+    return (jnp.transpose(o, (0, 2, 1, 3, 4)).reshape(b, tq, hq, d),
+            lse.reshape(b, hq, tq))
 
 
 def flash_attention(
-    q, k, v, causal: bool = False, block_q: int = 128, interpret=None
+    q, k, v, causal: bool = False, block_q: int = 128, interpret=None,
+    *, block_k: int = BLOCK_K, scale=None, out_dtype=None
 ):
-    """Fused attention on (B, T, H, D) with a fused flash backward;
-    bit-comparable to ``mha_reference`` (same softmax, same end-aligned
-    ``tril(k=tk-tq)`` causal convention, fp32 accumulation) and
-    grad-pinned against ``jax.grad`` of it.  Any T_q >= 1 works — a
-    ragged T_q is end-padded to the q-block internally."""
-    if interpret is None:
-        interpret = not lowerable()
-    b, tq, h, d = q.shape
-    tk = k.shape[1]
+    """Fused attention on ``q`` (B, T, Hq, D) and ``k``, ``v`` (B, T, Hkv,
+    D) — K/V head ``j`` serves query heads ``[j, j + 1) * Hq // Hkv`` —
+    with a fused flash backward; bit-comparable to ``mha_reference`` (same
+    softmax, same end-aligned ``tril(k=tk-tq)`` causal convention, fp32
+    accumulation) and grad-pinned against ``jax.grad`` of it.  Any T_q >= 1
+    works — ragged lengths are end-padded to whole blocks internally.
+    ``scale`` multiplies the scores (default ``D ** -0.5``); the output
+    takes ``out_dtype`` (default: the inputs')."""
+    tq, tk = q.shape[1], k.shape[1]
     if tq == 0:
         raise ValueError(
             "flash_attention: T_q=0 — an empty query block has no "
             "attention output (check the caller's slicing)"
         )
-    block_q = min(block_q, tq)
-    qf, pad = _pad_to_block(_flatten_heads(q), block_q)
-    kf, vf = _flatten_heads(k), _flatten_heads(v)
     offs = jnp.asarray([tk - tq, 0], jnp.int32)
-    o, _ = _flash_core(qf, kf, vf, offs, causal, block_q, bool(interpret))
-    if pad:
-        o = o[:, :tq]
-    return jnp.transpose(o.reshape(b, h, tq, d), (0, 2, 1, 3))
+    return _attend(q, k, v, offs, causal, block_q, block_k, interpret,
+                   scale, out_dtype)[0]
 
 
 def flash_attention_step(
@@ -315,12 +554,6 @@ def flash_attention_step(
     shard, with the row logsumexp so the caller can merge steps via
     the online-softmax combine; a fully-masked row is (0, -inf).
     Gradients are exact through BOTH outputs (the dlse term)."""
-    if interpret is None:
-        interpret = not lowerable()
-    b, tq, h, d = q.shape
-    block_q = min(block_q, tq)
-    qf, pad = _pad_to_block(_flatten_heads(q), block_q)
-    kf, vf = _flatten_heads(k), _flatten_heads(v)
     if causal:
         offs = jnp.stack(
             [jnp.asarray(q_offset, jnp.int32),
@@ -331,10 +564,9 @@ def flash_attention_step(
         # axis-index arithmetic out of the (DCE'd) operand sidesteps an
         # XLA SPMD PartitionId lowering bug under shard_map
         offs = jnp.zeros((2,), jnp.int32)
-    o, lse = _flash_core(qf, kf, vf, offs, causal, block_q, bool(interpret))
-    if pad:
-        o, lse = o[:, :tq], lse[:, :tq]
-    return o.reshape(b, h, tq, d), lse.reshape(b, h, tq)
+    o, lse = _attend(q, k, v, offs, causal, block_q, BLOCK_K, interpret,
+                     scale=None, out_dtype=None)
+    return jnp.transpose(o, (0, 2, 1, 3)), lse
 
 
 # ----------------------------------------------------------------------
@@ -344,7 +576,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, scale, s):
     q = q_ref[0]  # (1, d)
     k = k_ref[0]  # (s, d)
     n = len_ref[pl.program_id(0)]
-    scores = _dot(q, k, _NT) * scale
+    scores = jax.lax.dot_general(
+        q, k, _NT, preferred_element_type=jnp.float32) * scale
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, s), 1)
     scores = jnp.where(k_pos < n, scores, -jnp.inf)
     m = jnp.max(scores, axis=-1, keepdims=True)

@@ -15,6 +15,9 @@ eight layers, and the decay's, ``A_log`` / ``dt_bias`` / ``g``, through the
 exponentials of running sums: 1.1e-4 was measured on ``dt_bias``).
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -558,6 +561,190 @@ def test_blockwise_gqa_attention_matches_full_attention(t, block_q, segments):
         want = jax.jit(jax.grad(weigh(full), argnums=(0, 1, 2)))(q, k, v)
     for g_, w_ in zip(got, want):
         assert rel(g_, w_) < 2e-5
+
+
+def gqa_inputs(t, hq, hkv, d, seed=0):
+    keys = jax.random.split(jax.random.key(seed + t + hq), 4)
+    shape = lambda h: (2, t, h, d)  # noqa: E731
+    return tuple(jax.random.normal(key, shape(h))
+                 for key, h in zip(keys, (hq, hkv, hkv, hq)))
+
+
+def full_gqa_attention(q, k, v):
+    """``mha_reference`` on K/V repeated for their groups."""
+    from sparknet_tpu.ops.attention import mha_reference
+
+    group = q.shape[2] // k.shape[2]
+    return mha_reference(q, jnp.repeat(k, group, axis=2),
+                         jnp.repeat(v, group, axis=2), causal=True)
+
+
+# float32: the XLA path's bounds above; bfloat16: rounding of q, k, v, p and
+# the cotangents, a twentieth of the cell's forward band at most
+ATTENTION_BOUNDS = {"float32": (2e-6, 2e-5), "bfloat16": (6e-3, 1e-2)}
+
+
+# the kernels in interpreter mode, several blocks a side: groups of 1 and 8,
+# heads of 128 and 256, whole blocks and a ragged tail (T = 50: the last
+# query block and the last key block are padded), query blocks smaller than,
+# equal to and larger than the key blocks
+@pytest.mark.parametrize("dtype", sorted(ATTENTION_BOUNDS))
+@pytest.mark.parametrize("t, hq, hkv, d, block_q, block_k", [
+    (64, 8, 1, 128, 16, 32),
+    (50, 8, 1, 128, 16, 32),
+    (64, 2, 2, 256, 32, 16),
+    (50, 16, 2, 256, 16, 16),
+    (64, 1, 1, 128, 16, 16),
+])
+def test_attention_kernels_match_full_attention(
+        t, hq, hkv, d, block_q, block_k, dtype):
+    """Output and the three gradients of the flash kernels as
+    ``causal_gqa_attention`` calls them, against full attention on repeated
+    K/V."""
+    from sparknet_tpu.ops import pallas_attention
+
+    cd = jnp.dtype(dtype)
+    q, k, v, weights = gqa_inputs(t, hq, hkv, d)
+
+    def kernels(q, k, v):
+        q = (q * d ** -0.5).astype(cd)
+        return pallas_attention.flash_attention(
+            q, k.astype(cd), v.astype(cd), causal=True, block_q=block_q,
+            block_k=block_k, scale=1.0, out_dtype=jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(jax.jit(kernels), q, k, v)
+        want, want_vjp = jax.vjp(jax.jit(full_gqa_attention), q, k, v)
+        grads, want_grads = vjp(weights), want_vjp(weights)
+    out_tol, grad_tol = ATTENTION_BOUNDS[dtype]
+    assert got.dtype == jnp.float32 and got.shape == q.shape
+    assert rel(got, want) < out_tol
+    for name, g_, w_ in zip("qkv", grads, want_grads):
+        assert g_.shape == w_.shape and rel(g_, w_) < grad_tol, name
+    with open(os.path.join(os.path.dirname(ref.__file__), os.pardir,
+                           "configs", "qwen3-next-80b-a3b.json")) as f:
+        band = json.load(f)["check"]["forward_rel_tol"]["logits"]
+    assert out_tol < band and grad_tol < band
+
+
+@pytest.mark.parametrize("tq, tk, block_q, block_k", [
+    (64, 64, 16, 16), (64, 64, 16, 32), (64, 64, 32, 16), (50, 50, 16, 32),
+    (16, 64, 16, 16), (8192, 8192, 512, 512), (8192, 8192, 256, 512)])
+def test_causal_attention_meets_the_blocks_below_the_diagonal(
+        tq, tk, block_q, block_k):
+    """``blocks_met`` (the kernels' own predicate) against the mask itself:
+    a block is met iff some key of it is visible to some query of it."""
+    from sparknet_tpu.ops import pallas_attention
+
+    nq, nk = -(-tq // block_q), -(-tk // block_k)
+    query = (tk - tq) + np.arange(nq * block_q)
+    visible = np.arange(nk * block_k)[None, :] <= query[:, None]
+    by_block = visible.reshape(nq, block_q, nk, block_k).any(axis=(1, 3))
+    computed, total = pallas_attention.blocks_met(tq, tk, block_q, block_k)
+    assert (computed, total) == (int(by_block.sum()), nq * nk)
+    if tq == tk and block_q == block_k and tq % block_q == 0:
+        assert computed == nq * (nq + 1) // 2
+
+
+@pytest.mark.parametrize("lowers, d, dtype, path, why", [
+    (False, 128, "bfloat16", "xla", "no Pallas lowering on cpu"),
+    (True, 16, "bfloat16", "xla", "whole lanes"),
+    (True, 128, "float16", "xla", "bfloat16 or float32"),
+    (True, 128, "bfloat16", "pallas", ""),
+    (True, 256, "float32", "pallas", ""),
+])
+def test_attention_selects_by_backend_shape_and_dtype(
+        monkeypatch, lowers, d, dtype, path, why):
+    """No switch: the backend, the shapes and the dtype decide, and each
+    trace says so in one ``obs`` instant, with the blocks it computes: at
+    ``n`` key blocks ``n (n + 1) / 2`` of ``n^2`` in the kernels, 5/8 of the
+    matrix in the XLA path's four runs."""
+    from sparknet_tpu import obs
+    from sparknet_tpu.obs.trace import Tracer
+    from sparknet_tpu.ops import attention, pallas_attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: lowers)
+    calls = []
+    real = pallas_attention.flash_attention
+    monkeypatch.setattr(
+        pallas_attention, "flash_attention",
+        lambda *a, **kw: calls.append(kw) or real(*a, **kw))
+    t, hq, hkv = 8192, 8, 2
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, t, h, d), jnp.float32)
+    tracer = obs.install_tracer(Tracer())
+    try:  # traced, not run: the instant is the trace's
+        o = jax.eval_shape(
+            lambda q, k, v: causal_gqa_attention(
+                q, k, v, compute_dtype=jnp.dtype(dtype)),
+            shape(hq), shape(hkv), shape(hkv))
+    finally:
+        obs.uninstall_tracer()
+    assert (o.shape, o.dtype) == ((1, t, hq, d), jnp.float32)
+    assert len(calls) == (path == "pallas")
+    events = [e for e in tracer.events() if e["name"] == "attention_path"]
+    assert len(events) == 1  # one a trace
+    args = events[0]["args"]
+    assert args["path"] == path and why in args["why"]
+    assert (args["why"] == "") == (path == "pallas")
+    assert (args["backend"], args["t"], args["hq"], args["hkv"], args["d"],
+            args["dtype"]) == ("cpu", t, hq, hkv, d, dtype)
+    n = t // args["block_k"]
+    if path == "pallas":
+        assert args["block_k"] == 512 and calls[0]["block_k"] == 512
+        assert calls[0]["block_q"] == args["block_q"]
+        # a MiB of queries (4 heads' rows of d in the dtype), a block of
+        # keys at most
+        rows = (1 << 20) // (4 * d * jnp.dtype(dtype).itemsize)
+        assert args["block_q"] == min(rows, 512)
+        met = n * (n + 1) // 2 * (args["block_k"] // args["block_q"])
+        assert args["blocks_computed"] == met
+        assert args["blocks_computed"] / args["blocks_total"] == (n + 1) / (2 * n)
+    else:
+        assert args["block_q"] == args["block_k"] == 512
+        assert args["blocks_computed"] / args["blocks_total"] == 5 / 8
+
+
+def test_selected_kernels_match_the_xla_path(monkeypatch):
+    """``causal_gqa_attention`` through the kernels (interpreter mode)
+    against itself through the XLA path, the oracle: output and gradients,
+    in bfloat16 as the cell computes."""
+    from sparknet_tpu.ops import attention
+
+    q, k, v, weights = gqa_inputs(40, 4, 2, 128)
+    run = lambda: jax.vjp(  # noqa: E731
+        lambda *a: causal_gqa_attention(*a, compute_dtype=jnp.bfloat16),
+        q, k, v)
+    want, want_vjp = run()
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    got, vjp = run()
+    assert rel(got, want) < 6e-3
+    for g_, w_ in zip(vjp(weights), want_vjp(weights)):
+        assert rel(g_, w_) < 1e-2
+
+
+@pytest.mark.parametrize("keeps, forwards", [(False, 2), (True, 1)])
+def test_mixer_recomputation_keeps_what_the_flash_kernels_name(
+        monkeypatch, keeps, forwards):
+    """Under the mixers' ``jax.checkpoint`` policy the kernels' ``o`` and
+    ``lse`` are kept and the backward pass holds the forward kernel once,
+    not twice (traced, not run); dq and dk/dv once either way."""
+    from sparknet_tpu.models.hybrid_lm import MIXER_KEEPS
+    from sparknet_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    q, k, v, _ = gqa_inputs(64, 4, 2, 128)
+
+    def mixer(q, k, v):  # work before and after, as a layer has
+        return 3.0 * causal_gqa_attention(
+            2.0 * q, k, v, compute_dtype=jnp.bfloat16)
+
+    kept = jax.checkpoint(mixer, policy=MIXER_KEEPS if keeps else None)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kept(*a)), argnums=(0, 1, 2)))(q, k, v))
+    count = lambda name: jaxpr.count(f"name={name}")  # noqa: E731
+    assert count("flash_attention_forward") == forwards
+    assert count("flash_attention_dq") == count("flash_attention_dkv") == 1
 
 
 def test_lm_app_trains_the_hybrid_model_from_a_configuration_file(tmp_path):

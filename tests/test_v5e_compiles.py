@@ -3,7 +3,7 @@ runs them at, without a chip: the TPU's compiler is installed here and
 compiles for a chip that is described, not attached.  This catches what
 interpreter mode cannot — a block shape, a layout or a VMEM budget that
 Mosaic refuses — at no chip time.  Nothing runs; results are tested elsewhere
-(``tests/test_hybrid_lm.py``).
+(``tests/test_hybrid_lm.py``, ``tests/test_attention.py``).
 
 The topology is described inside a fixture, never while a module is imported:
 only one process at a time may load the TPU's library, and every xdist worker
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from sparknet_tpu.ops import pallas_delta_rule
+from sparknet_tpu.ops import pallas_attention, pallas_delta_rule
 
 
 @pytest.fixture(scope="module")
@@ -55,3 +55,37 @@ def test_delta_rule_kernels_compile_for_the_v5e(
         *inputs).compile()
     name = "delta_rule_within_chunks" + ("_backward" if backward else "")
     assert name in compiled.as_text()
+
+
+# the flash kernels: qwen3next-train-8k's gated-attention layer (2 sequences
+# of 8,192 tokens, 16 query heads on 2 K/V heads of 256, bfloat16, the blocks
+# ``causal_gqa_attention`` gives them) and its check in float32 (T = 1,024);
+# the byte LM's (``models/transformer_lm.py``: heads of 128 in ``flash_
+# attention``'s default blocks) at T = 4,096, which the whole-head kernels
+# these replaced were refused at (PR 21), and heads-first at a head of 64
+@pytest.mark.parametrize(
+    "batch, t, hq, hkv, d, dtype, block_q, out_dtype, backward", [
+        (2, 8192, 16, 2, 256, "bfloat16", 256, "float32", True),
+        (2, 1024, 16, 2, 256, "float32", 128, "float32", False),
+        (2, 4096, 8, 8, 128, "bfloat16", 128, None, True),
+        (2, 4096, 8, 8, 64, "bfloat16", 128, None, True),
+    ])
+def test_flash_attention_kernels_compile_for_the_v5e(
+        one_chip, batch, t, hq, hkv, d, dtype, block_q, out_dtype, backward):
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (batch, t, h, d), jnp.dtype(dtype), sharding=one_chip)
+
+    def forward(q, k, v):
+        return pallas_attention.flash_attention(
+            q, k, v, causal=True, block_q=block_q, interpret=False,
+            out_dtype=out_dtype)
+
+    def gradients(q, k, v):
+        out, vjp = jax.vjp(forward, q, k, v)
+        return vjp(out)
+
+    compiled = jax.jit(gradients if backward else forward).lower(
+        shape(hq), shape(hkv), shape(hkv)).compile()
+    names = ["flash_attention_forward"] + (
+        ["flash_attention_dq", "flash_attention_dkv"] if backward else [])
+    assert all(name in compiled.as_text() for name in names)
