@@ -92,28 +92,31 @@ def build_hybrid_lm_solver(config: dict):
     return lm, solver
 
 
-def set_routing_gauges(lm, stacked_params, tokens):
+def set_routing_gauges(lm, stacked_params, tokens, stacked_stats=None):
     """Where one batch of ``(B, T)`` tokens goes, per layer: assignments to
     the held experts a token and the largest held expert's load over the
     mean (``models/hybrid_lm.routing_gauges``), set as the gauges
     ``sparknet_lm_held_assignments_per_token`` / ``sparknet_lm_held_load_skew``
     where training metrics are on, and returned.  Outside the timed loop: it
     runs a forward pass.  ``stacked_params`` are the trainer's (worker-major);
-    worker 0 is sliced inside the jit, so no copy of the weights is made."""
+    worker 0 is sliced inside the jit, so no copy of the weights is made.
+    ``stacked_stats`` carry the routers' selection biases, where the model
+    has them; without, the selection is on the unbiased scores."""
     import jax
 
     from sparknet_tpu import obs
     from sparknet_tpu.models.hybrid_lm import routing_gauges
     from sparknet_tpu.parallel import first_worker
 
-    counts = jax.jit(
-        lambda p: lm.routing_counts(first_worker(p), tokens))(stacked_params)
+    counts = jax.jit(lambda p, s: lm.routing_counts(
+        first_worker(p), tokens, first_worker(s)))(
+            stacked_params, stacked_stats or {})
     gauges = routing_gauges(counts, tokens=int(np.prod(tokens.shape)))
     tm = obs.training_metrics()
     if tm is not None:
-        for i, (per_token, skew) in enumerate(zip(
-                gauges["held_assignments_per_token"],
-                gauges["held_load_skew"])):
+        for i, per_token, skew in zip(
+                lm.routed_layers, gauges["held_assignments_per_token"],
+                gauges["held_load_skew"]):
             tm.lm_held_assignments.labels(str(i)).set(per_token)
             tm.lm_held_load_skew.labels(str(i)).set(skew)
     return gauges
@@ -472,7 +475,7 @@ def main(argv=None) -> int:
     if args.model_config:
         # where the first round's tokens go, by layer: gauges, set once
         first = samplers[0].window_for_round(start_round, 1)["tokens"][0]
-        gauges = set_routing_gauges(lm, state.params, first)
+        gauges = set_routing_gauges(lm, state.params, first, state.stats)
         log.log(f"routing of the first minibatch, by layer: {gauges}")
 
     tokens_per_round = n_workers * args.tau * args.batch * args.seq_len

@@ -1,38 +1,66 @@
-"""Decoder-only LM whose layer pattern, norms, positions and head counts are
-VALUES: linear-attention (Gated DeltaNet) layers with one gated softmax-
-attention layer every ``full_attention_interval``, a sparse mixture of
-experts with a shared expert in every layer, zero-centred RMSNorm, rotary
-positions on part of each head, grouped K/V heads, an untied head.
+"""Decoder-only LM whose layer pattern, mixers, feed-forwards, norms,
+positions, head counts, router and head are VALUES read from a published
+``config.json``: one block (``_layer``), one class, two families so far.
 
-Built from a plain dict of the keys of a published ``config.json``
-(``qwen3_next``'s names) plus two of this system's own:
+- ``qwen3_next`` (Qwen3-Next): linear-attention (Gated DeltaNet) layers with
+  one gated softmax-attention layer every ``full_attention_interval``, a
+  sparse mixture of experts with a shared expert in every layer (softmax
+  router), zero-centred RMSNorm, rotary positions on part of each head, an
+  untied head.
+- ``lfm2_moe`` (LFM2): gated short-convolution layers beside plain grouped
+  attention as ``layer_types`` lists them (no output gate, rotary on the
+  whole head, plain RMSNorm on q and k), a dense gated MLP in the first
+  ``num_dense_layers`` and routed experts without a shared expert after
+  (sigmoid scores, top-k on ``scores + expert_bias``, weights from the
+  unbiased scores; the bias moves by its balancing rule, below), plain
+  RMSNorm, the head tied to the embedding.
+
+``describe`` turns either file's keys into one description: a mixer kind a
+layer (``MIXERS``: ``gated_delta_net``, ``gated_attention``, ``short_conv``,
+``attention``), a feed-forward kind a layer (``dense`` or ``moe``), and the
+values the equations take.  Nothing below it asks which family it builds.
+Beside the published keys, three of this system's own:
 
 - ``experts_held: [lo, n]`` — the contiguous range of routed experts this
   chip holds.  The router keeps its published width; only the held experts'
   terms are added (``ops/moe.py``).  Default: all of them.
 - ``compute_dtype`` — matrix products take their operands in it and
   accumulate in float32 over float32 master weights; norms, softmaxes, the
-  router, the decay ``g``, the delta-rule state and the loss stay float32.
-  ``Solver(net=..., compute_dtype=...)`` sets it (``set_compute_dtype``).
+  router, the decay ``g``, the delta-rule state, the short convolution and
+  the loss stay float32.  ``Solver(net=..., compute_dtype=...)`` sets it
+  (``set_compute_dtype``).
+- ``expert_bias_update_rate`` — the rate of the selection bias's balancing
+  rule (``ops/moe.balance``); without the key the bias stays where it is.
 
 The block is written once (``_layer``), for training.  The generation seams
 of ``TransformerLM`` (``prefill_with_kv`` / ``decode_step_with_kv``) are not
-supported: a linear-attention layer decodes from a recurrent state beside
-the paged K/V, which ``serve/`` does not have (ROADMAP R5).
+supported: a linear-attention layer decodes from a recurrent state, a short
+convolution from its last ``width - 1`` inputs, beside the paged K/V, and
+``serve/`` has neither (ROADMAP R5).
 
 Solver protocol as ``TransformerLM``: ``init`` / ``loss_fn`` /
 ``param_multipliers`` / ``feed_blobs`` and the checkpoint interface
-(``layers`` + ``_blob_refs``).  Every sublayer runs under one
-``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md "Telemetry
-reference") with a ``jax.checkpoint`` INSIDE the scope: between sublayers
-only the residual stream and the normed input are kept (and, of an
-attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
+(``layers`` + ``_blob_refs``).  A router's selection bias is no parameter:
+no gradient reaches it and no optimizer holds state for it.  It lives in
+the ``stats`` collection (where a net's BatchNorm statistics live: carried
+from step to step by the solver, averaged over the workers with the
+parameters, checkpointed with its router's group), as ``stats["l<i>_router"]
+= [expert_bias, expert_load]``, both ``(num_experts,)`` float32: a training
+step leaves the assignments each expert received in ``expert_load`` and
+moves the bias one step of ``ops/moe.balance`` towards an even load.  A
+forward pass given no ``stats`` selects on the unbiased scores.  A tied head
+is the embedding's transpose: there is no ``head`` group.  Every sublayer
+runs under one ``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md
+"Telemetry reference") with a ``jax.checkpoint`` INSIDE the scope: between
+sublayers only the residual stream and the normed input are kept (and, of
+an attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
 sublayer's forward is recomputed in its backward, and autodiff names both
 ``transpose(jvp(<Type>:<name>))``, i.e. backward.
 
-Layout of ``in_proj_qkvz`` / ``in_proj_ba`` columns: ``[q | k | v | z]`` and
-``[b | a]``, heads contiguous inside each part; ``q_proj`` is head-major,
-each head ``[q | gate]``.
+Layouts.  Gated DeltaNet: ``in_proj_qkvz`` / ``in_proj_ba`` columns ``[q |
+k | v | z]`` and ``[b | a]``, heads contiguous inside each part.  Gated
+attention: ``q_proj`` head-major, each head ``[q | gate]``.  Short
+convolution: ``in_proj`` columns ``[B | C | u]``.
 """
 
 from __future__ import annotations
@@ -49,6 +77,7 @@ from sparknet_tpu.ops import moe
 from sparknet_tpu.ops.attention import causal_gqa_attention
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
 from sparknet_tpu.ops.pallas_attention import SAVED as FLASH_SAVED
+from sparknet_tpu.ops.short_conv import causal_depthwise_conv, gated_short_conv
 
 F32 = jnp.float32
 # a mixer's recomputation keeps what the flash kernels name, their output and
@@ -56,7 +85,7 @@ F32 = jnp.float32
 # does not run the forward kernel a second time; nothing else is kept
 MIXER_KEEPS = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
 
-# the keys of config.json that decide a shape or an equation
+# the keys of a qwen3_next config.json that decide a shape or an equation
 CONFIG_KEYS = (
     "vocab_size", "hidden_size", "num_hidden_layers", "full_attention_interval",
     "num_attention_heads", "num_key_value_heads", "head_dim",
@@ -66,6 +95,22 @@ CONFIG_KEYS = (
     "num_experts", "num_experts_per_tok", "moe_intermediate_size",
     "shared_expert_intermediate_size",
 )
+# and of an lfm2_moe one (``head_dim``, ``tie_word_embeddings``,
+# ``use_expert_bias``, ``routed_scaling_factor`` at the family's defaults)
+LFM2_MOE_KEYS = (
+    "vocab_size", "hidden_size", "num_hidden_layers", "layer_types",
+    "num_dense_layers", "num_attention_heads", "num_key_value_heads",
+    "rope_parameters", "norm_eps", "conv_L_cache", "intermediate_size",
+    "num_experts", "num_experts_per_tok", "moe_intermediate_size",
+)
+# a mixer kind's scope type; its blobs and its function are the model's
+# ``_mixer_shapes`` and ``_<kind>``
+MIXERS = {
+    "gated_delta_net": "GatedDeltaNet", "gated_attention": "GatedAttention",
+    "short_conv": "ShortConv", "attention": "Attention",
+}
+# the renormalisation of LFM2's top-k weights: ``w / (sum(w) + 1e-6)``
+LFM2_TOPK_EPS = 1e-6
 
 
 def load_config(path: str) -> Dict:
@@ -75,10 +120,80 @@ def load_config(path: str) -> Dict:
         return json.load(f)
 
 
-def rms_norm0(x, w, eps):
-    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` in float32."""
+def _take(config: Dict, keys, name: str) -> Dict:
+    missing = [k for k in keys if k not in config]
+    if missing:
+        raise ValueError(f"{name}: configuration lacks {missing}")
+    return {k: config[k] for k in keys}
+
+
+def _describe_qwen3_next(config: Dict, name: str) -> Dict:
+    c = _take(config, CONFIG_KEYS, name)
+    depth = c["num_hidden_layers"]
+    return dict(
+        c,
+        mixers=tuple(
+            "gated_attention" if (i + 1) % c["full_attention_interval"] == 0
+            else "gated_delta_net" for i in range(depth)),
+        ffns=("moe",) * depth,
+        eps=c["rms_norm_eps"], zero_centred_norm=True,
+        rotary_dim=int(c["head_dim"] * c["partial_rotary_factor"]),
+        router_scores="softmax", expert_bias=False, routed_scaling_factor=1.0,
+        topk_eps=0.0, expert_bias_update_rate=0.0,
+        tied=bool(config.get("tie_word_embeddings", False)),
+    )
+
+
+def _describe_lfm2_moe(config: Dict, name: str) -> Dict:
+    c = _take(config, LFM2_MOE_KEYS, name)
+    depth = c["num_hidden_layers"]
+    kinds = {"conv": "short_conv", "full_attention": "attention"}
+    types = list(c["layer_types"])
+    if len(types) != depth or set(types) - set(kinds):
+        raise ValueError(
+            f"{name}: layer_types must list {depth} of {sorted(kinds)}")
+    if config.get("conv_bias", False):
+        raise ValueError(f"{name}: conv_bias=true is not supported")
+    head_dim = config.get(
+        "head_dim", c["hidden_size"] // c["num_attention_heads"])
+    return dict(
+        c,
+        mixers=tuple(kinds[t] for t in types),
+        ffns=tuple("dense" if i < c["num_dense_layers"] else "moe"
+                   for i in range(depth)),
+        head_dim=head_dim, rotary_dim=head_dim,
+        rope_theta=c["rope_parameters"]["rope_theta"],
+        eps=c["norm_eps"], zero_centred_norm=False,
+        shared_expert_intermediate_size=0,
+        router_scores="sigmoid",
+        expert_bias=bool(config.get("use_expert_bias", True)),
+        routed_scaling_factor=float(config.get("routed_scaling_factor", 1.0)),
+        topk_eps=LFM2_TOPK_EPS,
+        expert_bias_update_rate=float(
+            config.get("expert_bias_update_rate", 0.0)),
+        tied=bool(config.get("tie_word_embeddings", True)),
+    )
+
+
+DESCRIBERS = {"qwen3_next": _describe_qwen3_next, "lfm2_moe": _describe_lfm2_moe}
+
+
+def describe(config: Dict, name: str = "HybridMoELM") -> Dict:
+    """The description ``HybridMoELM`` builds from, by the file's
+    ``model_type`` (``qwen3_next`` where it has none)."""
+    family = config.get("model_type", "qwen3_next")
+    if family not in DESCRIBERS:
+        raise ValueError(
+            f"{name}: model_type {family!r} is none of {sorted(DESCRIBERS)}")
+    return DESCRIBERS[family](config, name)
+
+
+def rms_norm(x, w, eps, zero_centred: bool):
+    """``x * rsqrt(mean(x^2) + eps) * w`` in float32; ``(1 + w)`` in place
+    of ``w`` where the weight is zero-centred."""
     x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (
+        (1.0 + w) if zero_centred else w)
 
 
 def rotary(x, theta: float, rotary_dim: int):
@@ -99,10 +214,7 @@ class HybridMoELM:
     learned positions."""
 
     def __init__(self, config: Dict, name: str = "HybridMoELM"):
-        missing = [k for k in CONFIG_KEYS if k not in config]
-        if missing:
-            raise ValueError(f"{name}: configuration lacks {missing}")
-        c = self.config = {k: config[k] for k in CONFIG_KEYS}
+        c = self.config = describe(config, name)
         if not config.get("norm_topk_prob", True):
             raise ValueError(f"{name}: norm_topk_prob=false is not supported")
         self.experts_held = tuple(
@@ -113,85 +225,121 @@ class HybridMoELM:
                 f"experts_held={list(self.experts_held)} is not a range of "
                 f"the {c['num_experts']} experts")
         if c["num_attention_heads"] % c["num_key_value_heads"] or (
-                c["linear_num_value_heads"] % c["linear_num_key_heads"]):
+                "gated_delta_net" in c["mixers"]
+                and c["linear_num_value_heads"] % c["linear_num_key_heads"]):
             raise ValueError("query / value heads must divide by K/V / key heads")
         self.set_compute_dtype(config.get("compute_dtype"))
         self.name = name
         self.depth = c["num_hidden_layers"]
+        self.routed_layers = tuple(
+            i for i in range(self.depth) if c["ffns"][i] == "moe")
         self.feed_blobs = ("tokens", "targets")
-        self._group_blobs = self._blob_plan()
+        plan = self._blob_plan()
+        self._group_blobs = [(g, [s for s, _ in blobs]) for g, blobs in plan]
+        self._blob_inits = {g: [how for _, how in blobs] for g, blobs in plan}
         self.layers = [_Group(k) for k, _ in self._group_blobs]
         self._blob_refs = {
             k: [_Ref(k, i) for i in range(len(shapes))]
             for k, shapes in self._group_blobs
         }
+        # a selection bias and its load are checkpointed with their router,
+        # from ``stats``
+        self.biased_routers = tuple(
+            f"l{i}_router" for i in self.routed_layers if c["expert_bias"])
+        for group in self.biased_routers:
+            for index in range(2):
+                ref = _Ref(group, index)
+                ref.collection = "stats"
+                self._blob_refs[group].append(ref)
 
     def set_compute_dtype(self, dtype) -> None:
         """``None`` is float32 throughout."""
         self.compute_dtype = None if dtype is None else jnp.dtype(dtype)
 
     def is_attention_layer(self, i: int) -> bool:
-        return (i + 1) % self.config["full_attention_interval"] == 0
+        """Whether layer ``i`` mixes by softmax attention, gated or plain."""
+        return self.config["mixers"][i] in ("gated_attention", "attention")
 
     # ------------------------------------------------------------------
-    def _blob_plan(self) -> List[Tuple[str, List[Tuple[int, ...]]]]:
+    def _mixer_shapes(self, kind: str):
+        """A mixer's blobs as ``(shape, how it is initialised)``."""
         c = self.config
-        e, v = c["hidden_size"], c["vocab_size"]
+        e = c["hidden_size"]
         hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
                       c["head_dim"])
+        w, norm = "matrix", "norm"
+        if kind in ("gated_attention", "attention"):
+            q_width = (2 if kind == "gated_attention" else 1) * hq * d
+            return [((e, q_width), w), ((e, hkv * d), w), ((e, hkv * d), w),
+                    ((d,), norm), ((d,), norm), ((hq * d, e), w)]
+        if kind == "short_conv":
+            return [((e, 3 * e), w), ((e, c["conv_L_cache"]), w), ((e, e), w)]
         hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
         dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
+        channels = 2 * hk * dk + hv * dv
+        return [((e, channels + hv * dv), w), ((e, 2 * hv), w),
+                ((channels, c["linear_conv_kernel_dim"]), w),
+                ((hv,), "a_log"), ((hv,), "ones"), ((dv,), "ones"),
+                ((hv * dv, e), w)]
+
+    def _blob_plan(self) -> List[Tuple[str, List[Tuple[Tuple[int, ...], str]]]]:
+        c = self.config
+        e, v = c["hidden_size"], c["vocab_size"]
         f, fs = c["moe_intermediate_size"], c["shared_expert_intermediate_size"]
         n = self.experts_held[1]
-        conv_channels = 2 * hk * dk + hv * dv
-        plan = [("embed", [(v, e)])]
+        w, norm = "matrix", "norm"
+        mlp = lambda width: [((e, width), w), ((e, width), w),  # noqa: E731
+                             ((width, e), w)]
+        plan = [("embed", [((v, e), w)])]
         for i in range(c["num_hidden_layers"]):
-            plan.append((f"l{i}_n1", [(e,)]))
-            if self.is_attention_layer(i):
-                plan.append((f"l{i}_mixer", [
-                    (e, 2 * hq * d), (e, hkv * d), (e, hkv * d), (d,), (d,),
-                    (hq * d, e)]))
-            else:
-                plan.append((f"l{i}_mixer", [
-                    (e, conv_channels + hv * dv), (e, 2 * hv),
-                    (conv_channels, c["linear_conv_kernel_dim"]),
-                    (hv,), (hv,), (dv,), (hv * dv, e)]))
-            plan.append((f"l{i}_n2", [(e,)]))
-            plan.append((f"l{i}_router", [(e, c["num_experts"])]))
-            plan.append((f"l{i}_experts", [(n, e, f), (n, e, f), (n, f, e)]))
-            plan.append((f"l{i}_shared", [(e, fs), (e, fs), (fs, e), (e, 1)]))
-        plan.append(("norm_f", [(e,)]))
-        plan.append(("head", [(e, v)]))
+            plan.append((f"l{i}_n1", [((e,), norm)]))
+            plan.append((f"l{i}_mixer", self._mixer_shapes(c["mixers"][i])))
+            plan.append((f"l{i}_n2", [((e,), norm)]))
+            if c["ffns"][i] == "dense":
+                plan.append((f"l{i}_mlp", mlp(c["intermediate_size"])))
+                continue
+            plan.append((f"l{i}_router", [((e, c["num_experts"]), w)]))
+            plan.append((f"l{i}_experts", [((n, e, f), w), ((n, e, f), w),
+                                           ((n, f, e), w)]))
+            if fs:
+                plan.append((f"l{i}_shared", mlp(fs) + [((e, 1), w)]))
+        plan.append(("norm_f", [((e,), norm)]))
+        if not c["tied"]:
+            plan.append(("head", [((e, v), w)]))
         return plan
 
     def init(self, seed: int = 0):
-        """Matrices normal(0, 0.02); zero-centred norm weights 0; the
-        DeltaNet output norm 1; ``A_log = log U(0, 16)``, ``dt_bias = 1``.
-        No running statistics.  One jitted program with the key as its
-        argument: blob by blob, eagerly, the 35 generators take a minute to
-        compile on the chip."""
+        """Matrices normal(0, 0.02); a norm's weight its identity (0 where
+        zero-centred, else 1); the DeltaNet output norm 1; ``A_log = log
+        U(0, 16)``, ``dt_bias = 1``.  The ``stats`` are the routers'
+        selection biases and loads, zeros: where the balancing rule starts
+        from.  One jitted program with the key as its argument: blob by
+        blob, eagerly, the 35 generators take a minute to compile on the
+        chip."""
+        identity = 0.0 if self.config["zero_centred_norm"] else 1.0
+
+        def one(key, shape, how):
+            if how == "matrix":
+                return 0.02 * jax.random.normal(key, shape, F32)
+            if how == "a_log":
+                return jnp.log(jax.random.uniform(
+                    key, shape, F32, minval=2.0 ** -20, maxval=16.0))
+            return jnp.full(shape, identity if how == "norm" else 1.0, F32)
 
         def make(key):
             params: Dict[str, List[jnp.ndarray]] = {}
             for gi, (group, shapes) in enumerate(self._group_blobs):
                 gkey = jax.random.fold_in(key, gi)
-                delta = group.endswith("_mixer") and len(shapes) == 7
-                blobs = []
-                for bi, shape in enumerate(shapes):
-                    bkey = jax.random.fold_in(gkey, bi)
-                    if len(shape) > 1:
-                        blobs.append(0.02 * jax.random.normal(bkey, shape, F32))
-                    elif delta and bi == 3:  # A_log
-                        blobs.append(jnp.log(jax.random.uniform(
-                            bkey, shape, F32, minval=2.0 ** -20, maxval=16.0)))
-                    elif delta and bi in (4, 5):  # dt_bias, the output norm
-                        blobs.append(jnp.ones(shape, F32))
-                    else:
-                        blobs.append(jnp.zeros(shape, F32))
-                params[group] = blobs
+                params[group] = [
+                    one(jax.random.fold_in(gkey, bi), shape, how)
+                    for bi, (shape, how) in enumerate(
+                        zip(shapes, self._blob_inits[group]))]
             return params
 
-        return jax.jit(make)(jax.random.PRNGKey(seed)), {}
+        experts = self.config["num_experts"]
+        stats = {g: [jnp.zeros((experts,), F32), jnp.zeros((experts,), F32)]
+                 for g in self.biased_routers}
+        return jax.jit(make)(jax.random.PRNGKey(seed)), stats
 
     def param_multipliers(self):
         """lr_mult 1 everywhere; weight decay on the matrices only."""
@@ -210,28 +358,47 @@ class HybridMoELM:
         y = jnp.dot(x.astype(cd), w.astype(cd), preferred_element_type=F32)
         return y.astype(out_dtype or cd)
 
-    def _gated_attention(self, x, blobs):
+    def _norm(self, x, w):
+        return rms_norm(
+            x, w, self.config["eps"], self.config["zero_centred_norm"])
+
+    def _softmax_attention(self, x, blobs, gated: bool):
         q_proj, k_proj, v_proj, q_norm, k_norm, o_proj = blobs
         c = self.config
         b, t, _ = x.shape
         hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
                       c["head_dim"])
-        eps, theta = c["rms_norm_eps"], c["rope_theta"]
-        rotary_dim = int(d * c["partial_rotary_factor"])
-        qg = self._dot(x, q_proj).reshape(b, t, hq, 2 * d)
-        q, gate = qg[..., :d], qg[..., d:]
+        theta, rotary_dim = c["rope_theta"], c["rotary_dim"]
+        if gated:
+            qg = self._dot(x, q_proj).reshape(b, t, hq, 2 * d)
+            q, gate = qg[..., :d], qg[..., d:]
+        else:
+            q = self._dot(x, q_proj).reshape(b, t, hq, d)
         k = self._dot(x, k_proj).reshape(b, t, hkv, d)
         v = self._dot(x, v_proj).reshape(b, t, hkv, d)
-        q = rotary(rms_norm0(q, q_norm, eps), theta, rotary_dim)
-        k = rotary(rms_norm0(k, k_norm, eps), theta, rotary_dim)
+        q = rotary(self._norm(q, q_norm), theta, rotary_dim)
+        k = rotary(self._norm(k, k_norm), theta, rotary_dim)
         attn = causal_gqa_attention(q, k, v, compute_dtype=self.compute_dtype)
         # gated with heads side by side, (B, T, Hq D), as the kernels write
         # the output and o_proj reads it: heads apart, (.., Hq, D) tiles
         # otherwise, and the float32 output and its cotangent are each
         # copied from one tiling to the other (0.8 ms each on the v5e)
-        attn = attn.reshape(b, t, hq * d) * jax.nn.sigmoid(
-            gate.reshape(b, t, hq * d).astype(F32))
+        attn = attn.reshape(b, t, hq * d)
+        if gated:
+            attn = attn * jax.nn.sigmoid(
+                gate.reshape(b, t, hq * d).astype(F32))
         return self._dot(attn, o_proj, F32)
+
+    def _gated_attention(self, x, blobs):
+        return self._softmax_attention(x, blobs, gated=True)
+
+    def _attention(self, x, blobs):
+        return self._softmax_attention(x, blobs, gated=False)
+
+    def _short_conv(self, x, blobs):
+        in_proj, conv, out_proj = blobs
+        return self._dot(
+            gated_short_conv(self._dot(x, in_proj), conv), out_proj, F32)
 
     def _gated_delta_net(self, x, blobs):
         in_qkvz, in_ba, conv, a_log, dt_bias, norm, out_proj = blobs
@@ -239,14 +406,11 @@ class HybridMoELM:
         b, t, _ = x.shape
         hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
         dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
-        width = c["linear_conv_kernel_dim"]
         channels = 2 * hk * dk + hv * dv
         qkvz = self._dot(x, in_qkvz)
         ba = self._dot(x, in_ba, F32)
         mixed, z = qkvz[..., :channels], qkvz[..., channels:]
-        padded = jnp.pad(mixed, ((0, 0), (width - 1, 0), (0, 0)))
-        mixed = jax.nn.silu(sum(
-            padded[:, j:j + t].astype(F32) * conv[:, j] for j in range(width)))
+        mixed = jax.nn.silu(causal_depthwise_conv(mixed, conv))
         q = mixed[..., :hk * dk].reshape(b, t, hk, dk)
         k = mixed[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk)
         v = mixed[..., 2 * hk * dk:].reshape(b, t, hv, dv)
@@ -260,15 +424,18 @@ class HybridMoELM:
         o = gated_delta_rule(q, k, v, g, beta,
                              compute_dtype=self.compute_dtype)
         o = norm * o * jax.lax.rsqrt(
-            jnp.mean(o * o, -1, keepdims=True) + c["rms_norm_eps"])
+            jnp.mean(o * o, -1, keepdims=True) + c["eps"])
         o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(F32))
         return self._dot(o.reshape(b, t, hv * dv), out_proj, F32)
 
-    def _route(self, h2d, norm_w, w_router):
+    def _route(self, h2d, norm_w, w_router, bias=None):
         # the router reads the float32 normed input, recomputed here so that
         # only its compute-dtype copy is kept for the experts
-        x2d = rms_norm0(h2d, norm_w, self.config["rms_norm_eps"])
-        weights, ids = moe.route(x2d, w_router, self.config["num_experts_per_tok"])
+        c = self.config
+        weights, ids = moe.route(
+            self._norm(h2d, norm_w), w_router, c["num_experts_per_tok"],
+            scores=c["router_scores"], bias=bias,
+            scale=c["routed_scaling_factor"], eps=c["topk_eps"])
         order, counts = moe.plan(ids, *self.experts_held)
         return weights, ids, order, counts
 
@@ -287,61 +454,91 @@ class HybridMoELM:
         return y * jax.nn.sigmoid(
             jnp.dot(x2d.astype(F32), w_s, precision=jax.lax.Precision.HIGHEST))
 
-    def _layer(self, params, i: int, x):
-        """``h = x + mixer(norm(x)); y = h + moe(norm(h))``; also the held
-        experts' assignment counts ``(n,)``."""
-        eps = self.config["rms_norm_eps"]
+    def _dense_mlp(self, x2d, blobs):
+        return moe.gated_mlp(x2d, *blobs, self.compute_dtype)
+
+    def _layer(self, params, i: int, x, bias=None):
+        """``h = x + mixer(norm(x)); y = h + ffn(norm(h))``; of a layer with
+        routed experts also the held experts' assignment counts ``(n,)``
+        and, given its selection ``bias``, every expert's load
+        ``(num_experts,)``; else ``None``."""
+        c = self.config
         cd = self.compute_dtype or F32
-        attention = self.is_attention_layer(i)
+        kind = c["mixers"][i]
         with jax.named_scope(f"RMSNorm:l{i}_n1"):
-            normed = rms_norm0(x, params[f"l{i}_n1"][0], eps).astype(cd)
-        kind = "GatedAttention" if attention else "GatedDeltaNet"
-        mixer = self._gated_attention if attention else self._gated_delta_net
-        with jax.named_scope(f"{kind}:l{i}_mixer"):
-            h = x + jax.checkpoint(mixer, policy=MIXER_KEEPS)(
-                normed, params[f"l{i}_mixer"])
+            normed = self._norm(x, params[f"l{i}_n1"][0]).astype(cd)
+        with jax.named_scope(f"{MIXERS[kind]}:l{i}_mixer"):
+            h = x + jax.checkpoint(
+                getattr(self, "_" + kind), policy=MIXER_KEEPS)(
+                    normed, params[f"l{i}_mixer"])
         b, t, e = h.shape
         with jax.named_scope(f"RMSNorm:l{i}_n2"):
-            normed = rms_norm0(h, params[f"l{i}_n2"][0], eps).astype(cd)
+            normed = self._norm(h, params[f"l{i}_n2"][0]).astype(cd)
             normed = normed.reshape(b * t, e)
+        if c["ffns"][i] == "dense":
+            with jax.named_scope(f"DenseMLP:l{i}_mlp"):
+                y = jax.checkpoint(self._dense_mlp)(
+                    normed, params[f"l{i}_mlp"])
+            return h + y.reshape(b, t, e), None, None
         with jax.named_scope(f"MoERouter:l{i}_router"):
             weights, ids, order, counts = jax.checkpoint(self._route)(
                 h.reshape(b * t, e), params[f"l{i}_n2"][0],
-                params[f"l{i}_router"][0])
+                *params[f"l{i}_router"], bias)
+            # what the balancing rule reads: every expert's load, held or not
+            load = None if bias is None else moe.load(ids, c["num_experts"])
         with jax.named_scope(f"MoEExperts:l{i}_experts"):
-            routed = jax.checkpoint(self._held_experts)(
+            y = jax.checkpoint(self._held_experts)(
                 normed, weights, ids, order, counts, params[f"l{i}_experts"])
-        with jax.named_scope(f"MoEShared:l{i}_shared"):
-            shared = jax.checkpoint(self._shared_expert)(
-                normed, params[f"l{i}_shared"])
-        return h + (routed + shared).reshape(b, t, e), counts
+        if c["shared_expert_intermediate_size"]:
+            with jax.named_scope(f"MoEShared:l{i}_shared"):
+                shared = jax.checkpoint(self._shared_expert)(
+                    normed, params[f"l{i}_shared"])
+            y = y + shared
+        return h + y.reshape(b, t, e), counts, load
 
-    def _hidden(self, params, tokens):
+    def _hidden(self, params, tokens, stats=None):
+        """The last layer's output, the held experts' counts ``(routed
+        layers, n)`` and, by router group, the load of every expert whose
+        selection bias ``stats`` holds."""
         tokens = tokens.astype(jnp.int32)
         with jax.named_scope("Embedding:embed"):
             x = jnp.take(params["embed"][0], tokens, axis=0)
-        counts = []
+        biases = {g: blobs[0] for g, blobs in (stats or {}).items()}
+        counts, loads = [], {}
         for i in range(self.depth):
-            x, c = self._layer(params, i, x)
-            counts.append(c)
-        return x, jnp.stack(counts)
+            group = f"l{i}_router"
+            x, held, load = self._layer(params, i, x, biases.get(group))
+            if held is not None:
+                counts.append(held)
+            if load is not None:
+                loads[group] = load
+        if not counts:  # no layer routes
+            return x, jnp.zeros((0, self.experts_held[1]), jnp.int32), loads
+        return x, jnp.stack(counts), loads
+
+    def _head_blobs(self, params):
+        """The final norm's weight and the head as ``(E, vocab)``: a tied
+        head is the embedding's transpose, which the product absorbs."""
+        head = (params["embed"][0].T if self.config["tied"]
+                else params["head"][0])
+        return params["norm_f"][0], head
 
     def _head(self, x, norm_f, head):
-        return self._dot(
-            rms_norm0(x, norm_f, self.config["rms_norm_eps"]), head, F32)
+        return self._dot(self._norm(x, norm_f), head, F32)
 
-    def forward_logits(self, params, tokens):
+    def forward_logits(self, params, tokens, stats=None):
         """``(B, T)`` int tokens -> ``(B, T, vocab)`` float32 logits."""
-        x, _ = self._hidden(params, tokens)
+        x, _, _ = self._hidden(params, tokens, stats)
         with jax.named_scope("LMHead:head"):
-            return self._head(x, params["norm_f"][0], params["head"][0])
+            return self._head(x, *self._head_blobs(params))
 
     def loss_fn(self, params, stats, batch, rng=None, train=True):
         """Next-token cross-entropy over the global token count.  Returns
         ``(loss, (aux, stats))``; ``aux`` is empty: logits of ``(B, T,
         vocab)`` are not kept beside a training step
-        (``forward_logits`` gives them)."""
-        x, _ = self._hidden(params, batch["tokens"])
+        (``forward_logits`` gives them).  A training step moves each
+        selection bias in ``stats`` one step of its balancing rule."""
+        x, _, loads = self._hidden(params, batch["tokens"], stats)
         targets = batch["targets"].astype(jnp.int32)
 
         def nll_sum(x, norm_f, head):
@@ -349,24 +546,30 @@ class HybridMoELM:
             return -jnp.sum(jnp.take_along_axis(logp, targets[..., None], -1))
 
         with jax.named_scope("LMHead:head"):
-            total = jax.checkpoint(nll_sum)(
-                x, params["norm_f"][0], params["head"][0])
+            total = jax.checkpoint(nll_sum)(x, *self._head_blobs(params))
+        if train and loads:
+            rate = self.config["expert_bias_update_rate"]
+            stats = {**stats, **{
+                g: [moe.balance(stats[g][0], load, rate), load]
+                for g, load in loads.items()}}
         return total / jnp.asarray(targets.size, F32), ({}, stats)
 
     def forward(self, params, stats, batch, rng=None):
-        return {"logits": self.forward_logits(params, batch["tokens"])}
+        return {"logits": self.forward_logits(params, batch["tokens"], stats)}
 
-    def routing_counts(self, params, tokens):
-        """Assignments each held expert receives, per layer: ``(layers, n)``
-        int32 for the ``(B, T)`` tokens given."""
-        return self._hidden(params, tokens)[1]
+    def routing_counts(self, params, tokens, stats=None):
+        """Assignments each held expert receives, per layer that routes
+        (``routed_layers``): ``(routed layers, n)`` int32 for the ``(B, T)``
+        tokens given."""
+        return self._hidden(params, tokens, stats)[1]
 
     # ------------------------------------------------------------------
     def prefill_with_kv(self, *args, **kwargs):
         raise NotImplementedError(
             f"{self.name}: generation is not supported — a linear-attention "
-            "layer decodes from a recurrent state beside the paged K/V, which "
-            "serve/ does not have (ROADMAP R5)")
+            "layer decodes from a recurrent state, a short convolution from "
+            "its last inputs, beside the paged K/V, which serve/ does not "
+            "have (ROADMAP R5)")
 
     decode_step_with_kv = prefill_with_kv
 
@@ -375,9 +578,9 @@ class HybridMoELM:
 
 
 def routing_gauges(counts, tokens: int) -> Dict[str, List[float]]:
-    """From ``routing_counts`` (``(layers, n)``) and the number of tokens
-    routed: per layer, assignments to held experts a token, and the largest
-    held expert's load over the mean."""
+    """From ``routing_counts`` (``(routed layers, n)``) and the number of
+    tokens routed: per layer, assignments to held experts a token, and the
+    largest held expert's load over the mean."""
     counts = np.asarray(counts, np.float64)
     mean = np.maximum(counts.mean(axis=1), 1e-30)
     return {

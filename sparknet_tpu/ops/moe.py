@@ -1,5 +1,6 @@
-"""Sparse mixture-of-experts pieces: a top-k router over ALL experts and
-the part of the result that the experts HELD HERE give.
+"""Sparse mixture-of-experts pieces: a top-k router over ALL experts
+(softmax or sigmoid scores, with or without a selection bias) and the part
+of the result that the experts HELD HERE give.
 
 An expert-parallel deployment divides a layer's experts over chips; each
 chip routes every token over the whole published router width, and adds
@@ -35,14 +36,46 @@ HIGHEST = jax.lax.Precision.HIGHEST
 ROWS_SLACK = 2.0
 
 
-def route(x, w_router, top_k: int):
+def route(x, w_router, top_k: int, *, scores: str = "softmax", bias=None,
+          scale: float = 1.0, eps: float = 0.0):
     """``x``: ``(N, E)``; ``w_router``: ``(E, experts)``.  Float32 at full
-    precision throughout: a top-k over rounded probabilities picks other
-    experts.  Returns the renormalised weights ``(N, top_k)`` and the
-    expert ids ``(N, top_k)``."""
+    precision throughout: a top-k over rounded scores picks other experts.
+    ``scores`` is ``softmax`` over the experts or ``sigmoid`` of each
+    logit.  With a selection ``bias`` ``(experts,)`` the top-k is taken on
+    ``scores + bias`` and the weights are gathered from the UNbiased scores;
+    no gradient reaches the bias.  The weights are renormalised, ``w /
+    (sum(w) + eps)``, then times ``scale``.  Returns the weights ``(N,
+    top_k)`` and the expert ids ``(N, top_k)``."""
     logits = jnp.dot(x.astype(F32), w_router.astype(F32), precision=HIGHEST)
-    weights, ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return weights / jnp.sum(weights, axis=-1, keepdims=True), ids
+    if scores not in ("softmax", "sigmoid"):
+        raise ValueError(f"router scores {scores!r}: softmax or sigmoid")
+    s = (jax.nn.softmax(logits, axis=-1) if scores == "softmax"
+         else jax.nn.sigmoid(logits))
+    if bias is None:
+        weights, ids = jax.lax.top_k(s, top_k)
+    else:
+        _, ids = jax.lax.top_k(
+            s + jax.lax.stop_gradient(bias.astype(F32)), top_k)
+        weights = jnp.take_along_axis(s, ids, axis=-1)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    # no operation is traced for a term at its identity (eps 0, scale 1):
+    # a softmax router's program stays what it was
+    weights = weights / (total + eps if eps else total)
+    return (weights * scale if scale != 1.0 else weights), ids
+
+
+def load(ids, experts: int):
+    """Assignments each of the ``experts`` received: ``(experts,)`` float32
+    from the ids ``(N, top_k)`` of one step's tokens."""
+    return jnp.zeros((experts,), F32).at[ids.reshape(-1)].add(1.0)
+
+
+def balance(bias, load, rate: float):
+    """The selection bias after one step of the balancing rule that goes
+    with it (auxiliary-loss-free balancing, arXiv:2408.15664): up by ``rate``
+    where an expert received less than the mean load, down by ``rate`` where
+    more.  No gradient and no optimizer state: the step's ``load`` decides."""
+    return bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
 def plan(ids, lo: int, n: int):

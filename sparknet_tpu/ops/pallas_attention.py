@@ -81,9 +81,12 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T without materializing b.T
 
 
 def accepts(hq: int, hkv: int, d: int, dtype) -> bool:
-    """What ``flash_attention`` takes in place, without a transpose: heads
-    of whole lanes, query heads in whole groups, a dtype the MXU takes."""
-    return (d % LANES == 0 and hq % hkv == 0
+    """What ``flash_attention`` is worth taking for: query heads in whole
+    groups, a dtype the MXU takes, and heads of whole lanes (read in place)
+    or a K/V head's group of whole lanes side by side (heads-first: four
+    heads of 64 are 256 lanes of queries against 64 of keys)."""
+    return (hq % hkv == 0
+            and (d % LANES == 0 or (hq // hkv * d) % LANES == 0)
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)))
 
 
