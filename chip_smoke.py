@@ -15,8 +15,9 @@ user would call, at the full width of CaffeNet:
   averaging round;
 - ``kernels``: every Pallas kernel ``lowerable()`` routes to by default,
   compiled (``interpret=False``), run and compared with its dense/unfused
-  reference at the shapes the LM produces today and at one real head shape;
-  the opt-in LRN kernels compiled and compared once;
+  reference at the shapes the LM produces today and at one real head shape,
+  the sequence models' loss at the two sequence cells' shapes; the opt-in LRN
+  kernels compiled and compared once;
 - ``lm-train``: ``apps.lm_app.main`` at its default preset with
   ``attention=auto``, so the default-on flash path is reached through a
   normal entry point.
@@ -57,6 +58,14 @@ FULL = {
     ),
     "comm_legs": (
         ("fp32", False), ("bf16", False), ("int8", False), ("int8", True),
+    ),
+    # (name, rows, width, vocabulary, head as (vocab, E)): the loss kernels at
+    # the two sequence cells' shapes, lfm2moe-train-8k's tied head and
+    # qwen3next-train-8k's untied one with its ragged tail (which the kernels
+    # take and lm_loss.nll_sum does not hand them: PERF.md section 6, PR 32)
+    "lm_loss_shapes": (
+        ("lfm2", 16384, 2048, 8192, True),
+        ("qwen3next", 16384, 2048, 18992, False),
     ),
     "lrn_shape": (8, 96, 55, 55),
     "lm_rounds": 4,
@@ -340,6 +349,51 @@ def _attention_kernels(name, b, t, h, d, dtype, interpret):
     return {k: float("%.2e" % e) for k, e in errs.items()}
 
 
+def _lm_loss_kernels(name, rows, width, vocab, vocab_first, interpret):
+    """The sequence models' loss through its kernels in bfloat16, in the
+    blocks the program runs them in: loss, dx and dhead against the XLA
+    oracle in float32 at full matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparknet_tpu.ops import lm_loss, pallas_lm_loss
+
+    key = jax.random.key(0)
+    x = jax.random.normal(jax.random.fold_in(key, 0), (rows, width))
+    head = 0.02 * jax.random.normal(
+        jax.random.fold_in(key, 1),
+        (vocab, width) if vocab_first else (width, vocab))
+    targets = jax.random.randint(jax.random.fold_in(key, 2), (rows,), 0, vocab)
+    targets = targets.at[0].set(vocab - 1)  # the last column of the last block
+
+    def kernels(x, head):
+        return jnp.sum(pallas_lm_loss.nll_rows(
+            x, head, targets, jnp.bfloat16, vocab_first=vocab_first,
+            interpret=interpret))
+
+    def oracle(x, head):
+        return lm_loss._xla_nll_sum(
+            x, head, targets, jnp.dtype(jnp.float32), vocab_first)
+
+    grads = lambda f: jax.jit(jax.value_and_grad(f, argnums=(0, 1)))  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        ref, (ref_dx, ref_dhead) = grads(oracle)(x, head)
+    loss, (dx, dhead) = grads(kernels)(x, head)
+    errs = {
+        "loss": abs(float(loss) - float(ref)) / abs(float(ref)),
+        "dx": _rel_err(dx, ref_dx),
+        "dhead": _rel_err(dhead, ref_dhead),
+    }
+    for what, err in errs.items():
+        tol = 1e-3 if what == "loss" else 5e-2
+        assert np.isfinite(err) and err < tol, (
+            f"lm loss {what} at {name} {(rows, width, vocab)}: rel err "
+            f"{err} >= {tol}"
+        )
+    return {k: float("%.2e" % e) for k, e in errs.items()}
+
+
 def _comm_kernels(legs, lm_args, interpret):
     """fused encode / apply / correction against the unfused closures,
     through the trainer that calls them: the LM's own parameter leaves,
@@ -453,6 +507,10 @@ def phase_kernels(sizes):
     for name, b, t, h, d, dtype in sizes["attention_shapes"]:
         out[f"attention_{name}_B{b}_T{t}_H{h}_D{d}_{dtype}"] = (
             _attention_kernels(name, b, t, h, d, dtype, interpret)
+        )
+    for name, rows, width, vocab, vocab_first in sizes["lm_loss_shapes"]:
+        out[f"lm_loss_{name}_{rows}x{width}x{vocab}_bfloat16"] = (
+            _lm_loss_kernels(name, rows, width, vocab, vocab_first, interpret)
         )
     out["comm_lm_leaves"] = _comm_kernels(
         sizes["comm_legs"], sizes["lm_args"], interpret

@@ -55,7 +55,12 @@ runs under one ``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md
 sublayers only the residual stream and the normed input are kept (and, of
 an attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
 sublayer's forward is recomputed in its backward, and autodiff names both
-``transpose(jvp(<Type>:<name>))``, i.e. backward.
+``transpose(jvp(<Type>:<name>))``, i.e. backward.  The loss is one operation
+(``ops/lm_loss.nll_sum``, under ``LMHead:head`` with the final norm and
+recomputed like a sublayer: ``HEAD_KEEPS``): where its kernels take the
+shapes it walks the vocabulary in blocks with its own backward, reads a tied
+head where the embedding lies and keeps a float32 ``lse`` a row; elsewhere
+``log_softmax`` over float32 logits, which are not kept.
 
 Layouts.  Gated DeltaNet: ``in_proj_qkvz`` / ``in_proj_ba`` columns ``[q |
 k | v | z]`` and ``[b | a]``, heads contiguous inside each part.  Gated
@@ -73,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparknet_tpu.models.transformer_lm import _Group, _Ref
-from sparknet_tpu.ops import moe
+from sparknet_tpu.ops import lm_loss, moe
 from sparknet_tpu.ops.attention import causal_gqa_attention
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
 from sparknet_tpu.ops.pallas_attention import SAVED as FLASH_SAVED
@@ -84,6 +89,9 @@ F32 = jnp.float32
 # its row log-sum-exp (269 MB an attention layer at 2 x 8,192 tokens), and so
 # does not run the forward kernel a second time; nothing else is kept
 MIXER_KEEPS = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
+# the head's recomputation is the final norm's: the loss kernels' row
+# log-sum-exp is kept (64 KB), the XLA path's logits are not
+HEAD_KEEPS = jax.checkpoint_policies.save_only_these_names(*lm_loss.SAVED)
 
 # the keys of a qwen3_next config.json that decide a shape or an equation
 CONFIG_KEYS = (
@@ -539,14 +547,17 @@ class HybridMoELM:
         (``forward_logits`` gives them).  A training step moves each
         selection bias in ``stats`` one step of its balancing rule."""
         x, _, loads = self._hidden(params, batch["tokens"], stats)
-        targets = batch["targets"].astype(jnp.int32)
+        targets = batch["targets"]
+        tied = self.config["tied"]
 
         def nll_sum(x, norm_f, head):
-            logp = jax.nn.log_softmax(self._head(x, norm_f, head), axis=-1)
-            return -jnp.sum(jnp.take_along_axis(logp, targets[..., None], -1))
+            return lm_loss.nll_sum(self._norm(x, norm_f), head, targets,
+                                   self.compute_dtype, vocab_first=tied)
 
         with jax.named_scope("LMHead:head"):
-            total = jax.checkpoint(nll_sum)(x, *self._head_blobs(params))
+            # a tied head is read where the embedding lies, (vocab, E)
+            total = jax.checkpoint(nll_sum, policy=HEAD_KEEPS)(
+                x, params["norm_f"][0], params["embed" if tied else "head"][0])
         if train and loads:
             rate = self.config["expert_bias_update_rate"]
             stats = {**stats, **{

@@ -17,6 +17,7 @@ TINY = {
     "classes": 10,
     "rounds": 2,
     "attention_shapes": (("tiny", 1, 8, 1, 8, "float32"),),
+    "lm_loss_shapes": (("tiny", 32, 128, 300, True),),
     "comm_legs": (("int8", True),),
     "lrn_shape": (1, 5, 3, 3),
     "lm_rounds": 2,
@@ -68,6 +69,8 @@ def test_phase_plumbing_on_the_cpu_mesh(tmp_path):
     assert phases["train-1chip"]["rounds"] == 2
     assert phases["train-4chip"]["devices_per_leaf"] == 4
     assert phases["train-4chip"]["worker_param_spread"] == 0.0
+    assert set(phases["kernels"]["lm_loss_tiny_32x128x300_bfloat16"]) == {
+        "loss", "dx", "dhead"}
     assert phases["lm-train"]["flash_kernel"] is False  # dense off the TPU
     assert list(tmp_path.glob("training_log_*.txt"))  # logs land in out_dir
 

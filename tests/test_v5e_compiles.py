@@ -12,12 +12,20 @@ imports every test file.  All such tests stay in this one file.
 
 import os
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from sparknet_tpu.ops import pallas_attention, pallas_delta_rule
+from sparknet_tpu.models import hybrid_lm
+from sparknet_tpu.ops import (
+    lm_loss,
+    pallas_attention,
+    pallas_delta_rule,
+    pallas_lm_loss,
+)
 
 
 @pytest.fixture(scope="module")
@@ -89,3 +97,60 @@ def test_flash_attention_kernels_compile_for_the_v5e(
     names = ["flash_attention_forward"] + (
         ["flash_attention_dq", "flash_attention_dkv"] if backward else [])
     assert all(name in compiled.as_text() for name in names)
+
+
+# the sequence models' loss, forward + backward in bfloat16 at rows x width x
+# vocabulary: lfm2moe-train-8k's window (a tied head, read as the embedding
+# lies), qwen3next-train-8k's (18,992 = 18 blocks of 1,024 + 560: the last
+# block hangs over the edge; ``nll_rows`` takes it, ``lm_loss.nll_sum`` does
+# not hand it over) and the step check's 2 x 1,024 tokens
+@pytest.mark.parametrize("rows, width, vocab, vocab_first", [
+    (16384, 2048, 8192, True),
+    (16384, 2048, 18992, False),
+    (2048, 2048, 8192, True),
+])
+def test_lm_loss_kernels_compile_for_the_v5e(
+        one_chip, rows, width, vocab, vocab_first):
+    shape = lambda s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+
+    def gradients(x, head, targets):
+        return jax.value_and_grad(
+            lambda x, head: jnp.sum(pallas_lm_loss.nll_rows(
+                x, head, targets, jnp.bfloat16, vocab_first=vocab_first,
+                interpret=False)), argnums=(0, 1))(x, head)
+
+    compiled = jax.jit(gradients).lower(
+        shape((rows, width)),
+        shape((vocab, width) if vocab_first else (width, vocab)),
+        shape((rows,), jnp.int32)).compile()
+    assert all(name in compiled.as_text()
+               for name in ("lm_loss_forward", "lm_loss_backward"))
+
+
+def test_no_reduce_window_over_the_vocabulary(one_chip, monkeypatch):
+    """``HybridMoELM.loss_fn``'s head at lfm2moe-train-8k's shape, (2, 8,192)
+    tokens x 8,192 columns.  Of ``log_softmax`` over ``(B, T, vocab)`` float32
+    logits the v5e's compiler made a ``reduce-window`` of size 16,383 for the
+    row maximum, quadratic in the vocabulary: 54.6 ms where the product is 3,
+    forward and again in the backward (PERF.md section 6, PR 31 and PR 32).
+    The loss hands XLA no such tensor: this guards against its coming back."""
+    for module in (lm_loss, pallas_lm_loss):  # the path, and no interpreter
+        monkeypatch.setattr(module, "lowerable", lambda: True)
+    shape = lambda s, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+
+    def head_of_loss_fn(x, embed, targets):
+        with jax.named_scope("LMHead:head"):
+            return jax.checkpoint(
+                lambda x, embed: lm_loss.nll_sum(
+                    x, embed, targets, jnp.bfloat16, vocab_first=True),
+                policy=hybrid_lm.HEAD_KEEPS)(x, embed)
+
+    text = jax.jit(jax.value_and_grad(head_of_loss_fn, argnums=(0, 1))).lower(
+        shape((2, 8192, 2048)), shape((8192, 2048)),
+        shape((2, 8192), jnp.int32)).compile().as_text()
+    # the recomputation keeps ``lse``: each kernel is one custom call
+    for kernel in ("lm_loss_forward", "lm_loss_backward"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = .* custom-call\(", text)) == 1
+    assert "reduce-window" not in text
