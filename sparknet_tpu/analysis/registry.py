@@ -105,6 +105,10 @@ CANONICAL_METRICS = {
     # sparse-expert routing of models/hybrid_lm.py (--model_config)
     "sparknet_lm_held_assignments_per_token": ("layer",),
     "sparknet_lm_held_load_skew": ("layer",),
+    # the learned selection of a selected-key attention layer
+    # (ops/sparse_attention.py)
+    "sparknet_lm_indexer_loss": ("layer",),
+    "sparknet_lm_selection_mass": ("layer",),
     # autoregressive generation serving (serve/generate.py KV arena +
     # serve/batcher.py StreamBatcher + serve/fleet.py stream routing)
     "sparknet_kv_blocks_total": (),

@@ -33,6 +33,7 @@ cache when --corpus is omitted)
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import tempfile
 
@@ -119,6 +120,50 @@ def set_routing_gauges(lm, stacked_params, tokens, stacked_stats=None):
                 gauges["held_load_skew"]):
             tm.lm_held_assignments.labels(str(i)).set(per_token)
             tm.lm_held_load_skew.labels(str(i)).set(skew)
+    return gauges
+
+
+@functools.lru_cache(maxsize=4)
+def selection_probe(lm):
+    """``(stacked params, stacked stats, tokens (B, T)) ->
+    models/hybrid_lm.selection_readings`` of worker 0 (sliced inside the jit,
+    so no copy of the weights is made), jitted once a model: every batch of
+    one shape runs the one compiled forward pass."""
+    import jax
+
+    from sparknet_tpu.parallel import first_worker
+
+    return jax.jit(lambda p, s, tokens: lm.selection_readings(
+        first_worker(p), tokens, first_worker(s)))
+
+
+def set_selection_gauges(lm, stacked_params, tokens, stacked_stats=None,
+                         probe=None):
+    """Of one batch of ``(B, T)`` tokens, per selected-key attention layer
+    (``models/hybrid_lm.selection_readings``): the alignment loss and the
+    share of the dense attention's probability the selected keys hold, set
+    as the gauges ``sparknet_lm_indexer_loss`` / ``sparknet_lm_selection_mass``
+    (and ``sparknet_kernel_path{kernel="sparse_attention"}``: 0, the XLA
+    path, until a kernel takes a keep-mask) where training metrics are on,
+    and returned with the pass's ``held_counts``; ``{}`` for a model without
+    such a layer.  Outside the timed loop: a forward pass,
+    ``selection_probe(lm)`` or the caller's compiled ``probe`` of it."""
+    from sparknet_tpu import obs
+
+    layers = [i for i, kind in enumerate(lm.config["mixers"])
+              if kind == "dsa_attention"]
+    if not layers:
+        return {}
+    readings = (probe or selection_probe(lm))(
+        stacked_params, stacked_stats or {}, tokens)
+    gauges = {k: np.asarray(v).tolist() for k, v in readings.items()}
+    tm = obs.training_metrics()
+    if tm is not None:
+        tm.kernel_path.labels("sparse_attention").set(0.0)
+        for i, loss, mass in zip(
+                layers, gauges["indexer_loss"], gauges["selection_mass"]):
+            tm.lm_indexer_loss.labels(str(i)).set(loss)
+            tm.lm_selection_mass.labels(str(i)).set(mass)
     return gauges
 
 
@@ -477,6 +522,9 @@ def main(argv=None) -> int:
         first = samplers[0].window_for_round(start_round, 1)["tokens"][0]
         gauges = set_routing_gauges(lm, state.params, first, state.stats)
         log.log(f"routing of the first minibatch, by layer: {gauges}")
+        gauges = set_selection_gauges(lm, state.params, first, state.stats)
+        if gauges:
+            log.log(f"selection of the first minibatch, by layer: {gauges}")
 
     tokens_per_round = n_workers * args.tau * args.batch * args.seq_len
     ring_bytes_per_round = (

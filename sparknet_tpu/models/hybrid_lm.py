@@ -1,6 +1,6 @@
 """Decoder-only LM whose layer pattern, mixers, feed-forwards, norms,
 positions, head counts, router and head are VALUES read from a published
-``config.json``: one block (``_layer``), one class, two families so far.
+``config.json``: one block (``_layer``), one class, three families so far.
 
 - ``qwen3_next`` (Qwen3-Next): linear-attention (Gated DeltaNet) layers with
   one gated softmax-attention layer every ``full_attention_interval``, a
@@ -14,11 +14,23 @@ positions, head counts, router and head are VALUES read from a published
   (sigmoid scores, top-k on ``scores + expert_bias``, weights from the
   unbiased scores; the bias moves by its balancing rule, below), plain
   RMSNorm, the head tied to the embedding.
+- ``KeyeVL2`` (Keye-VL-2.0's language model): every layer plain grouped
+  attention (RMSNorm on q and k, rotary on the whole head) over the
+  ``sa_config.topk`` keys a learned indexer selects for each query
+  (``ops/sparse_attention.py``: index scores from ``indexer_num_heads`` small
+  heads against ONE shared key, a per-row threshold, a masked pass), with the
+  indexer's alignment loss beside the language model's; routed experts
+  without a shared expert (softmax router), plain RMSNorm, an untied head;
+  its seeded weights start at ``init_std`` (the embedding normal(0, 1), the
+  matrices that write to the residual stream 0.02 / sqrt(2 x layers)): at
+  0.02 throughout, the flat attention of an all-attention stack hands every
+  router the same input.
 
-``describe`` turns either file's keys into one description: a mixer kind a
+``describe`` turns a file's keys into one description: a mixer kind a
 layer (``MIXERS``: ``gated_delta_net``, ``gated_attention``, ``short_conv``,
-``attention``), a feed-forward kind a layer (``dense`` or ``moe``), and the
-values the equations take.  Nothing below it asks which family it builds.
+``attention``, ``dsa_attention``), a feed-forward kind a layer (``dense`` or
+``moe``), and the values the equations take.  Nothing below it asks which
+family it builds.
 Beside the published keys, three of this system's own:
 
 - ``experts_held: [lo, n]`` — the contiguous range of routed experts this
@@ -55,7 +67,11 @@ runs under one ``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md
 sublayers only the residual stream and the normed input are kept (and, of
 an attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
 sublayer's forward is recomputed in its backward, and autodiff names both
-``transpose(jvp(<Type>:<name>))``, i.e. backward.  The loss is one operation
+``transpose(jvp(<Type>:<name>))``, i.e. backward.  A ``dsa_attention`` layer
+opens four scopes where the others open one (``_dsa_mixer``) and hands an
+auxiliary scalar, its alignment loss, up to ``loss_fn`` as a routed layer
+hands up its counts; what it keeps between forward and backward is stated
+there.  The loss is one operation
 (``ops/lm_loss.nll_sum``, under ``LMHead:head`` with the final norm and
 recomputed like a sublayer: ``HEAD_KEEPS``): where its kernels take the
 shapes it walks the vocabulary in blocks with its own backward, reads a tied
@@ -65,7 +81,9 @@ head where the embedding lies and keeps a float32 ``lse`` a row; elsewhere
 Layouts.  Gated DeltaNet: ``in_proj_qkvz`` / ``in_proj_ba`` columns ``[q |
 k | v | z]`` and ``[b | a]``, heads contiguous inside each part.  Gated
 attention: ``q_proj`` head-major, each head ``[q | gate]``.  Short
-convolution: ``in_proj`` columns ``[B | C | u]``.
+convolution: ``in_proj`` columns ``[B | C | u]``.  Selected-key attention:
+the plain attention's six blobs, then the indexer's ``index_q`` (heads
+contiguous), ``index_k``, its LayerNorm's weight and bias, ``index_w``.
 """
 
 from __future__ import annotations
@@ -78,7 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sparknet_tpu.models.transformer_lm import _Group, _Ref
-from sparknet_tpu.ops import lm_loss, moe
+from sparknet_tpu.ops import lm_loss, moe, sparse_attention
 from sparknet_tpu.ops.attention import causal_gqa_attention
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
 from sparknet_tpu.ops.pallas_attention import SAVED as FLASH_SAVED
@@ -87,8 +105,10 @@ from sparknet_tpu.ops.short_conv import causal_depthwise_conv, gated_short_conv
 F32 = jnp.float32
 # a mixer's recomputation keeps what the flash kernels name, their output and
 # its row log-sum-exp (269 MB an attention layer at 2 x 8,192 tokens), and so
-# does not run the forward kernel a second time; nothing else is kept
-MIXER_KEEPS = jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED)
+# does not run the forward kernel a second time (the selected-key attention's
+# blocks name theirs likewise); nothing else is kept
+MIXER_KEEPS = jax.checkpoint_policies.save_only_these_names(
+    *FLASH_SAVED, *sparse_attention.SAVED)
 # the head's recomputation is the final norm's: the loss kernels' row
 # log-sum-exp is kept (64 KB), the XLA path's logits are not
 HEAD_KEEPS = jax.checkpoint_policies.save_only_these_names(*lm_loss.SAVED)
@@ -111,12 +131,25 @@ LFM2_MOE_KEYS = (
     "rope_parameters", "norm_eps", "conv_L_cache", "intermediate_size",
     "num_experts", "num_experts_per_tok", "moe_intermediate_size",
 )
+# and of a KeyeVL2 one (``sa_config``: ``SA_KEYS``)
+KEYE_VL2_KEYS = (
+    "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "rope_theta", "rms_norm_eps",
+    "sa_config", "num_experts", "num_experts_per_tok", "moe_intermediate_size",
+)
+SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
+           "topk", "q_chunk_size")
 # a mixer kind's scope type; its blobs and its function are the model's
-# ``_mixer_shapes`` and ``_<kind>``
+# ``_mixer_shapes`` and ``_<kind>`` (``dsa_attention``: ``_dsa_mixer``, which
+# opens the scopes ``DSA_SCOPES`` beside it)
 MIXERS = {
     "gated_delta_net": "GatedDeltaNet", "gated_attention": "GatedAttention",
     "short_conv": "ShortConv", "attention": "Attention",
+    "dsa_attention": "DSAAttention",
 }
+DSA_SCOPES = ("DSAIndexer", "DSASelect", "DSAAttention", "DSAIndexerLoss")
+# the indexer's LayerNorm (DeepSeek-V3.2-Exp's public implementation)
+INDEX_NORM_EPS = 1e-6
 # the renormalisation of LFM2's top-k weights: ``w / (sum(w) + 1e-6)``
 LFM2_TOPK_EPS = 1e-6
 
@@ -183,7 +216,40 @@ def _describe_lfm2_moe(config: Dict, name: str) -> Dict:
     )
 
 
-DESCRIBERS = {"qwen3_next": _describe_qwen3_next, "lfm2_moe": _describe_lfm2_moe}
+def _describe_keye_vl2(config: Dict, name: str) -> Dict:
+    c = _take(config, KEYE_VL2_KEYS, name)
+    sa = _take(c["sa_config"], SA_KEYS, name + " sa_config")
+    depth = c["num_hidden_layers"]
+    if sa["indexer_num_kv_heads"] != 1:
+        raise ValueError(f"{name}: the indexer's heads share ONE key head")
+    if config.get("mlp_only_layers") or config.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(f"{name}: every layer routes (mlp_only_layers empty, "
+                         "decoder_sparse_step 1)")
+    if config.get("use_sliding_window", False):
+        raise ValueError(f"{name}: use_sliding_window=true is not supported")
+    return dict(
+        c,
+        mixers=("dsa_attention",) * depth, ffns=("moe",) * depth,
+        eps=c["rms_norm_eps"], zero_centred_norm=False,
+        rotary_dim=c["head_dim"],
+        index_heads=sa["indexer_num_heads"], index_dim=sa["indexer_head_dim"],
+        index_topk=sa["topk"],
+        # the published tiling of the indexer's computation is the query
+        # block of every pass over the scores; it changes no equation
+        index_block=sa["q_chunk_size"],
+        # where the seeded weights start (``init``): at 0.02 throughout, an
+        # all-attention stack on a 0.9-long embedding routes every token
+        # alike (the flat attention's output, the values' mean, is 6 long)
+        init_std={"embed": 1.0, "out": 0.02 * (2 * depth) ** -0.5},
+        shared_expert_intermediate_size=0,
+        router_scores="softmax", expert_bias=False, routed_scaling_factor=1.0,
+        topk_eps=0.0, expert_bias_update_rate=0.0,
+        tied=bool(config.get("tie_word_embeddings", False)),
+    )
+
+
+DESCRIBERS = {"qwen3_next": _describe_qwen3_next, "lfm2_moe": _describe_lfm2_moe,
+              "KeyeVL2": _describe_keye_vl2}
 
 
 def describe(config: Dict, name: str = "HybridMoELM") -> Dict:
@@ -266,7 +332,8 @@ class HybridMoELM:
 
     def is_attention_layer(self, i: int) -> bool:
         """Whether layer ``i`` mixes by softmax attention, gated or plain."""
-        return self.config["mixers"][i] in ("gated_attention", "attention")
+        return self.config["mixers"][i] in (
+            "gated_attention", "attention", "dsa_attention")
 
     # ------------------------------------------------------------------
     def _mixer_shapes(self, kind: str):
@@ -275,30 +342,36 @@ class HybridMoELM:
         e = c["hidden_size"]
         hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
                       c["head_dim"])
-        w, norm = "matrix", "norm"
-        if kind in ("gated_attention", "attention"):
+        # ``out``: a matrix that writes to the residual stream
+        w, norm, out = "matrix", "norm", "out"
+        if kind in ("gated_attention", "attention", "dsa_attention"):
             q_width = (2 if kind == "gated_attention" else 1) * hq * d
-            return [((e, q_width), w), ((e, hkv * d), w), ((e, hkv * d), w),
-                    ((d,), norm), ((d,), norm), ((hq * d, e), w)]
+            blobs = [((e, q_width), w), ((e, hkv * d), w), ((e, hkv * d), w),
+                     ((d,), norm), ((d,), norm), ((hq * d, e), out)]
+            if kind == "dsa_attention":
+                j, di = c["index_heads"], c["index_dim"]
+                blobs += [((e, j * di), w), ((e, di), w), ((di,), "ones"),
+                          ((di,), "zeros"), ((e, j), w)]
+            return blobs
         if kind == "short_conv":
-            return [((e, 3 * e), w), ((e, c["conv_L_cache"]), w), ((e, e), w)]
+            return [((e, 3 * e), w), ((e, c["conv_L_cache"]), w), ((e, e), out)]
         hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
         dk, dv = c["linear_key_head_dim"], c["linear_value_head_dim"]
         channels = 2 * hk * dk + hv * dv
         return [((e, channels + hv * dv), w), ((e, 2 * hv), w),
                 ((channels, c["linear_conv_kernel_dim"]), w),
                 ((hv,), "a_log"), ((hv,), "ones"), ((dv,), "ones"),
-                ((hv * dv, e), w)]
+                ((hv * dv, e), out)]
 
     def _blob_plan(self) -> List[Tuple[str, List[Tuple[Tuple[int, ...], str]]]]:
         c = self.config
         e, v = c["hidden_size"], c["vocab_size"]
         f, fs = c["moe_intermediate_size"], c["shared_expert_intermediate_size"]
         n = self.experts_held[1]
-        w, norm = "matrix", "norm"
+        w, norm, out = "matrix", "norm", "out"
         mlp = lambda width: [((e, width), w), ((e, width), w),  # noqa: E731
-                             ((width, e), w)]
-        plan = [("embed", [((v, e), w)])]
+                             ((width, e), out)]
+        plan = [("embed", [((v, e), "embed")])]
         for i in range(c["num_hidden_layers"]):
             plan.append((f"l{i}_n1", [((e,), norm)]))
             plan.append((f"l{i}_mixer", self._mixer_shapes(c["mixers"][i])))
@@ -308,7 +381,7 @@ class HybridMoELM:
                 continue
             plan.append((f"l{i}_router", [((e, c["num_experts"]), w)]))
             plan.append((f"l{i}_experts", [((n, e, f), w), ((n, e, f), w),
-                                           ((n, f, e), w)]))
+                                           ((n, f, e), out)]))
             if fs:
                 plan.append((f"l{i}_shared", mlp(fs) + [((e, 1), w)]))
         plan.append(("norm_f", [((e,), norm)]))
@@ -317,22 +390,28 @@ class HybridMoELM:
         return plan
 
     def init(self, seed: int = 0):
-        """Matrices normal(0, 0.02); a norm's weight its identity (0 where
-        zero-centred, else 1); the DeltaNet output norm 1; ``A_log = log
-        U(0, 16)``, ``dt_bias = 1``.  The ``stats`` are the routers'
+        """Matrices normal(0, 0.02), but where the description names another
+        deviation for the embedding or for the matrices that write to the
+        residual stream (``init_std``: ``embed``, ``out``); a norm's weight
+        its identity (0 where zero-centred, else 1); the DeltaNet output
+        norm 1; ``A_log = log U(0, 16)``, ``dt_bias = 1``; the indexer's
+        LayerNorm weight 1, bias 0.  The ``stats`` are the routers'
         selection biases and loads, zeros: where the balancing rule starts
         from.  One jitted program with the key as its argument: blob by
         blob, eagerly, the 35 generators take a minute to compile on the
         chip."""
         identity = 0.0 if self.config["zero_centred_norm"] else 1.0
+        std = {"matrix": 0.02, "embed": 0.02, "out": 0.02,
+               **self.config.get("init_std", {})}
 
         def one(key, shape, how):
-            if how == "matrix":
-                return 0.02 * jax.random.normal(key, shape, F32)
+            if how in std:
+                return std[how] * jax.random.normal(key, shape, F32)
             if how == "a_log":
                 return jnp.log(jax.random.uniform(
                     key, shape, F32, minval=2.0 ** -20, maxval=16.0))
-            return jnp.full(shape, identity if how == "norm" else 1.0, F32)
+            return jnp.full(shape, {"norm": identity, "zeros": 0.0}.get(how, 1.0),
+                            F32)
 
         def make(key):
             params: Dict[str, List[jnp.ndarray]] = {}
@@ -370,13 +449,16 @@ class HybridMoELM:
         return rms_norm(
             x, w, self.config["eps"], self.config["zero_centred_norm"])
 
-    def _softmax_attention(self, x, blobs, gated: bool):
-        q_proj, k_proj, v_proj, q_norm, k_norm, o_proj = blobs
+    def _qkv(self, x, blobs, gated: bool = False):
+        """The projections as heads, RMSNorm over each head of ``q`` and
+        ``k``, rotary: ``(q, k, v, gate or None)``."""
+        q_proj, k_proj, v_proj, q_norm, k_norm = blobs[:5]
         c = self.config
         b, t, _ = x.shape
         hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
                       c["head_dim"])
         theta, rotary_dim = c["rope_theta"], c["rotary_dim"]
+        gate = None
         if gated:
             qg = self._dot(x, q_proj).reshape(b, t, hq, 2 * d)
             q, gate = qg[..., :d], qg[..., d:]
@@ -386,22 +468,100 @@ class HybridMoELM:
         v = self._dot(x, v_proj).reshape(b, t, hkv, d)
         q = rotary(self._norm(q, q_norm), theta, rotary_dim)
         k = rotary(self._norm(k, k_norm), theta, rotary_dim)
+        return q, k, v, gate
+
+    def _softmax_attention(self, x, blobs, gated: bool):
+        b, t, _ = x.shape
+        q, k, v, gate = self._qkv(x, blobs, gated)
         attn = causal_gqa_attention(q, k, v, compute_dtype=self.compute_dtype)
         # gated with heads side by side, (B, T, Hq D), as the kernels write
         # the output and o_proj reads it: heads apart, (.., Hq, D) tiles
         # otherwise, and the float32 output and its cotangent are each
         # copied from one tiling to the other (0.8 ms each on the v5e)
-        attn = attn.reshape(b, t, hq * d)
+        attn = attn.reshape(b, t, -1)
         if gated:
-            attn = attn * jax.nn.sigmoid(
-                gate.reshape(b, t, hq * d).astype(F32))
-        return self._dot(attn, o_proj, F32)
+            attn = attn * jax.nn.sigmoid(gate.reshape(b, t, -1).astype(F32))
+        return self._dot(attn, blobs[5], F32)
 
     def _gated_attention(self, x, blobs):
         return self._softmax_attention(x, blobs, gated=True)
 
     def _attention(self, x, blobs):
         return self._softmax_attention(x, blobs, gated=False)
+
+    def _dsa_indexer(self, u, blobs):
+        """The indexer's three projections of ``u``: ``qI (B, T, J, Di)``
+        and its one shared key ``kI (B, T, Di)`` (LayerNorm first), both
+        under rotary and in the compute dtype, and the heads' weights ``(B,
+        T, J)`` float32 with the two scale factors in them."""
+        index_q, index_k, norm_w, norm_b, index_w = blobs
+        c = self.config
+        b, t, _ = u.shape
+        j, di = c["index_heads"], c["index_dim"]
+        cd = self.compute_dtype or F32
+        qi = self._dot(u, index_q, F32).reshape(b, t, j, di)
+        ki = self._dot(u, index_k, F32)
+        mean = jnp.mean(ki, -1, keepdims=True)
+        ki = (ki - mean) * jax.lax.rsqrt(
+            jnp.mean((ki - mean) ** 2, -1, keepdims=True) + INDEX_NORM_EPS
+        ) * norm_w + norm_b
+        qi = rotary(qi, c["rope_theta"], di)
+        ki = rotary(ki[:, :, None, :], c["rope_theta"], di)[:, :, 0]
+        w = self._dot(u, index_w, F32) * (j ** -0.5 * di ** -0.5)
+        return qi.astype(cd), ki.astype(cd), w
+
+    def _dsa_attention(self, x, blobs, mask):
+        """Plain grouped attention over the keys ``mask`` keeps; beside the
+        output, what the alignment loss reads of it: the scaled queries, the
+        keys and the rows' log-sum-exp."""
+        b, t, _ = x.shape
+        q, k, v, _ = self._qkv(x, blobs)
+        q = sparse_attention.scaled_queries(q, self.compute_dtype)
+        k = k.astype(q.dtype)
+        attn, lse = sparse_attention.masked_attention(
+            q, k, v, mask, block_q=self.config["index_block"])
+        return self._dot(attn.reshape(b, t, -1), blobs[5], F32), q, k, lse
+
+    def _dsa_select(self, i: int, normed, blobs):
+        """The indexer's parts and the selection of ``normed``'s queries as
+        bits.  No gradient reaches ``normed`` from here: the indexer learns
+        from its alignment loss alone."""
+        c = self.config
+        with jax.named_scope(f"DSAIndexer:l{i}_indexer"):
+            qi, ki, w = jax.checkpoint(self._dsa_indexer)(
+                jax.lax.stop_gradient(normed), blobs)
+            scores = sparse_attention.index_scores_by_run(
+                *jax.lax.stop_gradient((qi, w, ki)), block_q=c["index_block"])
+        with jax.named_scope(f"DSASelect:l{i}_select"):
+            mask = sparse_attention.select(
+                scores, normed.shape[1], c["index_topk"],
+                block_q=c["index_block"])
+        return qi, ki, w, mask
+
+    def _dsa_mixer(self, i: int, normed, blobs, probe: bool = False):
+        """A selected-key attention layer's mixer under its four scopes:
+        ``Attn(normed)`` and the layer's alignment loss, the mean over its
+        queries (with ``probe``, a pair: that loss and the selected keys'
+        share of the dense attention's probability).  Kept between forward and
+        backward, beside the normed input: the indexer's ``qI`` / ``kI`` /
+        ``w`` (36 MB at 16,384 tokens), the selection as bits (33.5 MB), the
+        scaled queries and the keys in the compute dtype (151 MB), the
+        attention's output and log-sum-exp (270 MB); the index scores of
+        ``(T, T)`` float32 live from ``DSAIndexer`` to ``DSASelect`` in the
+        forward pass alone."""
+        c = self.config
+        b, t, _ = normed.shape
+        qi, ki, w, mask = self._dsa_select(i, normed, blobs[6:])
+        with jax.named_scope(f"DSAAttention:l{i}_mixer"):
+            out, q, k, lse = jax.checkpoint(
+                self._dsa_attention, policy=MIXER_KEEPS)(normed, blobs[:6], mask)
+        with jax.named_scope(f"DSAIndexerLoss:l{i}_align"):
+            align = sparse_attention.alignment_loss(
+                qi, w, ki, q, k, lse, mask, block_q=c["index_block"]) / (b * t)
+        if probe:
+            return out, (align, sparse_attention.selection_mass(
+                q, k, mask, block_q=c["index_block"]))
+        return out, align
 
     def _short_conv(self, x, blobs):
         in_proj, conv, out_proj = blobs
@@ -465,20 +625,26 @@ class HybridMoELM:
     def _dense_mlp(self, x2d, blobs):
         return moe.gated_mlp(x2d, *blobs, self.compute_dtype)
 
-    def _layer(self, params, i: int, x, bias=None):
+    def _layer(self, params, i: int, x, bias=None, probe: bool = False):
         """``h = x + mixer(norm(x)); y = h + ffn(norm(h))``; of a layer with
         routed experts also the held experts' assignment counts ``(n,)``
         and, given its selection ``bias``, every expert's load
-        ``(num_experts,)``; else ``None``."""
+        ``(num_experts,)``; else ``None``; of a selected-key attention layer
+        what ``_dsa_mixer`` hands up (its alignment loss), else ``None``."""
         c = self.config
         cd = self.compute_dtype or F32
         kind = c["mixers"][i]
+        aux = None
         with jax.named_scope(f"RMSNorm:l{i}_n1"):
             normed = self._norm(x, params[f"l{i}_n1"][0]).astype(cd)
-        with jax.named_scope(f"{MIXERS[kind]}:l{i}_mixer"):
-            h = x + jax.checkpoint(
-                getattr(self, "_" + kind), policy=MIXER_KEEPS)(
-                    normed, params[f"l{i}_mixer"])
+        if kind == "dsa_attention":
+            mixed, aux = self._dsa_mixer(i, normed, params[f"l{i}_mixer"], probe)
+            h = x + mixed
+        else:
+            with jax.named_scope(f"{MIXERS[kind]}:l{i}_mixer"):
+                h = x + jax.checkpoint(
+                    getattr(self, "_" + kind), policy=MIXER_KEEPS)(
+                        normed, params[f"l{i}_mixer"])
         b, t, e = h.shape
         with jax.named_scope(f"RMSNorm:l{i}_n2"):
             normed = self._norm(h, params[f"l{i}_n2"][0]).astype(cd)
@@ -487,7 +653,7 @@ class HybridMoELM:
             with jax.named_scope(f"DenseMLP:l{i}_mlp"):
                 y = jax.checkpoint(self._dense_mlp)(
                     normed, params[f"l{i}_mlp"])
-            return h + y.reshape(b, t, e), None, None
+            return h + y.reshape(b, t, e), None, None, aux
         with jax.named_scope(f"MoERouter:l{i}_router"):
             weights, ids, order, counts = jax.checkpoint(self._route)(
                 h.reshape(b * t, e), params[f"l{i}_n2"][0],
@@ -502,27 +668,33 @@ class HybridMoELM:
                 shared = jax.checkpoint(self._shared_expert)(
                     normed, params[f"l{i}_shared"])
             y = y + shared
-        return h + y.reshape(b, t, e), counts, load
+        return h + y.reshape(b, t, e), counts, load, aux
 
-    def _hidden(self, params, tokens, stats=None):
+    def _hidden(self, params, tokens, stats=None, probe: bool = False):
         """The last layer's output, the held experts' counts ``(routed
-        layers, n)`` and, by router group, the load of every expert whose
-        selection bias ``stats`` holds."""
+        layers, n)``, by router group the load of every expert whose
+        selection bias ``stats`` holds, and the selected-key attention
+        layers' alignment losses, a list in layer order."""
         tokens = tokens.astype(jnp.int32)
         with jax.named_scope("Embedding:embed"):
             x = jnp.take(params["embed"][0], tokens, axis=0)
         biases = {g: blobs[0] for g, blobs in (stats or {}).items()}
-        counts, loads = [], {}
+        counts, loads, aligns = [], {}, []
         for i in range(self.depth):
             group = f"l{i}_router"
-            x, held, load = self._layer(params, i, x, biases.get(group))
+            x, held, load, aux = self._layer(
+                params, i, x, biases.get(group), probe)
             if held is not None:
                 counts.append(held)
             if load is not None:
                 loads[group] = load
+            if aux is not None:
+                aligns.append(aux)
         if not counts:  # no layer routes
-            return x, jnp.zeros((0, self.experts_held[1]), jnp.int32), loads
-        return x, jnp.stack(counts), loads
+            counts = jnp.zeros((0, self.experts_held[1]), jnp.int32)
+        else:
+            counts = jnp.stack(counts)
+        return x, counts, loads, aligns
 
     def _head_blobs(self, params):
         """The final norm's weight and the head as ``(E, vocab)``: a tied
@@ -536,17 +708,24 @@ class HybridMoELM:
 
     def forward_logits(self, params, tokens, stats=None):
         """``(B, T)`` int tokens -> ``(B, T, vocab)`` float32 logits."""
-        x, _, _ = self._hidden(params, tokens, stats)
+        x = self._hidden(params, tokens, stats)[0]
         with jax.named_scope("LMHead:head"):
             return self._head(x, *self._head_blobs(params))
 
     def loss_fn(self, params, stats, batch, rng=None, train=True):
-        """Next-token cross-entropy over the global token count.  Returns
-        ``(loss, (aux, stats))``; ``aux`` is empty: logits of ``(B, T,
-        vocab)`` are not kept beside a training step
-        (``forward_logits`` gives them).  A training step moves each
-        selection bias in ``stats`` one step of its balancing rule."""
-        x, _, loads = self._hidden(params, batch["tokens"], stats)
+        """Next-token cross-entropy over the global token count, plus the
+        selected-key attention layers' alignment losses where the model has
+        them: the two reach disjoint parameters (the indexer reads a
+        ``stop_gradient`` of its input, the selection passes no gradient and
+        the attention's probabilities enter the alignment loss as
+        constants), so one backward pass trains both.  Returns ``(loss,
+        (aux, stats))``; ``aux`` is empty but for such a model, where it
+        holds the two losses apart (``lm_loss``, ``indexer_loss``, and
+        ``indexer_loss_by_layer``): logits of ``(B, T, vocab)`` are not kept
+        beside a training step (``forward_logits`` gives them).  A training
+        step moves each selection bias in ``stats`` one step of its
+        balancing rule."""
+        x, _, loads, aligns = self._hidden(params, batch["tokens"], stats)
         targets = batch["targets"]
         tied = self.config["tied"]
 
@@ -563,7 +742,13 @@ class HybridMoELM:
             stats = {**stats, **{
                 g: [moe.balance(stats[g][0], load, rate), load]
                 for g, load in loads.items()}}
-        return total / jnp.asarray(targets.size, F32), ({}, stats)
+        loss = total / jnp.asarray(targets.size, F32)
+        if not aligns:
+            return loss, ({}, stats)
+        by_layer = jnp.stack(aligns)
+        aux = {"lm_loss": loss, "indexer_loss": jnp.sum(by_layer),
+               "indexer_loss_by_layer": by_layer}
+        return loss + aux["indexer_loss"], (aux, stats)
 
     def forward(self, params, stats, batch, rng=None):
         return {"logits": self.forward_logits(params, batch["tokens"], stats)}
@@ -573,6 +758,19 @@ class HybridMoELM:
         (``routed_layers``): ``(routed layers, n)`` int32 for the ``(B, T)``
         tokens given."""
         return self._hidden(params, tokens, stats)[1]
+
+    def selection_readings(self, params, tokens, stats=None):
+        """Of the ``(B, T)`` tokens given, per selected-key attention layer:
+        its alignment loss, and the share of the dense causal attention's
+        probability (head mean) that its selected keys hold; and, of the
+        same pass, ``routing_counts``.  A forward pass, outside any training
+        step; ``(layers,)`` float32 each, empty where the model has no such
+        layer."""
+        _, counts, _, pairs = self._hidden(params, tokens, stats, probe=True)
+        loss, mass = ([jnp.stack(x) for x in zip(*pairs)] if pairs
+                      else [jnp.zeros((0,), F32)] * 2)
+        return {"indexer_loss": loss, "selection_mass": mass,
+                "held_counts": counts}
 
     # ------------------------------------------------------------------
     def prefill_with_kv(self, *args, **kwargs):

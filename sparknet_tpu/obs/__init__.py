@@ -225,7 +225,8 @@ class TrainingMetrics:
             "sparknet_kernel_path",
             "1 when the named hot path rides its fused Pallas kernel, "
             "0 on the dense/XLA fallback (the ops/pallas_attention."
-            "lowerable() routing gate; kernel=attention|epilogue)",
+            "lowerable() routing gate; kernel=attention|epilogue|"
+            "sparse_attention)",
             labels=("kernel",),
         )
         self.kernel_fused_chunks = registry.counter(
@@ -410,6 +411,21 @@ class TrainingMetrics:
             "sparknet_lm_held_load_skew",
             "largest held expert's assignments over the held experts' "
             "mean (1.0 = even routing), by layer",
+            labels=("layer",),
+        )
+        self.lm_indexer_loss = registry.gauge(
+            "sparknet_lm_indexer_loss",
+            "a selected-key attention layer's alignment loss (the KL from "
+            "the attention's head-mean probabilities over the selected keys "
+            "to the indexer's softmax over them), by layer; set outside the "
+            "round loop from models/hybrid_lm selection_readings",
+            labels=("layer",),
+        )
+        self.lm_selection_mass = registry.gauge(
+            "sparknet_lm_selection_mass",
+            "share of the DENSE causal attention's probability (head mean) "
+            "that the indexer's selected keys hold, by layer: what the "
+            "alignment loss raises",
             labels=("layer",),
         )
         # bounded-staleness averaging series (parallel/stale.py,

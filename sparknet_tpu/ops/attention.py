@@ -207,65 +207,157 @@ def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
                 path="xla" if why else "pallas", why=why, backend=backend,
                 t=t, hq=hq, hkv=hkv, d=d, dtype=cd.name, block_q=block_q,
                 block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
-    if why:
-        return _blockwise_gqa(q, k, v, block_q, segments, cd)
     q = (q.astype(jnp.float32) * d ** -0.5).astype(cd)
+    if why:
+        return _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments)
     return pallas_attention.flash_attention(
         q, k.astype(cd), v.astype(cd), causal=True, block_q=block_q,
         block_k=block_k, scale=1.0, out_dtype=jnp.float32)
 
 
-def _blockwise_gqa(q, k, v, block_q, segments, cd):
-    """``causal_gqa_attention`` in XLA, in query blocks.  The query blocks
-    run one after another (``lax.map`` over ``jax.checkpoint``ed blocks), so
-    no more than ``block_q x T`` scores a head exist at once, forward or
-    backward.  A ``lax.map`` needs one shape for all its blocks: the sequence
-    is cut into ``segments`` runs of blocks, and a run meets only the keys up
-    to its own end, a static slice, so with four runs 5/8 of the full score
-    matrix is computed where causality needs 1/2."""
+# -- the XLA blockwise passes: query blocks in runs, a keep-mask as bits ------
+NEG = -1e30  # a finite mask: see ``_blockwise_gqa``
+
+
+def runs_of(t: int, block_q: int, segments: int):
+    """``(block_q, [(first block, end block, keys)])``: the query blocks of a
+    sequence of ``t`` in at most ``segments`` runs; a run's queries meet the
+    first ``keys`` keys, which reach its own end."""
+    block_q = min(block_q, t)
+    blocks = -(-t // block_q)
+    per_run = -(-blocks // segments)
+    return block_q, [
+        (lo, min(lo + per_run, blocks), min(min(lo + per_run, blocks) * block_q, t))
+        for lo in range(0, blocks, per_run)]
+
+
+def blocked(x, block_q: int):
+    """``(B, T, ...)`` -> ``(blocks, B, block_q, ...)``, zeros after ``T``."""
+    b, t = x.shape[:2]
+    pad = (-t) % block_q
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape(b, (t + pad) // block_q, block_q, *x.shape[2:])
+    return jnp.moveaxis(x, 1, 0)
+
+
+def unblocked(x, t: int):
+    """``(blocks, B, block_q, ...)`` -> ``(B, T, ...)``."""
+    x = jnp.moveaxis(x, 0, 1)
+    return x.reshape(x.shape[0], -1, *x.shape[3:])[:, :t]
+
+
+def by_run(fn, queries, t: int, block_q: int, segments: int):
+    """``fn(keys, first, *blocks)`` over every query block, one ``lax.map`` a
+    run (a ``lax.map`` needs one shape for all its blocks, and a run meets
+    only the keys up to its own end, a static slice); ``queries`` are
+    ``(blocks, B, block_q, ...)`` arrays or, a run, lists of them.  Returns
+    the outputs a run, each ``(run's blocks, ...)``."""
+    block_q, runs = runs_of(t, block_q, segments)
+    out = []
+    for r, (lo, hi, keys) in enumerate(runs):
+        mine = [x[r] if isinstance(x, (list, tuple)) else x[lo:hi]
+                for x in queries]
+        out.append(jax.lax.map(
+            lambda xs, keys=keys: fn(keys, xs[0], *xs[1:]),
+            (jnp.arange(lo, hi) * block_q, *mine)))
+    return out
+
+
+def joined(per_run, t: int):
+    """The runs' outputs as one ``(B, T, ...)`` array a leaf."""
+    return jax.tree_util.tree_map(
+        lambda *xs: unblocked(jnp.concatenate(xs, axis=0), t), *per_run)
+
+
+BITS = 32
+
+
+def words_of(t: int) -> int:
+    """Words of 32 bits a row of a keep-mask: key ``s`` is bit ``s // words``
+    of word ``s % words``, so that packing and unpacking cut the key axis
+    into whole slices and never reshape it."""
+    return -(-t // BITS)
+
+
+def pack_mask(keep, words: int):
+    """``(..., S)`` bool -> ``(..., words)`` uint32; ``S <= 32 * words``."""
+    s = keep.shape[-1]
+    out = jnp.zeros((*keep.shape[:-1], words), jnp.uint32)
+    for bit in range(-(-s // words)):
+        piece = keep[..., bit * words:(bit + 1) * words].astype(jnp.uint32)
+        if piece.shape[-1] < words:
+            piece = jnp.pad(piece, [(0, 0)] * (piece.ndim - 1)
+                            + [(0, words - piece.shape[-1])])
+        out = out | (piece << jnp.uint32(bit))
+    return out
+
+
+def unpack_mask(packed, s: int):
+    """The first ``s`` keys of ``pack_mask``'s rows: ``(..., s)`` bool."""
+    words = packed.shape[-1]
+    pieces = [(packed >> jnp.uint32(bit)) & jnp.uint32(1)
+              for bit in range(-(-s // words))]
+    return jnp.concatenate(pieces, axis=-1)[..., :s].astype(bool)
+
+
+def _blockwise_gqa(q, k, v, block_q, segments, keep=None):
+    """``causal_gqa_attention`` in XLA, in query blocks; ``q`` comes scaled
+    and all three in the compute dtype.  The query blocks run one after
+    another (``lax.map`` over ``jax.checkpoint``ed blocks), so no more than
+    ``block_q x T`` scores a head exist at once, forward or backward; with
+    four runs (``by_run``) 5/8 of the full score matrix is computed where
+    causality needs 1/2.
+
+    With ``keep`` (``(B, T, words_of(T))`` bits, ``pack_mask``'s layout, a
+    subset of the causal keys, unpacked a block at a time) the softmax runs
+    over each query's kept keys alone, and the rows' log-sum-exp ``(B, T,
+    Hq)`` float32 is returned beside the output."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     group = hq // hkv
     f32 = jnp.float32
-    pad = (-t) % block_q  # padded keys lie after every real query: masked
-    if pad:
-        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                   for x in (q, k, v))
-    blocks = (t + pad) // block_q
+    cd = q.dtype
+    block_q, _ = runs_of(t, block_q, segments)
     # heads before positions, the layout batched products are made for: a
     # block of queries is (B, Hkv, group * block_q, D) against (B, Hkv, S, D)
-    q = (q.astype(f32) * d ** -0.5).astype(cd)
-    q = q.reshape(b, blocks, block_q, hkv, group, d).transpose(1, 0, 3, 4, 2, 5)
-    q = q.reshape(blocks, b, hkv, group * block_q, d)
-    k = k.astype(cd).transpose(0, 2, 1, 3)
-    v = v.astype(cd).transpose(0, 2, 1, 3)
+    qb = blocked(q, block_q)
+    blocks = qb.shape[0]
+    qb = qb.reshape(blocks, b, block_q, hkv, group, d).transpose(0, 1, 3, 4, 2, 5)
+    qb = qb.reshape(blocks, b, hkv, group * block_q, d)
+    kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     row = jnp.tile(jnp.arange(block_q), group)[:, None]
 
-    @jax.checkpoint
-    def block(qi, first, ki, vi):
+    def block(keys, first, qi, *bits):
+        ki, vi = kh[:, :, :keys], vh[:, :, :keys]
         s = jnp.einsum("bkrd,bksd->bkrs", qi, ki, preferred_element_type=f32)
+        if bits:  # (B, block_q, keys), the same for every head of the group
+            kept = jnp.tile(unpack_mask(bits[0], keys), (1, group, 1))[:, None]
+        else:
+            kept = first + row >= jnp.arange(keys)[None, :]
         # a finite mask and the softmax written out, normalised after the
         # second product: on the v5e `where(.., -inf)` + `jax.nn.softmax`
         # in float32 runs 16 x slower than this (49 ms against 3 ms a block
-        # of 4096 x 8192 scores, PERF.md section 6, PR 27).  Every row keeps
-        # its own position, so its maximum is a real score
-        s = jnp.where(first + row >= jnp.arange(ki.shape[2])[None, :], s,
-                      -1e30)
-        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        # of 4096 x 8192 scores, PERF.md section 6, PR 27).  Every real row
+        # keeps its own position, so its maximum is a real score; a row of
+        # padding under a keep-mask keeps none and reads the values' mean,
+        # cut off below
+        s = jnp.where(kept, s, NEG)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        e = jnp.exp(s - m)
+        total = jnp.sum(e, axis=-1, keepdims=True)
         o = jnp.einsum("bkrs,bksd->bkrd", e.astype(cd), vi,
-                       preferred_element_type=f32)
-        return o / jnp.sum(e, axis=-1, keepdims=True)
+                       preferred_element_type=f32) / total
+        return o if keep is None else (o, (m + jnp.log(total))[..., 0])
 
-    per_run = -(-blocks // segments)
-    out = []
-    for lo in range(0, blocks, per_run):
-        hi = min(lo + per_run, blocks)
-        ki, vi = k[:, :, :hi * block_q], v[:, :, :hi * block_q]
-        out.append(jax.lax.map(
-            lambda x: block(x[0], x[1], ki, vi),
-            (q[lo:hi], jnp.arange(lo, hi) * block_q)))
-    # (blocks, B, Hkv, group * block_q, D) -> (B, T, Hq, D)
-    out = jnp.concatenate(out, axis=0)
-    out = out.reshape(blocks, b, hkv, group, block_q, d)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t + pad, hq, d)
-    return out[:, :t]
+    out = by_run(jax.checkpoint(block, static_argnums=(0,)),
+                 (qb,) if keep is None else (qb, blocked(keep, block_q)),
+                 t, block_q, segments)
+    # (blocks, B, Hkv, group * block_q, ...) -> (B, T, Hq, ...)
+    heads = lambda x: unblocked(jnp.moveaxis(  # noqa: E731
+        x.reshape(blocks, b, hkv, group, block_q, *x.shape[4:]), 4, 2),
+        t).reshape(b, t, hq, *x.shape[4:])
+    if keep is None:
+        return heads(jnp.concatenate(out, axis=0))
+    return tuple(heads(jnp.concatenate([x[i] for x in out], axis=0))
+                 for i in range(2))

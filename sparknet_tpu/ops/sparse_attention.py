@@ -1,0 +1,252 @@
+"""Causal grouped attention over the keys a learned indexer selects (the
+sparse attention of DeepSeek-V3.2-Exp, as Keye-VL-2.0's ``sa_config`` states
+it), in four pieces that a layer runs under four scopes:
+
+- ``index_scores``: ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])`` for
+  ``s <= t``, one small key shared by all index heads; the products take
+  their operands in the compute dtype and accumulate in float32, the ReLU,
+  the weighting and the sum over heads are float32.
+- ``select``: ``S_t``, the ``min(t + 1, topk)`` keys ``s <= t`` of largest
+  ``I[t, s]``, ties to the lower ``s``, as a bit mask.  No sort: the k-th
+  largest score of a row is found bit by bit on the scores' order-preserving
+  integer image (32 counts over the row), the mask is ``score >= it``, and a
+  row in which more than the k-th tie at that value takes them by position.
+  No gradient passes through it.
+- ``masked_attention``: ``softmax_{s in S_t}(q . k / sqrt(D)) v`` with
+  grouped K/V heads, and the row's log-sum-exp.
+- ``alignment_loss``: ``sum_t KL(p[t, .] || softmax_{S_t}(I[t, .]))`` with
+  ``p = stop_gradient(mean_h A[t, h, .])``, the attention's own
+  probabilities recomputed from ``q``, ``k`` and the log-sum-exp; its
+  gradient reaches the index scores alone.
+
+Why a MASKED pass and not a gather: on the v5e a gather of 2,048 K/V rows a
+query is 4 MB a token (69 GB a layer at 16,384 tokens, ~84 ms at 819 GB/s)
+where the masked dense product is 2.2 TFLOP (~14 ms at 80% of the bf16
+peak); PERF.md section 6, PR 33 has what was read.
+
+All four run in XLA on the ONE blockwise loop of ``ops/attention.py``
+(``by_run``: a block of ``block_q`` queries at a time, ``lax.map`` over
+``jax.checkpoint``ed blocks, the sequence cut into ``segments`` runs that
+each meet only the keys up to their own end), so that no more than ``block_q
+x T`` scores a head exist at once, forward or backward; the masked pass IS
+``_blockwise_gqa``, given the keep-mask.  Between the pieces go the indexer's
+``qI`` / ``kI`` / ``w``, the mask as bits (``T * T / 8`` bytes: 33.5 MB at
+16,384 tokens, no float tensor of ``(T, T)``), ``q`` / ``k`` and the
+log-sum-exp.  ``index_scores_by_run`` alone materialises float32 scores of
+whole runs, ``(B, T, T)`` x the causal share, forward only: ``select``
+consumes them and nothing keeps them for the backward.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from sparknet_tpu import obs
+from sparknet_tpu.ops.attention import (  # noqa: F401  (the mask's layout)
+    BITS, NEG, _blockwise_gqa, blocked, by_run, joined, pack_mask, runs_of,
+    unpack_mask, words_of)
+
+F32 = jnp.float32
+U32 = jnp.uint32
+BLOCK_Q = 512
+SEGMENTS = 8
+# What a ``jax.checkpoint`` around the caller may keep (``policy=jax.
+# checkpoint_policies.save_only_these_names(*SAVED)``) so that its
+# recomputation does not run the attention's blocks a second time.
+SAVED = ("sparse_attention_o", "sparse_attention_lse")
+
+
+# -- the indexer's scores -----------------------------------------------------
+def index_scores(qi, w, ki):
+    """``qi``: ``(B, Q, J, Di)`` and ``ki``: ``(B, S, Di)`` in the compute
+    dtype; ``w``: ``(B, Q, J)`` float32, the scale factors in it.  Returns
+    ``(B, Q, S)`` float32, no mask applied; a ``-0.0`` reads ``0.0``, so
+    that equal scores are equal bit patterns."""
+    s = jnp.einsum("bqjd,bsd->bqjs", qi, ki.astype(qi.dtype),
+                   preferred_element_type=F32)
+    i = jnp.sum(jax.nn.relu(s) * w[..., None].astype(F32), axis=2)
+    return jnp.where(i == 0.0, 0.0, i)
+
+
+def index_scores_by_run(qi, w, ki, *, block_q: int = BLOCK_Q,
+                        segments: int = SEGMENTS):
+    """The scores of every causal pair, a list with one ``(run's blocks, B,
+    block_q, keys)`` float32 array a run (``runs_of``): what ``select``
+    reads.  Forward only."""
+    t = qi.shape[1]
+    block_q, _ = runs_of(t, block_q, segments)
+    return by_run(
+        lambda keys, first, qb, wb: index_scores(qb, wb, ki[:, :keys]),
+        (blocked(qi, block_q), blocked(w, block_q)), t, block_q, segments)
+
+
+# -- the selection ------------------------------------------------------------
+def _ordered(x):
+    """Float32 -> uint32 with the floats' order (finite values and the
+    infinities; every image is above 0)."""
+    u = jax.lax.bitcast_convert_type(x, U32)
+    return jnp.where(u >> U32(31) == U32(1), ~u, u | U32(1 << 31))
+
+
+def select_block(scores, first, t: int, topk: int):
+    """``scores``: ``(B, Q, S)`` of the queries ``first .. first + Q - 1``
+    against the keys ``0 .. S - 1``.  Returns the keep-mask ``(B, Q, S)``
+    bool: of each row's causal keys the ``min(row + 1, topk)`` of largest
+    score, ties to the lower key."""
+    q, s = scores.shape[1:]
+    rows = jnp.minimum(first + jnp.arange(q), t - 1)  # rows of padding: the last
+    causal = jnp.arange(s)[None, :] <= rows[:, None]
+    key = jnp.where(causal, _ordered(scores), U32(0))
+    want = jnp.minimum(rows + 1, topk)[None, :]
+
+    def bit(i, prefix):
+        # the k-th largest image, one bit at a time from the top: a bit stays
+        # where at least k images reach the prefix with it set
+        cand = prefix | (U32(1) << (U32(BITS - 1) - i.astype(U32)))
+        reach = jnp.sum(key >= cand[..., None], axis=-1, dtype=jnp.int32)
+        return jnp.where(reach >= want, cand, prefix)
+
+    # (zeros of the scores' own type: inside a shard_map the carry varies
+    # over the mesh axis as they do)
+    kth = jax.lax.fori_loop(
+        0, BITS, bit, jnp.zeros_like(scores[..., 0], U32))[..., None]
+    above, tied = key > kth, key == kth
+    room = want - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    ties = jnp.sum(tied, axis=-1, dtype=jnp.int32)
+    keep = jax.lax.cond(
+        jnp.any(ties > room),
+        lambda: above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                                 <= room[..., None])),
+        lambda: above | tied)
+    return keep & causal
+
+
+def select(scores_by_run, t: int, topk: int, *, block_q: int = BLOCK_Q,
+           segments: int = SEGMENTS):
+    """The selection as bits: ``(B, T, words_of(T))`` uint32 from
+    ``index_scores_by_run``'s scores (``pack_mask``'s layout)."""
+    words = words_of(t)
+    block_q, _ = runs_of(t, block_q, segments)
+    packed = by_run(
+        lambda keys, first, scores: pack_mask(
+            select_block(scores, first, t, topk), words),
+        (scores_by_run,), t, block_q, segments)
+    return jax.lax.stop_gradient(joined(packed, t))
+
+
+def causal_mask_bits(b: int, t: int):
+    """The mask that keeps every causal key, in ``select``'s layout."""
+    keep = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    return jnp.broadcast_to(pack_mask(keep, words_of(t)), (b, t, words_of(t)))
+
+
+# -- attention over the selection ---------------------------------------------
+def scaled_queries(q, compute_dtype=None):
+    """``q * D ** -0.5`` in the compute dtype, scaled before the cast: what
+    ``masked_attention``, ``alignment_loss`` and ``selection_mass`` take, so
+    that all three multiply the same numbers."""
+    return (q.astype(F32) * q.shape[-1] ** -0.5).astype(compute_dtype or F32)
+
+
+def _heads_first(q, k, block_q: int):
+    """Queries as ``(blocks, B, Hkv, G, block_q, D)``; keys (or values) as
+    ``(B, Hkv, T, D)`` in the queries' dtype."""
+    hq, d = q.shape[2:]
+    hkv = k.shape[2]
+    q = blocked(q, block_q)
+    q = q.reshape(*q.shape[:3], hkv, hq // hkv, d).transpose(0, 1, 3, 4, 2, 5)
+    return q, k.astype(q.dtype).transpose(0, 2, 1, 3)
+
+
+def _scores(qb, kb):
+    return jnp.einsum("bkgqd,bksd->bkgqs", qb, kb, preferred_element_type=F32)
+
+
+def masked_attention(q, k, v, mask, *, block_q: int = BLOCK_Q,
+                     segments: int = SEGMENTS):
+    """``q``: ``scaled_queries`` ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T,
+    Hkv, D)``; ``mask``: ``select``'s bits.  Softmax over each query's
+    selected keys: ``ops/attention._blockwise_gqa`` given the keep-mask
+    (scores and softmax float32, the products' operands in ``q``'s dtype).
+    Returns the output ``(B, T, Hq, D)`` float32 and the rows' log-sum-exp
+    ``(B, T, Hq)`` float32, both named for a caller's checkpoint policy
+    (``SAVED``)."""
+    cd = q.dtype
+    b, t, hq, d = q.shape
+    block_q, _ = runs_of(t, block_q, segments)
+    obs.instant("sparse_attention_path", cat="kernel", path="xla",
+                why="no kernel takes a keep-mask yet",
+                backend=jax.default_backend(), t=t, hq=hq, hkv=k.shape[2],
+                d=d, dtype=cd.name, block_q=block_q, segments=segments)
+    out = _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments,
+                         keep=mask)
+    return tuple(checkpoint_name(x, name) for x, name in zip(out, SAVED))
+
+
+def _head_mean_probabilities(qi, kh, lse, keep):
+    """``mean_h A[t, h, s]`` over the kept keys from the scaled queries, the
+    keys and the rows' log-sum-exp: ``(B, Q, S)`` float32."""
+    a = jnp.where(keep[:, None, None], jnp.exp(_scores(qi, kh) - lse[..., None]),
+                  0.0)
+    return jnp.mean(a, axis=(1, 2))
+
+
+def alignment_loss(qi, w, ki, q, k, lse, mask, *, block_q: int = BLOCK_Q,
+                   segments: int = SEGMENTS):
+    """``sum_{b, t} sum_{s in S_t} p (log p - log softmax_{S_t}(I)[s])`` with
+    ``p = mean_h A``, the attention's probabilities over its selected keys
+    (``q``, ``k``, ``lse`` as ``masked_attention`` took and gave them; no
+    gradient reaches them or ``mask``).  The gradient reaches ``qi``, ``w``
+    and ``ki``.  Float32 but for the two products' operands."""
+    b, t, hq, _ = q.shape
+    hkv = k.shape[2]
+    block_q, _ = runs_of(t, block_q, segments)
+    qb, kh = jax.lax.stop_gradient(_heads_first(q, k, block_q))
+    lse = jax.lax.stop_gradient(lse).reshape(b, t, hkv, hq // hkv)
+    lse = blocked(lse, block_q).transpose(0, 1, 3, 4, 2)
+
+    def block(keys, first, qib, wb, qm, lb, bits):
+        keep = unpack_mask(bits, keys)
+        p = _head_mean_probabilities(qm, kh[:, :, :keys], lb, keep)
+        i = jnp.where(keep, index_scores(qib, wb, ki[:, :keys]), NEG)
+        i = i - jnp.max(i, axis=-1, keepdims=True)
+        # (a row of padding keeps no key: the floor keeps its gradient finite)
+        log_q = i - jnp.log(jnp.maximum(jnp.sum(
+            jnp.where(keep, jnp.exp(i), 0.0), axis=-1, keepdims=True), 1e-30))
+        live = keep & (p > 0.0)
+        kl = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_q), 0.0)
+        rows = first + jnp.arange(kl.shape[1])
+        return jnp.sum(jnp.where(rows[None, :] < t, jnp.sum(kl, axis=-1), 0.0))
+
+    out = by_run(
+        jax.checkpoint(block, static_argnums=(0,)),
+        (blocked(qi, block_q), blocked(w, block_q), qb, lse,
+         blocked(mask, block_q)), t, block_q, segments)
+    return sum(jnp.sum(x) for x in out)
+
+
+def selection_mass(q, k, mask, *, block_q: int = BLOCK_Q,
+                   segments: int = SEGMENTS):
+    """The share of the DENSE causal attention's probability that the
+    selected keys hold, the mean over heads and queries (``q``:
+    ``scaled_queries``): what the alignment loss raises.  A reading, outside
+    any training step."""
+    b, t = q.shape[:2]
+    block_q, _ = runs_of(t, block_q, segments)
+    qb, kh = _heads_first(q, k, block_q)
+
+    def block(keys, first, qi, bits):
+        s = _scores(qi, kh[:, :, :keys])
+        rows = jnp.minimum(first + jnp.arange(s.shape[-2]), t - 1)
+        causal = jnp.arange(keys)[None, :] <= rows[:, None]
+        e = jnp.where(causal, jnp.exp(
+            s - jnp.max(jnp.where(causal, s, NEG), axis=-1, keepdims=True)), 0.0)
+        held = jnp.sum(jnp.where(unpack_mask(bits, keys)[:, None, None], e, 0.0),
+                       axis=-1) / jnp.sum(e, axis=-1)
+        real = (first + jnp.arange(s.shape[-2])) < t
+        return jnp.sum(jnp.where(real, jnp.mean(held, axis=(1, 2)), 0.0))
+
+    out = by_run(block, (qb, blocked(mask, block_q)), t, block_q, segments)
+    return sum(jnp.sum(x) for x in out) / (b * t)

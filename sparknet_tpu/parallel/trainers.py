@@ -683,6 +683,15 @@ class ParameterAveragingTrainer:
         self._live_cache[key] = placed
         return placed
 
+    def compile_round(self, state: TrainState, batches: Dict[str, jax.Array]):
+        """Compile the fused round program for a state and batches like
+        these, running nothing and donating nothing: ``round`` with the
+        default key and an all-alive mask then finds it compiled.  For a
+        caller's thread, while its data loads or its other programs
+        compile (jax's compile releases the interpreter)."""
+        live = self._place_live(np.ones((self.num_workers,), np.float32))
+        self._round.lower(state, batches, default_train_key(0), live).compile()
+
     def round(
         self,
         state: TrainState,
