@@ -143,12 +143,14 @@ def set_selection_gauges(lm, stacked_params, tokens, stacked_stats=None,
     (``models/hybrid_lm.selection_readings``): the alignment loss and the
     share of the dense attention's probability the selected keys hold, set
     as the gauges ``sparknet_lm_indexer_loss`` / ``sparknet_lm_selection_mass``
-    (and ``sparknet_kernel_path{kernel="sparse_attention"}``: 0, the XLA
-    path, until a kernel takes a keep-mask) where training metrics are on,
+    (and ``sparknet_kernel_path{kernel="sparse_attention"}``: 1 where
+    ``masked_attention`` takes the flash kernels for this batch's shapes, 0
+    on its XLA pass) where training metrics are on,
     and returned with the pass's ``held_counts``; ``{}`` for a model without
     such a layer.  Outside the timed loop: a forward pass,
     ``selection_probe(lm)`` or the caller's compiled ``probe`` of it."""
     from sparknet_tpu import obs
+    from sparknet_tpu.ops import sparse_attention
 
     layers = [i for i, kind in enumerate(lm.config["mixers"])
               if kind == "dsa_attention"]
@@ -159,7 +161,12 @@ def set_selection_gauges(lm, stacked_params, tokens, stacked_stats=None,
     gauges = {k: np.asarray(v).tolist() for k, v in readings.items()}
     tm = obs.training_metrics()
     if tm is not None:
-        tm.kernel_path.labels("sparse_attention").set(0.0)
+        c = lm.config
+        tm.kernel_path.labels("sparse_attention").set(float(
+            not sparse_attention.kernels_refuse(
+                tokens.shape[-1], c["num_attention_heads"],
+                c["num_key_value_heads"], c["head_dim"],
+                lm.compute_dtype or np.float32)))
         for i, loss, mass in zip(
                 layers, gauges["indexer_loss"], gauges["selection_mass"]):
             tm.lm_indexer_loss.labels(str(i)).set(loss)

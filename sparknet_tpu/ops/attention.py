@@ -152,6 +152,13 @@ KERNEL_Q_BYTES = 1 << 20
 KERNEL_BLOCK_K = 512
 
 
+def kernel_block_q(group: int, d: int, cd, block_k: int) -> int:
+    """The kernels' query block: ``KERNEL_Q_BYTES`` of a K/V head's group,
+    at least a row of lanes and at most a key block."""
+    rows = KERNEL_Q_BYTES // (group * d * jnp.dtype(cd).itemsize)
+    return min(max(rows, 128), block_k)
+
+
 def lowerable() -> bool:
     """``pallas_attention.lowerable``, imported when asked: ``ops/`` imports
     this module for every net, and Pallas takes 0.9 s that the image nets'
@@ -200,8 +207,7 @@ def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
                blocks * blocks)
     else:
         block_k = min(KERNEL_BLOCK_K, t)
-        rows = KERNEL_Q_BYTES // (hq // hkv * d * cd.itemsize)
-        block_q = min(max(rows, pallas_attention.LANES), block_k)
+        block_q = kernel_block_q(hq // hkv, d, cd, block_k)
         met = pallas_attention.blocks_met(t, t, block_q, block_k)
     obs.instant("attention_path", cat="kernel",
                 path="xla" if why else "pallas", why=why, backend=backend,

@@ -22,6 +22,18 @@ Causality by blocks: a key block wholly above the diagonal is neither
 computed (``pl.when``) nor fetched (its index map stays on the last block
 needed); the mask is applied on the blocks the diagonal crosses only.
 
+A keep-mask (``masked_flash_attention``: the selected-key attention of
+``ops/sparse_attention.py``) is a static specialisation of the same three
+kernels: one more operand, the selection as bits in ``ops/attention.
+pack_mask``'s layout — key ``s`` is bit ``s // words`` of word ``s % words``,
+so a key block of ``words`` keys is ONE bit of every word and a block of
+``block_q`` queries' words, ``(block_q, words)`` int32, fetched once a query
+block, holds the mask of every key block it meets.  The blocks above the
+diagonal are skipped as before; every block that is met takes the masked
+step with the tile unpacked in VMEM (an AND and a compare, the same for the
+heads of a group) in place of the positions' iotas; the dk/dv pass, whose
+scores have the keys on rows, transposes the unpacked tile (32-bit, 2-D).
+
 Backward: the FlashAttention recipe from the residuals ``(q, k, v, o, lse)``.
 The dq pass (query block outer) forms ``delta = rowsum(do * o) - dlse`` once a
 query block and hands it on; the dk/dv pass (key block outer) works on the
@@ -93,7 +105,8 @@ def accepts(hq: int, hkv: int, d: int, dtype) -> bool:
 class _Shape(NamedTuple):
     """What a kernel is specialised on.  ``q``, ``o``: ``(B, Tq, Hkv *
     group * d)``, ``k``, ``v``: ``(B, Tk, Hkv * d)``, both lengths whole
-    blocks; ``tk`` counts the real keys."""
+    blocks; ``tk`` counts the real keys.  ``words``: the words a row of the
+    keep-mask, 0 without one."""
     causal: bool
     scale: float
     group: int
@@ -103,6 +116,7 @@ class _Shape(NamedTuple):
     block_k: int
     out_dtype: np.dtype
     interpret: bool
+    words: int
 
 
 def _mm(a, b, dims):
@@ -153,7 +167,8 @@ def _first_query_block(j, offs, nq, c: _Shape):
 def _walk(offs_ref, i, j, nk, c: _Shape, step):
     """``step(masked)`` on block ``(i, j)``: not at all above the diagonal,
     with the mask where the diagonal or the end of the real keys crosses the
-    block, without it elsewhere."""
+    block, without it elsewhere; under a keep-mask with it on every block
+    met."""
     ragged = c.tk % c.block_k != 0  # the last key block holds padding
     if not (c.causal or ragged):
         step(False)
@@ -165,27 +180,46 @@ def _walk(offs_ref, i, j, nk, c: _Shape, step):
         return
     where = (i, j, offs_ref[0], offs_ref[1], c.block_q, c.block_k)
     needed = _needed(*where)
+    if c.words:
+        pl.when(needed)(lambda: step(True))
+        return
     whole = jnp.logical_and(whole, _whole(*where))
     pl.when(jnp.logical_and(needed, whole))(lambda: step(False))
     pl.when(jnp.logical_and(needed, jnp.logical_not(whole)))(
         lambda: step(True))
 
 
-def _keep(offs_ref, i, j, c: _Shape, transposed: bool):
+def _keep(offs_ref, bits_ref, i, j, c: _Shape, transposed: bool):
     """The block's mask, ``(group * block_q, block_k)`` (``transposed``: keys
-    on rows): a real key, at or before the query's position."""
-    one = (c.block_k, c.block_q) if transposed else (c.block_q, c.block_k)
-    q_dim, k_dim = (1, 0) if transposed else (0, 1)
-    key = jax.lax.broadcasted_iota(jnp.int32, one, k_dim)
-    ahead = key - jax.lax.broadcasted_iota(jnp.int32, one, q_dim)
-    keep = None
-    if c.causal:
-        keep = ahead <= (offs_ref[0] - offs_ref[1]
-                         + i * c.block_q - j * c.block_k)
-    if c.tk % c.block_k:
-        real = key < c.tk - j * c.block_k
-        keep = real if keep is None else jnp.logical_and(keep, real)
+    on rows): a real key, at or before the query's position; under a
+    keep-mask its bits alone, a subset of those."""
+    q_dim = 1 if transposed else 0
+    if c.words:
+        # key block j is bits j * per + [0, per) of every word, side by side
+        per = c.block_k // c.words
+        bits = bits_ref[...]
+        tile = jnp.concatenate(
+            [bits & jnp.left_shift(jnp.int32(1), j * per + r)
+             for r in range(per)], axis=1)
+        keep = (jnp.transpose(tile) if transposed else tile) != 0
+    else:
+        one = (c.block_k, c.block_q) if transposed else (c.block_q, c.block_k)
+        key = jax.lax.broadcasted_iota(jnp.int32, one, 1 - q_dim)
+        ahead = key - jax.lax.broadcasted_iota(jnp.int32, one, q_dim)
+        keep = None
+        if c.causal:
+            keep = ahead <= (offs_ref[0] - offs_ref[1]
+                             + i * c.block_q - j * c.block_k)
+        if c.tk % c.block_k:
+            real = key < c.tk - j * c.block_k
+            keep = real if keep is None else jnp.logical_and(keep, real)
     return jnp.concatenate([keep] * c.group, axis=q_dim)
+
+
+def _bits_first(refs, c: _Shape):
+    """A kernel's operands after the offsets: the keep-mask's block first
+    where there is one."""
+    return (refs[0], refs[1:]) if c.words else (None, refs)
 
 
 # -- a K/V head's group, stacked as rows --------------------------------------
@@ -226,8 +260,9 @@ def _column_to(ref, col, c: _Shape):
 
 
 # -- the kernels ----------------------------------------------------------------
-def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_ref, l_ref, acc_ref, *, c: _Shape):
+def _fwd_kernel(offs_ref, *refs, c: _Shape):
+    bits_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref,
+               m_ref, l_ref, acc_ref) = _bits_first(refs, c)
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
     @pl.when(j == 0)
@@ -241,7 +276,7 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if c.scale != 1.0:
             s = s * c.scale
         if masked:
-            keep = _keep(offs_ref, i, j, c, False)
+            keep = _keep(offs_ref, bits_ref, i, j, c, False)
             s = jnp.where(keep, s, MASK)
         m_prev = m_ref[...]
         m = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -276,9 +311,10 @@ def _probabilities(s, lse, keep, c: _Shape):
     return p if keep is None else jnp.where(keep, p, 0.0)
 
 
-def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-               dlse_ref, dq_ref, delta_ref,
-               do_scr, lse_scr, delta_scr, dq_scr, *, c: _Shape):
+def _dq_kernel(offs_ref, *refs, c: _Shape):
+    bits_ref, (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+               dq_ref, delta_ref,
+               do_scr, lse_scr, delta_scr, dq_scr) = _bits_first(refs, c)
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
     @pl.when(j == 0)
@@ -294,7 +330,7 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     def step(masked):
         k = k_ref[...]
-        keep = _keep(offs_ref, i, j, c, False) if masked else None
+        keep = _keep(offs_ref, bits_ref, i, j, c, False) if masked else None
         p = _probabilities(_mm(_stacked(q_ref, c), k, _NT), lse_scr[...],
                            keep, c)
         ds = p * (_mm(do_scr[...], v_ref[...], _NT) - delta_scr[...])
@@ -309,8 +345,9 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         _unstack_to(dq_ref, dq_scr[...], c)
 
 
-def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, c: _Shape):
+def _dkv_kernel(offs_ref, *refs, c: _Shape):
+    bits_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               dk_ref, dv_ref, dk_scr, dv_scr) = _bits_first(refs, c)
     # key block outer, query blocks inner; the scores transposed, keys on rows
     j, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
@@ -321,7 +358,7 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def step(masked):
         q, do = _stacked(q_ref, c), _stacked(do_ref, c)
-        keep = _keep(offs_ref, i, j, c, True) if masked else None
+        keep = _keep(offs_ref, bits_ref, i, j, c, True) if masked else None
         p = _probabilities(_mm(k_ref[...], q, _NT), _row(lse_ref, c), keep, c)
         dv_scr[...] += _mm(p.astype(do.dtype), do, _NN)
         ds = p * (_mm(v_ref[...], do, _NT) - _row(delta_ref, c))
@@ -363,13 +400,15 @@ _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # -- the calls ------------------------------------------------------------------
-def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs,
+def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
           *operands):
     """One kernel over (batch, K/V head, outer block, inner block), the inner
     blocks one after another.  ``ins`` / ``outs``: a letter an operand —
     ``q`` a block of queries' rows ``(block_q, group * d)``, ``k`` a block of
     keys' ``(block_k, d)``, ``r`` a per-row scalar ``(group, block_q)`` —
-    and for an output its dtype."""
+    and for an output its dtype.  ``keep``: the keep-mask's words ``(B, Tq,
+    words)`` int32 or None; a block of queries' whole rows of it goes first,
+    fetched again only when the query block changes."""
     b, tq = operands[0].shape[:2]
     tk = operands[1].shape[1]
     hkv = operands[1].shape[2] // c.d
@@ -402,13 +441,21 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs,
                            lambda *g: (g[0], g[1], 0, q_block(*g))),
               (b, hkv, c.group, tq)),
     }
+    in_specs = [specs[x][0] for x in ins]
+    if c.words:  # heads-first, a sequence's K/V heads share its mask
+        per_mask = b // keep.shape[0]
+        mask_spec = pl.BlockSpec(
+            (None, c.block_q, c.words),
+            lambda *g: (g[0] // per_mask, q_block(*g), 0))
+        in_specs = [mask_spec] + in_specs
+        operands = (keep, *operands)
     everything = (offs, *operands)
     return pl.pallas_call(
         partial(kernel, c=c),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, nk, nq) if key_outer else (b, hkv, nq, nk),
-            in_specs=[specs[x][0] for x in ins],
+            in_specs=in_specs,
             out_specs=[specs[x][0] for x, _ in outs],
             scratch_shapes=scratch),
         out_shape=[_out_struct(specs[x][1], dtype, *everything)
@@ -420,25 +467,25 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs,
     )(offs, *operands)
 
 
-def _forward(q, k, v, offs, c: _Shape):
+def _forward(q, k, v, offs, keep, c: _Shape):
     rows = c.group * c.block_q
     return _call(
         _fwd_kernel, "flash_attention_forward", c, False, "qkk",
         [("q", c.out_dtype), ("r", F32)],
         [pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, 1), F32),
          pltpu.VMEM((rows, c.d), F32)],
-        offs, q, k, v)
+        offs, keep, q, k, v)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _flash_core(q, k, v, offs, c: _Shape):
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _flash_core(q, k, v, offs, keep, c: _Shape):
     """``(o, lse)`` from ``q`` ``(B, Tq, Hkv * group * d)`` and ``k``, ``v``
     ``(B, Tk, Hkv * d)``, both lengths whole blocks.  ``offs`` is the int32
-    (2,) absolute (q_offset, k_offset) pair; ``lse`` is ``(B, Hkv, group,
-    Tq)`` float32, tokens on lanes.  Differentiable in q/k/v AND honest in
-    the lse output (nonzero dlse cotangents — the ring merge — feed the
-    backward's delta term)."""
-    return _forward(q, k, v, offs, c)
+    (2,) absolute (q_offset, k_offset) pair; ``keep`` the keep-mask's words
+    (``_call``) or None; ``lse`` is ``(B, Hkv, group, Tq)`` float32, tokens
+    on lanes.  Differentiable in q/k/v AND honest in the lse output (nonzero
+    dlse cotangents — the ring merge — feed the backward's delta term)."""
+    return _forward(q, k, v, offs, keep, c)
 
 
 # What a ``jax.checkpoint`` around the caller may keep (``policy=jax.
@@ -447,14 +494,14 @@ def _flash_core(q, k, v, offs, c: _Shape):
 SAVED = ("flash_attention_o", "flash_attention_lse")
 
 
-def _flash_core_fwd(q, k, v, offs, c):
-    o, lse = _forward(q, k, v, offs, c)
+def _flash_core_fwd(q, k, v, offs, keep, c):
+    o, lse = _forward(q, k, v, offs, keep, c)
     o, lse = (checkpoint_name(x, name) for x, name in zip((o, lse), SAVED))
-    return (o, lse), (q, k, v, offs, o, lse)
+    return (o, lse), (q, k, v, offs, keep, o, lse)
 
 
 def _flash_core_bwd(c, res, cts):
-    q, k, v, offs, o, lse = res
+    q, k, v, offs, keep, o, lse = res
     do, dlse = cts
     rows = c.group * c.block_q
     dq, delta = _call(
@@ -462,13 +509,14 @@ def _flash_core_bwd(c, res, cts):
         [("q", q.dtype), ("r", F32)],
         [pltpu.VMEM((rows, c.d), q.dtype), pltpu.VMEM((rows, 1), F32),
          pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, c.d), F32)],
-        offs, q, k, v, do, o, lse, dlse.astype(F32))
+        offs, keep, q, k, v, do, o, lse, dlse.astype(F32))
     dk, dv = _call(
         _dkv_kernel, "flash_attention_dkv", c, True, "qkkqrr",
         [("k", k.dtype), ("k", v.dtype)],
         [pltpu.VMEM((c.block_k, c.d), F32)] * 2,
-        offs, q, k, v, do.astype(q.dtype), lse, delta)
-    return dq, dk, dv, None  # integer offsets carry no cotangent
+        offs, keep, q, k, v, do.astype(q.dtype), lse, delta)
+    # the integer offsets and the mask's bits carry no cotangent
+    return dq, dk, dv, None, None
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -492,9 +540,10 @@ def _pad_rows(x, block):
 
 
 def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
-            out_dtype):
+            out_dtype, keep=None):
     """``(o, lse)`` of ``(B, Tq, Hq, D)`` against ``(B, Tk, Hkv, D)``, as
-    ``(B, Tq, Hq, D)`` and ``(B, Hq, Tq)``."""
+    ``(B, Tq, Hq, D)`` and ``(B, Hq, Tq)``; ``keep``: a keep-mask's bits
+    ``(B, Tq, words)`` uint32 (``masked_flash_attention``)."""
     if interpret is None:
         interpret = not lowerable()
     b, tq, hq, d = q.shape
@@ -502,6 +551,17 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not divide by {hkv} K/V heads")
     block_q, block_k = min(block_q, tq), min(block_k, tk)
+    words = 0
+    if keep is not None:
+        words = keep.shape[-1]
+        if not (causal and tq == tk and tq % block_q == 0
+                and tk % block_k == 0 and block_k % words == 0
+                and tk <= 32 * words):
+            raise ValueError(
+                f"a keep-mask of {words} words a row wants causal "
+                f"self-attention in whole blocks of whole words: T {tq} x "
+                f"{tk}, blocks {block_q} x {block_k}")
+        keep = jax.lax.bitcast_convert_type(keep, jnp.int32)
     in_place = d % LANES == 0
     if in_place:  # a head is a block of lanes
         flat = lambda x: x.reshape(*x.shape[:2], -1)  # noqa: E731
@@ -509,10 +569,10 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
         flat = partial(_heads_first, hkv=hkv)
     c = _Shape(bool(causal), float(d ** -0.5 if scale is None else scale),
                hq // hkv, d, tk, block_q, block_k,
-               np.dtype(out_dtype or q.dtype), bool(interpret))
+               np.dtype(out_dtype or q.dtype), bool(interpret), words)
     o, lse = _flash_core(
         _pad_rows(flat(q), block_q), _pad_rows(flat(k), block_k),
-        _pad_rows(flat(v), block_k), offs, c)
+        _pad_rows(flat(v), block_k), offs, keep, c)
     o, lse = o[:, :tq], lse[..., :tq]
     if in_place:
         return o.reshape(b, tq, hq, d), lse.reshape(b, hq, tq)
@@ -542,6 +602,19 @@ def flash_attention(
     offs = jnp.asarray([tk - tq, 0], jnp.int32)
     return _attend(q, k, v, offs, causal, block_q, block_k, interpret,
                    scale, out_dtype)[0]
+
+
+def masked_flash_attention(q, k, v, keep, *, block_q: int, block_k: int,
+                           interpret=None, scale=None, out_dtype=None):
+    """Causal self-attention over the keys a keep-mask holds: ``flash_
+    attention``'s kernels and layouts given ``keep``, ``(B, T, words)``
+    uint32 in ``ops/attention.pack_mask``'s layout, a subset of the causal
+    keys (a bit above the diagonal in a block the diagonal crosses WOULD be
+    attended).  ``T`` is whole blocks and ``block_k`` whole rows of words.
+    Returns ``(o (B, T, Hq, D), lse (B, Hq, T) float32)``, both
+    differentiable; a row that keeps no key comes out ``(0, -inf)``."""
+    return _attend(q, k, v, jnp.zeros((2,), jnp.int32), True, block_q,
+                   block_k, interpret, scale, out_dtype, keep)
 
 
 def flash_attention_step(

@@ -24,12 +24,17 @@ query is 4 MB a token (69 GB a layer at 16,384 tokens, ~84 ms at 819 GB/s)
 where the masked dense product is 2.2 TFLOP (~14 ms at 80% of the bf16
 peak); PERF.md section 6, PR 33 has what was read.
 
-All four run in XLA on the ONE blockwise loop of ``ops/attention.py``
-(``by_run``: a block of ``block_q`` queries at a time, ``lax.map`` over
-``jax.checkpoint``ed blocks, the sequence cut into ``segments`` runs that
-each meet only the keys up to their own end), so that no more than ``block_q
-x T`` scores a head exist at once, forward or backward; the masked pass IS
-``_blockwise_gqa``, given the keep-mask.  Between the pieces go the indexer's
+The index scores, the selection and the loss run in XLA on the ONE blockwise
+loop of ``ops/attention.py`` (``by_run``: a block of ``block_q`` queries at a
+time, ``lax.map`` over ``jax.checkpoint``ed blocks, the sequence cut into
+``segments`` runs that each meet only the keys up to their own end), so that
+no more than ``block_q x T`` scores a head exist at once, forward or
+backward.  The masked pass takes the K/V-blocked flash kernels given the
+selection's bits (``pallas_attention.masked_flash_attention``: no score
+leaves VMEM) where Pallas lowers and they accept the shapes, and elsewhere
+``_blockwise_gqa`` given the keep-mask, the same arithmetic on that loop and
+the oracle the kernels are tested against; an ``obs`` instant names the path
+at each trace.  Between the pieces go the indexer's
 ``qI`` / ``kI`` / ``w``, the mask as bits (``T * T / 8`` bytes: 33.5 MB at
 16,384 tokens, no float tensor of ``(T, T)``), ``q`` / ``k`` and the
 log-sum-exp.  ``index_scores_by_run`` alone materialises float32 scores of
@@ -44,6 +49,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from sparknet_tpu import obs
+from sparknet_tpu.ops import attention, pallas_attention
 from sparknet_tpu.ops.attention import (  # noqa: F401  (the mask's layout)
     BITS, NEG, _blockwise_gqa, blocked, by_run, joined, pack_mask, runs_of,
     unpack_mask, words_of)
@@ -52,6 +58,13 @@ F32 = jnp.float32
 U32 = jnp.uint32
 BLOCK_Q = 512
 SEGMENTS = 8
+# Keys a block of the flash kernels under a keep-mask, times the head's
+# width: 1,024 keys of 128 (``attention.KERNEL_BLOCK_K`` keys of 256).  A
+# step's two products and its softmax run one after the other, and a wider
+# tile lets Mosaic overlap them: on the v5e at T = 16,384, 32 / 4 heads of
+# 128 in bfloat16, forward + backward 65.2 ms at 1,024 keys against 70.9 at
+# 512 (forward 19.9 against 25.9; PERF.md section 6, PR 34).
+KERNEL_KEYS_X_WIDTH = attention.KERNEL_BLOCK_K * 256
 # What a ``jax.checkpoint`` around the caller may keep (``policy=jax.
 # checkpoint_policies.save_only_these_names(*SAVED)``) so that its
 # recomputation does not run the attention's blocks a second time.
@@ -164,24 +177,77 @@ def _scores(qb, kb):
     return jnp.einsum("bkgqd,bksd->bkgqs", qb, kb, preferred_element_type=F32)
 
 
+def kernels_refuse(t: int, hq: int, hkv: int, d: int, dtype) -> str:
+    """Why ``masked_attention`` does not take the flash kernels for these
+    shapes on this backend; empty where it takes them.
+
+    Float32 stays on the XLA pass: its products are six MXU passes either
+    way (on the v5e 326.8 ms against 344.5 forward + backward at the cell's
+    widths), and a Mosaic call in a program changes the scoped VMEM XLA
+    gives every fusion beside it, hence their tiling and their rounding: in
+    ``keye2-train-16k``'s float32 check of the selection the reference's two
+    passes over the index scores then break a near-tie differently, one pair
+    of 31 million, which its bound on the attention cannot take (PERF.md
+    section 6, PR 34).  The kernels themselves are as exact as the XLA pass
+    (``tests/test_keye_vl2.py``; on the chip 4.8e-7 against float64 where
+    the XLA pass reads 3.7e-7)."""
+    if not attention.lowerable():
+        return f"no Pallas lowering on {jax.default_backend()}"
+    if not pallas_attention.accepts(hq, hkv, d, dtype):
+        return "heads of whole lanes in whole groups, bfloat16 or float32"
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return "float32 products are six MXU passes on either path"
+    if t % (BITS * pallas_attention.LANES):
+        return "a keep-mask's row in whole lanes of words: T % 4096 == 0"
+    return ""
+
+
 def masked_attention(q, k, v, mask, *, block_q: int = BLOCK_Q,
                      segments: int = SEGMENTS):
     """``q``: ``scaled_queries`` ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T,
     Hkv, D)``; ``mask``: ``select``'s bits.  Softmax over each query's
-    selected keys: ``ops/attention._blockwise_gqa`` given the keep-mask
-    (scores and softmax float32, the products' operands in ``q``'s dtype).
-    Returns the output ``(B, T, Hq, D)`` float32 and the rows' log-sum-exp
-    ``(B, T, Hq)`` float32, both named for a caller's checkpoint policy
-    (``SAVED``)."""
+    selected keys (scores and softmax float32, the products' operands in
+    ``q``'s dtype).  Returns the output ``(B, T, Hq, D)`` float32 and the
+    rows' log-sum-exp ``(B, T, Hq)`` float32, both named for a caller's
+    checkpoint policy (``SAVED``).
+
+    Where Pallas lowers, ``pallas_attention.accepts`` the heads, ``q`` is
+    bfloat16 (``kernels_refuse`` says why) and a row of the mask is whole
+    lanes of words (``T % 4096 == 0``, so that a key block is whole bits of
+    every word), the flash kernels given the bits, in
+    ``causal_gqa_attention``'s query blocks (cut to a power of two, which
+    divides T) and key blocks of ``KERNEL_KEYS_X_WIDTH`` over the head's
+    width, in whole rows of words.  Elsewhere ``ops/attention._blockwise_gqa``
+    given the keep-mask; ``block_q`` and ``segments`` are its."""
     cd = q.dtype
     b, t, hq, d = q.shape
-    block_q, _ = runs_of(t, block_q, segments)
-    obs.instant("sparse_attention_path", cat="kernel", path="xla",
-                why="no kernel takes a keep-mask yet",
-                backend=jax.default_backend(), t=t, hq=hq, hkv=k.shape[2],
-                d=d, dtype=cd.name, block_q=block_q, segments=segments)
-    out = _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments,
-                         keep=mask)
+    hkv = k.shape[2]
+    words = mask.shape[-1]
+    why = kernels_refuse(t, hq, hkv, d, cd)
+    if why:  # a run's query blocks each meet the keys up to its end
+        block_q, runs = runs_of(t, block_q, segments)
+        block_k = block_q
+        met = (sum((hi - lo) * -(-keys // block_k) for lo, hi, keys in runs),
+               (-(-t // block_q)) ** 2)
+    else:
+        block_k = words * max(1, KERNEL_KEYS_X_WIDTH // d // words)
+        block_q = attention.kernel_block_q(hq // hkv, d, cd, block_k)
+        block_q = 1 << (block_q.bit_length() - 1)  # divides T
+        met = pallas_attention.blocks_met(t, t, block_q, block_k)
+    obs.instant("sparse_attention_path", cat="kernel",
+                path="xla" if why else "pallas", why=why,
+                backend=jax.default_backend(), t=t, hq=hq, hkv=hkv, d=d,
+                dtype=cd.name, block_q=block_q, block_k=block_k, words=words,
+                segments=segments,
+                blocks_computed=met[0], blocks_total=met[1])
+    k, v = k.astype(cd), v.astype(cd)
+    if why:
+        out = _blockwise_gqa(q, k, v, block_q, segments, keep=mask)
+    else:
+        o, lse = pallas_attention.masked_flash_attention(
+            q, k, v, mask, block_q=block_q, block_k=block_k, scale=1.0,
+            out_dtype=F32)
+        out = o, jnp.transpose(lse, (0, 2, 1))
     return tuple(checkpoint_name(x, name) for x, name in zip(out, SAVED))
 
 

@@ -747,6 +747,92 @@ def test_mixer_recomputation_keeps_what_the_flash_kernels_name(
     assert count("flash_attention_dq") == count("flash_attention_dkv") == 1
 
 
+def pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from pallas_calls(inner)
+
+
+BF16, FP32 = "bfloat16", "float32"
+# name -> (grid, operands after the (2,) int32 offsets, scratch), an array
+# as (shape, dtype); B = 2 sequences of 8,192 tokens in bfloat16
+UNMASKED_CALLS = {
+    # qwen3next-train-8k: 16 query heads on 2 K/V heads of 256, read in place
+    (16, 2, 256): {
+        "flash_attention_forward": (
+            (2, 2, 32, 16),
+            [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2,
+            [((2048, 1), FP32)] * 2 + [((2048, 256), FP32)]),
+        "flash_attention_dq": (
+            (2, 2, 32, 16),
+            [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2
+            + [((2, 8192, 4096), FP32)] * 2 + [((2, 2, 8, 8192), FP32)] * 2,
+            [((2048, 256), BF16)] + [((2048, 1), FP32)] * 2
+            + [((2048, 256), FP32)]),
+        "flash_attention_dkv": (
+            (2, 2, 16, 32),
+            [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2
+            + [((2, 8192, 4096), BF16)] + [((2, 2, 8, 8192), FP32)] * 2,
+            [((512, 256), FP32)] * 2),
+    },
+    # lfm2moe-train-8k: 32 query heads on 8 K/V heads of 64, heads-first
+    (32, 8, 64): {
+        "flash_attention_forward": (
+            (16, 1, 16, 16),
+            [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2,
+            [((2048, 1), FP32)] * 2 + [((2048, 64), FP32)]),
+        "flash_attention_dq": (
+            (16, 1, 16, 16),
+            [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2
+            + [((16, 8192, 256), FP32)] * 2 + [((16, 1, 4, 8192), FP32)] * 2,
+            [((2048, 64), BF16)] + [((2048, 1), FP32)] * 2
+            + [((2048, 64), FP32)]),
+        "flash_attention_dkv": (
+            (16, 1, 16, 16),
+            [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2
+            + [((16, 8192, 256), BF16)] + [((16, 1, 4, 8192), FP32)] * 2,
+            [((512, 64), FP32)] * 2),
+    },
+}
+
+
+@pytest.mark.parametrize("hq, hkv, d", sorted(UNMASKED_CALLS))
+def test_without_a_keep_mask_the_kernels_calls_are_pinned(
+        monkeypatch, hq, hkv, d):
+    """The three ``pallas_call``s of ``causal_gqa_attention``'s forward +
+    backward at the two sequence cells' head shapes: grid, operands and
+    scratch as they were before the kernels learned to take a keep-mask (PR
+    34).  ``qwen3next-train-8k``'s order of operations hangs on 50 MB of XLA's
+    own memory estimate (PERF.md section 6): an operand added to the unmasked
+    kernels has to fail here, not in a cell."""
+    from sparknet_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 8192, h, d), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(causal_gqa_attention(
+            *a, compute_dtype=jnp.bfloat16)), argnums=(0, 1, 2)))(
+                shape(hq), shape(hkv), shape(hkv))
+    avals = lambda vs: [(v.aval.shape, v.aval.dtype.name) for v in vs]  # noqa: E731
+    got = {}
+    for eqn in pallas_calls(jaxpr.jaxpr):
+        mapping = eqn.params["grid_mapping"]
+        assert mapping.num_index_operands == 1
+        assert avals(eqn.invars[:1]) == [((2,), "int32")]
+        name = eqn.params["name"]
+        assert name not in got  # each kernel once
+        got[name] = (
+            tuple(mapping.grid), avals(eqn.invars[1:]),
+            avals(eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]))
+    assert got == UNMASKED_CALLS[hq, hkv, d]
+
+
 def test_lm_app_trains_the_hybrid_model_from_a_configuration_file(tmp_path):
     """``lm_app --model_config``: the byte corpus through
     ``Solver(net=...)`` with ADAM and ``ParameterAveragingTrainer.round`` on

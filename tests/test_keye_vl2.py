@@ -471,22 +471,207 @@ def test_masked_attention_with_every_causal_key_is_causal_attention():
     assert rel(got, causal_gqa_attention(q, k, v)) < 2e-5
 
 
-def test_the_attention_path_is_named_at_each_trace():
+def path_events(fn, *args):
+    """``sparse_attention_path``'s arguments at each trace of ``fn`` (traced
+    anew: the instant is written while tracing)."""
     from sparknet_tpu import obs
     from sparknet_tpu.obs.trace import Tracer
 
-    q, k, v = attention_inputs(4)
     tracer = obs.install_tracer(Tracer())
     try:
-        jax.eval_shape(lambda *a: sa.masked_attention(
-            sa.scaled_queries(a[0], jnp.bfloat16), *a[1:],
-            sa.causal_mask_bits(2, T)), q, k, v)
+        jax.eval_shape(lambda *a: fn(*a), *args)
     finally:
         obs.uninstall_tracer()
-    (event,) = [e for e in tracer.events()
-                if e["name"] == "sparse_attention_path"]
-    assert event["args"]["path"] == "xla" and event["args"]["t"] == T
-    assert event["args"]["dtype"] == "bfloat16"
+    return [e["args"] for e in tracer.events()
+            if e["name"] == "sparse_attention_path"]
+
+
+def test_the_attention_path_is_named_at_each_trace():
+    q, k, v = attention_inputs(4)
+    (event,) = path_events(lambda *a: sa.masked_attention(
+        sa.scaled_queries(a[0], jnp.bfloat16), *a[1:],
+        sa.causal_mask_bits(2, T)), q, k, v)
+    assert event["path"] == "xla" and event["t"] == T
+    assert event["why"] == "no Pallas lowering on cpu"
+    assert event["dtype"] == "bfloat16" and event["words"] == sa.words_of(T)
+    # one run of one block of T queries against T keys
+    assert (event["block_q"], event["block_k"]) == (T, T)
+    assert (event["blocks_computed"], event["blocks_total"]) == (1, 1)
+
+
+# -- the flash kernels under a keep-mask (interpreter mode) --------------------
+KT = 64  # two words a row; key blocks of 16 and 32 are 8 and 16 bits of each
+
+
+def kernel_masks(kind, b=2, t=KT):
+    """Keep-masks as bits.  ``select``: the indexer's own; ``late``: most
+    rows keep a few keys just before their own position (nothing in their
+    first key blocks, so the running maximum stays at the mask's value past
+    the first blocks met) and every third row keeps only itself;
+    ``causal``: every causal key."""
+    if kind == "causal":
+        return sa.causal_mask_bits(b, t)
+    if kind == "select":
+        qi, w, ki = indexer_inputs(11, b=b, t=t)
+        return sa.select(sa.index_scores_by_run(
+            qi, w, ki, block_q=16, segments=2), t, 8, block_q=16, segments=2)
+    row, key = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    near = (key <= row) & (key >= row - 4) & (key % 2 == row % 2)
+    keep = jnp.where((row % 3 == 0), key == row, near)
+    return jnp.broadcast_to(sa.pack_mask(keep, sa.words_of(t)),
+                            (b, t, sa.words_of(t)))
+
+
+@pytest.mark.parametrize("kind", ["select", "late", "causal"])
+@pytest.mark.parametrize("block_q, block_k, d", [(16, 16, 8), (32, 64, 128)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [1, 8])
+def test_flash_kernels_under_a_keep_mask_match_the_xla_pass(
+        group, dtype, block_q, block_k, d, kind):
+    """``(o, lse)`` and the gradients w.r.t. ``q``, ``k``, ``v`` under a
+    cotangent on BOTH outputs, against ``_blockwise_gqa`` given the same
+    bits; a head of 8 goes heads-first (a sequence's K/V heads share its
+    mask), a head of 128 is read in place."""
+    from sparknet_tpu.ops import pallas_attention
+    from sparknet_tpu.ops.attention import _blockwise_gqa
+
+    hkv = 2 if d == 8 else 1
+    cd = jnp.dtype(dtype)
+    q, k, v = attention_inputs(5, t=KT, hq=hkv * group, hkv=hkv, d=d)
+    q, k, v = sa.scaled_queries(q, cd), k.astype(cd), v.astype(cd)
+    bits = kernel_masks(kind)
+    key = jax.random.key(6)
+    do = jax.random.normal(jax.random.fold_in(key, 0), q.shape)
+    dlse = jax.random.normal(jax.random.fold_in(key, 1), q.shape[:3])
+
+    def kernels(q, k, v):
+        o, lse = pallas_attention.masked_flash_attention(
+            q, k, v, bits, block_q=block_q, block_k=block_k, interpret=True,
+            scale=1.0, out_dtype=jnp.float32)
+        return o, jnp.transpose(lse, (0, 2, 1))
+
+    def xla(q, k, v):
+        return _blockwise_gqa(q, k, v, 16, 2, keep=bits)
+
+    def both(fn):
+        def loss(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o * do) + jnp.sum(lse * dlse), (o, lse)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+
+    with jax.default_matmul_precision("highest"):
+        got_grads, got = both(kernels)(q, k, v)
+        want_grads, want = both(xla)(q, k, v)
+        if kind == "causal":  # the bits say what the positions say
+            dense = pallas_attention.flash_attention(
+                q, k, v, causal=True, block_q=block_q, block_k=block_k,
+                interpret=True, scale=1.0, out_dtype=jnp.float32)
+            assert rel(got[0], dense) < 1e-6
+    assert got[0].dtype == got[1].dtype == jnp.float32
+    bound = 2e-5 if cd == jnp.float32 else 2e-2
+    assert rel(got[0], want[0]) < bound and rel(got[1], want[1]) < bound
+    for g, w in zip(got_grads, want_grads):
+        assert g.dtype == cd and rel(g, w) < bound
+
+
+@pytest.mark.parametrize("change", [
+    dict(block_k=24),  # whole words, but 64 keys are not whole blocks of 24
+    dict(block_q=48),
+    dict(block_k=17),  # not whole rows of two words
+])
+def test_a_keep_mask_wants_whole_blocks_of_whole_words(change):
+    from sparknet_tpu.ops import pallas_attention
+
+    q, k, v = attention_inputs(5, t=KT)
+    kw = {**dict(block_q=16, block_k=16, interpret=True), **change}
+    with pytest.raises(ValueError, match="whole blocks of whole words"):
+        pallas_attention.masked_flash_attention(
+            q, k, v, kernel_masks("causal"), **kw)
+
+
+@pytest.mark.parametrize("t, hq, hkv, d, dtype, path, why", [
+    (4096, 32, 4, 128, "bfloat16", "pallas", ""),
+    (16384, 32, 4, 128, "bfloat16", "pallas", ""),
+    (4096, 32, 4, 128, "float32", "xla", "six MXU passes"),
+    (40, 32, 4, 128, "bfloat16", "xla", "T % 4096 == 0"),
+    (4090, 32, 4, 128, "bfloat16", "xla", "T % 4096 == 0"),
+    (4096, 32, 4, 128, "float16", "xla", "bfloat16 or float32"),
+    (4096, 6, 2, 40, "bfloat16", "xla", "heads of whole lanes"),
+])
+def test_the_kernels_are_taken_where_the_shapes_allow(
+        monkeypatch, t, hq, hkv, d, dtype, path, why):
+    """The dispatch, by what the code observes (shapes alone are traced:
+    nothing runs): the kernels where Pallas lowers, the heads are accepted
+    and a row of the mask is whole lanes of words; the XLA pass elsewhere,
+    with the reason named."""
+    from sparknet_tpu.ops import attention, pallas_attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    cd = jnp.dtype(dtype)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, cd)  # noqa: E731
+    bits = jax.ShapeDtypeStruct((1, t, sa.words_of(t)), jnp.uint32)
+    (event,) = path_events(
+        sa.masked_attention, shape(1, t, hq, d), shape(1, t, hkv, d),
+        shape(1, t, hkv, d), bits)
+    assert event["path"] == path and why in event["why"]
+    assert bool(event["why"]) == (path == "xla")
+    if path == "pallas":  # the cell's blocks: 512 x 1,024
+        assert (event["block_q"], event["block_k"]) == (512, 1024)
+        assert event["words"] == t // 32 and 1024 % event["words"] == 0
+        assert (event["blocks_computed"], event["blocks_total"]
+                ) == pallas_attention.blocks_met(t, t, 512, 1024)
+
+
+@pytest.mark.parametrize("t, blocks", [(16384, (272, 512)), (4096, (20, 32))])
+def test_blocks_the_kernels_meet_at_the_cells_lengths(monkeypatch, t, blocks):
+    """17/32 of the score matrix at T = 16,384 in blocks of 512 x 1,024,
+    where the XLA pass's eight runs meet 9/16."""
+    from sparknet_tpu.ops import attention
+
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, t, h, 128), jnp.bfloat16)
+    bits = jax.ShapeDtypeStruct((1, t, sa.words_of(t)), jnp.uint32)
+    args = (shape(32), shape(4), shape(4), bits)
+    (xla,) = path_events(sa.masked_attention, *args)
+    assert (xla["blocks_computed"], xla["blocks_total"]) == (
+        9 * (t // 512) ** 2 // 16, (t // 512) ** 2)
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    (event,) = path_events(sa.masked_attention, *args)
+    assert (event["blocks_computed"], event["blocks_total"]) == blocks
+
+
+def test_masked_attention_through_the_kernels_keeps_its_interface(monkeypatch):
+    """``masked_attention`` on the kernels' path (interpreter mode) at the
+    smallest length that takes it: the output and the log-sum-exp in the XLA
+    pass's shapes and values, named for ``MIXER_KEEPS``, and a recomputation
+    under that policy runs the forward kernel once."""
+    from sparknet_tpu.models.hybrid_lm import MIXER_KEEPS
+    from sparknet_tpu.ops import attention
+
+    t = 4096
+    q, k, v = attention_inputs(8, b=1, t=t, hq=2, hkv=1, d=128)
+    q = sa.scaled_queries(q, jnp.bfloat16)
+    row, key = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    bits = sa.pack_mask(((key <= row) & ((row - key) % 37 == 0))[None],
+                        sa.words_of(t))
+    want = jax.jit(sa.masked_attention)(q, k, v, bits)
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    (event,) = path_events(sa.masked_attention, q, k, v, bits)
+    assert event["path"] == "pallas"
+    got = jax.jit(sa.masked_attention)(q, k, v, bits)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype and rel(g, w) < 1e-2
+
+    def loss(q, k, v):
+        o, lse = jax.checkpoint(
+            lambda *a: sa.masked_attention(*a, bits), policy=MIXER_KEEPS)(
+                q, k, v)
+        return jnp.sum(o) + jnp.sum(lse)
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    assert text.count("name=flash_attention_forward") == 1
+    assert text.count("name=flash_attention_dq") == 1
+    assert text.count("name=flash_attention_dkv") == 1
 
 
 # -- one chip's share --------------------------------------------------------

@@ -99,6 +99,37 @@ def test_flash_attention_kernels_compile_for_the_v5e(
     assert all(name in compiled.as_text() for name in names)
 
 
+# the same three kernels WITH a keep-mask (``masked_flash_attention``), as
+# ``ops/sparse_attention.masked_attention`` hands keye2-train-16k's layer to
+# them: one sequence, 32 query heads on 4 K/V heads of 128, the selection as
+# ``(1, T, T / 32)`` words, blocks of 512 x 1,024 in bfloat16 and 256 x 1,024
+# in its checks' float32; at T = 16,384 a key block is two bits of every
+# word side by side, at the checks' 4,096 eight.  What is settled here: the
+# blocks fit VMEM, and Mosaic takes the unpack and the dk/dv pass's 32-bit
+# transpose of the unpacked tile
+@pytest.mark.parametrize("dtype, block_q", [("bfloat16", 512), ("float32", 256)])
+@pytest.mark.parametrize("t", [16384, 4096])
+def test_flash_attention_kernels_under_a_keep_mask_compile_for_the_v5e(
+        one_chip, t, dtype, block_q):
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, t, h, 128), jnp.dtype(dtype), sharding=one_chip)
+    bits = jax.ShapeDtypeStruct((1, t, t // 32), jnp.uint32, sharding=one_chip)
+
+    def forward(q, k, v, bits):
+        return pallas_attention.masked_flash_attention(
+            q, k, v, bits, block_q=block_q, block_k=1024, interpret=False,
+            scale=1.0, out_dtype=jnp.float32)
+
+    def gradients(q, k, v, bits):
+        out, vjp = jax.vjp(lambda *qkv: forward(*qkv, bits), q, k, v)
+        return vjp(out)
+
+    text = jax.jit(gradients).lower(
+        shape(32), shape(4), shape(4), bits).compile().as_text()
+    assert all(name in text for name in (
+        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+
+
 # the sequence models' loss, forward + backward in bfloat16 at rows x width x
 # vocabulary: lfm2moe-train-8k's window (a tied head, read as the embedding
 # lies), qwen3next-train-8k's (18,992 = 18 blocks of 1,024 + 560: the last
