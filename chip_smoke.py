@@ -73,43 +73,30 @@ FULL = {
     "interpret": False,
 }
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+def builds_since(mark=0):
+    """What the program built since ``mark`` (a length of
+    ``obs.programs()``), from its own account: every ``obs.Program`` the
+    trainer and the solver build writes one record a signature, and counts
+    it in ``sparknet_program_builds_total{program,cache}``."""
+    from sparknet_tpu import obs
+
+    rows = obs.programs()[mark:]
+    return {
+        "compiles": len(rows),
+        "compile_s": round(sum(r["compile_s"] for r in rows), 2),
+        "trace_lower_s": round(sum(r["trace_lower_s"] for r in rows), 2),
+        "cache_hits": sum(r["cache"] == "hit" for r in rows),
+        "programs": [r["program"] for r in rows],
+    }
 
 
-class CompileLog:
-    """jax's own compile events: every XLA compile request (seconds, a
-    persistent-cache hit included at its retrieval time) and every
-    persistent-cache hit."""
+def builds_counted():
+    """The same count as the program's counter holds it, over all labels."""
+    from sparknet_tpu import obs
 
-    def __init__(self):
-        import jax
-
-        self.seconds = []
-        self.names = []
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, fun_name="", **_):
-        if event == _COMPILE_EVENT:
-            self.seconds.append(duration)
-            self.names.append(str(fun_name))
-
-    def _event(self, event, **_):
-        if event == _CACHE_HIT_EVENT:
-            self.hits += 1
-
-    def mark(self):
-        return len(self.seconds), self.hits
-
-    def since(self, mark):
-        n, hits = mark
-        return {
-            "compiles": len(self.seconds) - n,
-            "compile_s": round(sum(self.seconds[n:]), 2),
-            "cache_hits": self.hits - hits,
-        }
+    tm = obs.enable_training_metrics()
+    return int(sum(c.value for c in tm.program_builds.children()))
 
 
 def _shard_devices(tree):
@@ -128,18 +115,20 @@ class TrainerSpy:
     and records what the smoke asserts on.  The apps return only an exit
     code; this reads the trainer they built."""
 
-    def __init__(self, compile_log):
-        self.compile_log = compile_log
-        self.first_compile = compile_log.mark()[0]
+    def __init__(self):
+        from sparknet_tpu import obs
+
+        self.first_build = len(obs.programs())
         self.trainer = None
         self.state = None
         self.init_norm = None
-        self.rounds = []  # per round: losses, compiles inside round(), seconds
+        self.rounds = []  # per round: losses, builds inside round(), seconds
         self.placements = []  # per round: device sets before the round ran
         self.eval_scores = []
 
     @contextlib.contextmanager
     def installed(self):
+        from sparknet_tpu import obs
         from sparknet_tpu.parallel import ParameterAveragingTrainer as T
 
         orig = T.init_state, T.round, T.test_and_store_result
@@ -160,14 +149,14 @@ class TrainerSpy:
                 "history": _shard_devices(state.history),
                 "batches": _shard_devices(batches),
             })
-            mark = spy.compile_log.mark()
+            mark = len(obs.programs())
             t0 = time.perf_counter()
             out = orig[1](trainer, state, batches, *a, **kw)
             losses = np.asarray(jax.block_until_ready(out[1]))
             spy.rounds.append({
                 "losses": losses,
                 "seconds": time.perf_counter() - t0,
-                **spy.compile_log.since(mark),
+                **builds_since(mark),
             })
             spy.state = out[0]
             return out
@@ -222,15 +211,10 @@ class TrainerSpy:
             )
             if r > 0:
                 assert rec["compiles"] == 0, (
-                    f"round {r} compiled {rec['compiles']} program(s) — "
-                    "only round 0 may"
+                    f"round {r} built {rec['programs']} — only round 0 may"
                 )
-        round_compiles = self.compile_log.names[self.first_compile:].count(
-            "jit(round_body)"
-        )
-        assert round_compiles == 1, (
-            f"the jitted round compiled {round_compiles}x"
-        )
+        round_builds = builds_since(self.first_build)["programs"].count("round")
+        assert round_builds == 1, f"the round was built {round_builds}x"
         final_norm, spread = self.param_stats(self.state)
         assert final_norm != self.init_norm, "training left the weights alone"
         assert spread == 0.0, (
@@ -251,13 +235,13 @@ class TrainerSpy:
         }
 
 
-def phase_train(sizes, workers, compile_log):
+def phase_train(sizes, workers):
     """The flagship app at the configuration's width on ``workers`` chips."""
     import numpy as np
 
     from sparknet_tpu.apps import imagenet_app
 
-    spy = TrainerSpy(compile_log)
+    spy = TrainerSpy()
     with spy.installed():
         rc = imagenet_app.main([
             f"--model={sizes['model']}",
@@ -519,7 +503,7 @@ def phase_kernels(sizes):
     return out
 
 
-def phase_lm(sizes, compile_log):
+def phase_lm(sizes):
     """lm_app at its default preset, attention=auto: on the chip the
     Pallas flash kernel is the train step's attention."""
     import jax
@@ -534,7 +518,7 @@ def phase_lm(sizes, compile_log):
         flash_calls.append(1)
         return real_flash(*a, **kw)
 
-    spy = TrainerSpy(compile_log)
+    spy = TrainerSpy()
     pallas_attention.flash_attention = counting_flash
     try:
         with spy.installed():
@@ -562,20 +546,19 @@ def run(sizes, out_dir=OUT_DIR):
     import jax
 
     os.makedirs(out_dir, exist_ok=True)
-    compile_log = CompileLog()
     n = jax.device_count()
     plan = [
-        ("train-1chip", lambda: phase_train(sizes, 1, compile_log)),
+        ("train-1chip", lambda: phase_train(sizes, 1)),
         ("train-4chip",
-         (lambda: phase_train(sizes, 4, compile_log)) if n >= 4 else None),
+         (lambda: phase_train(sizes, 4)) if n >= 4 else None),
         ("kernels", lambda: phase_kernels(sizes)),
-        ("lm-train", lambda: phase_lm(sizes, compile_log)),
+        ("lm-train", lambda: phase_lm(sizes)),
     ]
     phases = {}
     log_dir = os.environ.get("SPARKNET_LOG_DIR")
     os.environ["SPARKNET_LOG_DIR"] = out_dir  # TrainingLog files land here
     try:
-        _run_plan(plan, phases, compile_log, n)
+        _run_plan(plan, phases, n)
     finally:
         if log_dir is None:
             del os.environ["SPARKNET_LOG_DIR"]
@@ -588,14 +571,16 @@ def run(sizes, out_dir=OUT_DIR):
     return ok, phases
 
 
-def _run_plan(plan, phases, compile_log, n):
+def _run_plan(plan, phases, n):
+    from sparknet_tpu import obs
+
     for name, fn in plan:
         if fn is None:
             phases[name] = {"status": f"skipped: {n} devices"}
             print(f"[chip_smoke] {name}: {phases[name]['status']}", flush=True)
             continue
         print(f"[chip_smoke] {name}: start", flush=True)
-        mark = compile_log.mark()
+        mark, counted = len(obs.programs()), builds_counted()
         t0 = time.perf_counter()
         try:
             result = {"status": "ran", **fn()}
@@ -603,7 +588,9 @@ def _run_plan(plan, phases, compile_log, n):
             # phases still run: one chip call should say all that is broken
             traceback.print_exc()
             result = {"status": "failed", "error": f"{type(e).__name__}: {e}"[:400]}
-        result.update(compile_log.since(mark))
+        result.update(builds_since(mark))
+        # the program's counter and its records are one account
+        result["builds_counted"] = builds_counted() - counted
         result["wall_s"] = round(time.perf_counter() - t0, 2)
         phases[name] = result
         print(f"[chip_smoke] {name}: {json.dumps(result)}", flush=True)

@@ -52,8 +52,13 @@ CANONICAL_METRICS = {
     "sparknet_straggler_rounds_total": (),
     "sparknet_achieved_flops": (),
     "sparknet_mfu": (),
-    "sparknet_jit_cache_size": (),
-    "sparknet_device_bytes": (),
+    # every program the trainer builds (obs/program.py Program): what it
+    # cost to build and what it holds on the chip; read by /metrics,
+    # chip_smoke.py and (the memory gauge) the benchmark's reader
+    "sparknet_program_builds_total": ("program", "cache"),
+    "sparknet_program_build_seconds": ("program", "stage"),
+    "sparknet_program_bytes": ("program", "kind"),
+    "sparknet_device_memory_bytes": ("kind",),
     "sparknet_host_rss_bytes": (),
     "sparknet_grad_norm": (),
     "sparknet_nonfinite_total": (),
@@ -182,6 +187,11 @@ CANONICAL_SPANS = {
         "snapshot", "restore", "verify",
     }),
     "cache": frozenset({"cache_read", "cache_fetch"}),
+    # a program's build (obs/program.py): ``build`` with its children
+    # ``trace_lower`` and ``compile`` (args ``program``), and the
+    # trainers' ``init_state``; the memory marks beside them are instants
+    # (``memory``, cat ``memory``), which this inventory does not list
+    "build": frozenset({"build", "trace_lower", "compile", "init_state"}),
     # the LM data plane's host-side window sampling (apps/lm_app.py —
     # nests under the producer thread's assemble span in traces)
     "data": frozenset({"sample_text"}),

@@ -38,8 +38,6 @@ from __future__ import annotations
 import json
 import threading
 import time
-import weakref
-from collections import deque
 from typing import Optional
 
 from sparknet_tpu.obs import flight  # noqa: F401
@@ -49,8 +47,14 @@ from sparknet_tpu.obs.fleet import (  # noqa: F401
     DEFAULT_FLEET_PORT,
     FleetCollector,
 )
+from sparknet_tpu.obs import program  # noqa: F401
 from sparknet_tpu.obs.flight import FlightRecorder  # noqa: F401
 from sparknet_tpu.obs.profile import RoundProfiler  # noqa: F401
+from sparknet_tpu.obs.program import (  # noqa: F401
+    Program,
+    memory_marks,
+    programs,
+)
 from sparknet_tpu.obs.ship import Shipper  # noqa: F401
 from sparknet_tpu.obs.metrics import (  # noqa: F401
     LATENCY_BUCKETS_S,
@@ -66,6 +70,7 @@ from sparknet_tpu.obs.trace import (  # noqa: F401
     install_tracer,
     instant,
     jsonl_path_for,
+    recording,
     set_phase_observer,
     set_ship,
     span,
@@ -73,45 +78,6 @@ from sparknet_tpu.obs.trace import (  # noqa: F401
 )
 
 DEFAULT_OBS_PORT = 8380
-
-# jitted callables whose _cache_size() feeds the jit-cache gauge; weak
-# references, bounded — trainers register on construction and a bench
-# that builds dozens must not pin them all in memory
-_tracked_jits: "deque" = deque(maxlen=8)
-
-
-def track_jit(jitted) -> None:
-    """Register a jitted callable for the ``sparknet_jit_cache_size``
-    gauge (sum of ``_cache_size()`` over the most recent registrants)."""
-    try:
-        _tracked_jits.append(weakref.ref(jitted))
-    except TypeError:  # not weakref-able: skip rather than leak
-        pass
-
-
-def _jit_cache_size() -> int:
-    total = 0
-    for ref in list(_tracked_jits):
-        fn = ref()
-        if fn is None:
-            continue
-        try:
-            total += int(fn._cache_size())
-        except Exception:
-            pass
-    return total
-
-
-def _device_bytes() -> float:
-    """Bytes held by live jax arrays on this process's devices; guarded
-    — any backend that can't report (or a mid-teardown runtime) reads 0
-    rather than poisoning a scrape."""
-    try:
-        import jax
-
-        return float(sum(a.nbytes for a in jax.live_arrays()))
-    except Exception:
-        return 0.0
 
 
 def _host_rss_bytes() -> float:
@@ -269,17 +235,40 @@ class TrainingMetrics:
             "model FLOP utilization vs the chip's bf16 peak (0 when the "
             "peak is unknown, e.g. CPU)",
         )
-        self.jit_cache = registry.gauge(
-            "sparknet_jit_cache_size",
-            "compiled programs behind tracked jitted fns (constant "
-            "after warmup iff no recompiles)",
-            fn=_jit_cache_size,
+        # every program the trainer builds (obs/program.py Program):
+        # zero until one is built with metrics on
+        self.program_builds = registry.counter(
+            "sparknet_program_builds_total",
+            "programs lowered and compiled, by program name and "
+            "persistent-cache verdict (hit/miss/off); flat after warm-up "
+            "iff nothing recompiles, and a second build under one name IS "
+            "a recompile (obs.programs() holds both signatures' shapes)",
+            labels=("program", "cache"),
         )
-        self.device_bytes = registry.gauge(
-            "sparknet_device_bytes",
-            "bytes held by live jax arrays (jax.live_arrays accounting)",
-            fn=_device_bytes,
+        self.program_build_seconds = registry.gauge(
+            "sparknet_program_build_seconds",
+            "the program's last build: stage=trace_lower (Python tracing "
+            "and lowering to StableHLO, which no cache saves) or "
+            "stage=compile (XLA's compile, or the fetch from the "
+            "persistent cache)",
+            labels=("program", "stage"),
         )
+        self.program_bytes = registry.gauge(
+            "sparknet_program_bytes",
+            "the last-built executable's Compiled.memory_analysis() per "
+            "device: kind=temp|argument|output|alias|code",
+            labels=("program", "kind"),
+        )
+        self.device_memory = registry.gauge(
+            "sparknet_device_memory_bytes",
+            "device.memory_stats() of this process's fullest device at "
+            "scrape: kind=in_use|peak_in_use|reserved|peak_reserved|limit "
+            "(temporaries and reserved bytes included; 0 where the "
+            "backend reports nothing)",
+            labels=("kind",), fn=program.memory_gauge,
+        )
+        for kind in program.MEMORY_KINDS:
+            self.device_memory.labels(kind)
         self.host_rss = registry.gauge(
             "sparknet_host_rss_bytes", "peak resident set size",
             fn=_host_rss_bytes,
@@ -486,6 +475,7 @@ def enable_training_metrics() -> TrainingMetrics:
     with _lock:
         if _training is None:
             _training = TrainingMetrics(MetricsRegistry())
+            program.set_metrics(_training)
             fam = _training.phase_latency
             set_phase_observer(
                 lambda name, dur_s: fam.labels(name).observe(dur_s)
@@ -511,6 +501,7 @@ def _reset_training_metrics_for_tests() -> None:
         _sentry = None
         _membership = None
         _slo_evaluator = None
+        program.set_metrics(None)
         set_phase_observer(None)
         set_ship(None)
     flight.uninstall()

@@ -22,6 +22,7 @@ not N ad-hoc instruments.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
@@ -254,10 +255,12 @@ class MetricFamily:
     carry the label set — the Prometheus family convention."""
 
     def __init__(self, cls, name: str, help: str,
-                 label_names: Sequence[str], **kwargs):
+                 label_names: Sequence[str], fn=None, **kwargs):
         if not label_names:
             raise ValueError("a MetricFamily needs at least one label name")
         self._cls = cls
+        # a callback family: each child samples fn(*its label values)
+        self._fn = fn
         self.TYPE = cls.TYPE
         self.name, self.help = name, help
         self._label_names = tuple(label_names)
@@ -275,7 +278,10 @@ class MetricFamily:
         with self._lock:
             child = self._children.get(key)
             if child is None:
-                child = self._cls(self.name, self.help, **self._kwargs)
+                kwargs = self._kwargs
+                if self._fn is not None:
+                    kwargs = {**kwargs, "fn": functools.partial(self._fn, *key)}
+                child = self._cls(self.name, self.help, **kwargs)
                 child.labelstr = ",".join(
                     f'{n}="{_escape_label(v)}"'
                     for n, v in zip(self._label_names, key)
@@ -321,15 +327,11 @@ class MetricsRegistry:
         labels: Optional[Sequence[str]] = None,
     ):
         if labels:
-            if fn is not None:
-                # one shared callback cannot distinguish children, so
-                # the combination would render dead 0-valued samples —
-                # fail loudly; labeled gauges use set()/inc() per child
-                raise ValueError(
-                    f"{name}: callback gauges cannot be labeled — "
-                    "use set() on labels(...) children instead"
-                )
-            return self.register(MetricFamily(Gauge, name, help, labels))
+            # a labeled callback gauge hands its children's label values
+            # to fn; the children exist once labels(...) has named them
+            return self.register(
+                MetricFamily(Gauge, name, help, labels, fn=fn)
+            )
         return self.register(Gauge(name, help, fn=fn))
 
     def histogram(

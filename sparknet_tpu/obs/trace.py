@@ -327,6 +327,12 @@ def span(name: str, cat: str = "phase", **args):
     return _Span(name, cat, args or None)
 
 
+def recording() -> bool:
+    """Whether a sink that keeps instants is installed: what a caller asks
+    before it READS something only to report it (a per-round memory mark)."""
+    return _tracer is not None or _flight is not None or _ship is not None
+
+
 def instant(name: str, cat: str = "event", **args) -> None:
     """Record a tagged point event (no-op when tracing and flight
     recording are off)."""

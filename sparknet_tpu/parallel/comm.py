@@ -260,15 +260,18 @@ class CommPlane:
         # batch_spec: the trainer's generalized batch partitioning
         # (sequence parallelism) — same in_spec as the fused round
         batch_in_spec = P(axis) if batch_spec is None else batch_spec
-        self._local = jax.jit(
+        # the local window is the plane's one large program: an
+        # obs.Program, keyed on its batches (obs/program.py).  The chunk
+        # programs below stay bare jits: one name would hold a record a
+        # chunk, and a second record would not mean a recompile
+        self._local = obs.Program("comm_local", jax.jit(
             shard_map(
                 local_body,
                 mesh=mesh,
                 in_specs=(P(axis), batch_in_spec, P(), P(axis)),
                 out_specs=out_specs,
             )
-        )
-        obs.track_jit(self._local)
+        ), watch=(1,), devices=mesh.local_devices)
 
         def _dequant(q, scale, mode: str):
             if mode == "int8":

@@ -341,7 +341,7 @@ class BoundedStalenessTrainer:
         batch_in_spec = (
             P(axis) if batch_spec is None else batch_spec
         )
-        self._stale_round = jax.jit(
+        self._stale_round = obs.Program("stale_round", jax.jit(
             shard_map(
                 stale_body,
                 mesh=mesh,
@@ -351,8 +351,7 @@ class BoundedStalenessTrainer:
                 out_specs=out_specs,
             ),
             donate_argnums=(0, 1),
-        )
-        obs.track_jit(self._stale_round)
+        ), watch=(1,), devices=mesh.local_devices)
 
         # asymmetric hierarchy: intra-slice boundaries average the
         # arrived workers WITHIN each slice (stacked per-slice psum —
@@ -397,18 +396,21 @@ class BoundedStalenessTrainer:
                 return finish(params, stats, history, it, losses,
                               astats, keep, bad, sswmean, any_arr)
 
-            self._stale_slice_round = jax.jit(
-                shard_map(
-                    stale_slice_body,
-                    mesh=mesh,
-                    in_specs=(
-                        P(axis), batch_in_spec, P(), P(axis), P(axis)
+            self._stale_slice_round = obs.Program(
+                "stale_slice_round",
+                jax.jit(
+                    shard_map(
+                        stale_slice_body,
+                        mesh=mesh,
+                        in_specs=(
+                            P(axis), batch_in_spec, P(), P(axis), P(axis)
+                        ),
+                        out_specs=out_specs,
                     ),
-                    out_specs=out_specs,
+                    donate_argnums=(0, 1),
                 ),
-                donate_argnums=(0, 1),
+                watch=(1,), devices=mesh.local_devices,
             )
-            obs.track_jit(self._stale_slice_round)
 
     # ------------------------------------------------------------------
     # delegation: placement / eval / jobstate surfaces are the base's

@@ -420,7 +420,12 @@ class ParameterAveragingTrainer:
         out_specs = (
             (P(axis), P(axis), P(axis)) if audit else (P(axis), P(axis))
         )
-        self._round = jax.jit(
+        # every program below is an obs.Program: built (lowered, compiled,
+        # accounted for: obs.programs()) on a batch it has not seen, then
+        # the jax.jit it wraps is called.  A call is keyed on the batches
+        # alone; the marks read the memory of this mesh's own devices
+        self._devices = mesh.local_devices
+        self._round = obs.Program("round", jax.jit(
             shard_map(
                 round_body,
                 mesh=mesh,
@@ -428,8 +433,7 @@ class ParameterAveragingTrainer:
                 out_specs=out_specs,
             ),
             donate_argnums=(0, 1),
-        )
-        obs.track_jit(self._round)  # feeds the jit-cache gauge
+        ), watch=(1,), devices=self._devices)
         # per-mask placed live masks, cached: the chaos/degraded loops
         # pass the SAME mask for many consecutive rounds, and the
         # all-alive default mask is placed exactly once.  A true LRU
@@ -523,7 +527,7 @@ class ParameterAveragingTrainer:
                     )
                 return tree_map(lambda x: x[None], st), losses[None]
 
-            self._slice_round = jax.jit(
+            self._slice_round = obs.Program("slice_round", jax.jit(
                 shard_map(
                     slice_body,
                     mesh=mesh,
@@ -531,8 +535,7 @@ class ParameterAveragingTrainer:
                     out_specs=out_specs,
                 ),
                 donate_argnums=(0, 1),
-            )
-            obs.track_jit(self._slice_round)
+            ), watch=(1,), devices=self._devices)
 
         def eval_body(state, batches, counts):
             # heterogeneous partitions: every worker's batches are padded
@@ -547,20 +550,29 @@ class ParameterAveragingTrainer:
             # CifarApp.scala:113)
             return {k: jax.lax.psum(v, axis) for k, v in scores.items()}
 
-        self._eval = jax.jit(
+        self._eval = obs.Program("eval", jax.jit(
             shard_map(
                 eval_body,
                 mesh=mesh,
                 in_specs=(P(axis), P(axis), P(axis)),
                 out_specs=P(),
             )
-        )
+        ), watch=(1, 2), devices=self._devices)
 
     # ------------------------------------------------------------------
     def init_state(self, seed: int = 0) -> TrainState:
         """All workers start from identical weights (the initial broadcast,
         CifarApp.scala:92-97); per-worker slots stacked on axis 0 and
-        sharded over ``dp``."""
+        sharded over ``dp``.  Host time to return, as ``average`` is: the
+        span adds no sync; the two memory marks around it bracket what the
+        replicas hold (``in_use`` after, less before)."""
+        obs.program.mark_memory("init_state:enter", self._devices)
+        with obs.span("init_state", cat="build", workers=self.num_workers):
+            state = self._init_state(seed)
+        obs.program.mark_memory("init_state", self._devices)
+        return state
+
+    def _init_state(self, seed: int) -> TrainState:
         st = self.solver.init_state(seed)
         n = self.num_workers
         sharding = leading_sharding(self.mesh, self.axis)
@@ -571,10 +583,10 @@ class ParameterAveragingTrainer:
             # one device until it returns, twice a state of gigabytes
             # (PERF.md section 6, PR 27).  Several workers take the host
             # path as before
-            return jax.jit(
+            return obs.Program("stack_state", jax.jit(
                 lambda tree: tree_map(lambda x: x[None], tree),
                 out_shardings=sharding, donate_argnums=(0,),
-            )(st)
+            ), devices=self._devices)(st)
 
         # identical init in every process; each device's shard is cut
         # from a broadcast VIEW of the one host replica, so the n-fold
@@ -684,13 +696,14 @@ class ParameterAveragingTrainer:
         return placed
 
     def compile_round(self, state: TrainState, batches: Dict[str, jax.Array]):
-        """Compile the fused round program for a state and batches like
-        these, running nothing and donating nothing: ``round`` with the
-        default key and an all-alive mask then finds it compiled.  For a
-        caller's thread, while its data loads or its other programs
-        compile (jax's compile releases the interpreter)."""
+        """Build the fused round program for a state and batches like
+        these (``obs.Program.build``: lowered, compiled and accounted for,
+        running nothing and donating nothing): ``round`` with the default
+        key and an all-alive mask then finds it compiled.  For a caller's
+        thread, while its data loads or its other programs compile (jax's
+        compile releases the interpreter)."""
         live = self._place_live(np.ones((self.num_workers,), np.float32))
-        self._round.lower(state, batches, default_train_key(0), live).compile()
+        self._round.build(state, batches, default_train_key(0), live)
 
     def round(
         self,
@@ -813,6 +826,10 @@ class ParameterAveragingTrainer:
             self._note_profile_work(prof, int(losses.shape[-1]), state)
             prof.observe_round(losses)
         obs.report_healthy()  # a completed round clears /healthz
+        if obs.recording():
+            # only where a sink keeps it: memory_stats() is a call into
+            # the runtime, and an unobserved round makes none
+            obs.program.mark_memory("round", self._devices, keep=False)
         if self.audit:
             return state, losses, astats
         return state, losses
@@ -958,14 +975,14 @@ class AllReduceTrainer:
             iter=repl,
         )
         self._state_shardings = state_shardings
-        self._jit_round = jax.jit(
+        self._devices = mesh.local_devices
+        self._jit_round = obs.Program("sync_round", jax.jit(
             solver._step_tau,
             donate_argnums=(0,),
             in_shardings=(state_shardings, batch_sharding, repl),
             out_shardings=(state_shardings, repl),
-        )
+        ), watch=(1,), devices=self._devices)
         self._batch_sharding = batch_sharding
-        obs.track_jit(self._jit_round)  # feeds the jit-cache gauge
 
     @property
     def batch_sharding(self):
@@ -995,8 +1012,12 @@ class AllReduceTrainer:
         return tree_map(place, params)
 
     def init_state(self, seed: int = 0) -> TrainState:
-        st = self.solver.init_state(seed)
-        return jax.device_put(st, self._state_shardings)
+        obs.program.mark_memory("init_state:enter", self._devices)
+        with obs.span("init_state", cat="build", workers=self.mesh.size):
+            st = self.solver.init_state(seed)
+            state = jax.device_put(st, self._state_shardings)
+        obs.program.mark_memory("init_state", self._devices)
+        return state
 
     def shard_state(self, state: TrainState) -> TrainState:
         """Place an existing (host or single-device) TrainState onto the

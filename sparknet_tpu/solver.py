@@ -236,8 +236,14 @@ class Solver:
         # materializes them on read.  Keeping the hot loop free of
         # device->host syncs is standard TPU async-dispatch discipline.
         self._pending_losses: list = []
-        self._jit_step = jax.jit(self._step_tau, donate_argnums=(0,))
-        self._jit_forward_test = jax.jit(self._forward_test)
+        # obs.Program: built and accounted for on batches not seen before
+        # (obs/program.py), then the jax.jit is called as ever
+        self._jit_step = obs.Program(
+            "step", jax.jit(self._step_tau, donate_argnums=(0,)), watch=(1,)
+        )
+        self._jit_forward_test = obs.Program(
+            "forward_test", jax.jit(self._forward_test), watch=(2,)
+        )
 
     @property
     def test_net(self) -> JaxNet:
@@ -407,9 +413,9 @@ class Solver:
         probes (``tools/perf_probe.py``) or single-batch overfit tests."""
         rng = rng if rng is not None else default_train_key(0)
         if not hasattr(self, "_jit_step_repeat"):
-            self._jit_step_repeat = jax.jit(
+            self._jit_step_repeat = obs.Program("step_repeat", jax.jit(
                 self._step_repeat, donate_argnums=(0,), static_argnums=(3,)
-            )
+            ), watch=(1, 3))
         state, out = self._jit_step_repeat(state, batch, rng, tau)
         if self.audit:
             losses, stats = out

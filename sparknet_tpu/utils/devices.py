@@ -134,19 +134,21 @@ def enable_compile_cache() -> str:
     """Turn on jax's persistent compilation cache; returns its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it and this sets
-    nothing; otherwise the cache lives at ``<checkout>/.jax_cache``.  Every
+    no other; otherwise the cache lives at ``<checkout>/.jax_cache``.  Every
     entry point calls this before its first compile, so processes of one
     chip call — and calls on a machine that keeps that directory — share
     compiled programs."""
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        directory = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", directory)
     if not cpu_requested():
         # jax skips programs that compile in under a second; a process on
         # the chip compiles over a hundred of those (17 of the smoke's 29
-        # warm compile seconds, PERF.md "On the chip"), so keep them all
+        # warm compile seconds, PERF.md "On the chip"), so keep them all,
+        # wherever the cache lives: a program that is never written reads
+        # ``miss`` in its build record for ever (obs/programs.py)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return DEFAULT_COMPILE_CACHE_DIR
+    return directory

@@ -158,10 +158,17 @@ def test_label_arity_and_duplicates_rejected():
         fam.labels("a", "b")
     with pytest.raises(ValueError):
         r.counter("c_total", "dup")
-    # a labeled CALLBACK gauge cannot work (one fn, many children):
-    # the registry refuses it loudly instead of rendering dead zeros
-    with pytest.raises(ValueError):
-        r.gauge("g_bytes", "", fn=lambda: 1.0, labels=("device",))
+    # a labeled CALLBACK gauge hands each child's label values to the one
+    # fn, so that the children differ (no dead zeros); a child exists once
+    # labels(...) has named it
+    sizes = {"a": 1.0, "b": 2.5}
+    fam = r.gauge("g_bytes", "", fn=sizes.get, labels=("device",))
+    assert "g_bytes{" not in r.render()
+    fam.labels("a"), fam.labels("b")
+    assert 'g_bytes{device="a"} 1' in r.render()
+    assert 'g_bytes{device="b"} 2.5' in r.render()
+    sizes["a"] = 4.0  # sampled at render time
+    assert 'g_bytes{device="a"} 4' in r.render()
     # labeled set()-style gauges are fine
     g = r.gauge("g_depth", "", labels=("queue",))
     g.labels("feed").set(3)
