@@ -145,7 +145,9 @@ def set_selection_gauges(lm, stacked_params, tokens, stacked_stats=None,
     as the gauges ``sparknet_lm_indexer_loss`` / ``sparknet_lm_selection_mass``
     (and ``sparknet_kernel_path{kernel="sparse_attention"}``: 1 where
     ``masked_attention`` takes the flash kernels for this batch's shapes, 0
-    on its XLA pass) where training metrics are on,
+    on its XLA pass; its twin ``{kernel="alignment_loss"}``: 1, the loss
+    hands back its gradient with its value on every differentiated trace,
+    whatever the shapes) where training metrics are on,
     and returned with the pass's ``held_counts``; ``{}`` for a model without
     such a layer.  Outside the timed loop: a forward pass,
     ``selection_probe(lm)`` or the caller's compiled ``probe`` of it."""
@@ -167,6 +169,7 @@ def set_selection_gauges(lm, stacked_params, tokens, stacked_stats=None,
                 tokens.shape[-1], c["num_attention_heads"],
                 c["num_key_value_heads"], c["head_dim"],
                 lm.compute_dtype or np.float32)))
+        tm.kernel_path.labels("alignment_loss").set(1.0)
         for i, loss, mass in zip(
                 layers, gauges["indexer_loss"], gauges["selection_mass"]):
             tm.lm_indexer_loss.labels(str(i)).set(loss)

@@ -543,12 +543,15 @@ class HybridMoELM:
         ``Attn(normed)`` and the layer's alignment loss, the mean over its
         queries (with ``probe``, a pair: that loss and the selected keys'
         share of the dense attention's probability).  Kept between forward and
-        backward, beside the normed input: the indexer's ``qI`` / ``kI`` /
-        ``w`` (36 MB at 16,384 tokens), the selection as bits (33.5 MB), the
-        scaled queries and the keys in the compute dtype (151 MB), the
-        attention's output and log-sum-exp (270 MB); the index scores of
-        ``(T, T)`` float32 live from ``DSAIndexer`` to ``DSASelect`` in the
-        forward pass alone."""
+        backward, beside the normed input: the selection as bits (33.5 MB at
+        16,384 tokens), the attention's output and log-sum-exp (270 MB), and
+        the alignment loss's gradient to ``qI`` / ``w`` / ``kI`` (36.5 MB),
+        which its forward computes with its value
+        (``sparse_attention.alignment_loss``): the loss keeps neither the
+        scaled queries and keys (151 MB: ``_dsa_attention``'s backward makes
+        its own again) nor the indexer's parts; the index scores of ``(T,
+        T)`` float32 live from ``DSAIndexer`` to ``DSASelect`` in the forward
+        pass alone."""
         c = self.config
         b, t, _ = normed.shape
         qi, ki, w, mask = self._dsa_select(i, normed, blobs[6:])

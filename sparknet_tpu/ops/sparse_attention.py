@@ -16,8 +16,11 @@ it), in four pieces that a layer runs under four scopes:
   grouped K/V heads, and the row's log-sum-exp.
 - ``alignment_loss``: ``sum_t KL(p[t, .] || softmax_{S_t}(I[t, .]))`` with
   ``p = stop_gradient(mean_h A[t, h, .])``, the attention's own
-  probabilities recomputed from ``q``, ``k`` and the log-sum-exp; its
-  gradient reaches the index scores alone.
+  probabilities computed once a step from ``q``, ``k`` and the log-sum-exp;
+  its gradient reaches the index scores alone, and comes with its value:
+  under differentiation the ONE pass over the blocks that computes the loss
+  computes its gradient to ``qI``, ``w`` and ``kI`` in closed form
+  (``_alignment_with_gradient``), and the backward pass scales those three.
 
 Why a MASKED pass and not a gather: on the v5e a gather of 2,048 K/V rows a
 query is 4 MB a token (69 GB a layer at 16,384 tokens, ~84 ms at 819 GB/s)
@@ -26,23 +29,30 @@ peak); PERF.md section 6, PR 33 has what was read.
 
 The index scores, the selection and the loss run in XLA on the ONE blockwise
 loop of ``ops/attention.py`` (``by_run``: a block of ``block_q`` queries at a
-time, ``lax.map`` over ``jax.checkpoint``ed blocks, the sequence cut into
-``segments`` runs that each meet only the keys up to their own end), so that
-no more than ``block_q x T`` scores a head exist at once, forward or
-backward.  The masked pass takes the K/V-blocked flash kernels given the
-selection's bits (``pallas_attention.masked_flash_attention``: no score
-leaves VMEM) where Pallas lowers and they accept the shapes, and elsewhere
+time, ``lax.map`` over the blocks, the sequence cut into ``segments`` runs
+that each meet only the keys up to their own end), so that no more than
+``block_q x T`` scores a head exist at once; nothing differentiates through
+that loop here (``_blockwise_gqa`` and the plain ``alignment_value`` wrap
+their blocks in ``jax.checkpoint`` for whoever does).  The masked pass takes
+the K/V-blocked flash kernels given the selection's bits
+(``pallas_attention.masked_flash_attention``: no score leaves VMEM) where
+Pallas lowers and they accept the shapes, and elsewhere
 ``_blockwise_gqa`` given the keep-mask, the same arithmetic on that loop and
 the oracle the kernels are tested against; an ``obs`` instant names the path
-at each trace.  Between the pieces go the indexer's
-``qI`` / ``kI`` / ``w``, the mask as bits (``T * T / 8`` bytes: 33.5 MB at
-16,384 tokens, no float tensor of ``(T, T)``), ``q`` / ``k`` and the
-log-sum-exp.  ``index_scores_by_run`` alone materialises float32 scores of
+at each trace (``sparse_attention_path``; ``alignment_loss_path`` says
+whether the loss was traced for its value or with its gradient).  Between
+the pieces go the indexer's ``qI`` / ``kI`` / ``w``, the mask as bits (``T *
+T / 8`` bytes: 33.5 MB at 16,384 tokens, no float tensor of ``(T, T)``),
+``q`` / ``k`` and the log-sum-exp; from the forward pass to the backward the
+loss keeps its three gradients (the sizes of ``qI``, ``w`` and ``kI``) and
+nothing else.  ``index_scores_by_run`` alone materialises float32 scores of
 whole runs, ``(B, T, T)`` x the causal share, forward only: ``select``
 consumes them and nothing keeps them for the backward.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -177,6 +187,14 @@ def _scores(qb, kb):
     return jnp.einsum("bkgqd,bksd->bkgqs", qb, kb, preferred_element_type=F32)
 
 
+def _blocks_met(t: int, block_q: int, segments: int):
+    """``(computed, total)`` blocks of ``block_q x block_q`` scores where a
+    run's query blocks each meet the keys up to the run's end."""
+    block_q, runs = runs_of(t, block_q, segments)
+    return (sum((hi - lo) * -(-keys // block_q) for lo, hi, keys in runs),
+            (-(-t // block_q)) ** 2)
+
+
 def kernels_refuse(t: int, hq: int, hkv: int, d: int, dtype) -> str:
     """Why ``masked_attention`` does not take the flash kernels for these
     shapes on this backend; empty where it takes them.
@@ -225,10 +243,9 @@ def masked_attention(q, k, v, mask, *, block_q: int = BLOCK_Q,
     words = mask.shape[-1]
     why = kernels_refuse(t, hq, hkv, d, cd)
     if why:  # a run's query blocks each meet the keys up to its end
-        block_q, runs = runs_of(t, block_q, segments)
+        block_q, _ = runs_of(t, block_q, segments)
         block_k = block_q
-        met = (sum((hi - lo) * -(-keys // block_k) for lo, hi, keys in runs),
-               (-(-t // block_q)) ** 2)
+        met = _blocks_met(t, block_q, segments)
     else:
         block_k = words * max(1, KERNEL_KEYS_X_WIDTH // d // words)
         block_q = attention.kernel_block_q(hq // hkv, d, cd, block_k)
@@ -259,38 +276,153 @@ def _head_mean_probabilities(qi, kh, lse, keep):
     return jnp.mean(a, axis=(1, 2))
 
 
-def alignment_loss(qi, w, ki, q, k, lse, mask, *, block_q: int = BLOCK_Q,
-                   segments: int = SEGMENTS):
+def _kl(keep, p, scores, first, t: int):
+    """Of a block of rows ``first ..`` of a sequence of ``t``: ``sum_{s in
+    S_t} p (log p - log softmax_{S_t}(scores)[s])`` summed over the real rows,
+    that log-softmax ``(B, Q, S)`` and which rows are real ``(Q,)``."""
+    i = jnp.where(keep, scores, NEG)
+    i = i - jnp.max(i, axis=-1, keepdims=True)
+    # (a row of padding keeps no key: the floor keeps its gradient finite)
+    log_q = i - jnp.log(jnp.maximum(jnp.sum(
+        jnp.where(keep, jnp.exp(i), 0.0), axis=-1, keepdims=True), 1e-30))
+    live = keep & (p > 0.0)
+    kl = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_q), 0.0)
+    real = first + jnp.arange(kl.shape[1]) < t
+    return (jnp.sum(jnp.where(real[None, :], jnp.sum(kl, axis=-1), 0.0)),
+            log_q, real)
+
+
+def _alignment_blocks(qi, w, q, k, lse, mask, block_q: int):
+    """What ``by_run`` hands a block of the alignment loss: the indexer's
+    queries and weights, the attention's scaled queries heads first and
+    log-sum-exp ``(.., B, Hkv, G, block_q)``, both constants, and the mask's
+    words, each ``(blocks, B, block_q, ...)``; and the keys heads first."""
+    b, t, hq, _ = q.shape
+    hkv = k.shape[2]
+    qb, kh = jax.lax.stop_gradient(_heads_first(q, k, block_q))
+    lse = jax.lax.stop_gradient(lse).reshape(b, t, hkv, hq // hkv)
+    return (blocked(qi, block_q), blocked(w, block_q), qb,
+            blocked(lse, block_q).transpose(0, 1, 3, 4, 2),
+            blocked(mask, block_q)), kh
+
+
+def alignment_value(qi, w, ki, q, k, lse, mask, *, block_q: int = BLOCK_Q,
+                    segments: int = SEGMENTS):
     """``sum_{b, t} sum_{s in S_t} p (log p - log softmax_{S_t}(I)[s])`` with
     ``p = mean_h A``, the attention's probabilities over its selected keys
     (``q``, ``k``, ``lse`` as ``masked_attention`` took and gave them; no
-    gradient reaches them or ``mask``).  The gradient reaches ``qi``, ``w``
-    and ``ki``.  Float32 but for the two products' operands."""
-    b, t, hq, _ = q.shape
-    hkv = k.shape[2]
+    gradient reaches them or ``mask``).  Float32 but for the two products'
+    operands.  The plain function: ``alignment_loss``'s value, and under
+    ``jax.grad`` (a ``jax.checkpoint`` a block, autodiff through
+    ``index_scores``) the oracle its closed-form gradient is tested
+    against."""
+    t = q.shape[1]
     block_q, _ = runs_of(t, block_q, segments)
-    qb, kh = jax.lax.stop_gradient(_heads_first(q, k, block_q))
-    lse = jax.lax.stop_gradient(lse).reshape(b, t, hkv, hq // hkv)
-    lse = blocked(lse, block_q).transpose(0, 1, 3, 4, 2)
+    blocks, kh = _alignment_blocks(qi, w, q, k, lse, mask, block_q)
 
     def block(keys, first, qib, wb, qm, lb, bits):
         keep = unpack_mask(bits, keys)
         p = _head_mean_probabilities(qm, kh[:, :, :keys], lb, keep)
-        i = jnp.where(keep, index_scores(qib, wb, ki[:, :keys]), NEG)
-        i = i - jnp.max(i, axis=-1, keepdims=True)
-        # (a row of padding keeps no key: the floor keeps its gradient finite)
-        log_q = i - jnp.log(jnp.maximum(jnp.sum(
-            jnp.where(keep, jnp.exp(i), 0.0), axis=-1, keepdims=True), 1e-30))
-        live = keep & (p > 0.0)
-        kl = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - log_q), 0.0)
-        rows = first + jnp.arange(kl.shape[1])
-        return jnp.sum(jnp.where(rows[None, :] < t, jnp.sum(kl, axis=-1), 0.0))
+        return _kl(keep, p, index_scores(qib, wb, ki[:, :keys]), first, t)[0]
 
-    out = by_run(
-        jax.checkpoint(block, static_argnums=(0,)),
-        (blocked(qi, block_q), blocked(w, block_q), qb, lse,
-         blocked(mask, block_q)), t, block_q, segments)
+    out = by_run(jax.checkpoint(block, static_argnums=(0,)), blocks, t,
+                 block_q, segments)
     return sum(jnp.sum(x) for x in out)
+
+
+def _alignment_event(path: str, qi, block_q: int, segments: int):
+    t = qi.shape[1]
+    block_q, _ = runs_of(t, block_q, segments)
+    met = _blocks_met(t, block_q, segments)
+    obs.instant("alignment_loss_path", cat="kernel", path=path,
+                backend=jax.default_backend(), t=t,
+                ds_dtype=qi.dtype.name if path == "with_gradient" else "",
+                block_q=block_q, segments=segments,
+                blocks_computed=met[0], blocks_total=met[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _alignment(qi, w, ki, q, k, lse, mask, block_q, segments):
+    _alignment_event("value", qi, block_q, segments)
+    return alignment_value(qi, w, ki, q, k, lse, mask, block_q=block_q,
+                           segments=segments)
+
+
+def _alignment_with_gradient(qi, w, ki, q, k, lse, mask, block_q, segments):
+    """``alignment_value`` and, from the same pass over the blocks, its
+    gradient to ``qi``, ``w`` and ``ki`` in closed form.  With ``g =
+    dL/dI = softmax_{S_t}(I) * sum_s p - p`` on the kept keys of the real
+    rows where ``I != 0`` (``index_scores``' ``where``), float32, and ``M_j =
+    g [s_j > 0]`` in the products' operand dtype (what the MXU is fed of a
+    float32 cotangent at default precision: the v5e rounds it to bfloat16,
+    bit for bit what the cast gives): ``U_j = M_j @ kI``, ``dL/dqI_j = w_j
+    U_j``, ``dL/dw_j = qI_j . U_j`` (``relu(s_j) = [s_j > 0] qI_j . kI``:
+    no sum over the keys of a ``(.., J, keys)`` array) and ``dL/dkI = sum_j
+    M_j^T @ (w_j qI_j)``, every sum float32.  (Autodiff sums ``dL/dw``'s terms
+    in float32 with ``g`` unrounded: in bfloat16 this ``dL/dw`` is 4.9e-4
+    from it, alone on the v5e at T = 16,384; inside a step nothing a check
+    can read, PERF.md section 6, PR 36.)  The index scores are formed twice,
+    for ``I`` and again for ``M`` once the rows' softmax is known: nothing
+    of ``(.., J, keys)`` is float32 in HBM (on the v5e XLA keeps the sixteen
+    ``[s_j > 0]`` as the bits of one ``u16 (block_q, keys)`` and forms ``M``
+    inside each product's fusion)."""
+    _alignment_event("with_gradient", qi, block_q, segments)
+    t = q.shape[1]
+    cd = qi.dtype
+    block_q, _ = runs_of(t, block_q, segments)
+    blocks, kh = _alignment_blocks(qi, w, q, k, lse, mask, block_q)
+
+    def block(keys, first, qib, wb, qm, lb, bits):
+        kib = ki[:, :keys].astype(cd)
+        keep = unpack_mask(bits, keys)
+        p = _head_mean_probabilities(qm, kh[:, :, :keys], lb, keep)
+        scores = index_scores(qib, wb, kib)
+        loss, log_q, real = _kl(keep, p, scores, first, t)
+        g = jnp.where(
+            keep & (scores != 0.0) & real[None, :, None],
+            jnp.exp(log_q) * jnp.sum(p, axis=-1, keepdims=True) - p, 0.0)
+        # (behind a barrier, or XLA keeps the first product's float32 scores
+        # for this second use instead of forming them again)
+        qib, kib, g = jax.lax.optimization_barrier((qib, kib, g))
+        s = jnp.einsum("bqjd,bsd->bqjs", qib, kib, preferred_element_type=F32)
+        m = jnp.where(s > 0.0, g.astype(cd)[:, :, None], 0.0)
+        u = jnp.einsum("bqjs,bsd->bqjd", m, kib, preferred_element_type=F32)
+        wb = wb[..., None].astype(F32)
+        return (loss, (wb * u).astype(cd), jnp.sum(qib.astype(F32) * u, -1),
+                jnp.einsum("bqjs,bqjd->bsd", m, (wb * qib).astype(cd),
+                           preferred_element_type=F32))
+
+    out = by_run(block, blocks, t, block_q, segments)
+    d_qi, d_w = joined([x[1:3] for x in out], t)
+    # a run's blocks meet the keys up to its end: the sum over them, after
+    # which the later keys read 0
+    d_ki = sum(jnp.pad(jnp.sum(x[3], axis=0),
+                       ((0, 0), (0, t - x[3].shape[2]), (0, 0))) for x in out)
+    return (sum(jnp.sum(x[0]) for x in out),
+            (d_qi, d_w.astype(w.dtype), d_ki.astype(ki.dtype)))
+
+
+def _alignment_backward(block_q, segments, gradients, ct):
+    return (*((g * ct).astype(g.dtype) for g in gradients),
+            None, None, None, None)
+
+
+_alignment.defvjp(_alignment_with_gradient, _alignment_backward)
+
+
+def alignment_loss(qi, w, ki, q, k, lse, mask, *, block_q: int = BLOCK_Q,
+                   segments: int = SEGMENTS):
+    """``alignment_value`` that hands back its gradient with its value: under
+    differentiation ONE blockwise pass computes the loss and, in closed
+    form, its gradient to ``qi``, ``w`` and ``ki`` at cotangent 1
+    (``_alignment_with_gradient``); those three are all it keeps for the
+    backward pass, which scales them by the loss's cotangent.  Not
+    differentiated it is ``alignment_value``.  No gradient reaches ``q``,
+    ``k``, ``lse`` or ``mask``.  An ``obs`` instant, ``alignment_loss_path``,
+    names the path at each trace."""
+    # (constants before the rule sees them: it hands nothing back for them)
+    q, k, lse = jax.lax.stop_gradient((q, k, lse))
+    return _alignment(qi, w, ki, q, k, lse, mask, block_q, segments)
 
 
 def selection_mass(q, k, mask, *, block_q: int = BLOCK_Q,

@@ -471,8 +471,8 @@ def test_masked_attention_with_every_causal_key_is_causal_attention():
     assert rel(got, causal_gqa_attention(q, k, v)) < 2e-5
 
 
-def path_events(fn, *args):
-    """``sparse_attention_path``'s arguments at each trace of ``fn`` (traced
+def path_events(fn, *args, name="sparse_attention_path"):
+    """The instant ``name``'s arguments at each trace of ``fn`` (traced
     anew: the instant is written while tracing)."""
     from sparknet_tpu import obs
     from sparknet_tpu.obs.trace import Tracer
@@ -482,8 +482,7 @@ def path_events(fn, *args):
         jax.eval_shape(lambda *a: fn(*a), *args)
     finally:
         obs.uninstall_tracer()
-    return [e["args"] for e in tracer.events()
-            if e["name"] == "sparse_attention_path"]
+    return [e["args"] for e in tracer.events() if e["name"] == name]
 
 
 def test_the_attention_path_is_named_at_each_trace():
@@ -499,7 +498,7 @@ def test_the_attention_path_is_named_at_each_trace():
     assert (event["blocks_computed"], event["blocks_total"]) == (1, 1)
 
 
-# -- the flash kernels under a keep-mask (interpreter mode) --------------------
+# -- keep-masks as bits, for the alignment loss and the flash kernels -----------
 KT = 64  # two words a row; key blocks of 16 and 32 are 8 and 16 bits of each
 
 
@@ -508,7 +507,9 @@ def kernel_masks(kind, b=2, t=KT):
     rows keep a few keys just before their own position (nothing in their
     first key blocks, so the running maximum stays at the mask's value past
     the first blocks met) and every third row keeps only itself;
-    ``causal``: every causal key."""
+    ``causal``: every causal key; ``early``: the rows of the second half
+    keep only the first four keys (a run's later keys hold nothing of them),
+    the others every causal key."""
     if kind == "causal":
         return sa.causal_mask_bits(b, t)
     if kind == "select":
@@ -516,12 +517,106 @@ def kernel_masks(kind, b=2, t=KT):
         return sa.select(sa.index_scores_by_run(
             qi, w, ki, block_q=16, segments=2), t, 8, block_q=16, segments=2)
     row, key = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
-    near = (key <= row) & (key >= row - 4) & (key % 2 == row % 2)
-    keep = jnp.where((row % 3 == 0), key == row, near)
+    if kind == "early":
+        keep = (key <= row) & ((row < t // 2) | (key < 4))
+    else:
+        near = (key <= row) & (key >= row - 4) & (key % 2 == row % 2)
+        keep = jnp.where((row % 3 == 0), key == row, near)
     return jnp.broadcast_to(sa.pack_mask(keep, sa.words_of(t)),
                             (b, t, sa.words_of(t)))
 
 
+# -- the alignment loss hands back its gradient with its value ------------------
+def alignment_inputs(t, dtype, bits, j=4, di=8, **kw):
+    """``alignment_loss``'s six arrays before the mask, in ``dtype``; ``w``
+    at the size the model's scale factors leave it."""
+    cd = jnp.dtype(dtype)
+    q, k, v = attention_inputs(7, t=t)
+    qi, w, ki = indexer_inputs(8, t=t, j=j, di=di)
+    q, k = sa.scaled_queries(q, cd), k.astype(cd)
+    _, lse = sa.masked_attention(q, k, v, bits, **kw)
+    return qi.astype(cd), w * 32 ** -0.5, ki.astype(cd), q, k, lse
+
+
+@pytest.mark.parametrize("kind", ["select", "causal", "early"])
+@pytest.mark.parametrize("t", [37, 32])  # rows of padding in the last block, none
+@pytest.mark.parametrize("block_q, segments", [(16, 2), (8, 3)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_alignment_gradient_from_the_one_pass_matches_autodiff(
+        dtype, block_q, segments, t, kind):
+    """``alignment_loss``'s closed-form gradient to ``qi``, ``w``, ``ki``
+    under a cotangent that is not 1, against ``jax.grad`` of the plain
+    function; the value under differentiation is the plain function's; no
+    gradient reaches ``q``, ``k`` or ``lse``.  Float32 differs by summation
+    order; in bfloat16 ``g`` is rounded where it meets the products (what the
+    MXU does to a float32 cotangent; the CPU's autodiff keeps it float32) and
+    two of the three gradients are bfloat16 themselves: eps is 3.9e-3."""
+    kw = dict(block_q=block_q, segments=segments)
+    bits = kernel_masks(kind, t=t)
+    args = alignment_inputs(t, dtype, bits, **kw)
+    with jax.default_matmul_precision("highest"):
+        got, want = (jax.jit(jax.value_and_grad(
+            lambda *a: 0.3 * fn(*a, bits, **kw), argnums=range(6)))(*args)
+            for fn in (sa.alignment_loss, sa.alignment_value))
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-6)
+    assert float(got[0]) > 0.0
+    for g, wg in zip(got[1][:3], want[1][:3]):
+        assert g.dtype == wg.dtype and g.shape == wg.shape
+        assert np.asarray(wg, np.float32).any()
+        assert rel(g, wg) < (5e-6 if dtype == "float32" else 1e-2)
+    assert all(not np.asarray(g, np.float32).any() for g in got[1][3:])
+
+
+def test_alignment_loss_keeps_its_three_gradients_and_nothing_else():
+    """Not differentiated, ``alignment_loss`` is the plain function (its
+    own jaxpr, called); differentiated, what its forward keeps for the
+    backward are the gradients to ``qi``, ``w`` and ``ki`` (no leaf of
+    ``q``'s, ``k``'s, the log-sum-exp's or the mask's shape), and its
+    backward scales them: no product."""
+    kw = dict(block_q=16, segments=2)
+    bits = kernel_masks("select", t=T)
+    # (3 index heads of 6: no two of the seven arrays share a shape)
+    args = alignment_inputs(T, "float32", bits, j=3, di=6, **kw)
+    plain = jax.make_jaxpr(lambda *a: sa.alignment_value(*a, **kw))(*args, bits)
+    ours = jax.make_jaxpr(lambda *a: sa.alignment_loss(*a, **kw))(*args, bits)
+    *constants, call = ours.jaxpr.eqns
+    assert {e.primitive.name for e in constants} == {"stop_gradient"}
+    assert call.primitive.name == "custom_vjp_call"
+    assert str(call.params["call_jaxpr"]) == str(plain)
+    value, vjp = jax.vjp(
+        lambda *a: sa.alignment_loss(*a, bits, **kw), *args)
+    kept = sorted(x.shape for x in jax.tree_util.tree_leaves(vjp)
+                  if hasattr(x, "shape") and x.ndim)
+    assert kept == sorted(x.shape for x in args[:3])
+    assert not {x.shape for x in (*args[3:], bits)} & set(kept)
+    backward = str(jax.make_jaxpr(vjp)(jnp.float32(0.3)))
+    assert "dot_general" not in backward and "mul" in backward
+    assert float(value) == pytest.approx(
+        float(sa.alignment_value(*args, bits, **kw)), rel=1e-6)
+
+
+def test_the_alignment_path_is_named_at_each_trace():
+    """``alignment_loss_path``: ``value`` where nothing differentiates it,
+    ``with_gradient`` (and the dtype ``dS`` meets the products in) where
+    ``jax.grad`` does, the blocks ``by_run`` computes of the square."""
+    kw = dict(block_q=8, segments=2)
+    bits = kernel_masks("causal", t=T)
+    args = alignment_inputs(T, "bfloat16", bits, **kw)
+    loss = lambda *a: sa.alignment_loss(*a, bits, **kw)  # noqa: E731
+    (value,) = path_events(loss, *args, name="alignment_loss_path")
+    (grad,) = path_events(jax.grad(loss, argnums=(0, 1, 2)), *args,
+                          name="alignment_loss_path")
+    assert (value["path"], value["ds_dtype"]) == ("value", "")
+    assert (grad["path"], grad["ds_dtype"]) == ("with_gradient", "bfloat16")
+    for event in (value, grad):
+        # five blocks of 8 rows in runs of 3 and 2: 3 x 3 + 2 x 5 of 25
+        assert (event["t"], event["block_q"], event["segments"]) == (T, 8, 2)
+        assert (event["blocks_computed"], event["blocks_total"]) == (19, 25)
+    # the cell's: 32 blocks of 512 in 8 runs
+    assert sa._blocks_met(16384, 512, 8) == (576, 1024)
+
+
+# -- the flash kernels under a keep-mask (interpreter mode) --------------------
 @pytest.mark.parametrize("kind", ["select", "late", "causal"])
 @pytest.mark.parametrize("block_q, block_k, d", [(16, 16, 8), (32, 64, 128)])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -847,6 +942,7 @@ def test_lm_app_trains_keye_from_a_configuration_file(tmp_path):
         assert 0.2 < tm.lm_selection_mass.labels(str(i)).value <= 1.0
         assert 0.5 < tm.lm_held_assignments.labels(str(i)).value < 3.5
     assert tm.kernel_path.labels("sparse_attention").value == 0.0
+    assert tm.kernel_path.labels("alignment_loss").value == 1.0
 
 
 def test_the_benchmarks_configuration_builds_the_published_model():

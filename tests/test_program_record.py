@@ -120,11 +120,15 @@ def test_changed_batch_shape_is_a_second_round_record_and_counts():
     first, second = _named("round")
     assert first["shapes"] != second["shapes"]
     assert second["shapes"][0] == "float32[2,3,4,6]@host"
-    cache = first["cache"]
-    assert tm.program_builds.labels("round", cache).value == 2
+    # (where an earlier test file of this process turned the persistent cache
+    # on, one build can be a hit and the other a miss: count over both)
+    builds = lambda: sum(  # noqa: E731
+        tm.program_builds.labels("round", cache).value
+        for cache in {first["cache"], second["cache"]})
+    assert builds() == 2
     state, _ = trainer.round(state, _batch(tau=3))
     state, _ = trainer.round(state, _batch())
-    assert tm.program_builds.labels("round", cache).value == 2
+    assert builds() == 2
     assert len(obs.programs()) == 2
 
 

@@ -25,6 +25,7 @@ from sparknet_tpu.ops import (
     pallas_attention,
     pallas_delta_rule,
     pallas_lm_loss,
+    sparse_attention,
 )
 
 
@@ -128,6 +129,29 @@ def test_flash_attention_kernels_under_a_keep_mask_compile_for_the_v5e(
         shape(32), shape(4), shape(4), bits).compile().as_text()
     assert all(name in text for name in (
         "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+
+
+def test_alignment_loss_with_its_gradient_holds_no_float32_head_scores(
+        one_chip):
+    """keye2-train-16k's alignment loss with its gradient (``ops/
+    sparse_attention.alignment_loss``: one sequence of 16,384, 32 / 4 heads of
+    128, 16 index heads of 64, bfloat16) compiles for the v5e in 174 MiB of
+    temporaries (read from the first compile, PR 36; autodiff through the
+    blocks took 671).  One float32 array of ``(512, 16, keys)`` in HBM, the
+    index scores a head or their cotangent, is 512 MiB at the widest run:
+    what the ceiling catches if a change brings it back."""
+    t = 16384
+    shape = lambda s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, t, *s), dtype, sharding=one_chip)
+
+    def loss(qi, w, ki, q, k, lse, bits):
+        return sparse_attention.alignment_loss(qi, w, ki, q, k, lse, bits) / t
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape((16, 64)), shape((16,), jnp.float32), shape((64,)),
+        shape((32, 128)), shape((4, 128)), shape((32,), jnp.float32),
+        shape((t // 32,), jnp.uint32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
 # the sequence models' loss, forward + backward in bfloat16 at rows x width x
