@@ -16,6 +16,7 @@ user would call, at the full width of CaffeNet:
 - ``kernels``: every Pallas kernel ``lowerable()`` routes to by default,
   compiled (``interpret=False``), run and compared with its dense/unfused
   reference at the shapes the LM produces today and at one real head shape,
+  the flash kernels with a second score term at kanana2-train-8k's heads,
   the sequence models' loss at the two sequence cells' shapes; the opt-in LRN
   kernels compiled and compared once;
 - ``lm-train``: ``apps.lm_app.main`` at its default preset with
@@ -56,6 +57,9 @@ FULL = {
         ("lm", 8, 128, 2, 32, "float32"),
         ("head", 2, 1024, 8, 128, "bfloat16"),
     ),
+    # (name, B, T, H, D, R, dtype): the flash kernels with a second score
+    # term, kanana2-train-8k's heads of 128 + 64 against values of 128
+    "mla_shapes": (("kanana2", 2, 1024, 8, 128, 64, "bfloat16"),),
     "comm_legs": (
         ("fp32", False), ("bf16", False), ("int8", False), ("int8", True),
     ),
@@ -485,12 +489,64 @@ def _lrn_kernels(shape, interpret):
     return out
 
 
+def _mla_kernels(name, b, t, h, d, r, dtype, interpret):
+    """The flash kernels with a second score term (latent attention: heads
+    ``d + r`` wide, the ``r`` against ONE key every head shares, values
+    ``d`` wide), forward and the five gradients, against the whole masked
+    score matrix in float32 at full matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from sparknet_tpu.ops import pallas_attention as pa
+
+    tol = {"float32": 2e-2, "bfloat16": 5e-2}[dtype]
+    rng = np.random.RandomState(0)
+    shapes = ((b, t, h, d), (b, t, h, r), (b, t, h, d), (b, t, r), (b, t, h, d))
+    xs = [jnp.asarray(rng.randn(*shape), dtype) for shape in shapes]
+
+    def loss(attn):
+        return lambda *xs: jnp.sum(jnp.square(attn(*xs).astype(jnp.float32)))
+
+    def flash(*xs):
+        return pa.mla_flash_attention(
+            *xs, block_q=min(512, t), interpret=interpret)
+
+    def dense(q_nope, q_rope, k_nope, k_rope, v):
+        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+             + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope)) * (d + r) ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+    f32 = [x.astype(jnp.float32) for x in xs]
+    every = tuple(range(5))
+    with jax.default_matmul_precision("highest"):
+        ref_o = jax.jit(dense)(*f32)
+        ref_g = jax.jit(jax.grad(loss(dense), argnums=every))(*f32)
+    o = jax.jit(flash)(*xs)
+    grads = jax.jit(jax.grad(loss(flash), argnums=every))(*xs)
+    errs = {"mla_fwd": _rel_err(o, ref_o)}
+    for part, got, ref in zip(
+            ("q_nope", "q_rope", "k_nope", "k_rope", "v"), grads, ref_g):
+        errs["mla_bwd_" + part] = _rel_err(got, ref)
+    for kernel, err in errs.items():
+        assert np.isfinite(err) and err < tol, (
+            f"{kernel} at {name} {(b, t, h, d, r, dtype)}: rel err {err} "
+            f">= {tol}"
+        )
+    return {k: float("%.2e" % e) for k, e in errs.items()}
+
+
 def phase_kernels(sizes):
     interpret = sizes["interpret"]
     out = {"interpret": interpret}
     for name, b, t, h, d, dtype in sizes["attention_shapes"]:
         out[f"attention_{name}_B{b}_T{t}_H{h}_D{d}_{dtype}"] = (
             _attention_kernels(name, b, t, h, d, dtype, interpret)
+        )
+    for name, b, t, h, d, r, dtype in sizes["mla_shapes"]:
+        out[f"mla_{name}_B{b}_T{t}_H{h}_D{d}+{r}_{dtype}"] = (
+            _mla_kernels(name, b, t, h, d, r, dtype, interpret)
         )
     for name, rows, width, vocab, vocab_first in sizes["lm_loss_shapes"]:
         out[f"lm_loss_{name}_{rows}x{width}x{vocab}_bfloat16"] = (

@@ -1,6 +1,6 @@
 """Decoder-only LM whose layer pattern, mixers, feed-forwards, norms,
 positions, head counts, router and head are VALUES read from a published
-``config.json``: one block (``_layer``), one class, three families so far.
+``config.json``: one block (``_layer``), one class, four families so far.
 
 - ``qwen3_next`` (Qwen3-Next): linear-attention (Gated DeltaNet) layers with
   one gated softmax-attention layer every ``full_attention_interval``, a
@@ -26,10 +26,24 @@ positions, head counts, router and head are VALUES read from a published
   0.02 throughout, the flat attention of an all-attention stack hands every
   router the same input.
 
+- ``deepseek_v3`` (as kanana-2-30b-a3b publishes it): every layer multi-head
+  LATENT attention without a query rank (``ops/attention.causal_mla_
+  attention``: the keys and values of all heads are made from ONE normed
+  latent of ``kv_lora_rank``; a head's score is ``qk_nope_head_dim +
+  qk_rope_head_dim`` wide, 128 + 64, the second part against ONE rotary key
+  every head shares, rotary over adjacent pairs; its value ``v_head_dim``,
+  128), no norm on the heads; a dense gated MLP in the first
+  ``first_k_dense_replace`` layers and after them routed experts (sigmoid
+  scores, ``noaux_tc``: top-k on ``scores + bias`` as LFM2's, one group,
+  renormalised with 1e-20, times ``routed_scaling_factor``) beside shared
+  experts, one gated MLP of ``n_shared_experts x moe_intermediate_size``
+  WITHOUT Qwen3-Next's output gate; plain RMSNorm, an untied head; seeded
+  weights start as KeyeVL2's, an all-attention stack too.
+
 ``describe`` turns a file's keys into one description: a mixer kind a
 layer (``MIXERS``: ``gated_delta_net``, ``gated_attention``, ``short_conv``,
-``attention``, ``dsa_attention``), a feed-forward kind a layer (``dense`` or
-``moe``), and the values the equations take.  Nothing below it asks which
+``attention``, ``dsa_attention``, ``mla_attention``), a feed-forward kind a
+layer (``dense`` or ``moe``), and the values the equations take.  Nothing below it asks which
 family it builds.
 Beside the published keys, three of this system's own:
 
@@ -67,7 +81,9 @@ runs under one ``jax.named_scope("<Type>:<name>")`` (ARCHITECTURE.md
 sublayers only the residual stream and the normed input are kept (and, of
 an attention layer, what the flash kernels name: ``MIXER_KEEPS``), each
 sublayer's forward is recomputed in its backward, and autodiff names both
-``transpose(jvp(<Type>:<name>))``, i.e. backward.  A ``dsa_attention`` layer
+``transpose(jvp(<Type>:<name>))``, i.e. backward.  An ``mla_attention`` layer
+opens two (``_mla_mixer``: what making q, k and v costs apart from the
+kernels and the output projection).  A ``dsa_attention`` layer
 opens four scopes where the others open one (``_dsa_mixer``) and hands an
 auxiliary scalar, its alignment loss, up to ``loss_fn`` as a routed layer
 hands up its counts; what it keeps between forward and backward is stated
@@ -84,6 +100,11 @@ attention: ``q_proj`` head-major, each head ``[q | gate]``.  Short
 convolution: ``in_proj`` columns ``[B | C | u]``.  Selected-key attention:
 the plain attention's six blobs, then the indexer's ``index_q`` (heads
 contiguous), ``index_k``, its LayerNorm's weight and bias, ``index_w``.
+Latent attention: ``q_proj`` ``(E, H (nope + rope))`` head-major, each head
+``[nope | rope]``; ``kv_a_proj`` ``(E, kv_lora_rank + rope)``, columns
+``[latent | the one rope key]``; the latent's RMSNorm weight
+``(kv_lora_rank,)``; ``kv_b_proj`` ``(kv_lora_rank, H (nope + v))``
+head-major, each head ``[k_nope | v]``; ``o_proj`` ``(H v, E)``.
 """
 
 from __future__ import annotations
@@ -97,7 +118,10 @@ import numpy as np
 
 from sparknet_tpu.models.transformer_lm import _Group, _Ref
 from sparknet_tpu.ops import lm_loss, moe, sparse_attention
-from sparknet_tpu.ops.attention import causal_gqa_attention
+from sparknet_tpu.ops.attention import (
+    causal_gqa_attention,
+    causal_mla_attention,
+)
 from sparknet_tpu.ops.delta_rule import gated_delta_rule
 from sparknet_tpu.ops.pallas_attention import SAVED as FLASH_SAVED
 from sparknet_tpu.ops.short_conv import causal_depthwise_conv, gated_short_conv
@@ -139,15 +163,42 @@ KEYE_VL2_KEYS = (
 )
 SA_KEYS = ("indexer_head_dim", "indexer_num_heads", "indexer_num_kv_heads",
            "topk", "q_chunk_size")
+# and of a deepseek_v3 one
+DEEPSEEK_V3_KEYS = (
+    "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
+    "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+    "rope_theta", "rms_norm_eps", "first_k_dense_replace",
+    "intermediate_size", "n_routed_experts", "n_shared_experts",
+    "num_experts_per_tok", "moe_intermediate_size", "routed_scaling_factor",
+)
+# what a deepseek_v3 file may say of the mechanisms this class has not: a
+# key, the one value taken (``None``: the key absent counts too), the
+# mechanism a different value asks for
+DEEPSEEK_V3_ONLY = (
+    ("q_lora_rank", None, "a query rank (a latent for q too)"),
+    ("rope_scaling", None, "scaled rotary positions"),
+    ("n_group", 1, "group-limited routing"),
+    ("topk_group", 1, "group-limited routing"),
+    ("moe_layer_freq", 1, "dense layers between the routed ones"),
+    ("attention_bias", False, "biases on the attention's projections"),
+    ("norm_topk_prob", True, "top-k weights that are not renormalised"),
+    ("scoring_func", "sigmoid", "softmax scores under noaux_tc"),
+    ("topk_method", "noaux_tc", "a top-k without the selection bias"),
+    ("rope_interleave", True, "rotate-half on the rope part"),
+)
+# the renormalisation of DeepSeek-V3's top-k weights: ``w / (sum(w) + 1e-20)``
+DEEPSEEK_V3_TOPK_EPS = 1e-20
 # a mixer kind's scope type; its blobs and its function are the model's
 # ``_mixer_shapes`` and ``_<kind>`` (``dsa_attention``: ``_dsa_mixer``, which
 # opens the scopes ``DSA_SCOPES`` beside it)
 MIXERS = {
     "gated_delta_net": "GatedDeltaNet", "gated_attention": "GatedAttention",
     "short_conv": "ShortConv", "attention": "Attention",
-    "dsa_attention": "DSAAttention",
+    "dsa_attention": "DSAAttention", "mla_attention": "MLAAttention",
 }
 DSA_SCOPES = ("DSAIndexer", "DSASelect", "DSAAttention", "DSAIndexerLoss")
+# a latent-attention layer's (``_mla_mixer``)
+MLA_SCOPES = ("MLALatent", "MLAAttention")
 # the indexer's LayerNorm (DeepSeek-V3.2-Exp's public implementation)
 INDEX_NORM_EPS = 1e-6
 # the renormalisation of LFM2's top-k weights: ``w / (sum(w) + 1e-6)``
@@ -179,6 +230,7 @@ def _describe_qwen3_next(config: Dict, name: str) -> Dict:
         ffns=("moe",) * depth,
         eps=c["rms_norm_eps"], zero_centred_norm=True,
         rotary_dim=int(c["head_dim"] * c["partial_rotary_factor"]),
+        shared_expert_gate=True,
         router_scores="softmax", expert_bias=False, routed_scaling_factor=1.0,
         topk_eps=0.0, expert_bias_update_rate=0.0,
         tied=bool(config.get("tie_word_embeddings", False)),
@@ -248,8 +300,54 @@ def _describe_keye_vl2(config: Dict, name: str) -> Dict:
     )
 
 
+def _describe_deepseek_v3(config: Dict, name: str) -> Dict:
+    c = _take(config, DEEPSEEK_V3_KEYS, name)
+    for key, taken, mechanism in DEEPSEEK_V3_ONLY:
+        if config.get(key, taken) != taken:
+            raise ValueError(
+                f"{name}: {key}={config[key]!r} is not supported "
+                f"({mechanism}); only {key}={taken!r}")
+    nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
+    if config.get("qk_head_dim", nope + rope) != nope + rope:
+        raise ValueError(f"{name}: qk_head_dim is not qk_nope_head_dim + "
+                         "qk_rope_head_dim")
+    if c["v_head_dim"] != nope:
+        raise ValueError(f"{name}: v_head_dim={c['v_head_dim']} beside "
+                         f"qk_nope_head_dim={nope} is not supported (the "
+                         "kernels' value block is the nope part's)")
+    if rope % 2:
+        raise ValueError(f"{name}: qk_rope_head_dim must be even")
+    depth = c["num_hidden_layers"]
+    return dict(
+        c,
+        mixers=("mla_attention",) * depth,
+        ffns=tuple("dense" if i < c["first_k_dense_replace"] else "moe"
+                   for i in range(depth)),
+        eps=c["rms_norm_eps"], zero_centred_norm=False,
+        # a key head a query head; ``head_dim`` (the file's is the rope
+        # part's) and ``rotary_dim`` are the other attentions' and unused
+        num_key_value_heads=c["num_attention_heads"],
+        head_dim=nope, rotary_dim=rope,
+        num_experts=c["n_routed_experts"],
+        # the shared experts are ONE gated MLP of their widths side by side,
+        # added whole, with no gate on its output (no ``w_s`` blob)
+        shared_expert_intermediate_size=(
+            c["n_shared_experts"] * c["moe_intermediate_size"]),
+        shared_expert_gate=False,
+        # an all-attention stack: as ``_describe_keye_vl2``'s, and why
+        init_std={"embed": 1.0, "out": 0.02 * (2 * depth) ** -0.5},
+        router_scores="sigmoid", expert_bias=True,
+        routed_scaling_factor=float(c["routed_scaling_factor"]),
+        topk_eps=DEEPSEEK_V3_TOPK_EPS,
+        expert_bias_update_rate=float(
+            config.get("expert_bias_update_rate", 0.0)),
+        tied=bool(config.get("tie_word_embeddings", False)),
+    )
+
+
 DESCRIBERS = {"qwen3_next": _describe_qwen3_next, "lfm2_moe": _describe_lfm2_moe,
-              "KeyeVL2": _describe_keye_vl2}
+              "KeyeVL2": _describe_keye_vl2,
+              "deepseek_v3": _describe_deepseek_v3}
 
 
 def describe(config: Dict, name: str = "HybridMoELM") -> Dict:
@@ -281,6 +379,23 @@ def rotary(x, theta: float, rotary_dim: int):
     x1, x2 = x[..., :half], x[..., half:rotary_dim]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary_dim:]], -1)
+
+
+def rotary_pairs(x, theta: float):
+    """Rotary over ADJACENT pairs of the whole last axis of ``(B, T, H,
+    D)``: ``(x[2i], x[2i + 1])`` turns by ``t theta^(-2i / D)`` and stays
+    where it lies (``rope_interleave``; a score is the same as under the
+    public implementation, which moves the pairs' halves apart first, on
+    queries and keys alike)."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(d // 2, dtype=F32) * 2.0 / d)
+    angle = jnp.arange(x.shape[1], dtype=F32)[:, None] * inv_freq
+    cos = jnp.cos(angle)[None, :, None, :]
+    sin = jnp.sin(angle)[None, :, None, :]
+    pairs = x.reshape(*x.shape[:-1], d // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    return jnp.stack(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).reshape(x.shape)
 
 
 class HybridMoELM:
@@ -333,7 +448,7 @@ class HybridMoELM:
     def is_attention_layer(self, i: int) -> bool:
         """Whether layer ``i`` mixes by softmax attention, gated or plain."""
         return self.config["mixers"][i] in (
-            "gated_attention", "attention", "dsa_attention")
+            "gated_attention", "attention", "dsa_attention", "mla_attention")
 
     # ------------------------------------------------------------------
     def _mixer_shapes(self, kind: str):
@@ -353,6 +468,12 @@ class HybridMoELM:
                 blobs += [((e, j * di), w), ((e, di), w), ((di,), "ones"),
                           ((di,), "zeros"), ((e, j), w)]
             return blobs
+        if kind == "mla_attention":
+            nope, rope = c["qk_nope_head_dim"], c["qk_rope_head_dim"]
+            rank, dv = c["kv_lora_rank"], c["v_head_dim"]
+            return [((e, hq * (nope + rope)), w), ((e, rank + rope), w),
+                    ((rank,), norm), ((rank, hq * (nope + dv)), w),
+                    ((hq * dv, e), out)]
         if kind == "short_conv":
             return [((e, 3 * e), w), ((e, c["conv_L_cache"]), w), ((e, e), out)]
         hk, hv = c["linear_num_key_heads"], c["linear_num_value_heads"]
@@ -383,7 +504,8 @@ class HybridMoELM:
             plan.append((f"l{i}_experts", [((n, e, f), w), ((n, e, f), w),
                                            ((n, f, e), out)]))
             if fs:
-                plan.append((f"l{i}_shared", mlp(fs) + [((e, 1), w)]))
+                plan.append((f"l{i}_shared", mlp(fs) + (
+                    [((e, 1), w)] if c["shared_expert_gate"] else [])))
         plan.append(("norm_f", [((e,), norm)]))
         if not c["tied"]:
             plan.append(("head", [((e, v), w)]))
@@ -566,6 +688,50 @@ class HybridMoELM:
                 q, k, mask, block_q=c["index_block"]))
         return out, align
 
+    def _mla_latent(self, x, blobs):
+        """What the latent attention reads, from the normed input: ``q_nope
+        (B, T, H, nope)``, ``q_rope (B, T, H, rope)``, ``k_nope`` and ``v (B,
+        T, H, nope)`` from the normed latent, and the ONE ``k_rope (B, T, 1,
+        rope)``; rotary over adjacent pairs on the rope parts alone, in
+        float32; all five in the compute dtype."""
+        q_proj, kv_a, kv_norm, kv_b = blobs
+        c = self.config
+        b, t, _ = x.shape
+        h, nope, rope = (c["num_attention_heads"], c["qk_nope_head_dim"],
+                         c["qk_rope_head_dim"])
+        rank, theta = c["kv_lora_rank"], c["rope_theta"]
+        cd = self.compute_dtype or F32
+        q = self._dot(x, q_proj, F32).reshape(b, t, h, nope + rope)
+        latent = self._dot(x, kv_a, F32)
+        k_rope = latent[..., rank:].reshape(b, t, 1, rope)
+        kv = self._dot(self._norm(latent[..., :rank], kv_norm), kv_b)
+        kv = kv.reshape(b, t, h, nope + c["v_head_dim"])
+        return (q[..., :nope].astype(cd),
+                rotary_pairs(q[..., nope:], theta).astype(cd),
+                kv[..., :nope], rotary_pairs(k_rope, theta).astype(cd),
+                kv[..., nope:])
+
+    def _mla_attention(self, parts, o_proj):
+        """The kernels (or the XLA path, as ``attention_path`` says) and the
+        output projection; heads side by side as the kernels write them."""
+        attn = causal_mla_attention(*parts, compute_dtype=self.compute_dtype)
+        b, t = attn.shape[:2]
+        return self._dot(attn.reshape(b, t, -1), o_proj, F32)
+
+    def _mla_mixer(self, i: int, normed, blobs):
+        """A latent-attention layer's mixer under its two scopes, each with
+        its ``jax.checkpoint`` inside: ``MLALatent`` holds what making q, k
+        and v costs (``q_proj``, ``kv_a_proj``, the latent's norm,
+        ``kv_b_proj``, rotary), ``MLAAttention`` the attention and
+        ``o_proj``.  Kept between forward and backward, beside the normed
+        input: the five parts in the compute dtype (472 MB a layer at 2 x
+        8,192 tokens in bfloat16) and what the flash kernels name."""
+        with jax.named_scope(f"MLALatent:l{i}_latent"):
+            parts = jax.checkpoint(self._mla_latent)(normed, blobs[:4])
+        with jax.named_scope(f"MLAAttention:l{i}_mixer"):
+            return jax.checkpoint(self._mla_attention, policy=MIXER_KEEPS)(
+                parts, blobs[4])
+
     def _short_conv(self, x, blobs):
         in_proj, conv, out_proj = blobs
         return self._dot(
@@ -620,10 +786,13 @@ class HybridMoELM:
             compute_dtype=self.compute_dtype)
 
     def _shared_expert(self, x2d, blobs):
-        gate, up, down, w_s = blobs
-        y = moe.gated_mlp(x2d, gate, up, down, self.compute_dtype)
-        return y * jax.nn.sigmoid(
-            jnp.dot(x2d.astype(F32), w_s, precision=jax.lax.Precision.HIGHEST))
+        """The shared expert's gated MLP; times ``sigmoid(x w_s)`` where the
+        family gates its output (a fourth blob)."""
+        y = moe.gated_mlp(x2d, *blobs[:3], self.compute_dtype)
+        if len(blobs) == 3:
+            return y
+        return y * jax.nn.sigmoid(jnp.dot(
+            x2d.astype(F32), blobs[3], precision=jax.lax.Precision.HIGHEST))
 
     def _dense_mlp(self, x2d, blobs):
         return moe.gated_mlp(x2d, *blobs, self.compute_dtype)
@@ -643,6 +812,8 @@ class HybridMoELM:
         if kind == "dsa_attention":
             mixed, aux = self._dsa_mixer(i, normed, params[f"l{i}_mixer"], probe)
             h = x + mixed
+        elif kind == "mla_attention":
+            h = x + self._mla_mixer(i, normed, params[f"l{i}_mixer"])
         else:
             with jax.named_scope(f"{MIXERS[kind]}:l{i}_mixer"):
                 h = x + jax.checkpoint(

@@ -168,6 +168,45 @@ def lowerable() -> bool:
     return pallas_attention.lowerable()
 
 
+def _path(t: int, hq: int, hkv: int, d: int, cd, block_q: int,
+          segments: int, rope: int = 0):
+    """Which of the two paths a causal attention of these shapes takes, and
+    the ``attention_path`` instant that says so: ``(why not the kernels, ""
+    where they run; block_q; block_k)``.  ``rope``: the width of a second
+    score term (``causal_mla_attention``), whose values stay ``d`` wide."""
+    from sparknet_tpu.ops import pallas_attention  # see lowerable
+
+    backend = jax.default_backend()
+    if not lowerable():
+        why = f"no Pallas lowering on {backend}"
+    elif not pallas_attention.accepts(hq, hkv, d, cd, rope):
+        why = "heads of whole lanes in whole groups, bfloat16 or float32"
+    else:
+        why = ""
+    if why:  # a run's query blocks each meet the key blocks up to its end
+        block_k = block_q
+        blocks = -(-t // block_q)
+        ends = [min(lo + -(-blocks // segments), blocks)
+                for lo in range(0, blocks, -(-blocks // segments))]
+        met = (sum(hi * (hi - lo) for lo, hi in zip([0] + ends, ends)),
+               blocks * blocks)
+    else:
+        # a second score term: a head a K/V head, so a block's rows are one
+        # head's; keys x width as the selected-key attention holds them
+        # (1,024 keys at D = 128): on the v5e at 2 x 8,192 tokens, 32 heads,
+        # forward + backward 59.0 ms against 71.3 at 512 x 512, 63.0 at
+        # 2,048 x 1,024 (PERF.md section 6, PR 37)
+        block_k = min(KERNEL_BLOCK_K * 256 // d if rope else KERNEL_BLOCK_K, t)
+        block_q = kernel_block_q(hq // hkv, d + rope, cd, block_k)
+        met = pallas_attention.blocks_met(t, t, block_q, block_k)
+    obs.instant("attention_path", cat="kernel",
+                path="xla" if why else "pallas", why=why, backend=backend,
+                t=t, hq=hq, hkv=hkv, d=d, d_qk=d + rope, d_v=d,
+                dtype=cd.name, block_q=block_q,
+                block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
+    return why, block_q, block_k
+
+
 def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
                          compute_dtype=None):
     """Causal softmax attention with grouped K/V heads.
@@ -191,34 +230,58 @@ def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     cd = jnp.dtype(compute_dtype or jnp.float32)
-    backend = jax.default_backend()
-    if not lowerable():
-        why = f"no Pallas lowering on {backend}"
-    elif not pallas_attention.accepts(hq, hkv, d, cd):
-        why = "heads of whole lanes in whole groups, bfloat16 or float32"
-    else:
-        why = ""
-    if why:  # a run's query blocks each meet the key blocks up to its end
-        block_k = block_q
-        blocks = -(-t // block_q)
-        ends = [min(lo + -(-blocks // segments), blocks)
-                for lo in range(0, blocks, -(-blocks // segments))]
-        met = (sum(hi * (hi - lo) for lo, hi in zip([0] + ends, ends)),
-               blocks * blocks)
-    else:
-        block_k = min(KERNEL_BLOCK_K, t)
-        block_q = kernel_block_q(hq // hkv, d, cd, block_k)
-        met = pallas_attention.blocks_met(t, t, block_q, block_k)
-    obs.instant("attention_path", cat="kernel",
-                path="xla" if why else "pallas", why=why, backend=backend,
-                t=t, hq=hq, hkv=hkv, d=d, dtype=cd.name, block_q=block_q,
-                block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
+    why, block_q, block_k = _path(t, hq, hkv, d, cd, block_q, segments)
     q = (q.astype(jnp.float32) * d ** -0.5).astype(cd)
     if why:
         return _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments)
     return pallas_attention.flash_attention(
         q, k.astype(cd), v.astype(cd), causal=True, block_q=block_q,
         block_k=block_k, scale=1.0, out_dtype=jnp.float32)
+
+
+def causal_mla_attention(q_nope, q_rope, k_nope, k_rope, v, *,
+                         block_q: int = 512, segments: int = 4,
+                         compute_dtype=None):
+    """Causal softmax attention whose key is part per head and part one
+    rotary key every head shares (multi-head latent attention, training
+    form), the score wider than the value.
+
+    ``q_nope``, ``k_nope``, ``v``: ``(B, T, H, D)``; ``q_rope``: ``(B, T, H,
+    R)``; ``k_rope``: ``(B, T, 1, R)``.  ``s = (q_nope . k_nope + q_rope .
+    k_rope) (D + R) ** -0.5``; dtypes as ``causal_gqa_attention`` (both
+    parts of ``q`` scaled before they are cast).  Returns ``(B, T, H, D)``,
+    the values never padded to the score's width, in the COMPUTE dtype: the
+    output projection that follows rounds it there in any case, and what a
+    layer's recomputation keeps of the kernels (their output, and the
+    cotangent XLA hands them) is then half of float32's, 0.67 GiB over five
+    layers at 2 x 8,192 tokens.
+
+    Where the kernels take the shapes (``pallas_attention.accepts(.., rope=
+    R)``), ``mla_flash_attention``: the score's two terms are two products
+    into one tile in VMEM, the one rope key is read where it lies by every
+    head (never repeated in memory), and of the MXU's passes 18% run half
+    empty (the 64-deep rope contraction; ``ops/pallas_attention.py``).
+    Elsewhere the rope key is repeated over the heads and joined to the
+    other part, and ``_blockwise_gqa`` runs on heads ``D + R`` wide against
+    values ``D`` wide: the CPU's path and the kernels' oracle."""
+    from sparknet_tpu.ops import pallas_attention  # see lowerable
+
+    b, t, h, d = q_nope.shape
+    rope = q_rope.shape[-1]
+    cd = jnp.dtype(compute_dtype or jnp.float32)
+    why, block_q, block_k = _path(t, h, h, d, cd, block_q, segments, rope)
+    scale = (d + rope) ** -0.5
+    q_nope, q_rope = ((x.astype(jnp.float32) * scale).astype(cd)
+                      for x in (q_nope, q_rope))
+    k_nope, k_rope, v = (x.astype(cd) for x in (k_nope, k_rope, v))
+    if why:
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, t, h, rope))], axis=-1)
+        return _blockwise_gqa(q, k, v, block_q, segments).astype(cd)
+    return pallas_attention.mla_flash_attention(
+        q_nope, q_rope, k_nope, k_rope[:, :, 0], v, block_q=block_q,
+        block_k=block_k, scale=1.0)
 
 
 # -- the XLA blockwise passes: query blocks in runs, a keep-mask as bits ------

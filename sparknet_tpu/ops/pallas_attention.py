@@ -34,6 +34,22 @@ step with the tile unpacked in VMEM (an AND and a compare, the same for the
 heads of a group) in place of the positions' iotas; the dk/dv pass, whose
 scores have the keys on rows, transposes the unpacked tile (32-bit, 2-D).
 
+A second score term (``mla_flash_attention``: latent attention, whose key is
+part per head, ``d`` wide, and part ONE rotary key shared by every head,
+``rope`` wide, against values ``d`` wide) is the other static specialisation
+(``_Shape.rope``, 0 without one): ``s = q k^T + q_rope k_rope^T``, two
+products into one float32 tile, so the 192-wide score never exists as one
+block and ``v`` and ``o`` stay ``d`` wide, read and written where they lie.
+The rope queries come heads-first, ``(B, H, T, rope)`` (a block of 64 lanes
+is whole only where 64 is the array's width), the rope key as it is, ``(B, T,
+rope)``, the same block for every head and never repeated in memory; the dq
+pass hands back ``dq_rope`` the same way and the dk/dv pass a float32
+``dk_rope`` a head, ``(B, H, T, rope)``, summed over the heads in XLA.  On the
+128 x 128 MXU the rope term's contraction fills half a pass: of the forward's
+three passes a block 2.5 are asked for, of the backward's eleven 9, so 18%
+of the fourteen are empty (a 192-wide block padded to 256 in VMEM would waste
+the same).
+
 Backward: the FlashAttention recipe from the residuals ``(q, k, v, o, lse)``.
 The dq pass (query block outer) forms ``delta = rowsum(do * o) - dlse`` once a
 query block and hands it on; the dk/dv pass (key block outer) works on the
@@ -92,11 +108,17 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T without materializing b.T
 
 
-def accepts(hq: int, hkv: int, d: int, dtype) -> bool:
+def accepts(hq: int, hkv: int, d: int, dtype, rope: int = 0) -> bool:
     """What ``flash_attention`` is worth taking for: query heads in whole
     groups, a dtype the MXU takes, and heads of whole lanes (read in place)
     or a K/V head's group of whole lanes side by side (heads-first: four
-    heads of 64 are 256 lanes of queries against 64 of keys)."""
+    heads of 64 are 256 lanes of queries against 64 of keys).  With a second
+    score term ``rope`` wide (``mla_flash_attention``: scores ``d + rope``
+    wide against values ``d`` wide): a key head a query head, read in place,
+    and a rope part of whole sublanes within one row of lanes."""
+    if rope and not (hq == hkv and d % LANES == 0
+                     and rope % 8 == 0 and rope <= LANES):
+        return False
     return (hq % hkv == 0
             and (d % LANES == 0 or (hq // hkv * d) % LANES == 0)
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(F32)))
@@ -106,7 +128,8 @@ class _Shape(NamedTuple):
     """What a kernel is specialised on.  ``q``, ``o``: ``(B, Tq, Hkv *
     group * d)``, ``k``, ``v``: ``(B, Tk, Hkv * d)``, both lengths whole
     blocks; ``tk`` counts the real keys.  ``words``: the words a row of the
-    keep-mask, 0 without one."""
+    keep-mask, 0 without one.  ``rope``: the width of a second score term,
+    0 without one (then ``group`` is 1)."""
     causal: bool
     scale: float
     group: int
@@ -117,6 +140,7 @@ class _Shape(NamedTuple):
     out_dtype: np.dtype
     interpret: bool
     words: int
+    rope: int
 
 
 def _mm(a, b, dims):
@@ -222,6 +246,24 @@ def _bits_first(refs, c: _Shape):
     return (refs[0], refs[1:]) if c.words else (None, refs)
 
 
+def _rope_apart(refs, c: _Shape, *at):
+    """The refs of the second score term, which sit at ``at`` among a
+    kernel's where there is one, and the others; Nones without one."""
+    if not c.rope:
+        return (None,) * len(at), refs
+    return (tuple(refs[i] for i in at),
+            tuple(r for i, r in enumerate(refs) if i not in at))
+
+
+def _scores(q, k, qr_ref, kr_ref, c: _Shape):
+    """``q k^T`` (``k q^T`` for the dk/dv pass, which hands the key's side
+    first) and, where there is one, the rope term into the same tile."""
+    s = _mm(q, k, _NT)
+    if c.rope:
+        s = s + _mm(qr_ref[...], kr_ref[...], _NT)
+    return s
+
+
 # -- a K/V head's group, stacked as rows --------------------------------------
 def _stacked(ref, c: _Shape):
     """``(block_q, group * d)`` -> ``(group * block_q, d)``, head after head."""
@@ -261,8 +303,9 @@ def _column_to(ref, col, c: _Shape):
 
 # -- the kernels ----------------------------------------------------------------
 def _fwd_kernel(offs_ref, *refs, c: _Shape):
-    bits_ref, (q_ref, k_ref, v_ref, o_ref, lse_ref,
-               m_ref, l_ref, acc_ref) = _bits_first(refs, c)
+    bits_ref, refs = _bits_first(refs, c)
+    (qr_ref, kr_ref), refs = _rope_apart(refs, c, 3, 4)  # the last inputs
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
     @pl.when(j == 0)
@@ -272,7 +315,7 @@ def _fwd_kernel(offs_ref, *refs, c: _Shape):
         acc_ref[...] = jnp.zeros(acc_ref.shape, F32)
 
     def step(masked):
-        s = _mm(_stacked(q_ref, c), k_ref[...], _NT)
+        s = _scores(_stacked(q_ref, c), k_ref[...], qr_ref, kr_ref, c)
         if c.scale != 1.0:
             s = s * c.scale
         if masked:
@@ -312,9 +355,12 @@ def _probabilities(s, lse, keep, c: _Shape):
 
 
 def _dq_kernel(offs_ref, *refs, c: _Shape):
-    bits_ref, (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
-               dq_ref, delta_ref,
-               do_scr, lse_scr, delta_scr, dq_scr) = _bits_first(refs, c)
+    bits_ref, refs = _bits_first(refs, c)
+    # the rope term's: the last inputs, the last output, the last scratch
+    (qr_ref, kr_ref, dqr_ref, dqr_scr), refs = _rope_apart(
+        refs, c, 7, 8, 11, 16)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
+     dq_ref, delta_ref, do_scr, lse_scr, delta_scr, dq_scr) = refs
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
     @pl.when(j == 0)
@@ -327,27 +373,37 @@ def _dq_kernel(offs_ref, *refs, c: _Shape):
         lse_scr[...] = _column(lse_ref, c)
         do_scr[...] = do.astype(do_scr.dtype)
         dq_scr[...] = jnp.zeros(dq_scr.shape, F32)
+        if c.rope:
+            dqr_scr[...] = jnp.zeros(dqr_scr.shape, F32)
 
     def step(masked):
         k = k_ref[...]
         keep = _keep(offs_ref, bits_ref, i, j, c, False) if masked else None
-        p = _probabilities(_mm(_stacked(q_ref, c), k, _NT), lse_scr[...],
-                           keep, c)
+        p = _probabilities(_scores(_stacked(q_ref, c), k, qr_ref, kr_ref, c),
+                           lse_scr[...], keep, c)
         ds = p * (_mm(do_scr[...], v_ref[...], _NT) - delta_scr[...])
         if c.scale != 1.0:
             ds = ds * c.scale
         dq_scr[...] += _mm(ds.astype(k.dtype), k, _NN)
+        if c.rope:
+            dqr_scr[...] += _mm(ds.astype(k.dtype), kr_ref[...], _NN)
 
     _walk(offs_ref, i, j, nk, c, step)
 
     @pl.when(j == nk - 1)
     def _():
         _unstack_to(dq_ref, dq_scr[...], c)
+        if c.rope:
+            dqr_ref[...] = dqr_scr[...].astype(dqr_ref.dtype)
 
 
 def _dkv_kernel(offs_ref, *refs, c: _Shape):
-    bits_ref, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dk_ref, dv_ref, dk_scr, dv_scr) = _bits_first(refs, c)
+    bits_ref, refs = _bits_first(refs, c)
+    # the rope term's: the last inputs, the last output, the last scratch
+    (qr_ref, kr_ref, dkr_ref, dkr_scr), refs = _rope_apart(
+        refs, c, 6, 7, 10, 13)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dk_ref, dv_ref, dk_scr, dv_scr) = refs
     # key block outer, query blocks inner; the scores transposed, keys on rows
     j, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
 
@@ -355,16 +411,21 @@ def _dkv_kernel(offs_ref, *refs, c: _Shape):
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, F32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, F32)
+        if c.rope:
+            dkr_scr[...] = jnp.zeros(dkr_scr.shape, F32)
 
     def step(masked):
         q, do = _stacked(q_ref, c), _stacked(do_ref, c)
         keep = _keep(offs_ref, bits_ref, i, j, c, True) if masked else None
-        p = _probabilities(_mm(k_ref[...], q, _NT), _row(lse_ref, c), keep, c)
+        p = _probabilities(_scores(k_ref[...], q, kr_ref, qr_ref, c),
+                           _row(lse_ref, c), keep, c)
         dv_scr[...] += _mm(p.astype(do.dtype), do, _NN)
         ds = p * (_mm(v_ref[...], do, _NT) - _row(delta_ref, c))
         if c.scale != 1.0:
             ds = ds * c.scale
         dk_scr[...] += _mm(ds.astype(q.dtype), q, _NN)  # sums over the group
+        if c.rope:  # this head's part of the shared key's gradient
+            dkr_scr[...] += _mm(ds.astype(q.dtype), qr_ref[...], _NN)
 
     _walk(offs_ref, i, j, pl.num_programs(2), c, step)
 
@@ -372,6 +433,8 @@ def _dkv_kernel(offs_ref, *refs, c: _Shape):
     def _():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        if c.rope:
+            dkr_ref[...] = dkr_scr[...]
 
 
 # Scoped-VMEM ceiling handed to Mosaic.  The default (16 MiB on v5e) is
@@ -405,8 +468,11 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
     """One kernel over (batch, K/V head, outer block, inner block), the inner
     blocks one after another.  ``ins`` / ``outs``: a letter an operand —
     ``q`` a block of queries' rows ``(block_q, group * d)``, ``k`` a block of
-    keys' ``(block_k, d)``, ``r`` a per-row scalar ``(group, block_q)`` —
-    and for an output its dtype.  ``keep``: the keep-mask's words ``(B, Tq,
+    keys' ``(block_k, d)``, ``r`` a per-row scalar ``(group, block_q)``; of
+    the rope term ``a`` a block of a head's queries ``(block_q, rope)`` of
+    ``(B, H, Tq, rope)``, ``b`` a block of the ONE key ``(block_k, rope)`` of
+    ``(B, Tk, rope)`` and ``c`` a block of a head's part of that key's
+    gradient, ``(B, H, Tk, rope)`` — and for an output its dtype.  ``keep``: the keep-mask's words ``(B, Tq,
     words)`` int32 or None; a block of queries' whole rows of it goes first,
     fetched again only when the query block changes."""
     b, tq = operands[0].shape[:2]
@@ -441,6 +507,18 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
                            lambda *g: (g[0], g[1], 0, q_block(*g))),
               (b, hkv, c.group, tq)),
     }
+    if c.rope:
+        specs.update({
+            "a": (pl.BlockSpec((None, None, c.block_q, c.rope),
+                               lambda *g: (g[0], g[1], q_block(*g), 0)),
+                  (b, hkv, tq, c.rope)),
+            "b": (pl.BlockSpec((None, c.block_k, c.rope),
+                               lambda *g: (g[0], k_block(*g), 0)),
+                  (b, tk, c.rope)),
+            "c": (pl.BlockSpec((None, None, c.block_k, c.rope),
+                               lambda *g: (g[0], g[1], k_block(*g), 0)),
+                  (b, hkv, tk, c.rope)),
+        })
     in_specs = [specs[x][0] for x in ins]
     if c.words:  # heads-first, a sequence's K/V heads share its mask
         per_mask = b // keep.shape[0]
@@ -467,25 +545,28 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
     )(offs, *operands)
 
 
-def _forward(q, k, v, offs, keep, c: _Shape):
+def _forward(q, k, v, offs, keep, rope, c: _Shape):
     rows = c.group * c.block_q
+    ab, rope = ("ab", rope) if rope else ("", ())
     return _call(
-        _fwd_kernel, "flash_attention_forward", c, False, "qkk",
+        _fwd_kernel, "flash_attention_forward", c, False, "qkk" + ab,
         [("q", c.out_dtype), ("r", F32)],
         [pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, 1), F32),
          pltpu.VMEM((rows, c.d), F32)],
-        offs, keep, q, k, v)
+        offs, keep, q, k, v, *rope)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _flash_core(q, k, v, offs, keep, c: _Shape):
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash_core(q, k, v, offs, keep, rope, c: _Shape):
     """``(o, lse)`` from ``q`` ``(B, Tq, Hkv * group * d)`` and ``k``, ``v``
     ``(B, Tk, Hkv * d)``, both lengths whole blocks.  ``offs`` is the int32
     (2,) absolute (q_offset, k_offset) pair; ``keep`` the keep-mask's words
-    (``_call``) or None; ``lse`` is ``(B, Hkv, group, Tq)`` float32, tokens
-    on lanes.  Differentiable in q/k/v AND honest in the lse output (nonzero
-    dlse cotangents — the ring merge — feed the backward's delta term)."""
-    return _forward(q, k, v, offs, keep, c)
+    (``_call``) or None; ``rope`` the second score term's ``(q_rope (B, H,
+    Tq, rope), k_rope (B, Tk, rope))`` or None; ``lse`` is ``(B, Hkv, group,
+    Tq)`` float32, tokens on lanes.  Differentiable in q/k/v and the rope
+    pair AND honest in the lse output (nonzero dlse cotangents — the ring
+    merge — feed the backward's delta term)."""
+    return _forward(q, k, v, offs, keep, rope, c)
 
 
 # What a ``jax.checkpoint`` around the caller may keep (``policy=jax.
@@ -494,29 +575,35 @@ def _flash_core(q, k, v, offs, keep, c: _Shape):
 SAVED = ("flash_attention_o", "flash_attention_lse")
 
 
-def _flash_core_fwd(q, k, v, offs, keep, c):
-    o, lse = _forward(q, k, v, offs, keep, c)
+def _flash_core_fwd(q, k, v, offs, keep, rope, c):
+    o, lse = _forward(q, k, v, offs, keep, rope, c)
     o, lse = (checkpoint_name(x, name) for x, name in zip((o, lse), SAVED))
-    return (o, lse), (q, k, v, offs, keep, o, lse)
+    return (o, lse), (q, k, v, offs, keep, rope, o, lse)
 
 
 def _flash_core_bwd(c, res, cts):
-    q, k, v, offs, keep, o, lse = res
+    q, k, v, offs, keep, rope, o, lse = res
     do, dlse = cts
     rows = c.group * c.block_q
-    dq, delta = _call(
-        _dq_kernel, "flash_attention_dq", c, False, "qkkqqrr",
-        [("q", q.dtype), ("r", F32)],
+    # the rope term's inputs, output and scratch come last (``_rope_apart``)
+    ab, parts, n = ("ab", rope, 1) if rope else ("", (), 0)
+    dq, delta, *dqr = _call(
+        _dq_kernel, "flash_attention_dq", c, False, "qkkqqrr" + ab,
+        [("q", q.dtype), ("r", F32)] + [("a", q.dtype)] * n,
         [pltpu.VMEM((rows, c.d), q.dtype), pltpu.VMEM((rows, 1), F32),
-         pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, c.d), F32)],
-        offs, keep, q, k, v, do, o, lse, dlse.astype(F32))
-    dk, dv = _call(
-        _dkv_kernel, "flash_attention_dkv", c, True, "qkkqrr",
-        [("k", k.dtype), ("k", v.dtype)],
-        [pltpu.VMEM((c.block_k, c.d), F32)] * 2,
-        offs, keep, q, k, v, do.astype(q.dtype), lse, delta)
+         pltpu.VMEM((rows, 1), F32), pltpu.VMEM((rows, c.d), F32)]
+        + [pltpu.VMEM((rows, c.rope), F32)] * n,
+        offs, keep, q, k, v, do, o, lse, dlse.astype(F32), *parts)
+    dk, dv, *dkr = _call(
+        _dkv_kernel, "flash_attention_dkv", c, True, "qkkqrr" + ab,
+        [("k", k.dtype), ("k", v.dtype)] + [("c", F32)] * n,
+        [pltpu.VMEM((c.block_k, c.d), F32)] * 2
+        + [pltpu.VMEM((c.block_k, c.rope), F32)] * n,
+        offs, keep, q, k, v, do.astype(q.dtype), lse, delta, *parts)
+    if rope:  # the shared key's gradient: the heads' parts, summed in float32
+        rope = dqr[0], jnp.sum(dkr[0], axis=1).astype(rope[1].dtype)
     # the integer offsets and the mask's bits carry no cotangent
-    return dq, dk, dv, None, None
+    return dq, dk, dv, None, None, rope
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -540,10 +627,12 @@ def _pad_rows(x, block):
 
 
 def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
-            out_dtype, keep=None):
+            out_dtype, keep=None, rope=None):
     """``(o, lse)`` of ``(B, Tq, Hq, D)`` against ``(B, Tk, Hkv, D)``, as
     ``(B, Tq, Hq, D)`` and ``(B, Hq, Tq)``; ``keep``: a keep-mask's bits
-    ``(B, Tq, words)`` uint32 (``masked_flash_attention``)."""
+    ``(B, Tq, words)`` uint32 (``masked_flash_attention``); ``rope``: a
+    second score term's ``(q_rope (B, Tq, Hq, R), k_rope (B, Tk, R))``
+    (``mla_flash_attention``)."""
     if interpret is None:
         interpret = not lowerable()
     b, tq, hq, d = q.shape
@@ -551,6 +640,16 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not divide by {hkv} K/V heads")
     block_q, block_k = min(block_q, tq), min(block_k, tk)
+    width = 0
+    if rope is not None:
+        width = rope[0].shape[-1]
+        if not accepts(hq, hkv, d, q.dtype, width):
+            raise ValueError(
+                f"a second score term of {width} wants a key head a query "
+                f"head of whole lanes: {hq} / {hkv} heads of {d}")
+        # heads first: a block of `width` lanes is whole where the array ends
+        rope = (jnp.transpose(_pad_rows(rope[0], block_q), (0, 2, 1, 3)),
+                _pad_rows(rope[1], block_k))
     words = 0
     if keep is not None:
         words = keep.shape[-1]
@@ -569,10 +668,10 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
         flat = partial(_heads_first, hkv=hkv)
     c = _Shape(bool(causal), float(d ** -0.5 if scale is None else scale),
                hq // hkv, d, tk, block_q, block_k,
-               np.dtype(out_dtype or q.dtype), bool(interpret), words)
+               np.dtype(out_dtype or q.dtype), bool(interpret), words, width)
     o, lse = _flash_core(
         _pad_rows(flat(q), block_q), _pad_rows(flat(k), block_k),
-        _pad_rows(flat(v), block_k), offs, keep, c)
+        _pad_rows(flat(v), block_k), offs, keep, rope, c)
     o, lse = o[:, :tq], lse[..., :tq]
     if in_place:
         return o.reshape(b, tq, hq, d), lse.reshape(b, hq, tq)
@@ -615,6 +714,23 @@ def masked_flash_attention(q, k, v, keep, *, block_q: int, block_k: int,
     differentiable; a row that keeps no key comes out ``(0, -inf)``."""
     return _attend(q, k, v, jnp.zeros((2,), jnp.int32), True, block_q,
                    block_k, interpret, scale, out_dtype, keep)
+
+
+def mla_flash_attention(q_nope, q_rope, k_nope, k_rope, v, *, block_q: int,
+                        block_k: int = BLOCK_K, interpret=None, scale=None,
+                        out_dtype=None):
+    """Causal self-attention whose score is two terms, ``q_nope . k_nope +
+    q_rope . k_rope`` (latent attention: ``q_nope``, ``k_nope``, ``v`` ``(B,
+    T, H, D)`` with ``D`` whole lanes, ``q_rope`` ``(B, T, H, R)`` against
+    the ONE ``k_rope`` ``(B, T, R)`` every head shares): ``flash_attention``'s
+    kernels and layouts given the second term (the module docstring), the
+    values and the output ``D`` wide.  ``scale`` multiplies the scores
+    (default ``(D + R) ** -0.5``).  Returns ``(B, T, H, D)``."""
+    d_qk = q_nope.shape[-1] + q_rope.shape[-1]
+    return _attend(q_nope, k_nope, v, jnp.zeros((2,), jnp.int32), True,
+                   block_q, block_k, interpret,
+                   d_qk ** -0.5 if scale is None else scale, out_dtype,
+                   rope=(q_rope, k_rope))[0]
 
 
 def flash_attention_step(

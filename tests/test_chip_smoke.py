@@ -17,6 +17,7 @@ TINY = {
     "classes": 10,
     "rounds": 2,
     "attention_shapes": (("tiny", 1, 8, 1, 8, "float32"),),
+    "mla_shapes": (("tiny", 1, 16, 2, 128, 8, "float32"),),
     "lm_loss_shapes": (("tiny", 32, 128, 300, True),),
     "comm_legs": (("int8", True),),
     "lrn_shape": (1, 5, 3, 3),

@@ -131,6 +131,38 @@ def test_flash_attention_kernels_under_a_keep_mask_compile_for_the_v5e(
         "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
 
 
+# the same three kernels WITH a second score term (``mla_flash_attention``),
+# as ``ops/attention.causal_mla_attention`` hands kanana2-train-8k's layers to
+# them: 2 sequences of 8,192 tokens, 32 heads of 128 + 64 against values of
+# 128, the one rope key ``(2, T, 64)``, blocks of 1,024 x 1,024 in bfloat16,
+# and its checks' float32 at T = 1,024 (one block).  What is settled here: Mosaic takes the
+# rope blocks of 64 lanes (whole where the array ends) and the 64-deep and
+# 64-wide products, and the compiled gradient holds no float32 ``(heads,
+# queries, keys)`` scores
+@pytest.mark.parametrize("t, dtype, block_q", [
+    (8192, "bfloat16", 1024), (1024, "float32", 1024)])
+def test_flash_attention_kernels_with_a_rope_term_compile_for_the_v5e(
+        one_chip, t, dtype, block_q):
+    shape = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, t, *s), jnp.dtype(dtype), sharding=one_chip)
+
+    def forward(q_nope, q_rope, k_nope, k_rope, v):
+        return pallas_attention.mla_flash_attention(
+            q_nope, q_rope, k_nope, k_rope, v, block_q=block_q, block_k=1024,
+            interpret=False, scale=1.0, out_dtype=jnp.float32)
+
+    def gradients(*xs):
+        out, vjp = jax.vjp(forward, *xs)
+        return vjp(out)
+
+    text = jax.jit(gradients).lower(
+        shape(32, 128), shape(32, 64), shape(32, 128), shape(64),
+        shape(32, 128)).compile().as_text()
+    assert all(name in text for name in (
+        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+    assert not re.search(rf"f32\[(2,)?32,{t},{t}\]", text)
+
+
 def test_alignment_loss_with_its_gradient_holds_no_float32_head_scores(
         one_chip):
     """keye2-train-16k's alignment loss with its gradient (``ops/
