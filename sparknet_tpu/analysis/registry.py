@@ -110,6 +110,7 @@ CANONICAL_METRICS = {
     # sparse-expert routing of models/hybrid_lm.py (--model_config)
     "sparknet_lm_held_assignments_per_token": ("layer",),
     "sparknet_lm_held_load_skew": ("layer",),
+    "sparknet_lm_grouped_rows_used_share": ("layer",),
     # the learned selection of a selected-key attention layer
     # (ops/sparse_attention.py)
     "sparknet_lm_indexer_loss": ("layer",),

@@ -96,8 +96,12 @@ def build_hybrid_lm_solver(config: dict):
 def set_routing_gauges(lm, stacked_params, tokens, stacked_stats=None):
     """Where one batch of ``(B, T)`` tokens goes, per layer: assignments to
     the held experts a token and the largest held expert's load over the
-    mean (``models/hybrid_lm.routing_gauges``), set as the gauges
-    ``sparknet_lm_held_assignments_per_token`` / ``sparknet_lm_held_load_skew``
+    mean (``models/hybrid_lm.routing_gauges``), and the held assignments
+    over the grouped expert path's rows (``ops/moe.fast_rows_for``: the share
+    of those rows the grouped products still run where they stop at the
+    held assignments, over 1 where the layer runs in token chunks), set as
+    the gauges ``sparknet_lm_held_assignments_per_token`` /
+    ``sparknet_lm_held_load_skew`` / ``sparknet_lm_grouped_rows_used_share``
     where training metrics are on, and returned.  Outside the timed loop: it
     runs a forward pass.  ``stacked_params`` are the trainer's (worker-major);
     worker 0 is sliced inside the jit, so no copy of the weights is made.
@@ -107,19 +111,27 @@ def set_routing_gauges(lm, stacked_params, tokens, stacked_stats=None):
 
     from sparknet_tpu import obs
     from sparknet_tpu.models.hybrid_lm import routing_gauges
+    from sparknet_tpu.ops import moe
     from sparknet_tpu.parallel import first_worker
 
     counts = jax.jit(lambda p, s: lm.routing_counts(
         first_worker(p), tokens, first_worker(s)))(
             stacked_params, stacked_stats or {})
-    gauges = routing_gauges(counts, tokens=int(np.prod(tokens.shape)))
+    n_tokens = int(np.prod(tokens.shape))
+    gauges = routing_gauges(counts, tokens=n_tokens)
+    rows = moe.fast_rows_for(
+        n_tokens, lm.config["num_experts_per_tok"], lm.config["num_experts"],
+        lm.experts_held[1])
+    gauges["grouped_rows_used_share"] = list(
+        np.asarray(counts, np.float64).sum(axis=1) / rows)
     tm = obs.training_metrics()
     if tm is not None:
-        for i, per_token, skew in zip(
+        for i, per_token, skew, used in zip(
                 lm.routed_layers, gauges["held_assignments_per_token"],
-                gauges["held_load_skew"]):
+                gauges["held_load_skew"], gauges["grouped_rows_used_share"]):
             tm.lm_held_assignments.labels(str(i)).set(per_token)
             tm.lm_held_load_skew.labels(str(i)).set(skew)
+            tm.lm_grouped_rows_used.labels(str(i)).set(used)
     return gauges
 
 

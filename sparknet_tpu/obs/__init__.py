@@ -402,6 +402,14 @@ class TrainingMetrics:
             "mean (1.0 = even routing), by layer",
             labels=("layer",),
         )
+        self.lm_grouped_rows_used = registry.gauge(
+            "sparknet_lm_grouped_rows_used_share",
+            "held assignments over the rows of the grouped expert path "
+            "(ops/moe.fast_rows_for): the share of those rows the grouped "
+            "products run where they stop at the held assignments, over 1 "
+            "where the layer runs in token chunks, by layer",
+            labels=("layer",),
+        )
         self.lm_indexer_loss = registry.gauge(
             "sparknet_lm_indexer_loss",
             "a selected-key attention layer's alignment loss (the KL from "

@@ -11,19 +11,28 @@ for one.
 
 No token is dropped and no expert has a capacity.  The held assignments
 are sorted by expert and go through one grouped matrix product per
-projection (``jax.lax.ragged_dot``), then a weighted scatter-add.  XLA
-needs a static row count: the grouped path takes ``fast_rows`` rows, sized
-at ``slack`` times the expected number of held assignments, and when a
-batch routes more than that to the held experts the same grouped products
-run over chunks of tokens small enough that whatever a chunk routes fits
-(``lax.cond``; exact, slower, rare).  Rows of the grouped path beyond the held assignments carry weight
-0 and ride in the last expert's group, so every row is a real product.
+projection, then a weighted scatter-add.  XLA needs a static row count: the
+grouped path takes ``fast_rows`` rows, sized at ``slack`` times the expected
+number of held assignments, and when a batch routes more than that to the
+held experts the same grouped products run over chunks of tokens small
+enough that whatever a chunk routes fits (``lax.cond``; exact, slower,
+rare).  The products of the grouped path: on the TPU at bfloat16 and widths
+of whole lanes the kernels of ``ops/pallas_grouped_matmul.py``, which do no
+work on the rows past the held assignments and write them as zero;
+elsewhere (float32, the CPU, ragged widths) ``jax.lax.ragged_dot``, which
+multiplies every row, so there the rows past the held assignments ride in
+the last expert's group with weight 0.  The chunked path keeps
+``ragged_dot``.  An ``obs`` instant, ``grouped_matmul_path``, names the
+choice at each trace.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from sparknet_tpu import obs
+from sparknet_tpu.ops.attention import lowerable  # Pallas, imported when asked
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -107,24 +116,49 @@ def gated_mlp(x, gate, up, down, compute_dtype=None):
     return dot(jax.nn.silu(dot(x, gate)) * dot(x, up), down)
 
 
-def _expert_mlp_grouped(xs, gate, up, down, sizes, cd):
-    dot = lambda a, w: jax.lax.ragged_dot(  # noqa: E731
-        a.astype(cd), w.astype(cd), sizes, preferred_element_type=F32)
-    return dot(jax.nn.silu(dot(xs, gate)) * dot(xs, up), down)
+def _grouped_path(rows: int, width: int, inner: int, cd) -> str:
+    """Why the grouped path's ``rows`` x ``width`` products with experts
+    ``inner`` wide do NOT run in the Pallas kernels, ``""`` where they do;
+    the ``grouped_matmul_path`` instant says which path a trace took."""
+    from sparknet_tpu.ops import pallas_grouped_matmul  # see lowerable
+
+    backend = jax.default_backend()
+    if not lowerable():
+        why = f"no Pallas lowering on {backend}"
+    elif not pallas_grouped_matmul.accepts(rows, width, inner, cd):
+        why = pallas_grouped_matmul.ACCEPTS
+    else:
+        why = ""
+    obs.instant("grouped_matmul_path", cat="kernel",
+                path="ragged_dot" if why else "pallas", rows=rows, why=why,
+                backend=backend, width=width, inner=inner,
+                dtype=jnp.dtype(cd).name)
+    return why
 
 
-def _grouped(x, weights, ids, order, counts, gate, up, down, rows, cd):
+def _grouped(x, weights, ids, order, counts, gate, up, down, rows, cd,
+             kernel=False):
     """The held assignments' sum by grouped products over ``rows`` rows;
     needs ``sum(counts) <= rows``.  Rows beyond the held assignments carry
-    weight 0 and ride in the last expert's group."""
+    weight 0: the ``kernel``s skip them, ``ragged_dot`` runs them in the
+    last expert's group."""
     tokens, top_k = ids.shape
     n = gate.shape[0]
     total = jnp.sum(counts)
     first = order[:rows]
     token = first // top_k
     w = jnp.where(jnp.arange(rows) < total, weights.reshape(-1)[first], 0.0)
-    sizes = counts.at[n - 1].add(rows - total)
-    y = _expert_mlp_grouped(x[token], gate, up, down, sizes, cd)
+    if kernel:
+        from sparknet_tpu.ops.pallas_grouped_matmul import grouped_matmul
+
+        dot = lambda a, b: grouped_matmul(  # noqa: E731
+            a.astype(cd), b.astype(cd), counts)
+    else:
+        sizes = counts.at[n - 1].add(rows - total)
+        dot = lambda a, b: jax.lax.ragged_dot(  # noqa: E731
+            a.astype(cd), b.astype(cd), sizes, preferred_element_type=F32)
+    xs = x[token]
+    y = dot(jax.nn.silu(dot(xs, gate)) * dot(xs, up), down)
     return jnp.zeros((tokens, x.shape[1]), F32).at[token].add(y * w[:, None])
 
 
@@ -139,20 +173,22 @@ def held_experts(x, weights, ids, order, counts, gate, up, down, *, lo: int,
     When the batch routes more than ``fast_rows`` assignments to the held
     experts, the same grouped products run over chunks of tokens small
     enough that every assignment a chunk could make fits in ``fast_rows``
-    rows: exact, ``tokens * min(top_k, n) / fast_rows`` times the work."""
+    rows: exact, ``tokens * min(top_k, n) / fast_rows`` times the work,
+    in ``ragged_dot`` (``_grouped_path`` decides the fast path's products)."""
     cd = compute_dtype or F32
     tokens, top_k = ids.shape
     n = gate.shape[0]
     most = min(top_k, n)  # held assignments one token can make
-    rows = max(fast_rows, most)
-    if rows >= tokens * most:
+    rows = min(max(fast_rows, most), tokens * most)
+    kernel = not _grouped_path(rows, x.shape[1], gate.shape[2], cd)
+    if rows == tokens * most:
         return _grouped(x, weights, ids, order, counts, gate, up, down,
-                        tokens * most, cd)
+                        rows, cd, kernel)
     chunk = max(c for c in range(1, rows // most + 1) if tokens % c == 0)
 
     def fast(_):
         return _grouped(x, weights, ids, order, counts, gate, up, down,
-                        rows, cd)
+                        rows, cd, kernel)
 
     @jax.checkpoint
     def one_chunk(args):

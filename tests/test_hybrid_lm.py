@@ -856,7 +856,11 @@ def test_lm_app_trains_the_hybrid_model_from_a_configuration_file(tmp_path):
     assert tm is not None and tm.lm_tokens.value == 3 * 2 * 2 * 2 * 24
     per_token = [tm.lm_held_assignments.labels(str(i)).value for i in range(4)]
     skew = [tm.lm_held_load_skew.labels(str(i)).value for i in range(4)]
-    # 8 of 16 experts held, top-4: two assignments a token expected
+    used = [tm.lm_grouped_rows_used.labels(str(i)).value for i in range(4)]
+    # 8 of 16 experts held, top-4: two assignments a token expected, half the
+    # grouped path's rows (all of them here: ROWS_SLACK x 2 x 48 tokens is
+    # more than the 4 x 48 a batch can send, so it holds those)
     assert all(0.5 < x < 3.5 for x in per_token) and all(x >= 1 for x in skew)
+    assert all(abs(u - x / 4) < 1e-6 for u, x in zip(used, per_token))
     with pytest.raises(SystemExit, match="--sp 1"):
         lm_app.main(["--model_config", str(path), "--sp", "2"])

@@ -24,6 +24,7 @@ from sparknet_tpu.ops import (
     lm_loss,
     pallas_attention,
     pallas_delta_rule,
+    pallas_grouped_matmul,
     pallas_lm_loss,
     sparse_attention,
 )
@@ -241,3 +242,31 @@ def test_no_reduce_window_over_the_vocabulary(one_chip, monkeypatch):
     for kernel in ("lm_loss_forward", "lm_loss_backward"):
         assert len(re.findall(rf"%{kernel}[.\d]* = .* custom-call\(", text)) == 1
     assert "reduce-window" not in text
+
+
+# the held experts' grouped products (``ops/pallas_grouped_matmul.py``) with
+# their backward, at each sequence cell's grouped-path rows x hidden 2,048 x
+# experts of 512 / 1,536 / 768 (qwen3next-, lfm2moe-, keye2-, kanana2-train):
+# gate / up (2,048 -> F) and down (F -> 2,048).  What is settled here: the
+# blocks fit VMEM, Mosaic takes the transposed operands of ``dlhs`` and
+# ``drhs``, and no float32 array of ``(rows, width)`` or ``(n, K, N)`` is a
+# temporary (the smallest, 16,384 x 512 x 4 bytes, is 32 MiB)
+@pytest.mark.parametrize("rows, f, n", [
+    (20480, 512, 32), (16384, 1536, 8), (32768, 768, 16), (24576, 768, 16)])
+def test_grouped_matmul_kernels_compile_for_the_v5e(one_chip, rows, f, n):
+    shape = lambda *s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+
+    def gradients(lhs, rhs, sizes, dout):
+        out, vjp = jax.vjp(lambda a, b: pallas_grouped_matmul.grouped_matmul(
+            a, b, sizes, interpret=False), lhs, rhs)
+        return out, vjp(dout)
+
+    for k, width in ((2048, f), (f, 2048)):
+        compiled = jax.jit(gradients).lower(
+            shape(rows, k), shape(n, k, width), shape(n, dtype=jnp.int32),
+            shape(rows, width, dtype=jnp.float32)).compile()
+        text = compiled.as_text()
+        assert all(name in text for name in (
+            "grouped_matmul", "grouped_matmul_dlhs", "grouped_matmul_drhs"))
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
