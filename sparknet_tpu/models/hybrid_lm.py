@@ -40,10 +40,27 @@ positions, head counts, router and head are VALUES read from a published
   WITHOUT Qwen3-Next's output gate; plain RMSNorm, an untied head; seeded
   weights start as KeyeVL2's, an all-attention stack too.
 
+- ``laguna`` (Laguna-XS.2): every layer gated grouped attention (the
+  ``gated_attention`` layout and gate, RMSNorm on q and k), full causal at
+  the ``full_attention`` entries of ``layer_types`` and over the last
+  ``sliding_window`` keys at the ``sliding_attention`` ones
+  (``window_attention``); the query heads (``num_attention_heads_per_layer``)
+  and the rotary positions (``rope_parameters`` a layer type: rotate-half on
+  ``partial_rotary_factor`` of each head, at plain frequencies or YaRN's,
+  ``yarn_inv_freq``) are the layer's own; a dense gated MLP where
+  ``mlp_layer_types`` says ``dense`` and routed experts elsewhere (sigmoid
+  scores, top-k on ``scores + bias`` as DeepSeek-V3's, renormalised with
+  1e-20, times ``moe_routed_scaling_factor``) beside one shared expert WITHOUT
+  an output gate; plain RMSNorm, an untied head; seeded weights start as
+  KeyeVL2's, an all-attention stack too.
+
 ``describe`` turns a file's keys into one description: a mixer kind a
 layer (``MIXERS``: ``gated_delta_net``, ``gated_attention``, ``short_conv``,
-``attention``, ``dsa_attention``, ``mla_attention``), a feed-forward kind a
-layer (``dense`` or ``moe``), and the values the equations take.  Nothing below it asks which
+``attention``, ``dsa_attention``, ``mla_attention``, ``window_attention``),
+a feed-forward kind a layer (``dense`` or ``moe``), an attention layer's
+query heads, rotary positions and window (``heads``, ``rope``, ``window``:
+a value a layer; a family with one of each fills them from its scalars),
+and the values the equations take.  Nothing below it asks which
 family it builds.
 Beside the published keys, three of this system's own:
 
@@ -100,6 +117,7 @@ attention: ``q_proj`` head-major, each head ``[q | gate]``.  Short
 convolution: ``in_proj`` columns ``[B | C | u]``.  Selected-key attention:
 the plain attention's six blobs, then the indexer's ``index_q`` (heads
 contiguous), ``index_k``, its LayerNorm's weight and bias, ``index_w``.
+Windowed attention: the gated attention's.
 Latent attention: ``q_proj`` ``(E, H (nope + rope))`` head-major, each head
 ``[nope | rope]``; ``kv_a_proj`` ``(E, kv_lora_rank + rope)``, columns
 ``[latent | the one rope key]``; the latent's RMSNorm weight
@@ -110,6 +128,7 @@ head-major, each head ``[k_nope | v]``; ``o_proj`` ``(H v, E)``.
 from __future__ import annotations
 
 import json
+from functools import partial
 from typing import Dict, List, Tuple
 
 import jax
@@ -188,6 +207,31 @@ DEEPSEEK_V3_ONLY = (
 )
 # the renormalisation of DeepSeek-V3's top-k weights: ``w / (sum(w) + 1e-20)``
 DEEPSEEK_V3_TOPK_EPS = 1e-20
+# and of a laguna one (``rope_parameters``: ``ROPE_KEYS`` a layer type, and
+# ``YARN_KEYS`` beside them where its ``rope_type`` is ``yarn``)
+LAGUNA_KEYS = (
+    "vocab_size", "hidden_size", "num_hidden_layers", "layer_types",
+    "num_attention_heads_per_layer", "num_key_value_heads", "head_dim",
+    "rope_parameters", "partial_rotary_factor", "sliding_window",
+    "rms_norm_eps", "mlp_layer_types", "intermediate_size", "num_experts",
+    "num_experts_per_tok", "moe_intermediate_size",
+    "shared_expert_intermediate_size", "moe_routed_scaling_factor",
+)
+ROPE_KEYS = ("rope_type", "rope_theta")
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "attention_factor")
+# what a laguna file may say of the mechanisms this class has not (as
+# ``DEEPSEEK_V3_ONLY``)
+LAGUNA_ONLY = (
+    ("gating", True, "attention without the elementwise output gate"),
+    ("moe_apply_router_weight_on_input", False,
+     "the router's weights on the experts' input"),
+    ("attention_bias", False, "biases on the attention's projections"),
+    ("tie_word_embeddings", False, "a head tied to the embedding"),
+    ("moe_router_logit_softcapping", 0, "a soft cap on the router's logits"),
+)
+LAGUNA_LAYERS = {"full_attention": "gated_attention",
+                 "sliding_attention": "window_attention"}
 # a mixer kind's scope type; its blobs and its function are the model's
 # ``_mixer_shapes`` and ``_<kind>`` (``dsa_attention``: ``_dsa_mixer``, which
 # opens the scopes ``DSA_SCOPES`` beside it)
@@ -195,6 +239,7 @@ MIXERS = {
     "gated_delta_net": "GatedDeltaNet", "gated_attention": "GatedAttention",
     "short_conv": "ShortConv", "attention": "Attention",
     "dsa_attention": "DSAAttention", "mla_attention": "MLAAttention",
+    "window_attention": "WindowAttention",
 }
 DSA_SCOPES = ("DSAIndexer", "DSASelect", "DSAAttention", "DSAIndexerLoss")
 # a latent-attention layer's (``_mla_mixer``)
@@ -345,19 +390,92 @@ def _describe_deepseek_v3(config: Dict, name: str) -> Dict:
     )
 
 
+def _rope_of(params: Dict, head_dim: int, partial: float, name: str):
+    """A layer type's rotary positions from its ``rope_parameters`` entry:
+    ``{"theta", "dim", "yarn"}``, ``yarn`` None at plain frequencies."""
+    p = _take(params, ROPE_KEYS, name)
+    if p["rope_type"] not in ("default", "yarn"):
+        raise ValueError(f"{name}: rope_type={p['rope_type']!r} is not "
+                         "supported; only 'default' or 'yarn'")
+    dim = int(head_dim * params.get("partial_rotary_factor", partial))
+    if dim % 2 or not 0 < dim <= head_dim:
+        raise ValueError(f"{name}: a rotary part of {dim} of a head of "
+                         f"{head_dim} is not supported")
+    yarn = None
+    if p["rope_type"] == "yarn":
+        yarn = {k: float(v) for k, v in _take(params, YARN_KEYS, name).items()}
+    return {"theta": float(p["rope_theta"]), "dim": dim, "yarn": yarn}
+
+
+def _describe_laguna(config: Dict, name: str) -> Dict:
+    c = _take(config, LAGUNA_KEYS, name)
+    for key, taken, mechanism in LAGUNA_ONLY:
+        if config.get(key, taken) != taken:
+            raise ValueError(
+                f"{name}: {key}={config[key]!r} is not supported "
+                f"({mechanism}); only {key}={taken!r}")
+    depth = c["num_hidden_layers"]
+    types, ffns = list(c["layer_types"]), list(c["mlp_layer_types"])
+    heads = tuple(int(h) for h in c["num_attention_heads_per_layer"])
+    if len(types) != depth or set(types) - set(LAGUNA_LAYERS):
+        raise ValueError(f"{name}: layer_types must list {depth} of "
+                         f"{sorted(LAGUNA_LAYERS)}")
+    if len(ffns) != depth or set(ffns) - {"dense", "sparse"}:
+        raise ValueError(f"{name}: mlp_layer_types must list {depth} of "
+                         "['dense', 'sparse']")
+    if len(heads) != depth or any(h % c["num_key_value_heads"] for h in heads):
+        raise ValueError(
+            f"{name}: num_attention_heads_per_layer={list(heads)} must list "
+            f"{depth} head counts that divide by num_key_value_heads="
+            f"{c['num_key_value_heads']}")
+    rope = {t: _rope_of(c["rope_parameters"].get(t, {}), c["head_dim"],
+                        c["partial_rotary_factor"], f"{name} rope_parameters"
+                        f"[{t!r}]") for t in set(types)}
+    return dict(
+        c,
+        mixers=tuple(LAGUNA_LAYERS[t] for t in types),
+        ffns=tuple("dense" if f == "dense" else "moe" for f in ffns),
+        heads=heads, rope=tuple(rope[t] for t in types),
+        window=tuple(c["sliding_window"] if t == "sliding_attention" else None
+                     for t in types),
+        # the kernels hand their output over in the compute dtype
+        # (``causal_gqa_attention``'s ``out_dtype``)
+        attention_out_dtype=None,
+        eps=c["rms_norm_eps"], zero_centred_norm=False,
+        shared_expert_gate=False,
+        # an all-attention stack: as ``_describe_keye_vl2``'s, and why
+        init_std={"embed": 1.0, "out": 0.02 * (2 * depth) ** -0.5},
+        router_scores="sigmoid", expert_bias=True,
+        routed_scaling_factor=float(c["moe_routed_scaling_factor"]),
+        topk_eps=DEEPSEEK_V3_TOPK_EPS,
+        expert_bias_update_rate=float(
+            config.get("expert_bias_update_rate", 0.0)),
+        tied=False,
+    )
+
+
 DESCRIBERS = {"qwen3_next": _describe_qwen3_next, "lfm2_moe": _describe_lfm2_moe,
               "KeyeVL2": _describe_keye_vl2,
-              "deepseek_v3": _describe_deepseek_v3}
+              "deepseek_v3": _describe_deepseek_v3, "laguna": _describe_laguna}
 
 
 def describe(config: Dict, name: str = "HybridMoELM") -> Dict:
     """The description ``HybridMoELM`` builds from, by the file's
-    ``model_type`` (``qwen3_next`` where it has none)."""
+    ``model_type`` (``qwen3_next`` where it has none).  A family whose
+    attention layers are all alike gets its ``heads``, ``rope`` and
+    ``window`` a layer from its scalars here."""
     family = config.get("model_type", "qwen3_next")
     if family not in DESCRIBERS:
         raise ValueError(
             f"{name}: model_type {family!r} is none of {sorted(DESCRIBERS)}")
-    return DESCRIBERS[family](config, name)
+    c = DESCRIBERS[family](config, name)
+    if "heads" not in c:
+        depth = c["num_hidden_layers"]
+        c.update(heads=(c["num_attention_heads"],) * depth,
+                 rope=({"theta": c["rope_theta"], "dim": c["rotary_dim"],
+                        "yarn": None},) * depth,
+                 window=(None,) * depth, attention_out_dtype=F32)
+    return c
 
 
 def rms_norm(x, w, eps, zero_centred: bool):
@@ -368,14 +486,45 @@ def rms_norm(x, w, eps, zero_centred: bool):
         (1.0 + w) if zero_centred else w)
 
 
-def rotary(x, theta: float, rotary_dim: int):
+def yarn_inv_freq(theta: float, dim: int, factor: float,
+                  original_max_position_embeddings: float, beta_fast: float,
+                  beta_slow: float, **_) -> np.ndarray:
+    """YaRN's frequencies of a rotary part ``dim`` wide (arXiv:2309.00071,
+    as the public ``_compute_yarn_parameters`` computes them), float64:
+    ``f_e = theta^(-2i / dim)``, ``f_i = f_e / factor``; the pairs that turn
+    fewer than ``beta_slow`` times over the original positions interpolate,
+    those that turn more than ``beta_fast`` times keep ``f_e``, and a linear
+    ramp over the pair index joins them between ``low = floor(d(beta_fast))``
+    and ``high = ceil(d(beta_slow))``, ``d(r) = dim ln(original / (2 pi r))
+    / (2 ln theta)``."""
+    f_e = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair(rotations):
+        return dim * np.log(original_max_position_embeddings / (
+            rotations * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(pair(beta_fast)), 0)
+    high = min(np.ceil(pair(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return f_e / factor * ramp + f_e * (1 - ramp)
+
+
+def rotary(x, theta: float, rotary_dim: int, yarn=None):
     """Rotate-half on the first ``rotary_dim`` of each head of ``(B, T, H,
-    D)``; the rest passes through."""
+    D)``; the rest passes through.  ``yarn``: ``YARN_KEYS`` of a layer's
+    ``rope_parameters``, for YaRN's frequencies and its ``attention_factor``
+    on cos and sin."""
     half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=F32) * 2.0 / rotary_dim)
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=F32) * 2.0 / rotary_dim)
+    else:
+        inv_freq = jnp.asarray(
+            yarn_inv_freq(theta, rotary_dim, **yarn), F32)
     angle = jnp.arange(x.shape[1], dtype=F32)[:, None] * inv_freq
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.sin(angle)[None, :, None, :]
+    if yarn is not None:
+        cos, sin = (a * yarn["attention_factor"] for a in (cos, sin))
     x1, x2 = x[..., :half], x[..., half:rotary_dim]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary_dim:]], -1)
@@ -413,7 +562,7 @@ class HybridMoELM:
             raise ValueError(
                 f"experts_held={list(self.experts_held)} is not a range of "
                 f"the {c['num_experts']} experts")
-        if c["num_attention_heads"] % c["num_key_value_heads"] or (
+        if any(h % c["num_key_value_heads"] for h in c["heads"]) or (
                 "gated_delta_net" in c["mixers"]
                 and c["linear_num_value_heads"] % c["linear_num_key_heads"]):
             raise ValueError("query / value heads must divide by K/V / key heads")
@@ -448,19 +597,23 @@ class HybridMoELM:
     def is_attention_layer(self, i: int) -> bool:
         """Whether layer ``i`` mixes by softmax attention, gated or plain."""
         return self.config["mixers"][i] in (
-            "gated_attention", "attention", "dsa_attention", "mla_attention")
+            "gated_attention", "attention", "dsa_attention", "mla_attention",
+            "window_attention")
 
     # ------------------------------------------------------------------
-    def _mixer_shapes(self, kind: str):
-        """A mixer's blobs as ``(shape, how it is initialised)``."""
+    def _mixer_shapes(self, i: int):
+        """Layer ``i``'s mixer's blobs as ``(shape, how it is
+        initialised)``."""
         c = self.config
+        kind = c["mixers"][i]
         e = c["hidden_size"]
-        hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
-                      c["head_dim"])
+        hq, hkv, d = c["heads"][i], c["num_key_value_heads"], c["head_dim"]
         # ``out``: a matrix that writes to the residual stream
         w, norm, out = "matrix", "norm", "out"
-        if kind in ("gated_attention", "attention", "dsa_attention"):
-            q_width = (2 if kind == "gated_attention" else 1) * hq * d
+        if kind in ("gated_attention", "attention", "dsa_attention",
+                    "window_attention"):
+            gated = kind in ("gated_attention", "window_attention")
+            q_width = (2 if gated else 1) * hq * d
             blobs = [((e, q_width), w), ((e, hkv * d), w), ((e, hkv * d), w),
                      ((d,), norm), ((d,), norm), ((hq * d, e), out)]
             if kind == "dsa_attention":
@@ -495,7 +648,7 @@ class HybridMoELM:
         plan = [("embed", [((v, e), "embed")])]
         for i in range(c["num_hidden_layers"]):
             plan.append((f"l{i}_n1", [((e,), norm)]))
-            plan.append((f"l{i}_mixer", self._mixer_shapes(c["mixers"][i])))
+            plan.append((f"l{i}_mixer", self._mixer_shapes(i)))
             plan.append((f"l{i}_n2", [((e,), norm)]))
             if c["ffns"][i] == "dense":
                 plan.append((f"l{i}_mlp", mlp(c["intermediate_size"])))
@@ -571,15 +724,14 @@ class HybridMoELM:
         return rms_norm(
             x, w, self.config["eps"], self.config["zero_centred_norm"])
 
-    def _qkv(self, x, blobs, gated: bool = False):
-        """The projections as heads, RMSNorm over each head of ``q`` and
-        ``k``, rotary: ``(q, k, v, gate or None)``."""
+    def _qkv(self, x, blobs, i: int, gated: bool = False):
+        """Layer ``i``'s projections as heads, RMSNorm over each head of
+        ``q`` and ``k``, its rotary: ``(q, k, v, gate or None)``."""
         q_proj, k_proj, v_proj, q_norm, k_norm = blobs[:5]
         c = self.config
         b, t, _ = x.shape
-        hq, hkv, d = (c["num_attention_heads"], c["num_key_value_heads"],
-                      c["head_dim"])
-        theta, rotary_dim = c["rope_theta"], c["rotary_dim"]
+        hq, hkv, d = c["heads"][i], c["num_key_value_heads"], c["head_dim"]
+        rope = c["rope"][i]
         gate = None
         if gated:
             qg = self._dot(x, q_proj).reshape(b, t, hq, 2 * d)
@@ -588,14 +740,21 @@ class HybridMoELM:
             q = self._dot(x, q_proj).reshape(b, t, hq, d)
         k = self._dot(x, k_proj).reshape(b, t, hkv, d)
         v = self._dot(x, v_proj).reshape(b, t, hkv, d)
-        q = rotary(self._norm(q, q_norm), theta, rotary_dim)
-        k = rotary(self._norm(k, k_norm), theta, rotary_dim)
+        q = rotary(self._norm(q, q_norm), rope["theta"], rope["dim"],
+                   rope["yarn"])
+        k = rotary(self._norm(k, k_norm), rope["theta"], rope["dim"],
+                   rope["yarn"])
         return q, k, v, gate
 
-    def _softmax_attention(self, x, blobs, gated: bool):
+    def _softmax_attention(self, x, blobs, i: int, gated: bool):
+        """Layer ``i``'s attention over its window, if it has one, from the
+        normed input to the output projection."""
+        c = self.config
         b, t, _ = x.shape
-        q, k, v, gate = self._qkv(x, blobs, gated)
-        attn = causal_gqa_attention(q, k, v, compute_dtype=self.compute_dtype)
+        q, k, v, gate = self._qkv(x, blobs, i, gated)
+        attn = causal_gqa_attention(
+            q, k, v, compute_dtype=self.compute_dtype, window=c["window"][i],
+            out_dtype=c["attention_out_dtype"])
         # gated with heads side by side, (B, T, Hq D), as the kernels write
         # the output and o_proj reads it: heads apart, (.., Hq, D) tiles
         # otherwise, and the float32 output and its cotangent are each
@@ -605,11 +764,13 @@ class HybridMoELM:
             attn = attn * jax.nn.sigmoid(gate.reshape(b, t, -1).astype(F32))
         return self._dot(attn, blobs[5], F32)
 
-    def _gated_attention(self, x, blobs):
-        return self._softmax_attention(x, blobs, gated=True)
+    def _gated_attention(self, x, blobs, i: int):
+        return self._softmax_attention(x, blobs, i, gated=True)
 
-    def _attention(self, x, blobs):
-        return self._softmax_attention(x, blobs, gated=False)
+    _window_attention = _gated_attention
+
+    def _attention(self, x, blobs, i: int):
+        return self._softmax_attention(x, blobs, i, gated=False)
 
     def _dsa_indexer(self, u, blobs):
         """The indexer's three projections of ``u``: ``qI (B, T, J, Di)``
@@ -632,12 +793,12 @@ class HybridMoELM:
         w = self._dot(u, index_w, F32) * (j ** -0.5 * di ** -0.5)
         return qi.astype(cd), ki.astype(cd), w
 
-    def _dsa_attention(self, x, blobs, mask):
-        """Plain grouped attention over the keys ``mask`` keeps; beside the
-        output, what the alignment loss reads of it: the scaled queries, the
-        keys and the rows' log-sum-exp."""
+    def _dsa_attention(self, x, blobs, mask, i: int = 0):
+        """Layer ``i``'s plain grouped attention over the keys ``mask``
+        keeps; beside the output, what the alignment loss reads of it: the
+        scaled queries, the keys and the rows' log-sum-exp."""
         b, t, _ = x.shape
-        q, k, v, _ = self._qkv(x, blobs)
+        q, k, v, _ = self._qkv(x, blobs, i)
         q = sparse_attention.scaled_queries(q, self.compute_dtype)
         k = k.astype(q.dtype)
         attn, lse = sparse_attention.masked_attention(
@@ -679,7 +840,8 @@ class HybridMoELM:
         qi, ki, w, mask = self._dsa_select(i, normed, blobs[6:])
         with jax.named_scope(f"DSAAttention:l{i}_mixer"):
             out, q, k, lse = jax.checkpoint(
-                self._dsa_attention, policy=MIXER_KEEPS)(normed, blobs[:6], mask)
+                partial(self._dsa_attention, i=i), policy=MIXER_KEEPS)(
+                    normed, blobs[:6], mask)
         with jax.named_scope(f"DSAIndexerLoss:l{i}_align"):
             align = sparse_attention.alignment_loss(
                 qi, w, ki, q, k, lse, mask, block_q=c["index_block"]) / (b * t)
@@ -815,10 +977,12 @@ class HybridMoELM:
         elif kind == "mla_attention":
             h = x + self._mla_mixer(i, normed, params[f"l{i}_mixer"])
         else:
+            mixer = getattr(self, "_" + kind)
+            if self.is_attention_layer(i):  # the layer's heads, rotary, window
+                mixer = partial(mixer, i=i)
             with jax.named_scope(f"{MIXERS[kind]}:l{i}_mixer"):
-                h = x + jax.checkpoint(
-                    getattr(self, "_" + kind), policy=MIXER_KEEPS)(
-                        normed, params[f"l{i}_mixer"])
+                h = x + jax.checkpoint(mixer, policy=MIXER_KEEPS)(
+                    normed, params[f"l{i}_mixer"])
         b, t, e = h.shape
         with jax.named_scope(f"RMSNorm:l{i}_n2"):
             normed = self._norm(h, params[f"l{i}_n2"][0]).astype(cd)

@@ -153,10 +153,12 @@ KERNEL_BLOCK_K = 512
 
 
 def kernel_block_q(group: int, d: int, cd, block_k: int) -> int:
-    """The kernels' query block: ``KERNEL_Q_BYTES`` of a K/V head's group,
-    at least a row of lanes and at most a key block."""
+    """The kernels' query block: ``KERNEL_Q_BYTES`` of a K/V head's group
+    in whole rows of lanes (a group of 6 heads of 128 in float32 would be
+    341 rows, which Mosaic refuses), at least one and at most a key
+    block."""
     rows = KERNEL_Q_BYTES // (group * d * jnp.dtype(cd).itemsize)
-    return min(max(rows, 128), block_k)
+    return min(max(rows // 128 * 128, 128), block_k)
 
 
 def lowerable() -> bool:
@@ -169,11 +171,12 @@ def lowerable() -> bool:
 
 
 def _path(t: int, hq: int, hkv: int, d: int, cd, block_q: int,
-          segments: int, rope: int = 0):
+          segments: int, rope: int = 0, window=None):
     """Which of the two paths a causal attention of these shapes takes, and
     the ``attention_path`` instant that says so: ``(why not the kernels, ""
     where they run; block_q; block_k)``.  ``rope``: the width of a second
-    score term (``causal_mla_attention``), whose values stay ``d`` wide."""
+    score term (``causal_mla_attention``), whose values stay ``d`` wide;
+    ``window``: the keys a query sees (``causal_gqa_attention``)."""
     from sparknet_tpu.ops import pallas_attention  # see lowerable
 
     backend = jax.default_backend()
@@ -198,30 +201,35 @@ def _path(t: int, hq: int, hkv: int, d: int, cd, block_q: int,
         # 2,048 x 1,024 (PERF.md section 6, PR 37)
         block_k = min(KERNEL_BLOCK_K * 256 // d if rope else KERNEL_BLOCK_K, t)
         block_q = kernel_block_q(hq // hkv, d + rope, cd, block_k)
-        met = pallas_attention.blocks_met(t, t, block_q, block_k)
+        met = pallas_attention.blocks_met(t, t, block_q, block_k, window)
     obs.instant("attention_path", cat="kernel",
                 path="xla" if why else "pallas", why=why, backend=backend,
                 t=t, hq=hq, hkv=hkv, d=d, d_qk=d + rope, d_v=d,
-                dtype=cd.name, block_q=block_q,
+                dtype=cd.name, block_q=block_q, window=window,
                 block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
     return why, block_q, block_k
 
 
 def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
-                         compute_dtype=None):
+                         compute_dtype=None, window=None,
+                         out_dtype=jnp.float32):
     """Causal softmax attention with grouped K/V heads.
 
     ``q``: ``(B, T, Hq, D)``; ``k``, ``v``: ``(B, T, Hkv, D)``, each K/V head
     serving ``Hq // Hkv`` query heads (never repeated in memory).  Scores
     and softmax are float32; the products take their operands in
     ``compute_dtype``, ``q`` scaled by ``D ** -0.5`` before it is cast.
-    Returns ``(B, T, Hq, D)`` float32.
+    ``window``: query ``i`` sees the keys ``i - window < j <= i`` alone.
+    Returns ``(B, T, Hq, D)`` in ``out_dtype`` (``None``: the compute
+    dtype, as ``causal_mla_attention`` hands it over, and why).
 
     Where Pallas lowers and ``pallas_attention.accepts`` the shapes, the
     K/V-blocked flash kernels (``ops/pallas_attention.py``): no score leaves
     VMEM, the key blocks above the diagonal are neither computed nor fetched
-    (``(n + 1) / 2n`` of the score matrix at ``n`` key blocks), and the
-    backward is the kernels' own.  Elsewhere ``_blockwise_gqa``, the same
+    (``(n + 1) / 2n`` of the score matrix at ``n`` key blocks; under a
+    window the key blocks before it neither, so a query block meets the
+    band alone), and the backward is the kernels' own.  Elsewhere
+    ``_blockwise_gqa``, the same
     arithmetic in XLA, the fallback and the oracle the kernels are tested
     against; ``block_q`` and ``segments`` are its.  No switch picks between
     them: an ``obs`` instant names the path at each trace."""
@@ -230,13 +238,16 @@ def causal_gqa_attention(q, k, v, *, block_q: int = 512, segments: int = 4,
     b, t, hq, d = q.shape
     hkv = k.shape[2]
     cd = jnp.dtype(compute_dtype or jnp.float32)
-    why, block_q, block_k = _path(t, hq, hkv, d, cd, block_q, segments)
+    why, block_q, block_k = _path(t, hq, hkv, d, cd, block_q, segments,
+                                  window=window)
     q = (q.astype(jnp.float32) * d ** -0.5).astype(cd)
+    out_dtype = out_dtype or cd
     if why:
-        return _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments)
+        return _blockwise_gqa(q, k.astype(cd), v.astype(cd), block_q, segments,
+                              window=window).astype(out_dtype)
     return pallas_attention.flash_attention(
         q, k.astype(cd), v.astype(cd), causal=True, block_q=block_q,
-        block_k=block_k, scale=1.0, out_dtype=jnp.float32)
+        block_k=block_k, scale=1.0, out_dtype=out_dtype, window=window)
 
 
 def causal_mla_attention(q_nope, q_rope, k_nope, k_rope, v, *,
@@ -370,13 +381,13 @@ def unpack_mask(packed, s: int):
     return jnp.concatenate(pieces, axis=-1)[..., :s].astype(bool)
 
 
-def _blockwise_gqa(q, k, v, block_q, segments, keep=None):
+def _blockwise_gqa(q, k, v, block_q, segments, keep=None, window=None):
     """``causal_gqa_attention`` in XLA, in query blocks; ``q`` comes scaled
     and all three in the compute dtype.  The query blocks run one after
     another (``lax.map`` over ``jax.checkpoint``ed blocks), so no more than
     ``block_q x T`` scores a head exist at once, forward or backward; with
     four runs (``by_run``) 5/8 of the full score matrix is computed where
-    causality needs 1/2.
+    causality needs 1/2.  A ``window`` is a mask on the same blocks.
 
     With ``keep`` (``(B, T, words_of(T))`` bits, ``pack_mask``'s layout, a
     subset of the causal keys, unpacked a block at a time) the softmax runs
@@ -404,6 +415,8 @@ def _blockwise_gqa(q, k, v, block_q, segments, keep=None):
             kept = jnp.tile(unpack_mask(bits[0], keys), (1, group, 1))[:, None]
         else:
             kept = first + row >= jnp.arange(keys)[None, :]
+            if window:
+                kept = kept & (first + row - jnp.arange(keys)[None, :] < window)
         # a finite mask and the softmax written out, normalised after the
         # second product: on the v5e `where(.., -inf)` + `jax.nn.softmax`
         # in float32 runs 16 x slower than this (49 ms against 3 ms a block

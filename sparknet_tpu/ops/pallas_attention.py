@@ -22,6 +22,16 @@ Causality by blocks: a key block wholly above the diagonal is neither
 computed (``pl.when``) nor fetched (its index map stays on the last block
 needed); the mask is applied on the blocks the diagonal crosses only.
 
+A window (``flash_attention(.., window=W)``: query ``i`` sees the keys ``i -
+W < j <= i``, causal self-attention) is a static specialisation too
+(``_Shape.window``, 0 without one): the grid's inner axis spans the BAND, the
+most key blocks a query block meets (``band``; of the dk/dv pass, the most
+query blocks a key block is seen by), and walks them from the first the
+window reaches (``_first_key_block``; ``_last_query_block``), so the blocks
+outside the band are not grid steps at all; a step past the band's end is
+skipped and its index map stays on the band's last block.  The mask is the
+window's and the diagonal's where either crosses a block.
+
 A keep-mask (``masked_flash_attention``: the selected-key attention of
 ``ops/sparse_attention.py``) is a static specialisation of the same three
 kernels: one more operand, the selection as bits in ``ops/attention.
@@ -129,7 +139,9 @@ class _Shape(NamedTuple):
     group * d)``, ``k``, ``v``: ``(B, Tk, Hkv * d)``, both lengths whole
     blocks; ``tk`` counts the real keys.  ``words``: the words a row of the
     keep-mask, 0 without one.  ``rope``: the width of a second score term,
-    0 without one (then ``group`` is 1)."""
+    0 without one (then ``group`` is 1).  ``window``: the keys a query sees,
+    itself the last, 0 for all before it (then queries and keys are one
+    sequence, ``tk`` long)."""
     causal: bool
     scale: float
     group: int
@@ -141,6 +153,7 @@ class _Shape(NamedTuple):
     interpret: bool
     words: int
     rope: int
+    window: int
 
 
 def _mm(a, b, dims):
@@ -166,14 +179,44 @@ def _whole(i, j, q_off, k_off, block_q, block_k):
     return k_off + (j + 1) * block_k - 1 <= q_off + i * block_q
 
 
-def blocks_met(tq: int, tk: int, block_q: int, block_k: int):
-    """``(computed, total)`` key blocks of causal attention over ``tq``
-    queries end-aligned to ``tk`` keys: what the kernels' grid runs of what
-    it spans."""
+def _reached(i, j, q_off, k_off, block_q, block_k, window):
+    """Some key of the block lies within ``window`` of some query of it."""
+    return k_off + (j + 1) * block_k - 1 > q_off + i * block_q - window
+
+
+def _inside(i, j, q_off, k_off, block_q, block_k, window):
+    """Every key of the block lies within ``window`` of every query of it."""
+    return k_off + j * block_k > q_off + (i + 1) * block_q - 1 - window
+
+
+def _met(tq: int, tk: int, block_q: int, block_k: int, window=None):
+    """Which blocks causal attention (within ``window`` where given) over
+    ``tq`` queries end-aligned to ``tk`` keys computes, ``(query blocks, key
+    blocks)`` bool."""
     i = np.arange(-(-tq // block_q))[:, None]
     j = np.arange(-(-tk // block_k))[None, :]
     met = _needed(i, j, tk - tq, 0, block_q, block_k)
+    if window:
+        met = met & _reached(i, j, tk - tq, 0, block_q, block_k, window)
+    return met
+
+
+def blocks_met(tq: int, tk: int, block_q: int, block_k: int, window=None):
+    """``(computed, total)`` key blocks of causal attention (within
+    ``window`` where given) over ``tq`` queries end-aligned to ``tk`` keys:
+    what the kernels' grid runs of the blocks the whole score matrix
+    spans."""
+    met = _met(tq, tk, block_q, block_k, window)
     return int(np.sum(met)), met.size
+
+
+def band(t: int, block_q: int, block_k: int, window: int):
+    """``(key blocks, query blocks)``: the most key blocks a query block
+    meets, and the most query blocks a key block is seen by, under a
+    ``window`` over one sequence of ``t``: the inner grid axis of the
+    forward and dq passes, and of the dk/dv pass."""
+    met = _met(t, t, block_q, block_k, window)
+    return int(met.sum(1).max()), int(met.sum(0).max())
 
 
 def _last_key_block(i, offs, nk, c: _Shape):
@@ -188,12 +231,52 @@ def _first_query_block(j, offs, nq, c: _Shape):
     return jnp.minimum(jax.lax.div(jnp.maximum(reach, 0), c.block_q), nq - 1)
 
 
-def _walk(offs_ref, i, j, nk, c: _Shape, step):
+def _first_key_block(i, offs, c: _Shape):
+    """The first key block within the window of query block ``i``."""
+    reach = offs[0] - offs[1] + i * c.block_q - c.window + 1
+    return jax.lax.div(jnp.maximum(reach, 0), c.block_k)
+
+
+def _last_query_block(j, offs, nq, c: _Shape):
+    """The last query block that key block ``j`` lies within the window of."""
+    reach = offs[1] - offs[0] + (j + 1) * c.block_k - 1 + c.window - 1
+    return jnp.minimum(jax.lax.div(reach, c.block_q), nq - 1)
+
+
+def _blocks_of(c: _Shape):
+    """``(query blocks, key blocks)`` of a windowed call: one sequence."""
+    return -(-c.tk // c.block_q), -(-c.tk // c.block_k)
+
+
+def _banded(offs_ref, outer, inner, key_outer: bool, c: _Shape):
+    """Under a window, the grid's ``(outer, inner)`` step -> ``(query block,
+    key block, in the band)``: the inner step counts from the band's first
+    block."""
+    nq, nk = _blocks_of(c)
+    if key_outer:
+        i = _first_query_block(outer, offs_ref, nq, c) + inner
+        return i, outer, i <= _last_query_block(outer, offs_ref, nq, c)
+    j = _first_key_block(outer, offs_ref, c) + inner
+    return outer, j, j <= _last_key_block(outer, offs_ref, nk, c)
+
+
+def _walk(offs_ref, i, j, nk, c: _Shape, step, in_band=None):
     """``step(masked)`` on block ``(i, j)``: not at all above the diagonal,
     with the mask where the diagonal or the end of the real keys crosses the
     block, without it elsewhere; under a keep-mask with it on every block
-    met."""
+    met; under a window only ``in_band``, with the mask where the window's
+    edge crosses the block too."""
     ragged = c.tk % c.block_k != 0  # the last key block holds padding
+    if c.window:
+        where = (i, j, offs_ref[0], offs_ref[1], c.block_q, c.block_k)
+        whole = jnp.logical_and(
+            _whole(*where), _inside(*where, c.window))
+        if ragged:
+            whole = jnp.logical_and(whole, j < nk - 1)
+        pl.when(jnp.logical_and(in_band, whole))(lambda: step(False))
+        pl.when(jnp.logical_and(in_band, jnp.logical_not(whole)))(
+            lambda: step(True))
+        return
     if not (c.causal or ragged):
         step(False)
         return
@@ -232,8 +315,10 @@ def _keep(offs_ref, bits_ref, i, j, c: _Shape, transposed: bool):
         ahead = key - jax.lax.broadcasted_iota(jnp.int32, one, q_dim)
         keep = None
         if c.causal:
-            keep = ahead <= (offs_ref[0] - offs_ref[1]
-                             + i * c.block_q - j * c.block_k)
+            behind = offs_ref[0] - offs_ref[1] + i * c.block_q - j * c.block_k
+            keep = ahead <= behind
+            if c.window:
+                keep = jnp.logical_and(keep, ahead > behind - c.window)
         if c.tk % c.block_k:
             real = key < c.tk - j * c.block_k
             keep = real if keep is None else jnp.logical_and(keep, real)
@@ -307,8 +392,12 @@ def _fwd_kernel(offs_ref, *refs, c: _Shape):
     (qr_ref, kr_ref), refs = _rope_apart(refs, c, 3, 4)  # the last inputs
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    inner, n_inner, in_band = j, nk, None
+    if c.window:
+        i, j, in_band = _banded(offs_ref, i, j, False, c)
+        nk = _blocks_of(c)[1]
 
-    @pl.when(j == 0)
+    @pl.when(inner == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, MASK, F32)
         l_ref[...] = jnp.zeros(l_ref.shape, F32)
@@ -332,9 +421,9 @@ def _fwd_kernel(offs_ref, *refs, c: _Shape):
             p.astype(v_ref.dtype), v_ref[...], _NN)
         m_ref[...] = m
 
-    _walk(offs_ref, i, j, nk, c, step)
+    _walk(offs_ref, i, j, nk, c, step, in_band)
 
-    @pl.when(j == nk - 1)
+    @pl.when(inner == n_inner - 1)
     def _():
         l = l_ref[...]
         seen = l > 0.0
@@ -362,8 +451,12 @@ def _dq_kernel(offs_ref, *refs, c: _Shape):
     (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref,
      dq_ref, delta_ref, do_scr, lse_scr, delta_scr, dq_scr) = refs
     i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    inner, n_inner, in_band = j, nk, None
+    if c.window:
+        i, j, in_band = _banded(offs_ref, i, j, False, c)
+        nk = _blocks_of(c)[1]
 
-    @pl.when(j == 0)
+    @pl.when(inner == 0)
     def _():
         do = _stacked(do_ref, c)
         delta = jnp.sum(do.astype(F32) * _stacked(o_ref, c).astype(F32),
@@ -388,9 +481,9 @@ def _dq_kernel(offs_ref, *refs, c: _Shape):
         if c.rope:
             dqr_scr[...] += _mm(ds.astype(k.dtype), kr_ref[...], _NN)
 
-    _walk(offs_ref, i, j, nk, c, step)
+    _walk(offs_ref, i, j, nk, c, step, in_band)
 
-    @pl.when(j == nk - 1)
+    @pl.when(inner == n_inner - 1)
     def _():
         _unstack_to(dq_ref, dq_scr[...], c)
         if c.rope:
@@ -406,8 +499,11 @@ def _dkv_kernel(offs_ref, *refs, c: _Shape):
      dk_ref, dv_ref, dk_scr, dv_scr) = refs
     # key block outer, query blocks inner; the scores transposed, keys on rows
     j, i, nq = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    inner, n_inner, in_band = i, nq, None
+    if c.window:
+        i, j, in_band = _banded(offs_ref, j, i, True, c)
 
-    @pl.when(i == 0)
+    @pl.when(inner == 0)
     def _():
         dk_scr[...] = jnp.zeros(dk_scr.shape, F32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, F32)
@@ -427,9 +523,9 @@ def _dkv_kernel(offs_ref, *refs, c: _Shape):
         if c.rope:  # this head's part of the shared key's gradient
             dkr_scr[...] += _mm(ds.astype(q.dtype), qr_ref[...], _NN)
 
-    _walk(offs_ref, i, j, pl.num_programs(2), c, step)
+    _walk(offs_ref, i, j, pl.num_programs(2), c, step, in_band)
 
-    @pl.when(i == nq - 1)
+    @pl.when(inner == n_inner - 1)
     def _():
         dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -486,13 +582,19 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
 
     def q_block(bi, hi, x, y, offs_ref):
         i, j = blocks(x, y)
-        if c.causal and key_outer:  # query blocks before the first: not fetched
+        if c.window and key_outer:  # the band's steps; past its end, its last
+            i = jnp.minimum(_first_query_block(j, offs_ref, nq, c) + i,
+                            _last_query_block(j, offs_ref, nq, c))
+        elif c.causal and key_outer:  # query blocks before the first: not fetched
             i = jnp.maximum(i, _first_query_block(j, offs_ref, nq, c))
         return i
 
     def k_block(bi, hi, x, y, offs_ref):
         i, j = blocks(x, y)
-        if c.causal and not key_outer:  # key blocks after the last: not fetched
+        if c.window and not key_outer:  # as q_block's
+            j = jnp.minimum(_first_key_block(i, offs_ref, c) + j,
+                            _last_key_block(i, offs_ref, nk, c))
+        elif c.causal and not key_outer:  # key blocks after the last: not fetched
             j = jnp.minimum(j, _last_key_block(i, offs_ref, nk, c))
         return j
 
@@ -528,11 +630,14 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
         in_specs = [mask_spec] + in_specs
         operands = (keep, *operands)
     everything = (offs, *operands)
+    inner = nq if key_outer else nk
+    if c.window:  # the band's blocks alone
+        inner = band(c.tk, c.block_q, c.block_k, c.window)[int(key_outer)]
     return pl.pallas_call(
         partial(kernel, c=c),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, hkv, nk, nq) if key_outer else (b, hkv, nq, nk),
+            grid=(b, hkv, nk, inner) if key_outer else (b, hkv, nq, inner),
             in_specs=in_specs,
             out_specs=[specs[x][0] for x, _ in outs],
             scratch_shapes=scratch),
@@ -627,12 +732,13 @@ def _pad_rows(x, block):
 
 
 def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
-            out_dtype, keep=None, rope=None):
+            out_dtype, keep=None, rope=None, window=None):
     """``(o, lse)`` of ``(B, Tq, Hq, D)`` against ``(B, Tk, Hkv, D)``, as
     ``(B, Tq, Hq, D)`` and ``(B, Hq, Tq)``; ``keep``: a keep-mask's bits
     ``(B, Tq, words)`` uint32 (``masked_flash_attention``); ``rope``: a
     second score term's ``(q_rope (B, Tq, Hq, R), k_rope (B, Tk, R))``
-    (``mla_flash_attention``)."""
+    (``mla_flash_attention``); ``window``: the keys a query sees, itself
+    the last (``flash_attention``)."""
     if interpret is None:
         interpret = not lowerable()
     b, tq, hq, d = q.shape
@@ -640,6 +746,11 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not divide by {hkv} K/V heads")
     block_q, block_k = min(block_q, tq), min(block_k, tk)
+    if window is not None and not (causal and tq == tk and keep is None
+                                   and rope is None and window >= 1):
+        raise ValueError(
+            f"a window of {window} wants causal self-attention without a "
+            f"keep-mask or a second score term: T {tq} x {tk}")
     width = 0
     if rope is not None:
         width = rope[0].shape[-1]
@@ -668,7 +779,8 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
         flat = partial(_heads_first, hkv=hkv)
     c = _Shape(bool(causal), float(d ** -0.5 if scale is None else scale),
                hq // hkv, d, tk, block_q, block_k,
-               np.dtype(out_dtype or q.dtype), bool(interpret), words, width)
+               np.dtype(out_dtype or q.dtype), bool(interpret), words, width,
+               int(window or 0))
     o, lse = _flash_core(
         _pad_rows(flat(q), block_q), _pad_rows(flat(k), block_k),
         _pad_rows(flat(v), block_k), offs, keep, rope, c)
@@ -682,7 +794,7 @@ def _attend(q, k, v, offs, causal, block_q, block_k, interpret, scale,
 
 def flash_attention(
     q, k, v, causal: bool = False, block_q: int = 128, interpret=None,
-    *, block_k: int = BLOCK_K, scale=None, out_dtype=None
+    *, block_k: int = BLOCK_K, scale=None, out_dtype=None, window=None
 ):
     """Fused attention on ``q`` (B, T, Hq, D) and ``k``, ``v`` (B, T, Hkv,
     D) — K/V head ``j`` serves query heads ``[j, j + 1) * Hq // Hkv`` —
@@ -691,7 +803,9 @@ def flash_attention(
     accumulation) and grad-pinned against ``jax.grad`` of it.  Any T_q >= 1
     works — ragged lengths are end-padded to whole blocks internally.
     ``scale`` multiplies the scores (default ``D ** -0.5``); the output
-    takes ``out_dtype`` (default: the inputs')."""
+    takes ``out_dtype`` (default: the inputs').  ``window`` (causal
+    self-attention): query ``i`` sees the keys ``i - window < j <= i``, and
+    the key blocks outside that band are neither computed nor fetched."""
     tq, tk = q.shape[1], k.shape[1]
     if tq == 0:
         raise ValueError(
@@ -700,7 +814,7 @@ def flash_attention(
         )
     offs = jnp.asarray([tk - tq, 0], jnp.int32)
     return _attend(q, k, v, offs, causal, block_q, block_k, interpret,
-                   scale, out_dtype)[0]
+                   scale, out_dtype, window=window)[0]
 
 
 def masked_flash_attention(q, k, v, keep, *, block_q: int, block_k: int,

@@ -62,8 +62,11 @@ def planted():
                CELL, "--rehearse", "--seed", "3", "--plant", ""]
     for group in PLANTED:
         command += ["--plant", group]
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    env.pop("XLA_FLAGS", None)
+    # the rehearsal sizes its own virtual devices; its programs are compile
+    # time at these sizes, which LLVM's lowest level halves with the same
+    # verdicts
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_backend_optimization_level=0"}
     proc = subprocess.run(command, cwd=ROOT, env=env, capture_output=True,
                           text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -153,11 +156,13 @@ def test_the_tau50_cell_is_the_tau10_one_at_the_apps_tau():
     assert config == same
     assert traffic["tau"] == 50 and ten["tau"] == 10
     assert {k for k in traffic if traffic[k] != ten.get(k)} == {"tau", "what"}
-    # two of ten cells on four chips: inside the quarter
+    # two cells on four chips: inside the quarter
     cells = files.table()["workloads"]
     assert [w["name"] for w in cells if w["chips"] == 4] == [
         "caffenet-dp4", "caffenet-dp4-tau1"]
-    assert [w["name"] for w in cells[-2:]] == [CELL, "caffenet-train-tau50"]
+    assert len(cells) // 4 >= 2
+    names = [w["name"] for w in cells]
+    assert names.index("caffenet-train-tau50") == names.index(CELL) + 1
     # it reads the accepted metrics without a list, and none of its own
     reported = {m["name"] for m in files.metrics_of(
         "caffenet-train-tau50", "per_layer")}

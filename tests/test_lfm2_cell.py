@@ -49,8 +49,11 @@ def planted():
                CELL, "--rehearse", "--seed", "3", "--plant", ""]
     for group in PLANTED:
         command += ["--plant", group]
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    env.pop("XLA_FLAGS", None)
+    # the rehearsal sizes its own virtual devices; its programs are compile
+    # time at these sizes, which LLVM's lowest level halves with the same
+    # verdicts
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_backend_optimization_level=0"}
     proc = subprocess.run(command, cwd=ROOT, env=env, capture_output=True,
                           text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -163,7 +166,9 @@ def test_cell_and_its_files_are_in_the_table():
     assert config["solver"]["base_lr"] == 3e-6
     per_layer = {m["name"]: m for m in files.table()["per_layer"]}
     for name in [*MFU, *DEVICE_MS]:
-        assert per_layer[name]["workloads"] == [CELL]
+        # the dense MLP's reader reads the Laguna cell's leading layer too
+        assert per_layer[name]["workloads"] == [CELL, *(
+            ["laguna-train-8k"] if name == "dense_mlp_device_ms" else [])]
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "layer_metrics", name + ".json"))
     reported = {m["name"] for m in files.metrics_of(CELL, "per_layer")}

@@ -101,6 +101,45 @@ def test_flash_attention_kernels_compile_for_the_v5e(
     assert all(name in compiled.as_text() for name in names)
 
 
+# the same three kernels under a WINDOW, as ``ops/attention.
+# causal_gqa_attention`` hands laguna-train-8k's sliding layers to them: 2
+# sequences of 8,192 tokens, 64 query heads on 8 K/V heads of 128, a window
+# of 512, the blocks it gives them in bfloat16 (output handed over in
+# bfloat16) and in its check's float32.  What is settled here: Mosaic takes
+# the band's index maps (first block the window reaches, clamped to the
+# last) and the window's mask beside the diagonal's
+@pytest.mark.parametrize("t, hq, window, dtype, out_dtype", [
+    (8192, 64, 512, "bfloat16", None), (8192, 64, 512, "float32", "float32"),
+    # and the full layers' causal kernels in its step check's float32: a
+    # group of 6 heads, whose MiB of queries is 341 rows of 128 lanes
+    (1536, 48, None, "float32", "float32")])
+def test_flash_attention_kernels_under_a_window_compile_for_the_v5e(
+        one_chip, monkeypatch, t, hq, window, dtype, out_dtype):
+    from sparknet_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    calls = []
+    real = pallas_attention.flash_attention
+    monkeypatch.setattr(pallas_attention, "flash_attention",
+                        lambda *a, **kw: calls.append(kw) or real(
+                            *a, **kw, interpret=False))
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, t, h, 128), jnp.dtype(dtype), sharding=one_chip)
+
+    def gradients(q, k, v):
+        out, vjp = jax.vjp(lambda *a: attention.causal_gqa_attention(
+            *a, compute_dtype=jnp.dtype(dtype), window=window,
+            out_dtype=out_dtype), q, k, v)
+        return vjp(out)
+
+    text = jax.jit(gradients).lower(
+        shape(hq), shape(8), shape(8)).compile().as_text()
+    assert all(name in text for name in (
+        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+    assert calls[0]["window"] == window
+    assert calls[0]["block_q"] % 128 == 0
+
+
 # the same three kernels WITH a keep-mask (``masked_flash_attention``), as
 # ``ops/sparse_attention.masked_attention`` hands keye2-train-16k's layer to
 # them: one sequence, 32 query heads on 4 K/V heads of 128, the selection as
