@@ -282,7 +282,7 @@ def _rel_err(got, ref):
 
 
 def _attention_kernels(name, b, t, h, d, dtype, interpret):
-    """flash forward / backward-dq / backward-dkv and decode at one shape,
+    """flash forward, its backward's dq and dk/dv, and decode at one shape,
     against the dense references in float32 at full matmul precision."""
     import jax
     import jax.numpy as jnp

@@ -233,6 +233,10 @@ def build_lm_solver(args, sp: int):
             lm.attention == "auto" and pallas_attention.lowerable()
         )
         tm.kernel_path.labels("attention").set(1.0 if on_kernel else 0.0)
+        # a ring shard's keys, or the whole sequence's
+        fused = on_kernel and pallas_attention.backward_path(
+            args.seq_len // sp, args.dim // args.heads)[0] == "fused"
+        tm.kernel_path.labels("attention_backward").set(float(fused))
     return lm, solver
 
 

@@ -192,7 +192,8 @@ class TrainingMetrics:
             "1 when the named hot path rides its fused Pallas kernel, "
             "0 on the dense/XLA fallback (the ops/pallas_attention."
             "lowerable() routing gate; kernel=attention|epilogue|"
-            "sparse_attention)",
+            "sparse_attention; attention_backward: 1 on the one-pass "
+            "flash backward, 0 on its two passes or XLA)",
             labels=("kernel",),
         )
         self.kernel_fused_chunks = registry.counter(

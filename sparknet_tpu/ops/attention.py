@@ -202,11 +202,14 @@ def _path(t: int, hq: int, hkv: int, d: int, cd, block_q: int,
         block_k = min(KERNEL_BLOCK_K * 256 // d if rope else KERNEL_BLOCK_K, t)
         block_q = kernel_block_q(hq // hkv, d + rope, cd, block_k)
         met = pallas_attention.blocks_met(t, t, block_q, block_k, window)
+    backward, backward_why = ("xla", "") if why else (
+        pallas_attention.backward_path(t, d, rope, block_k))
     obs.instant("attention_path", cat="kernel",
                 path="xla" if why else "pallas", why=why, backend=backend,
                 t=t, hq=hq, hkv=hkv, d=d, d_qk=d + rope, d_v=d,
                 dtype=cd.name, block_q=block_q, window=window,
-                block_k=block_k, blocks_computed=met[0], blocks_total=met[1])
+                block_k=block_k, blocks_computed=met[0], blocks_total=met[1],
+                backward=backward, backward_why=backward_why)
     return why, block_q, block_k
 
 

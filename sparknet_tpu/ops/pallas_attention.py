@@ -1,10 +1,11 @@
 """Fused attention kernels in Pallas — the hot-op custom kernel path.
 
-One family of three K/V-blocked flash kernels (forward, dq, dk/dv) behind one
-``jax.custom_vjp`` (``_flash_core``) serves the dense path
-(``flash_attention``: ``models/transformer_lm.py`` and, with grouped K/V
-heads, ``ops/attention.causal_gqa_attention``) and the ring's per-shard step
-(``flash_attention_step``).  No score leaves VMEM, forward or backward, and
+One family of K/V-blocked flash kernels, a forward and ONE backward (with
+the dq and dk/dv passes it replaces kept for a K/V head too long for its
+VMEM budget), behind one ``jax.custom_vjp`` (``_flash_core``) serves the
+dense path (``flash_attention``: ``models/transformer_lm.py`` and, with
+grouped K/V heads, ``ops/attention.causal_gqa_attention``) and the ring's
+per-shard step (``flash_attention_step``).  No score leaves VMEM, forward or backward, and
 neither K/V nor a score matrix is ever held whole: the grid walks (batch, K/V
 head, query block, key block) with the key block innermost, and the running
 maximum, the running sum and the float32 accumulator live in VMEM scratch.
@@ -12,11 +13,11 @@ maximum, the running sum and the float32 accumulator live in VMEM scratch.
 Grouped heads: the ``group = Hq // Hkv`` query heads of a K/V head meet the
 same K/V block in VMEM, stacked as rows — a block of ``block_q`` tokens is
 ``(group * block_q, D)`` against ``(block_k, D)`` — so K and V are read once
-a group and never repeated in memory, and the dk/dv pass sums over the group
-in its contraction.  Where a head is whole lanes (``D % 128 == 0``) the
-kernels read ``q``, ``k``, ``v`` where they lie, ``(B, T, H * D)`` with a
-head as a block of lanes, and write ``o`` the same way; other widths go
-heads-first, a K/V head's group side by side.
+a group and never repeated in memory, and the backward sums dk and dv over
+the group in its contraction.  Where a head is whole lanes (``D % 128 ==
+0``) the kernels read ``q``, ``k``, ``v`` where they lie, ``(B, T, H * D)``
+with a head as a block of lanes, and write ``o`` the same way; other widths
+go heads-first, a K/V head's group side by side.
 
 Causality by blocks: a key block wholly above the diagonal is neither
 computed (``pl.when``) nor fetched (its index map stays on the last block
@@ -25,15 +26,16 @@ needed); the mask is applied on the blocks the diagonal crosses only.
 A window (``flash_attention(.., window=W)``: query ``i`` sees the keys ``i -
 W < j <= i``, causal self-attention) is a static specialisation too
 (``_Shape.window``, 0 without one): the grid's inner axis spans the BAND, the
-most key blocks a query block meets (``band``; of the dk/dv pass, the most
-query blocks a key block is seen by), and walks them from the first the
-window reaches (``_first_key_block``; ``_last_query_block``), so the blocks
-outside the band are not grid steps at all; a step past the band's end is
-skipped and its index map stays on the band's last block.  The mask is the
+most key blocks a query block meets (``band``; of the two-pass backward's
+dk/dv pass, the most query blocks a key block is seen by), and walks them
+from the first the window reaches (``_first_key_block``;
+``_last_query_block``), so the blocks outside the band are not grid steps
+at all; a step past the band's end is skipped and its index map stays on
+the band's last block.  The mask is the
 window's and the diagonal's where either crosses a block.
 
 A keep-mask (``masked_flash_attention``: the selected-key attention of
-``ops/sparse_attention.py``) is a static specialisation of the same three
+``ops/sparse_attention.py``) is a static specialisation of the same
 kernels: one more operand, the selection as bits in ``ops/attention.
 pack_mask``'s layout — key ``s`` is bit ``s // words`` of word ``s % words``,
 so a key block of ``words`` keys is ONE bit of every word and a block of
@@ -41,7 +43,7 @@ so a key block of ``words`` keys is ONE bit of every word and a block of
 block, holds the mask of every key block it meets.  The blocks above the
 diagonal are skipped as before; every block that is met takes the masked
 step with the tile unpacked in VMEM (an AND and a compare, the same for the
-heads of a group) in place of the positions' iotas; the dk/dv pass, whose
+heads of a group) in place of the positions' iotas; the backward, whose
 scores have the keys on rows, transposes the unpacked tile (32-bit, 2-D).
 
 A second score term (``mla_flash_attention``: latent attention, whose key is
@@ -52,21 +54,31 @@ products into one float32 tile, so the 192-wide score never exists as one
 block and ``v`` and ``o`` stay ``d`` wide, read and written where they lie.
 The rope queries come heads-first, ``(B, H, T, rope)`` (a block of 64 lanes
 is whole only where 64 is the array's width), the rope key as it is, ``(B, T,
-rope)``, the same block for every head and never repeated in memory; the dq
-pass hands back ``dq_rope`` the same way and the dk/dv pass a float32
-``dk_rope`` a head, ``(B, H, T, rope)``, summed over the heads in XLA.  On the
-128 x 128 MXU the rope term's contraction fills half a pass: of the forward's
-three passes a block 2.5 are asked for, of the backward's eleven 9, so 18%
-of the fourteen are empty (a 192-wide block padded to 256 in VMEM would waste
-the same).
+rope)``, the same block for every head and never repeated in memory; the
+backward hands back ``dq_rope`` the same way and a float32 ``dk_rope`` a
+head, ``(B, H, T, rope)``, summed over the heads in XLA.  On the 128 x 128
+MXU the rope term's contraction fills half a pass: of the forward's three
+passes a block 2.5 are asked for, of the backward's eight 6.5 (a 192-wide
+block padded to 256 in VMEM would waste the same).
 
-Backward: the FlashAttention recipe from the residuals ``(q, k, v, o, lse)``.
-The dq pass (query block outer) forms ``delta = rowsum(do * o) - dlse`` once a
-query block and hands it on; the dk/dv pass (key block outer) works on the
-transposed scores, ``k q^T``, so that no product needs a transposed left
-operand.  Both rebuild ``p = exp(s - lse)`` in VMEM and skip the blocks above
-the diagonal.  The ``dlse`` term makes ``(o, lse)`` an honest differentiable
-pair, which is what lets the ring merge per-step partial attentions.
+Backward: the FlashAttention recipe from the residuals ``(q, k, v, o, lse)``,
+in ONE pass (``flash_attention_backward``).  It walks the forward's grid,
+query block outer and key blocks inner, the blocks above the diagonal
+skipped, and forms ``delta = rowsum(do * o) - dlse`` once a query block.  A
+block pair's scores are formed once, transposed (``s^T = k q^T``, ``dp^T =
+v do^T``), and with ``p`` and ``ds`` feed all three gradients: ``dv += p^T
+do`` and ``dk += ds^T q`` into float32 accumulators that hold the K/V head's
+every key in VMEM and are written when its last query block ends, ``dq^T +=
+k^T ds^T`` into one that holds the query block and is written transposed
+once: five products a block pair where a dq pass and a dk/dv pass run seven,
+and one exponential where they run two.  Nothing accumulates in HBM.  Where
+the accumulators of a K/V head, ``Tk x (2 d + rope) x 4`` bytes, pass
+``BACKWARD_ACCUMULATOR_BYTES`` (a long ring shard), the backward is the two
+passes (``backward_path`` says which): the dq pass (query block outer)
+hands ``delta`` on to the dk/dv pass (key block outer), each rebuilding ``p
+= exp(s - lse)``.  The ``dlse`` term makes ``(o, lse)`` an honest
+differentiable pair, which is what lets the ring merge per-step partial
+attentions.
 
 Arithmetic: products take their operands in the compute dtype (the inputs')
 and accumulate in float32 — float32 operands at ``Precision.HIGHEST`` —;
@@ -214,7 +226,7 @@ def band(t: int, block_q: int, block_k: int, window: int):
     """``(key blocks, query blocks)``: the most key blocks a query block
     meets, and the most query blocks a key block is seen by, under a
     ``window`` over one sequence of ``t``: the inner grid axis of the
-    forward and dq passes, and of the dk/dv pass."""
+    forward, the backward and the dq pass, and of the dk/dv pass."""
     met = _met(t, t, block_q, block_k, window)
     return int(met.sum(1).max()), int(met.sum(0).max())
 
@@ -341,8 +353,9 @@ def _rope_apart(refs, c: _Shape, *at):
 
 
 def _scores(q, k, qr_ref, kr_ref, c: _Shape):
-    """``q k^T`` (``k q^T`` for the dk/dv pass, which hands the key's side
-    first) and, where there is one, the rope term into the same tile."""
+    """``q k^T`` (``k q^T`` for the backward and the dk/dv pass, which hand
+    the key's side first) and, where there is one, the rope term into the
+    same tile."""
     s = _mm(q, k, _NT)
     if c.rope:
         s = s + _mm(qr_ref[...], kr_ref[...], _NT)
@@ -366,7 +379,8 @@ def _unstack_to(ref, x, c: _Shape):
 
 # A per-row scalar lives in HBM with tokens on lanes, ``(group, block_q)`` a
 # block; the forward and dq passes want it as a column beside the scores'
-# rows, the dk/dv pass as a row above the transposed scores' columns.
+# rows, the backward and the dk/dv pass as a row above the transposed
+# scores' columns.
 def _row(ref, c: _Shape):
     """``(group, block_q)`` -> ``(1, group * block_q)``."""
     if c.group == 1:
@@ -380,8 +394,13 @@ def _column(ref, c: _Shape):
     return jnp.transpose(jnp.broadcast_to(row, (LANES, row.shape[1])))[:, :1]
 
 
+def _as_row(col):
+    """``(n, 1)`` -> ``(1, n)``."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+
+
 def _column_to(ref, col, c: _Shape):
-    row = jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+    row = _as_row(col)
     for g in range(c.group):
         ref[g:g + 1, :] = row[:, g * c.block_q:(g + 1) * c.block_q]
 
@@ -533,11 +552,103 @@ def _dkv_kernel(offs_ref, *refs, c: _Shape):
             dkr_ref[...] = dkr_scr[...]
 
 
+def _bwd_kernel(offs_ref, *refs, c: _Shape):
+    bits_ref, refs = _bits_first(refs, c)
+    # the rope term's: the last inputs, the last outputs, the last scratch
+    (qr_ref, kr_ref, dqr_ref, dkr_ref, dqr_scr), refs = _rope_apart(
+        refs, c, 7, 8, 12, 13, 19)
+    (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dlse_ref, dq_ref, dk_ref,
+     dv_ref, q_scr, do_scr, lse_scr, delta_scr, dq_scr, *acc) = refs
+    # float32 dk / dv accumulate in their outputs, others in scratch
+    dk_acc, dv_acc = acc or (dk_ref, dv_ref)
+    # query block outer, key blocks inner, as the dq pass walks them; the
+    # scores transposed, keys on rows, as the dk/dv pass forms them
+    first = pl.program_id(2) == 0
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+    i, j, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    inner, n_inner, in_band = j, nk, None
+    if c.window:
+        i, j, in_band = _banded(offs_ref, i, j, False, c)
+        nk = _blocks_of(c)[1]
+
+    @pl.when(jnp.logical_and(first, inner == 0))
+    def _():  # a K/V head's dk, dv over every key
+        dk_acc[...] = jnp.zeros(dk_acc.shape, F32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, F32)
+        if c.rope:
+            dkr_ref[...] = jnp.zeros(dkr_ref.shape, F32)
+
+    @pl.when(inner == 0)
+    def _():
+        do = _stacked(do_ref, c)
+        delta = jnp.sum(do.astype(F32) * _stacked(o_ref, c).astype(F32),
+                        axis=1, keepdims=True)
+        delta_scr[...] = _as_row(delta) - _row(dlse_ref, c)
+        lse_scr[...] = _row(lse_ref, c)
+        q_scr[...] = _stacked(q_ref, c)
+        do_scr[...] = do.astype(do_scr.dtype)
+        dq_scr[...] = jnp.zeros(dq_scr.shape, F32)
+        if c.rope:
+            dqr_scr[...] = jnp.zeros(dqr_scr.shape, F32)
+
+    def step(masked):
+        q, do, k = q_scr[...], do_scr[...], k_ref[...]
+        keep = _keep(offs_ref, bits_ref, i, j, c, True) if masked else None
+        p = _probabilities(_scores(k, q, kr_ref, qr_ref, c), lse_scr[...],
+                           keep, c)
+        ds = p * (_mm(v_ref[...], do, _NT) - delta_scr[...])
+        if c.scale != 1.0:
+            ds = ds * c.scale
+        ds = ds.astype(q.dtype)
+        keys = pl.ds(pl.multiple_of(j * c.block_k, c.block_k), c.block_k)
+        dv_acc[keys, :] += _mm(p.astype(do.dtype), do, _NN)
+        dk_acc[keys, :] += _mm(ds, q, _NN)  # sums over the group
+        dq_scr[...] += _mm(jnp.transpose(k), ds, _NN)  # dq^T, (d, rows)
+        if c.rope:  # this head's part of the shared key's gradient
+            dkr_ref[keys, :] += _mm(ds, qr_ref[...], _NN)
+            dqr_scr[...] += _mm(jnp.transpose(kr_ref[...]), ds, _NN)
+
+    _walk(offs_ref, i, j, nk, c, step, in_band)
+
+    @pl.when(inner == n_inner - 1)
+    def _():
+        _unstack_to(dq_ref, jnp.transpose(dq_scr[...]), c)
+        if c.rope:
+            dqr_ref[...] = jnp.transpose(dqr_scr[...]).astype(dqr_ref.dtype)
+
+    if acc:
+        @pl.when(jnp.logical_and(last, inner == n_inner - 1))
+        def _():
+            dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
 # Scoped-VMEM ceiling handed to Mosaic.  The default (16 MiB on v5e) is
 # less than a block of grouped heads with its float32 scores takes, well
 # before the chip's 128 MiB runs out.
 VMEM_LIMIT_BYTES = 100 << 20
 BLOCK_K = 512  # keys a block, where the caller does not say
+# What the one-pass backward holds in VMEM for a whole K/V head beside its
+# blocks: dk and dv (and a head's part of dk_rope) over every key, float32;
+# with the outputs they are written to, double-buffered, twice this in
+# either dtype.  16 MiB is 16,384 keys of heads of 128.
+BACKWARD_ACCUMULATOR_BYTES = 16 << 20
+
+
+def backward_path(tk: int, d: int, rope: int = 0, block_k: int = BLOCK_K):
+    """``(path, why)``: which backward the kernels take over ``tk`` keys
+    (padded to whole blocks of ``block_k``) of heads ``d`` wide with a
+    second score term ``rope`` wide: ``"fused"``, one pass, where a K/V
+    head's float32 dk, dv and dk_rope over every key fit
+    ``BACKWARD_ACCUMULATOR_BYTES``; ``"two_pass"``, the dq and dk/dv
+    passes, with the reason, where they do not."""
+    keys = -(-tk // block_k) * block_k
+    need = keys * (2 * d + rope) * 4
+    if need > BACKWARD_ACCUMULATOR_BYTES:
+        return "two_pass", (
+            f"a K/V head's dk/dv over {keys} keys is {need >> 20} MiB of "
+            f"VMEM, over {BACKWARD_ACCUMULATOR_BYTES >> 20}")
+    return "fused", ""
 
 
 def _compiler_params(*semantics):
@@ -560,17 +671,20 @@ _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 # -- the calls ------------------------------------------------------------------
 def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
-          *operands):
+          *operands, outer="parallel"):
     """One kernel over (batch, K/V head, outer block, inner block), the inner
     blocks one after another.  ``ins`` / ``outs``: a letter an operand —
     ``q`` a block of queries' rows ``(block_q, group * d)``, ``k`` a block of
-    keys' ``(block_k, d)``, ``r`` a per-row scalar ``(group, block_q)``; of
-    the rope term ``a`` a block of a head's queries ``(block_q, rope)`` of
-    ``(B, H, Tq, rope)``, ``b`` a block of the ONE key ``(block_k, rope)`` of
-    ``(B, Tk, rope)`` and ``c`` a block of a head's part of that key's
-    gradient, ``(B, H, Tk, rope)`` — and for an output its dtype.  ``keep``: the keep-mask's words ``(B, Tq,
-    words)`` int32 or None; a block of queries' whole rows of it goes first,
-    fetched again only when the query block changes."""
+    keys' ``(block_k, d)``, ``K`` a K/V head's every key ``(Tk, d)``, ``r`` a
+    per-row scalar ``(group, block_q)``; of the rope term ``a`` a block of a
+    head's queries ``(block_q, rope)`` of ``(B, H, Tq, rope)``, ``b`` a block
+    of the ONE key ``(block_k, rope)`` of ``(B, Tk, rope)``, ``c`` a block of
+    a head's part of that key's gradient and ``C`` all of it, of ``(B, H,
+    Tk, rope)`` — and for an output its dtype.  ``keep``: the keep-mask's
+    words ``(B, Tq, words)`` int32 or None; a block of queries' whole rows of
+    it goes first, fetched again only when the query block changes.
+    ``outer``: the outer block's axis semantics, ``"arbitrary"`` where a
+    kernel accumulates over it."""
     b, tq = operands[0].shape[:2]
     tk = operands[1].shape[1]
     hkv = operands[1].shape[2] // c.d
@@ -605,6 +719,8 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
         "k": (pl.BlockSpec((None, c.block_k, c.d),
                            lambda *g: (g[0], k_block(*g), g[1])),
               (b, tk, hkv * c.d)),
+        "K": (pl.BlockSpec((None, tk, c.d), lambda *g: (g[0], 0, g[1])),
+              (b, tk, hkv * c.d)),
         "r": (pl.BlockSpec((None, None, c.group, c.block_q),
                            lambda *g: (g[0], g[1], 0, q_block(*g))),
               (b, hkv, c.group, tq)),
@@ -619,6 +735,9 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
                   (b, tk, c.rope)),
             "c": (pl.BlockSpec((None, None, c.block_k, c.rope),
                                lambda *g: (g[0], g[1], k_block(*g), 0)),
+                  (b, hkv, tk, c.rope)),
+            "C": (pl.BlockSpec((None, None, tk, c.rope),
+                               lambda *g: (g[0], g[1], 0, 0)),
                   (b, hkv, tk, c.rope)),
         })
     in_specs = [specs[x][0] for x in ins]
@@ -644,7 +763,7 @@ def _call(kernel, name, c: _Shape, key_outer, ins, outs, scratch, offs, keep,
         out_shape=[_out_struct(specs[x][1], dtype, *everything)
                    for x, dtype in outs],
         compiler_params=_compiler_params(
-            "parallel", "parallel", "parallel", "arbitrary"),
+            "parallel", "parallel", outer, "arbitrary"),
         interpret=c.interpret,
         name=name,
     )(offs, *operands)
@@ -690,8 +809,22 @@ def _flash_core_bwd(c, res, cts):
     q, k, v, offs, keep, rope, o, lse = res
     do, dlse = cts
     rows = c.group * c.block_q
-    # the rope term's inputs, output and scratch come last (``_rope_apart``)
+    # the rope term's inputs, outputs and scratch come last (``_rope_apart``)
     ab, parts, n = ("ab", rope, 1) if rope else ("", (), 0)
+    if backward_path(k.shape[1], c.d, c.rope, c.block_k)[0] == "fused":
+        dq, dk, dv, *dr = _call(
+            _bwd_kernel, "flash_attention_backward", c, False,
+            "qkkqqrr" + ab,
+            [("q", q.dtype), ("K", k.dtype), ("K", v.dtype)]
+            + [("a", q.dtype), ("C", F32)] * n,
+            [pltpu.VMEM((rows, c.d), q.dtype)] * 2
+            + [pltpu.VMEM((1, rows), F32)] * 2
+            + [pltpu.VMEM((c.d, rows), F32)]
+            + [pltpu.VMEM((c.rope, rows), F32)] * n
+            + [pltpu.VMEM((k.shape[1], c.d), F32)] * 2 * (k.dtype != F32),
+            offs, keep, q, k, v, do, o, lse, dlse.astype(F32), *parts,
+            outer="arbitrary")
+        return _cotangents(dq, dk, dv, rope, *dr)
     dq, delta, *dqr = _call(
         _dq_kernel, "flash_attention_dq", c, False, "qkkqqrr" + ab,
         [("q", q.dtype), ("r", F32)] + [("a", q.dtype)] * n,
@@ -705,8 +838,12 @@ def _flash_core_bwd(c, res, cts):
         [pltpu.VMEM((c.block_k, c.d), F32)] * 2
         + [pltpu.VMEM((c.block_k, c.rope), F32)] * n,
         offs, keep, q, k, v, do.astype(q.dtype), lse, delta, *parts)
+    return _cotangents(dq, dk, dv, rope, *dqr, *dkr)
+
+
+def _cotangents(dq, dk, dv, rope, dqr=None, dkr=None):
     if rope:  # the shared key's gradient: the heads' parts, summed in float32
-        rope = dqr[0], jnp.sum(dkr[0], axis=1).astype(rope[1].dtype)
+        rope = dqr, jnp.sum(dkr, axis=1).astype(rope[1].dtype)
     # the integer offsets and the mask's bits carry no cotangent
     return dq, dk, dv, None, None, rope
 

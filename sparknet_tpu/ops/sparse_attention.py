@@ -251,12 +251,15 @@ def masked_attention(q, k, v, mask, *, block_q: int = BLOCK_Q,
         block_q = attention.kernel_block_q(hq // hkv, d, cd, block_k)
         block_q = 1 << (block_q.bit_length() - 1)  # divides T
         met = pallas_attention.blocks_met(t, t, block_q, block_k)
+    backward, backward_why = ("xla", "") if why else (
+        pallas_attention.backward_path(t, d, 0, block_k))
     obs.instant("sparse_attention_path", cat="kernel",
                 path="xla" if why else "pallas", why=why,
                 backend=jax.default_backend(), t=t, hq=hq, hkv=hkv, d=d,
                 dtype=cd.name, block_q=block_q, block_k=block_k, words=words,
                 segments=segments,
-                blocks_computed=met[0], blocks_total=met[1])
+                blocks_computed=met[0], blocks_total=met[1],
+                backward=backward, backward_why=backward_why)
     k, v = k.astype(cd), v.astype(cd)
     if why:
         out = _blockwise_gqa(q, k, v, block_q, segments, keep=mask)

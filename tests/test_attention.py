@@ -15,6 +15,7 @@ from sparknet_tpu.parallel import make_mesh
 from sparknet_tpu.parallel.ring_attention import ring_self_attention
 
 B, T, H, D = 2, 32, 4, 16
+F32 = jnp.float32
 
 
 def _qkv(seed=0):
@@ -293,3 +294,135 @@ def test_flash_jitted_step_zero_post_warmup_recompiles():
         assert np.isfinite(float(loss))
         assert np.all(np.isfinite(np.asarray(g)))
     assert step._cache_size() - warm == 0
+
+
+# ---------------------------------------------------------------------
+# the one-pass backward (``flash_attention_backward``, one kernel for dq,
+# dk, dv and the rope term's parts) against jax.grad of the dense
+# reference given the same keys, and against the dq + dk/dv passes it
+# replaces; every case with cotangents on BOTH outputs (the dlse term)
+
+def _masked_reference(q, k, v, keep, scale, rope=None):
+    """``mha_reference``'s arithmetic in float32 over the keys ``keep``
+    ``(B, Tq, Tk)`` holds, K/V heads repeated for their groups and the rope
+    term joined to the heads: ``(o (B, Tq, H, D), lse (B, H, Tq))``."""
+    group = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, group, 2), jnp.repeat(v, group, 2)
+    if rope is not None:
+        q = jnp.concatenate([q, rope[0]], -1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            rope[1][:, :, None], (*k.shape[:3], rope[1].shape[-1]))], -1)
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
+    s = jnp.where(keep[:, None], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v,
+                   precision="highest")
+    return o, lse
+
+
+# name -> (query heads, K/V heads, head width, T, query offset, window,
+# keep-mask, rope width, causal); blocks of 16 x 16
+ONE_PASS_CASES = {
+    "grouped heads in place": (4, 2, 128, 32, 0, None, False, 0, True),
+    "grouped heads first, ragged T": (4, 2, 16, 24, 0, None, False, 0, True),
+    "not causal, ragged T": (2, 2, 16, 24, 0, None, False, 0, False),
+    "a window band": (4, 2, 128, 48, 0, 20, False, 0, True),
+    "a keep-mask": (4, 2, 128, 32, 0, None, True, 0, True),
+    "a rope term": (2, 2, 128, 32, 0, None, False, 64, True),
+    "a ring step's offsets": (2, 1, 128, 32, 24, None, False, 0, True),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(ONE_PASS_CASES))
+def test_the_one_pass_backward_matches_the_reference_and_the_two_passes(
+        monkeypatch, case, dtype):
+    from sparknet_tpu.ops import pallas_attention as pa
+    from sparknet_tpu.ops.attention import pack_mask, words_of
+
+    hq, hkv, d, t, q_off, window, masked, rope, causal = ONE_PASS_CASES[case]
+    cd = jnp.dtype(dtype)
+    key = jax.random.key(len(case))
+    draw = lambda i, *s: jax.random.normal(  # noqa: E731
+        jax.random.fold_in(key, i), s).astype(cd)
+    q, k, v = draw(0, 1, t, hq, d), draw(1, 1, t, hkv, d), draw(2, 1, t, hkv, d)
+    parts = (draw(3, 1, t, hq, rope), draw(4, 1, t, rope)) if rope else None
+    scale = (d + rope) ** -0.5
+    query, keys = q_off + np.arange(t)[:, None], np.arange(t)[None, :]
+    keep = (keys <= query) if causal else np.ones((t, t), bool)
+    if window:
+        keep &= keys > query - window
+    bits = None
+    if masked:  # a scattered selection that keeps the diagonal
+        keep &= (np.random.RandomState(3).rand(t, t) < 0.4) | (keys == query)
+        bits = pack_mask(jnp.asarray(keep)[None], words_of(t))
+    offs = jnp.asarray([q_off, 0], jnp.int32)
+
+    def kernels(q, k, v, *parts):
+        o, lse = pa._attend(q, k, v, offs, causal, 16, 16, True, scale, F32,
+                            keep=bits, rope=parts or None, window=window)
+        return o, lse
+
+    def reference(q, k, v, *parts):
+        return _masked_reference(q, k, v, jnp.asarray(keep)[None], scale,
+                                 parts or None)
+
+    xs = (q, k, v) + (parts or ())
+    do = jax.random.normal(jax.random.fold_in(key, 5), (1, t, hq, d))
+    dlse = jax.random.normal(jax.random.fold_in(key, 6), (1, hq, t))
+
+    def grads(fn):  # and the kernels' names
+        def both(*xs):
+            out, vjp = jax.vjp(fn, *xs)
+            return out, vjp((do, dlse))
+        with jax.default_matmul_precision("highest"):
+            traced = jax.jit(both).trace(*xs)
+            return traced.lower().compile()(*xs), str(traced.jaxpr)
+
+    ((o, lse), fused), names = grads(kernels)
+    assert "name=flash_attention_backward" in names
+    assert "flash_attention_dkv" not in names
+    (want_out, want), _ = grads(reference)
+    monkeypatch.setattr(pa, "BACKWARD_ACCUMULATOR_BYTES", 0)
+    (_, two_pass), names = grads(kernels)
+    assert "name=flash_attention_dq" in names
+    assert "name=flash_attention_backward" not in names
+
+    rel = lambda a, b: float(  # noqa: E731
+        jnp.linalg.norm((a.astype(F32) - b.astype(F32)).ravel())
+        / jnp.linalg.norm(b.astype(F32).ravel()))
+    exact, close = (1e-6, 2e-5) if cd == jnp.float32 else (2e-3, 2e-2)
+    assert rel(o, want_out[0]) < close and rel(lse, want_out[1]) < close
+    for g, g2, w in zip(fused, two_pass, want):
+        assert g.dtype == w.dtype == cd
+        assert rel(g, g2) < exact and rel(g, w) < close
+
+
+@pytest.mark.parametrize("t, backward", [(16384, "fused"), (32768, "two_pass")])
+def test_over_the_vmem_budget_the_backward_takes_two_passes(
+        monkeypatch, t, backward):
+    """A K/V head's float32 dk and dv over every key, 2 x 128 x 4 bytes a
+    key, are 16 MiB at 16,384 keys, the budget: past it the backward is the
+    dq and dk/dv passes, and the ``attention_path`` instant says which and
+    why (traced, not run)."""
+    from sparknet_tpu import obs
+    from sparknet_tpu.obs.trace import Tracer
+    from sparknet_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "lowerable", lambda: True)
+    shape = lambda h: jax.ShapeDtypeStruct((1, t, h, 128), F32)  # noqa: E731
+    tracer = obs.install_tracer(Tracer())
+    try:
+        jaxpr = str(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(attention.causal_gqa_attention(
+                *a, compute_dtype=jnp.bfloat16)), argnums=(0, 1, 2)))(
+                    shape(8), shape(2), shape(2)))
+    finally:
+        obs.uninstall_tracer()
+    (event,) = [e for e in tracer.events() if e["name"] == "attention_path"]
+    args = event["args"]
+    assert (args["path"], args["backward"]) == ("pallas", backward)
+    assert ("32 MiB" in args["backward_why"]) == (backward == "two_pass")
+    assert ("name=flash_attention_backward" in jaxpr) == (backward == "fused")
+    assert ("name=flash_attention_dkv" in jaxpr) == (backward == "two_pass")

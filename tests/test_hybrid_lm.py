@@ -690,6 +690,9 @@ def test_attention_selects_by_backend_shape_and_dtype(
     assert (args["backend"], args["t"], args["hq"], args["hkv"], args["d"],
             args["dtype"]) == ("cpu", t, hq, hkv, d, dtype)
     n = t // args["block_k"]
+    # dk/dv over 8,192 keys of 128 or 256: 8 or 16 MiB, in the budget
+    assert args["backward"] == ("fused" if path == "pallas" else "xla")
+    assert args["backward_why"] == ""
     if path == "pallas":
         assert args["block_k"] == 512 and calls[0]["block_k"] == 512
         assert calls[0]["block_q"] == args["block_q"]
@@ -728,7 +731,7 @@ def test_mixer_recomputation_keeps_what_the_flash_kernels_name(
         monkeypatch, keeps, forwards):
     """Under the mixers' ``jax.checkpoint`` policy the kernels' ``o`` and
     ``lse`` are kept and the backward pass holds the forward kernel once,
-    not twice (traced, not run); dq and dk/dv once either way."""
+    not twice (traced, not run); the one backward kernel once either way."""
     from sparknet_tpu.models.hybrid_lm import MIXER_KEEPS
     from sparknet_tpu.ops import attention
 
@@ -744,7 +747,8 @@ def test_mixer_recomputation_keeps_what_the_flash_kernels_name(
         lambda *a: jnp.sum(kept(*a)), argnums=(0, 1, 2)))(q, k, v))
     count = lambda name: jaxpr.count(f"name={name}")  # noqa: E731
     assert count("flash_attention_forward") == forwards
-    assert count("flash_attention_dq") == count("flash_attention_dkv") == 1
+    assert count("flash_attention_backward") == 1
+    assert count("flash_attention_dq") == count("flash_attention_dkv") == 0
 
 
 def pallas_calls(jaxpr):
@@ -768,17 +772,12 @@ UNMASKED_CALLS = {
             (2, 2, 32, 16),
             [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2,
             [((2048, 1), FP32)] * 2 + [((2048, 256), FP32)]),
-        "flash_attention_dq": (
+        "flash_attention_backward": (
             (2, 2, 32, 16),
             [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2
             + [((2, 8192, 4096), FP32)] * 2 + [((2, 2, 8, 8192), FP32)] * 2,
-            [((2048, 256), BF16)] + [((2048, 1), FP32)] * 2
-            + [((2048, 256), FP32)]),
-        "flash_attention_dkv": (
-            (2, 2, 16, 32),
-            [((2, 8192, 4096), BF16)] + [((2, 8192, 512), BF16)] * 2
-            + [((2, 8192, 4096), BF16)] + [((2, 2, 8, 8192), FP32)] * 2,
-            [((512, 256), FP32)] * 2),
+            [((2048, 256), BF16)] * 2 + [((1, 2048), FP32)] * 2
+            + [((256, 2048), FP32)] + [((8192, 256), FP32)] * 2),
     },
     # lfm2moe-train-8k: 32 query heads on 8 K/V heads of 64, heads-first
     (32, 8, 64): {
@@ -786,17 +785,12 @@ UNMASKED_CALLS = {
             (16, 1, 16, 16),
             [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2,
             [((2048, 1), FP32)] * 2 + [((2048, 64), FP32)]),
-        "flash_attention_dq": (
+        "flash_attention_backward": (
             (16, 1, 16, 16),
             [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2
             + [((16, 8192, 256), FP32)] * 2 + [((16, 1, 4, 8192), FP32)] * 2,
-            [((2048, 64), BF16)] + [((2048, 1), FP32)] * 2
-            + [((2048, 64), FP32)]),
-        "flash_attention_dkv": (
-            (16, 1, 16, 16),
-            [((16, 8192, 256), BF16)] + [((16, 8192, 64), BF16)] * 2
-            + [((16, 8192, 256), BF16)] + [((16, 1, 4, 8192), FP32)] * 2,
-            [((512, 64), FP32)] * 2),
+            [((2048, 64), BF16)] * 2 + [((1, 2048), FP32)] * 2
+            + [((64, 2048), FP32)] + [((8192, 64), FP32)] * 2),
     },
 }
 
@@ -804,10 +798,12 @@ UNMASKED_CALLS = {
 @pytest.mark.parametrize("hq, hkv, d", sorted(UNMASKED_CALLS))
 def test_without_a_keep_mask_the_kernels_calls_are_pinned(
         monkeypatch, hq, hkv, d):
-    """The three ``pallas_call``s of ``causal_gqa_attention``'s forward +
+    """The two ``pallas_call``s of ``causal_gqa_attention``'s forward +
     backward at the two sequence cells' head shapes: grid, operands and
-    scratch as they were before the kernels learned to take a keep-mask (PR
-    34).  ``qwen3next-train-8k``'s order of operations hangs on 50 MB of XLA's
+    scratch (the forward's as before the kernels learned to take a
+    keep-mask; the one-pass backward's: the query block's rows and scalars,
+    dq transposed, and the K/V head's dk and dv over every key).
+    ``qwen3next-train-8k``'s order of operations hangs on 50 MB of XLA's
     own memory estimate (PERF.md section 6): an operand added to the unmasked
     kernels has to fail here, not in a cell."""
     from sparknet_tpu.ops import attention

@@ -378,6 +378,7 @@ def test_the_windowed_path_names_itself_and_counts_the_band(monkeypatch):
     (event,) = [e for e in tracer.events() if e["name"] == "attention_path"]
     args = event["args"]
     assert args["path"] == "pallas" and args["window"] == 512
+    assert args["backward"] == "fused"
     assert (args["block_q"], args["block_k"]) == (
         attention.kernel_block_q(8, 128, jnp.bfloat16,
                                  attention.KERNEL_BLOCK_K),
@@ -423,9 +424,9 @@ def grids(fn, *args):
 
 
 @pytest.mark.parametrize("window, want", [
-    # forward, dq: (B, Hkv, query blocks, key blocks); dk/dv: (.., key, query)
-    (None, [(2, 2, 5, 5), (2, 2, 5, 5), (2, 2, 5, 5)]),
-    (9, [(2, 2, 5, 2), (2, 2, 5, 2), (2, 2, 5, 2)]),
+    # forward, the one-pass backward: (B, Hkv, query blocks, key blocks)
+    (None, [(2, 2, 5, 5), (2, 2, 5, 5)]),
+    (9, [(2, 2, 5, 2), (2, 2, 5, 2)]),
 ])
 def test_without_a_window_the_kernels_grids_are_the_causal_ones(window, want):
     """``window=None`` leaves the accepted kernels' grids (every key block

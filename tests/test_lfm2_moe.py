@@ -501,6 +501,8 @@ def test_attention_path_at_half_lane_heads(monkeypatch, hq, hkv, d, path):
         obs.uninstall_tracer()
     (event,) = [e for e in tracer.events() if e["name"] == "attention_path"]
     assert event["args"]["path"] == path
+    assert event["args"]["backward"] == (
+        "fused" if path == "pallas" else "xla")
     assert (event["args"]["hq"], event["args"]["d"]) == (hq, d)
 
 

@@ -600,3 +600,30 @@ def test_cli_train_lm_dispatch(tmp_path):
         + ["--sp", "1"]
     )
     assert rc == 0
+
+
+@pytest.mark.parametrize("lowers, seq_len, sp, fused", [
+    (True, 1024, 1, 1.0),
+    (True, 65536, 1, 0.0),  # dk/dv over 65,536 keys: 64 MiB of VMEM
+    (True, 65536, 8, 1.0),  # a ring shard's 8,192
+    (False, 1024, 1, 0.0),
+])
+def test_the_attention_backward_gauge_names_the_one_pass(
+        monkeypatch, lowers, seq_len, sp, fused):
+    """``sparknet_kernel_path{kernel="attention_backward"}``: 1 where the
+    byte LM's attention takes the kernels and their backward is the one
+    pass, by ``pallas_attention.backward_path`` at its shapes."""
+    import argparse
+
+    from sparknet_tpu import obs
+    from sparknet_tpu.apps import lm_app
+    from sparknet_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(pallas_attention, "lowerable", lambda: lowers)
+    tm = obs.enable_training_metrics()
+    args = argparse.Namespace(
+        dim=256, depth=1, heads=2, seq_len=seq_len, base_lr=0.1,
+        momentum=0.9, weight_decay=0.0)
+    lm_app.build_lm_solver(args, sp)
+    assert tm.kernel_path.labels("attention").value == float(lowers)
+    assert tm.kernel_path.labels("attention_backward").value == fused

@@ -156,6 +156,7 @@ def test_mla_attention_names_its_path_and_both_widths(
     assert (args["d_qk"], args["d_v"], args["hq"], args["hkv"]) == (
         d + r, d, h, h)
     assert len(calls) == (path == "pallas")
+    assert args["backward"] == ("fused" if path == "pallas" else "xla")
     if path == "pallas":
         # keys x width as the selected-key attention's: 1,024 at D = 128
         assert args["block_q"] == args["block_k"] == 1024

@@ -97,17 +97,18 @@ def test_flash_attention_kernels_compile_for_the_v5e(
     compiled = jax.jit(gradients if backward else forward).lower(
         shape(hq), shape(hkv), shape(hkv)).compile()
     names = ["flash_attention_forward"] + (
-        ["flash_attention_dq", "flash_attention_dkv"] if backward else [])
+        ["flash_attention_backward"] if backward else [])
     assert all(name in compiled.as_text() for name in names)
 
 
-# the same three kernels under a WINDOW, as ``ops/attention.
+# the same kernels under a WINDOW, as ``ops/attention.
 # causal_gqa_attention`` hands laguna-train-8k's sliding layers to them: 2
 # sequences of 8,192 tokens, 64 query heads on 8 K/V heads of 128, a window
 # of 512, the blocks it gives them in bfloat16 (output handed over in
 # bfloat16) and in its check's float32.  What is settled here: Mosaic takes
 # the band's index maps (first block the window reaches, clamped to the
-# last) and the window's mask beside the diagonal's
+# last) and the window's mask beside the diagonal's, and the one-pass
+# backward's dk / dv over the sequence's 8,192 keys fit VMEM
 @pytest.mark.parametrize("t, hq, window, dtype, out_dtype", [
     (8192, 64, 512, "bfloat16", None), (8192, 64, 512, "float32", "float32"),
     # and the full layers' causal kernels in its step check's float32: a
@@ -135,18 +136,19 @@ def test_flash_attention_kernels_under_a_window_compile_for_the_v5e(
     text = jax.jit(gradients).lower(
         shape(hq), shape(8), shape(8)).compile().as_text()
     assert all(name in text for name in (
-        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+        "flash_attention_forward", "flash_attention_backward"))
     assert calls[0]["window"] == window
     assert calls[0]["block_q"] % 128 == 0
 
 
-# the same three kernels WITH a keep-mask (``masked_flash_attention``), as
+# the same kernels WITH a keep-mask (``masked_flash_attention``), as
 # ``ops/sparse_attention.masked_attention`` hands keye2-train-16k's layer to
 # them: one sequence, 32 query heads on 4 K/V heads of 128, the selection as
 # ``(1, T, T / 32)`` words, blocks of 512 x 1,024 in bfloat16 and 256 x 1,024
 # in its checks' float32; at T = 16,384 a key block is two bits of every
 # word side by side, at the checks' 4,096 eight.  What is settled here: the
-# blocks fit VMEM, and Mosaic takes the unpack and the dk/dv pass's 32-bit
+# blocks fit VMEM beside the one-pass backward's dk / dv over 16,384 keys
+# (16 MiB, its budget), and Mosaic takes the unpack and the backward's 32-bit
 # transpose of the unpacked tile
 @pytest.mark.parametrize("dtype, block_q", [("bfloat16", 512), ("float32", 256)])
 @pytest.mark.parametrize("t", [16384, 4096])
@@ -168,10 +170,10 @@ def test_flash_attention_kernels_under_a_keep_mask_compile_for_the_v5e(
     text = jax.jit(gradients).lower(
         shape(32), shape(4), shape(4), bits).compile().as_text()
     assert all(name in text for name in (
-        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+        "flash_attention_forward", "flash_attention_backward"))
 
 
-# the same three kernels WITH a second score term (``mla_flash_attention``),
+# the same kernels WITH a second score term (``mla_flash_attention``),
 # as ``ops/attention.causal_mla_attention`` hands kanana2-train-8k's layers to
 # them: 2 sequences of 8,192 tokens, 32 heads of 128 + 64 against values of
 # 128, the one rope key ``(2, T, 64)``, blocks of 1,024 x 1,024 in bfloat16,
@@ -199,8 +201,27 @@ def test_flash_attention_kernels_with_a_rope_term_compile_for_the_v5e(
         shape(32, 128), shape(32, 64), shape(32, 128), shape(64),
         shape(32, 128)).compile().as_text()
     assert all(name in text for name in (
-        "flash_attention_forward", "flash_attention_dq", "flash_attention_dkv"))
+        "flash_attention_forward", "flash_attention_backward"))
     assert not re.search(rf"f32\[(2,)?32,{t},{t}\]", text)
+
+
+# past the one-pass backward's budget (a K/V head's dk / dv over 32,768 keys
+# of 128, 32 MiB, as a long ring shard may hold) the dq and dk/dv passes,
+# which hold no more than a block of keys, still compile
+def test_the_two_pass_backward_compiles_for_the_v5e_past_the_budget(
+        one_chip):
+    shape = lambda h: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 32768, h, 128), jnp.bfloat16, sharding=one_chip)
+
+    def gradients(q, k, v):
+        out, vjp = jax.vjp(lambda *a: pallas_attention.flash_attention(
+            *a, causal=True, block_q=512, interpret=False), q, k, v)
+        return vjp(out)
+
+    text = jax.jit(gradients).lower(
+        shape(8), shape(2), shape(2)).compile().as_text()
+    assert "flash_attention_dkv" in text and "flash_attention_dq" in text
+    assert "flash_attention_backward" not in text
 
 
 def test_alignment_loss_with_its_gradient_holds_no_float32_head_scores(
