@@ -20,34 +20,38 @@ it), in four pieces that a layer runs under four scopes:
   its gradient reaches the index scores alone, and comes with its value:
   under differentiation the ONE pass over the blocks that computes the loss
   computes its gradient to ``qI``, ``w`` and ``kI`` in closed form
-  (``_alignment_with_gradient``), and the backward pass scales those three.
+  (``_alignment_with_gradient``: on the TPU one Pallas kernel,
+  ``ops/pallas_alignment.py``), and the backward pass scales those three.
 
 Why a MASKED pass and not a gather: on the v5e a gather of 2,048 K/V rows a
 query is 4 MB a token (69 GB a layer at 16,384 tokens, ~84 ms at 819 GB/s)
 where the masked dense product is 2.2 TFLOP (~14 ms at 80% of the bf16
 peak); PERF.md section 6, PR 33 has what was read.
 
-The index scores, the selection and the loss run in XLA on the ONE blockwise
-loop of ``ops/attention.py`` (``by_run``: a block of ``block_q`` queries at a
-time, ``lax.map`` over the blocks, the sequence cut into ``segments`` runs
-that each meet only the keys up to their own end), so that no more than
-``block_q x T`` scores a head exist at once; nothing differentiates through
-that loop here (``_blockwise_gqa`` and the plain ``alignment_value`` wrap
-their blocks in ``jax.checkpoint`` for whoever does).  The masked pass takes
-the K/V-blocked flash kernels given the selection's bits
+The index scores, the selection and the loss's value run in XLA on the ONE
+blockwise loop of ``ops/attention.py`` (``by_run``: a block of ``block_q``
+queries at a time, ``lax.map`` over the blocks, the sequence cut into
+``segments`` runs that each meet only the keys up to their own end), so that
+no more than ``block_q x T`` scores a head exist at once; nothing
+differentiates through that loop here (``_blockwise_gqa`` and the plain
+``alignment_value`` wrap their blocks in ``jax.checkpoint`` for whoever does).
+The masked pass takes the K/V-blocked flash kernels given the selection's bits
 (``pallas_attention.masked_flash_attention``: no score leaves VMEM) where
-Pallas lowers and they accept the shapes, and elsewhere
-``_blockwise_gqa`` given the keep-mask, the same arithmetic on that loop and
-the oracle the kernels are tested against; an ``obs`` instant names the path
-at each trace (``sparse_attention_path``; ``alignment_loss_path`` says
-whether the loss was traced for its value or with its gradient).  Between
-the pieces go the indexer's ``qI`` / ``kI`` / ``w``, the mask as bits (``T *
-T / 8`` bytes: 33.5 MB at 16,384 tokens, no float tensor of ``(T, T)``),
-``q`` / ``k`` and the log-sum-exp; from the forward pass to the backward the
-loss keeps its three gradients (the sizes of ``qI``, ``w`` and ``kI``) and
-nothing else.  ``index_scores_by_run`` alone materialises float32 scores of
-whole runs, ``(B, T, T)`` x the causal share, forward only: ``select``
-consumes them and nothing keeps them for the backward.
+Pallas lowers and they accept the shapes, and elsewhere ``_blockwise_gqa``
+given the keep-mask, the same arithmetic on that loop and the oracle the
+kernels are tested against; so does the loss with its gradient
+(``pallas_alignment.alignment_gradient``, on the same kernels' walk and bits;
+elsewhere its XLA form on that loop, the kernel's oracle).  An ``obs`` instant
+names the path at each trace (``sparse_attention_path``;
+``alignment_loss_path`` says whether the loss was traced for its value or with
+its gradient, and on which path).  Between the pieces go the indexer's ``qI``
+/ ``kI`` / ``w``, the mask as bits (``T * T / 8`` bytes: 33.5 MB at 16,384
+tokens, no float tensor of ``(T, T)``), ``q`` / ``k`` and the log-sum-exp;
+from the forward pass to the backward the loss keeps its three gradients (the
+sizes of ``qI``, ``w`` and ``kI``) and nothing else.  ``index_scores_by_run``
+alone materialises float32 scores of whole runs, ``(B, T, T)`` x the causal
+share, forward only: ``select`` consumes them and nothing keeps them for the
+backward.
 """
 
 from __future__ import annotations
@@ -59,7 +63,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from sparknet_tpu import obs
-from sparknet_tpu.ops import attention, pallas_attention
+from sparknet_tpu.ops import attention, pallas_alignment, pallas_attention
 from sparknet_tpu.ops.attention import (  # noqa: F401  (the mask's layout)
     BITS, NEG, _blockwise_gqa, blocked, by_run, joined, pack_mask, runs_of,
     unpack_mask, words_of)
@@ -333,27 +337,47 @@ def alignment_value(qi, w, ki, q, k, lse, mask, *, block_q: int = BLOCK_Q,
     return sum(jnp.sum(x) for x in out)
 
 
-def _alignment_event(path: str, qi, block_q: int, segments: int):
+def alignment_kernel_refuses(t: int, hq: int, hkv: int, d: int, j: int,
+                             di: int, dtype) -> str:
+    """Why ``alignment_loss`` under differentiation does not take the
+    kernel (``ops/pallas_alignment.py``) for these shapes on this backend;
+    empty where it takes it."""
+    if not attention.lowerable():
+        return f"no Pallas lowering on {jax.default_backend()}"
+    if not pallas_alignment.accepts(t, hq, hkv, d, j, di, dtype):
+        return pallas_alignment.ACCEPTS
+    return ""
+
+
+def _alignment_event(path: str, qi, mask, block_q: int, segments: int,
+                     why: str = ""):
     t = qi.shape[1]
-    block_q, _ = runs_of(t, block_q, segments)
-    met = _blocks_met(t, block_q, segments)
-    obs.instant("alignment_loss_path", cat="kernel", path=path,
+    if path == "pallas":  # the flash kernels' walk
+        block_q, block_k = pallas_alignment.blocks(t, mask.shape[-1])
+        met = pallas_attention.blocks_met(t, t, block_q, block_k)
+    else:  # a run's query blocks each meet the keys up to its end
+        block_q, _ = runs_of(t, block_q, segments)
+        block_k, met = block_q, _blocks_met(t, block_q, segments)
+    obs.instant("alignment_loss_path", cat="kernel", path=path, why=why,
                 backend=jax.default_backend(), t=t,
-                ds_dtype=qi.dtype.name if path == "with_gradient" else "",
-                block_q=block_q, segments=segments,
+                ds_dtype=qi.dtype.name if path != "value" else "",
+                block_q=block_q, block_k=block_k, segments=segments,
                 blocks_computed=met[0], blocks_total=met[1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _alignment(qi, w, ki, q, k, lse, mask, block_q, segments):
-    _alignment_event("value", qi, block_q, segments)
+    _alignment_event("value", qi, mask, block_q, segments)
     return alignment_value(qi, w, ki, q, k, lse, mask, block_q=block_q,
                            segments=segments)
 
 
 def _alignment_with_gradient(qi, w, ki, q, k, lse, mask, block_q, segments):
     """``alignment_value`` and, from the same pass over the blocks, its
-    gradient to ``qi``, ``w`` and ``ki`` in closed form.  With ``g =
+    gradient to ``qi``, ``w`` and ``ki`` in closed form: the kernel
+    (``pallas_alignment.alignment_gradient``, its own blocks) where
+    ``alignment_kernel_refuses`` is empty, else this XLA form on ``by_run``'s
+    blocks, the kernel's oracle.  With ``g =
     dL/dI = softmax_{S_t}(I) * sum_s p - p`` on the kept keys of the real
     rows where ``I != 0`` (``index_scores``' ``where``), float32, and ``M_j =
     g [s_j > 0]`` in the products' operand dtype (what the MXU is fed of a
@@ -369,8 +393,15 @@ def _alignment_with_gradient(qi, w, ki, q, k, lse, mask, block_q, segments):
     of ``(.., J, keys)`` is float32 in HBM (on the v5e XLA keeps the sixteen
     ``[s_j > 0]`` as the bits of one ``u16 (block_q, keys)`` and forms ``M``
     inside each product's fusion)."""
-    _alignment_event("with_gradient", qi, block_q, segments)
-    t = q.shape[1]
+    t, hq, d = q.shape[1:]
+    why = alignment_kernel_refuses(t, hq, k.shape[2], d, *qi.shape[2:],
+                                   qi.dtype)
+    if not why:
+        _alignment_event("pallas", qi, mask, block_q, segments)
+        block_q, block_k = pallas_alignment.blocks(t, mask.shape[-1])
+        return pallas_alignment.alignment_gradient(
+            qi, w, ki, q, k, lse, mask, block_q=block_q, block_k=block_k)
+    _alignment_event("with_gradient", qi, mask, block_q, segments, why)
     cd = qi.dtype
     block_q, _ = runs_of(t, block_q, segments)
     blocks, kh = _alignment_blocks(qi, w, q, k, lse, mask, block_q)

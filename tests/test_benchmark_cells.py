@@ -4,6 +4,7 @@ Reads ``benchmark/`` and edits nothing there; its own tests (the references,
 the readers of a trace) are ``benchmark/tests/``."""
 
 import ast
+import concurrent.futures
 import json
 import os
 import subprocess
@@ -16,19 +17,37 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(_REPO, "BENCHMARK.json")) as _f:
     _TABLE = json.load(_f)
 
+# rehearsals at a time: each is one process of a few cores and at most a few
+# GiB, and one after another they take over ten minutes
+_AT_ONCE = max(1, min(4, (os.cpu_count() or 1) // 2))
 
-@pytest.mark.parametrize("cell", [w["name"] for w in _TABLE["workloads"]])
-def test_cell_rehearses_on_the_cpu(cell):
+
+def _rehearse(cell):
     command = list(_TABLE["command"])
     if command[0].startswith("python"):
         command[0] = sys.executable
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     env.pop("XLA_FLAGS", None)  # the rehearsal sizes its own virtual devices
-    proc = subprocess.run(
+    return subprocess.run(
         [*command, "--workload", cell, "--seed", "3", "--seconds", "1",
          "--trace", "0", "--rehearse"],
         cwd=_REPO, env=env, capture_output=True, text=True, timeout=300,
     )
+
+
+@pytest.fixture(scope="module")
+def rehearsals(request):
+    """The selected cells' rehearsals, started in the tests' order on a pool
+    of ``_AT_ONCE`` as the first of them asks: each test waits for its own."""
+    cells = [item.callspec.params["cell"] for item in request.session.items
+             if item.module is request.module]
+    with concurrent.futures.ThreadPoolExecutor(_AT_ONCE) as pool:
+        yield {cell: pool.submit(_rehearse, cell) for cell in cells}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in _TABLE["workloads"]])
+def test_cell_rehearses_on_the_cpu(cell, rehearsals):
+    proc = rehearsals[cell].result()
     assert proc.returncode == 0, proc.stderr[-2000:]
     lines = proc.stdout.strip().splitlines()
     result = json.loads(lines[-1])

@@ -225,14 +225,21 @@ def test_the_two_pass_backward_compiles_for_the_v5e_past_the_budget(
 
 
 def test_alignment_loss_with_its_gradient_holds_no_float32_head_scores(
-        one_chip):
+        one_chip, monkeypatch):
     """keye2-train-16k's alignment loss with its gradient (``ops/
     sparse_attention.alignment_loss``: one sequence of 16,384, 32 / 4 heads of
-    128, 16 index heads of 64, bfloat16) compiles for the v5e in 174 MiB of
-    temporaries (read from the first compile, PR 36; autodiff through the
-    blocks took 671).  One float32 array of ``(512, 16, keys)`` in HBM, the
-    index scores a head or their cotangent, is 512 MiB at the widest run:
-    what the ceiling catches if a change brings it back."""
+    128, 16 index heads of 64, bfloat16) compiles for the v5e as the chip
+    runs it, through the kernel (``ops/pallas_alignment.py``, blocks of 512 x
+    512), in 160 MiB of temporaries: the index heads' and the weights' copies
+    heads first and the gradients handed back (the XLA form it replaced read
+    174, autodiff through the blocks 671; PERF.md section 6).  One float32
+    array of ``(512, 16, keys)`` in HBM, the index scores a head or their
+    cotangent, is 512 MiB at the widest run: what the ceiling catches if a
+    change brings it back."""
+    from sparknet_tpu.ops import attention, pallas_alignment
+
+    for module in (attention, pallas_alignment):  # the path, no interpreter
+        monkeypatch.setattr(module, "lowerable", lambda: True)
     t = 16384
     shape = lambda s, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         (1, t, *s), dtype, sharding=one_chip)
@@ -244,6 +251,7 @@ def test_alignment_loss_with_its_gradient_holds_no_float32_head_scores(
         shape((16, 64)), shape((16,), jnp.float32), shape((64,)),
         shape((32, 128)), shape((4, 128)), shape((32,), jnp.float32),
         shape((t // 32,), jnp.uint32)).compile()
+    assert "alignment_gradient" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
