@@ -17,11 +17,9 @@ user would call, at the full width of CaffeNet:
   compiled (``interpret=False``), run and compared with its dense/unfused
   reference at the shapes the LM produces today and at one real head shape,
   the flash kernels with a second score term at kanana2-train-8k's heads,
-  the sequence models' loss at the two sequence cells' shapes; the opt-in LRN
-  kernels compiled and compared once;
-- ``lm-train``: ``apps.lm_app.main`` at its default preset with
-  ``attention=auto``, so the default-on flash path is reached through a
-  normal entry point.
+  the sequence models' loss at the two sequence cells' shapes;
+- ``lm-train``: ``apps.lm_app.main`` at its default preset, so the
+  default-on flash path is reached through a normal entry point.
 
 What it writes (training logs, ``summary.json``) goes under
 ``chiprun_out/chip_smoke/``.  The last line of stdout is the result,
@@ -71,7 +69,6 @@ FULL = {
         ("lfm2", 16384, 2048, 8192, True),
         ("qwen3next", 16384, 2048, 18992, False),
     ),
-    "lrn_shape": (8, 96, 55, 55),
     "lm_rounds": 4,
     "lm_args": (),  # lm_app's default preset, here and for the comm legs
     "interpret": False,
@@ -447,48 +444,6 @@ def _comm_kernels(legs, lm_args, interpret):
     return out
 
 
-def _lrn_kernels(shape, interpret):
-    """The opt-in LRN kernels (SPARKNET_PALLAS_LRN / SPARKNET_FUSION):
-    compiled once, forward and backward, against the XLA lowering."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from sparknet_tpu.ops import pallas_lrn, pallas_plp
-    from sparknet_tpu.ops.vision import caffe_max_pool, lrn_across_channels
-
-    lrn = (5, 1e-4, 0.75, 1.0)
-    x = jnp.asarray(np.random.RandomState(0).randn(*shape), jnp.float32)
-    ph, pw = pallas_plp.pooled_hw(shape[2], shape[3])
-    pairs = {
-        "lrn": (
-            lambda x: pallas_lrn.lrn_across_channels(x, *lrn, interpret),
-            lambda x: lrn_across_channels(x, *lrn),
-        ),
-        "lrn_maxpool": (
-            lambda x: pallas_plp.lrn_maxpool(x, *lrn, interpret),
-            lambda x: caffe_max_pool(
-                lrn_across_channels(x, *lrn), (3, 3), (2, 2), (0, 0), (ph, pw)
-            ),
-        ),
-    }
-    out = {}
-    for name, (kernel, ref) in pairs.items():
-        def loss(f):
-            return lambda x: jnp.sum(jnp.square(f(x)))
-
-        errs = (
-            _rel_err(jax.jit(kernel)(x), jax.jit(ref)(x)),
-            _rel_err(jax.jit(jax.grad(loss(kernel)))(x),
-                     jax.jit(jax.grad(loss(ref)))(x)),
-        )
-        assert all(np.isfinite(e) and e < 2e-2 for e in errs), (name, errs)
-        out[f"{name}_fwd"], out[f"{name}_bwd"] = (
-            float("%.2e" % e) for e in errs
-        )
-    return out
-
-
 def _mla_kernels(name, b, t, h, d, r, dtype, interpret):
     """The flash kernels with a second score term (latent attention: heads
     ``d + r`` wide, the ``r`` against ONE key every head shares, values
@@ -555,12 +510,11 @@ def phase_kernels(sizes):
     out["comm_lm_leaves"] = _comm_kernels(
         sizes["comm_legs"], sizes["lm_args"], interpret
     )
-    out["opt_in"] = _lrn_kernels(sizes["lrn_shape"], interpret)
     return out
 
 
 def phase_lm(sizes):
-    """lm_app at its default preset, attention=auto: on the chip the
+    """lm_app at its default preset: on the chip the
     Pallas flash kernel is the train step's attention."""
     import jax
 

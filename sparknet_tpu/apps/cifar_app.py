@@ -33,11 +33,6 @@ def main(argv=None) -> int:
     parser.add_argument("--test_every", type=int, default=10)  # CifarApp.scala:101
     parser.add_argument("--batch", type=int, default=100)
     parser.add_argument("--seed", type=int, default=0)
-    parser.add_argument(
-        "--serial_feed", action="store_true",
-        help="disable the pipelined round feed: assemble and H2D run "
-        "on the training loop instead of a producer thread",
-    )
     from sparknet_tpu import obs
     from sparknet_tpu.io import journal as journal_mod
     from sparknet_tpu.parallel import comm, hierarchy
@@ -198,8 +193,7 @@ def main(argv=None) -> int:
 
     # pipelined round feed: round r+1's windows are drawn, stacked into
     # recycled buffers and device_put on a producer thread while round r
-    # executes (RoundFeed; --serial_feed restores the old serial path
-    # with identical numerics)
+    # executes (RoundFeed)
     run_obs = obs.start_from_args(args, echo=log.log)
     # --journal: the round ledger (io/journal.py).  This app keeps no
     # snapshots, so commits mark in-memory round completion only
@@ -217,7 +211,6 @@ def main(argv=None) -> int:
             out,
         ),
         place=lambda host: shard_leading_global(host, mesh),
-        pipelined=not args.serial_feed,
         num_rounds=args.rounds,
     )
     from sparknet_tpu.utils import SignalHandler, SolverAction

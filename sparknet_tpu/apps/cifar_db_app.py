@@ -53,11 +53,6 @@ def main(argv=None) -> int:
     parser.add_argument("--tau", type=int, default=10)
     parser.add_argument("--batch", type=int, default=100)
     parser.add_argument("--seed", type=int, default=0)
-    parser.add_argument(
-        "--serial_feed", action="store_true",
-        help="disable the pipelined round feed: assemble and H2D run "
-        "on the training loop instead of a producer thread",
-    )
     from sparknet_tpu import obs
     from sparknet_tpu.io import journal as journal_mod
     from sparknet_tpu.parallel import comm, hierarchy
@@ -160,7 +155,6 @@ def main(argv=None) -> int:
     feed = RoundFeed(
         assemble,
         mesh=mesh,
-        pipelined=not args.serial_feed,
         num_rounds=args.rounds,
     )
     try:

@@ -86,11 +86,6 @@ def main(argv=None) -> int:
     parser.add_argument("--no_mirror", action="store_true")
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument(
-        "--serial_feed", action="store_true",
-        help="disable the pipelined round feed: assemble and H2D run "
-        "on the training loop instead of a producer thread",
-    )
-    parser.add_argument(
         "--cache_dir", default=None,
         help="front the object store with the host-local content-"
         "addressed chunk cache rooted here (data/chunk_cache.py): "
@@ -397,7 +392,7 @@ def main(argv=None) -> int:
 
     # pipelined round feed: the uint8 windows for round r+1 are stacked
     # into recycled buffers and device_put on a producer thread while
-    # round r executes (--serial_feed restores the serial path)
+    # round r executes
     run_obs = obs.start_from_args(args, echo=log.log)
     # epoch switching runs on the feed's producer thread (assemble is
     # called once per round, in order): at an epoch boundary the shard
@@ -434,7 +429,6 @@ def main(argv=None) -> int:
             out,
         ),
         place=lambda host: shard_leading_global(host, mesh),
-        pipelined=not args.serial_feed,
         num_rounds=args.rounds,
     )
     # --journal: the round ledger (io/journal.py).  This app keeps no

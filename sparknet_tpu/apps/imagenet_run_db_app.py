@@ -52,11 +52,6 @@ def main(argv=None) -> int:
                         help="continue from the newest snapshot")
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument(
-        "--serial_feed", action="store_true",
-        help="disable the pipelined round feed: assemble and H2D run "
-        "on the training loop instead of a producer thread",
-    )
-    parser.add_argument(
         "--cache_dir", default=None,
         help="when --db_dir is a gs://|s3://|http(s)://|file:// url, "
         "stage the DB files through the host-local content-addressed "
@@ -345,12 +340,11 @@ def main(argv=None) -> int:
 
     # pipelined feed, resume-aware: rounds are absolute, so a resumed
     # run's producer starts at start_round and the reader pipelines pick
-    # up where the DB cursors sit (--serial_feed: old serial path)
+    # up where the DB cursors sit
     run_obs = obs.start_from_args(args, echo=log.log)
     feed = RoundFeed(
         assemble,
         mesh=mesh,
-        pipelined=not args.serial_feed,
         start_round=start_round,
         num_rounds=args.rounds,
     )

@@ -58,13 +58,6 @@ def add_lm_model_args(parser) -> None:
     parser.add_argument("--base_lr", type=float, default=0.1)
     parser.add_argument("--momentum", type=float, default=0.9)
     parser.add_argument("--weight_decay", type=float, default=1e-4)
-    parser.add_argument(
-        "--dense_attention", action="store_true",
-        help="train with the dense XLA attention reference instead of "
-        "the Pallas flash kernel (the kernel is the default wherever it "
-        "lowers natively — ops/pallas_attention.lowerable(); this flag "
-        "is the explicit fallback)",
-    )
 
 
 def build_hybrid_lm_solver(config: dict):
@@ -209,12 +202,6 @@ def build_lm_solver(args, sp: int):
         seq_len=args.seq_len,
         sp_axis="sp" if sp > 1 else None,
         sp_size=sp,
-        # --dense_attention is the explicit fallback; the default
-        # ("auto") rides the Pallas flash kernel wherever it lowers
-        # natively
-        attention=(
-            "dense" if getattr(args, "dense_attention", False) else "auto"
-        ),
     )
     solver_param = parse_solver_prototxt(
         f"base_lr: {args.base_lr} "
@@ -229,9 +216,7 @@ def build_lm_solver(args, sp: int):
 
     tm = obs.training_metrics()
     if tm is not None:
-        on_kernel = lm.attention == "flash" or (
-            lm.attention == "auto" and pallas_attention.lowerable()
-        )
+        on_kernel = pallas_attention.lowerable()
         tm.kernel_path.labels("attention").set(1.0 if on_kernel else 0.0)
         # a ring shard's keys, or the whole sequence's
         fused = on_kernel and pallas_attention.backward_path(
@@ -343,11 +328,6 @@ def main(argv=None) -> int:
     parser.add_argument("--batch", type=int, default=8)
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--log_every", type=int, default=5)
-    parser.add_argument(
-        "--serial_feed", action="store_true",
-        help="disable the pipelined round feed: assemble and H2D run "
-        "on the training loop instead of a producer thread",
-    )
     parser.add_argument(
         "--snapshot_prefix", default=None,
         help="snapshot path prefix; with --snapshot_every, every k-th "
@@ -574,7 +554,6 @@ def main(argv=None) -> int:
     feed = RoundFeed(
         assemble,
         sharding=lm_batch_sharding(mesh, sp),
-        pipelined=not args.serial_feed,
         start_round=start_round,
         num_rounds=args.rounds - start_round,
     )

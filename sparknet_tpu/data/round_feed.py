@@ -30,8 +30,9 @@ realistic threat to >=0.9 scaling at dp=32).
   orphaned allocation is the (free) zero-copy source.
 
 ``pipelined=False`` is the **serial mode**: assemble and put run on
-the consumer, between rounds, with identical numerics.  Every wired-in
-loop exposes it as ``--serial_feed``.
+the consumer, between rounds, with identical numerics.  The training
+loops run pipelined; the serial mode is the reference the tests hold
+the pipelined feed to.
 
 Determinism contract: ``assemble`` is called exactly once per round, in
 round order, from a single thread — a stateful sampler draws the same

@@ -12,9 +12,10 @@ Two attention paths, one function (pinned up to float associativity by
 
 - ``sp_axis=None`` (sp=1): single-shard causal attention — the Pallas
   flash kernel (``ops.pallas_attention.flash_attention``, fused
-  forward AND custom_vjp backward) wherever it lowers natively, the
-  dense ``ops.attention.mha_reference`` as the ``attention="dense"``
-  (``--dense_attention``) fallback and the correctness ground truth;
+  forward AND custom_vjp backward) wherever it lowers natively
+  (``pallas_attention.lowerable()``), the dense
+  ``ops.attention.mha_reference`` elsewhere and as the correctness
+  ground truth;
 - ``sp_axis="sp"``: ``parallel.ring_attention`` — the model then MUST
   run inside ``shard_map`` with that axis bound (the
   ``ParameterAveragingTrainer`` does this when given the matching
@@ -105,18 +106,10 @@ class TransformerLM:
         mlp_ratio: int = 4,
         sp_axis: Optional[str] = None,
         sp_size: int = 1,
-        attention: str = "auto",
         name: str = "TransformerLM",
     ):
         if dim % heads:
             raise ValueError(f"dim={dim} not divisible by heads={heads}")
-        if attention not in ("auto", "flash", "dense"):
-            raise ValueError(
-                f"attention={attention!r}: expected 'auto' (flash kernel "
-                "where it lowers natively), 'flash' (force the kernel — "
-                "interpreter mode off-TPU), or 'dense' "
-                "(--dense_attention: the XLA reference everywhere)"
-            )
         if sp_size > 1 and sp_axis is None:
             raise ValueError("sp_size > 1 needs sp_axis (the mesh axis name)")
         if sp_size > 1 and seq_len % sp_size:
@@ -133,7 +126,6 @@ class TransformerLM:
         self.mlp_ratio = int(mlp_ratio)
         self.sp_axis = sp_axis
         self.sp_size = int(sp_size)
-        self.attention = attention
         self.name = name
         self.feed_blobs = ("tokens", "targets")
         # declared feed shapes are per-shard (what one worker's batch
@@ -224,28 +216,18 @@ class TransformerLM:
             return (x @ w).reshape(B, T, H, D)
 
         q, k, v = split(wq), split(wk), split(wv)
-        # attention="auto": the Pallas flash kernel (fused forward AND
-        # backward — custom_vjp) is the training-step default wherever
-        # it lowers natively; "flash" forces it (interpret off-TPU, the
-        # test/bench pin), "dense" (--dense_attention) keeps the XLA
-        # reference
-        use_flash = {"auto": None, "flash": True, "dense": False}[
-            self.attention
-        ]
+        # the Pallas flash kernel (fused forward AND backward —
+        # custom_vjp) wherever it lowers natively, the XLA reference
+        # elsewhere
         if self.sp_axis is not None and self.sp_size > 1:
             # inside shard_map: T here is T_global/sp, KV rotate around
             # the ring (one ICI hop per step), global causality kept by
             # the ring's absolute position bookkeeping
-            out = ring_attention(
-                q, k, v, self.sp_axis, causal=True, use_flash=use_flash
-            )
+            out = ring_attention(q, k, v, self.sp_axis, causal=True)
+        elif pallas_attention.lowerable():
+            out = pallas_attention.flash_attention(q, k, v, causal=True)
         else:
-            if use_flash is None:
-                use_flash = pallas_attention.lowerable()
-            if use_flash:
-                out = pallas_attention.flash_attention(q, k, v, causal=True)
-            else:
-                out = mha_reference(q, k, v, causal=True)
+            out = mha_reference(q, k, v, causal=True)
         return out.reshape(B, T, E) @ wo
 
     def forward_logits(self, params, tokens):
@@ -418,7 +400,6 @@ class TransformerLM:
             mlp_ratio=self.mlp_ratio,
             sp_axis=sp_axis,
             sp_size=sp_size,
-            attention=self.attention,
             name=self.name,
         )
 

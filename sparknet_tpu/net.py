@@ -36,6 +36,13 @@ from sparknet_tpu.ops.base import BlobDef, Layer, create_layer
 Params = Dict[str, List[jax.Array]]
 Stats = Dict[str, List[jax.Array]]
 
+# Sibling convolutions fuse in groups of at least this many (JaxNet.
+# _plan_hconv).  Measured on the v5e: groups of three or more (GoogLeNet's
+# Inception branches) +6.2% throughput; ResNet-50's two-member stage-entry
+# pairs -3.9%, the concat and slices costing more than the wider tiling
+# gains (ROADMAP.md, north star).
+HCONV_MIN_MEMBERS = 3
+
 
 @dataclasses.dataclass
 class _BlobRef:
@@ -185,102 +192,21 @@ class JaxNet:
             b for b in self.feed_blobs if not (b in seen or seen.add(b))
         ]
 
-        self._plan_fusion()
         self._plan_hconv()
 
-    # ------------------------------------------------------------------
-    # Layer fusion (TPU-first: the LRN+MaxPool sandwich never
-    # materializes the LRN output in HBM — see ops/pallas_plp.py)
-    # ------------------------------------------------------------------
-    def _plan_fusion(self) -> None:
-        import os
-
-        self._plp_fused: Dict[int, Tuple[str, object]] = {}
-        self._plp_skip: set = set()
-        # Opt-in (SPARKNET_FUSION=1): when last measured on a v5e the
-        # Mosaic kernel's per-band overheads outweigh its HBM savings
-        # (measured 2-5x slower than the XLA lowering — see
-        # ops/pallas_plp.py and PERF.md); the kernel is kept correct and
-        # tested as the template for environments where the tradeoff
-        # flips.
-        if os.environ.get("SPARKNET_FUSION", "") != "1":
-            return
-        if self.phase != "TRAIN":
-            # keep the full named-blob map (getData parity) outside the
-            # training hot path
-            return
-        from sparknet_tpu.config.schema import LRNParameter
-        from sparknet_tpu.ops import pallas_plp
-        from sparknet_tpu.ops.vision import _pool_geometry
-
-        consumers: Dict[str, int] = {}
-        for layer in self.layers:
-            for b in layer.lp.bottom:
-                consumers[b] = consumers.get(b, 0) + 1
-        for i in range(len(self.layers) - 1):
-            lrn, pool = self.layers[i], self.layers[i + 1]
-            if lrn.lp.type != "LRN" or pool.lp.type != "Pooling":
-                continue
-            mid = lrn.lp.top[0]
-            if list(pool.lp.bottom) != [mid] or consumers.get(mid, 0) != 1:
-                continue
-            if any(self._loss_weights[lrn.name]) or any(
-                self._loss_weights[pool.name]
-            ):
-                continue
-            np_ = lrn.lp.lrn_param or LRNParameter()
-            shape = self.blob_shapes[lrn.lp.bottom[0]]
-            if len(shape) != 4:
-                continue
-            h, w = shape[2], shape[3]
-            pp = pool.lp.pooling_param
-            if pp.global_pooling:
-                continue
-            try:
-                kernel, stride, pad, _ = _pool_geometry(pp, h, w)
-            except ValueError:
-                continue
-            if not pallas_plp.fusable(
-                np_.norm_region, np_.local_size, pp.pool, kernel, stride,
-                pad, h, w,
-            ):
-                continue
-            n, alpha, beta, k = (
-                int(np_.local_size),
-                float(np_.alpha),
-                float(np_.beta),
-                float(np_.k),
-            )
-
-            def fn(x, n=n, alpha=alpha, beta=beta, k=k):
-                return pallas_plp.lrn_maxpool(x, n, alpha, beta, k)
-
-            self._plp_fused[i] = (pool.lp.top[0], fn)
-            self._plp_skip.add(i + 1)
-
     def _plan_hconv(self) -> None:
-        """Horizontal convolution fusion (default on; SPARKNET_HFUSE=0
-        opts out): sibling Convolution layers reading the *same* bottom
-        with identical geometry (the Inception pattern — 1x1, 3x3-reduce
-        and 5x5-reduce branches all read the block input; ResNet's
-        stage-entry projection + first bottleneck conv) execute as ONE
-        convolution whose output channels are the members' concatenated,
-        then split back to the named tops.  Each small conv tiles the
-        128x128 MXU poorly and re-reads the input from HBM; the fused
-        conv does one read and one large contraction — measured +6%
-        GoogLeNet throughput on v5e (PERF.md).  Parameters stay
-        per-layer (concat happens inside the step), so checkpoints,
-        weight import and the blob map are unchanged."""
-        import os
-
+        """Horizontal convolution fusion: sibling Convolution layers
+        reading the *same* bottom with identical geometry (the Inception
+        pattern — 1x1, 3x3-reduce and 5x5-reduce branches all read the
+        block input) execute as ONE convolution whose output channels are
+        the members' concatenated, then split back to the named tops.
+        Each small conv tiles the 128x128 MXU poorly and re-reads the
+        input from HBM; the fused conv does one read and one large
+        contraction.  Only groups of ``HCONV_MIN_MEMBERS`` or more fuse.
+        Parameters stay per-layer (concat happens inside the step), so
+        checkpoints, weight import and the blob map are unchanged."""
         self._hconv_groups: Dict[int, dict] = {}
         self._hconv_skip: set = set()
-        if os.environ.get("SPARKNET_HFUSE", "1") == "0":
-            return
-        # measured on v5e (PERF.md): 3+-way groups (Inception branches)
-        # win +6%; 2-way groups (ResNet stage-entry projection pairs)
-        # LOSE ~4% — the concat/slice overhead beats the tiling gain.
-        min_members = int(os.environ.get("SPARKNET_HFUSE_MIN", "3"))
         groups: Dict[tuple, List[int]] = {}
         for li, layer in enumerate(self.layers):
             lp = layer.lp
@@ -298,7 +224,7 @@ class JaxNet:
             key = (lp.bottom[0], geom, bool(cp.bias_term))
             groups.setdefault(key, []).append(li)
         for key, lis in groups.items():
-            if len(lis) < min_members:
+            if len(lis) < HCONV_MIN_MEMBERS:
                 continue
             bottom = key[0]
             # executing every member at the leader's slot must not change
@@ -458,7 +384,7 @@ class JaxNet:
         cd = self.compute_dtype
         for li, layer in enumerate(self.layers):
             lp = layer.lp
-            if li in self._hconv_skip or li in self._plp_skip:
+            if li in self._hconv_skip:
                 continue
             # everything the layer causes (casts, apply, loss term) under
             # one scope: the device trace reads time per layer from it
@@ -468,18 +394,6 @@ class JaxNet:
                         self._hconv_groups[li], blobs[lp.bottom[0]], params,
                         perturb, blobs,
                     )
-                    continue
-                if li in self._plp_fused:
-                    pool_top, fn = self._plp_fused[li]
-                    x = blobs[lp.bottom[0]]
-                    if cd is not None and jnp.issubdtype(
-                        x.dtype, jnp.floating
-                    ):
-                        x = x.astype(cd)
-                    y = fn(x)
-                    if perturb is not None and pool_top in perturb:
-                        y = y + perturb[pool_top]
-                    blobs[pool_top] = y
                     continue
                 if isinstance(layer, data_layers._HostFed):
                     # host blobs keep their dtype: index-valued blobs (labels)

@@ -115,9 +115,9 @@ from jax.experimental.pallas import tpu as pltpu
 def lowerable() -> bool:
     """True when the Pallas kernels lower natively on this backend.
     The single source of truth for "custom kernels run here": serving
-    decode, the LM train-step attention, the comm plane's fused
-    epilogue and the LRN/pool kernels all gate on this — TPU takes the
-    kernel, everything else takes the dense/XLA reference, and
+    decode, the LM train-step attention, the sequence models' delta
+    rule, loss, expert and alignment kernels and the comm plane's fused
+    epilogue all gate on this — TPU takes the kernel, everything else takes the dense/XLA reference, and
     interpreter mode stays a test-only tool (it is far slower than the
     XLA-compiled reference on CPU)."""
     return jax.default_backend() in ("tpu",)

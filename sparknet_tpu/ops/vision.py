@@ -13,7 +13,6 @@ both LRN normalization regions (``caffe/src/caffe/layers/pooling_layer.cpp``,
 from __future__ import annotations
 
 import math
-import os
 from typing import List, Sequence
 
 import jax
@@ -35,69 +34,6 @@ def _pair(lst, h_val, w_val, default):
     if len(lst) == 1:
         return int(lst[0]), int(lst[0])
     return int(lst[0]), int(lst[1])
-
-
-def _s2d_eligible(xshape, kh, kw, sh, sw, ph, pw, dh, dw, group) -> bool:
-    """Gate for the space-to-depth conv lowering: un-padded un-dilated
-    un-grouped strided conv over a thin input (the AlexNet/CaffeNet stem
-    shape class).  Opt-in (SPARKNET_S2D=1): measured NEUTRAL on v5e —
-    XLA's own convolution lowering already handles the thin strided stem
-    — kept as the exact re-bracketing for backends where it wins."""
-    if os.environ.get("SPARKNET_S2D") != "1":
-        return False
-    _, c, h, w = xshape
-    return (
-        c <= 4
-        and group == 1
-        and dh == dw == 1
-        and ph == pw == 0
-        and sh == sw
-        and sh in (2, 4)
-        and kh > sh
-        and kw > sw
-        and h >= kh
-        and w >= kw
-    )
-
-
-def _s2d_conv(x, wgt, kh, kw, s, _sw, *_ignored):
-    """stride-s conv as a stride-1 conv over the space-to-depth view.
-
-    Output (oh, ow) of the direct form reads input rows s*oh + k,
-    k < kh.  Writing k = s*kh' + a (a < s) maps it onto s2d row
-    oh + kh' of phase a — a kernel of ceil(kh/s) taps over s*s*C
-    channels.  Taps with s*kh' + a >= kh are zero.  Exact (same
-    multiply-adds, re-bracketed)."""
-    del _sw, _ignored
-    B, C, H, W = x.shape
-    O, _, KH, KW = wgt.shape
-    kh2, kw2 = -(-KH // s), -(-KW // s)
-    hp, wp = -(-H // s) * s, -(-W // s) * s
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, hp - H), (0, wp - W)))
-    # (B, C, hp/s, s, wp/s, s) -> (B, C, s, s, hp/s, wp/s) -> merge chans
-    xs = (
-        xp.reshape(B, C, hp // s, s, wp // s, s)
-        .transpose(0, 1, 3, 5, 2, 4)
-        .reshape(B, C * s * s, hp // s, wp // s)
-    )
-    # weight (O, C, KH, KW) -> (O, C*s*s, kh2, kw2), zero-padding the
-    # ragged taps; channel order must match xs: (c, a, b)
-    wp_ = jnp.pad(wgt, ((0, 0), (0, 0), (0, kh2 * s - KH), (0, kw2 * s - KW)))
-    ws = (
-        wp_.reshape(O, C, kh2, s, kw2, s)
-        .transpose(0, 1, 3, 5, 2, 4)
-        .reshape(O, C * s * s, kh2, kw2)
-    )
-    y = lax.conv_general_dilated(
-        xs,
-        ws,
-        window_strides=(1, 1),
-        padding=[(0, 0), (0, 0)],
-        dimension_numbers=("NCHW", "OIHW", "NCHW"),
-    )
-    oh = (H - KH) // s + 1
-    ow = (W - KW) // s + 1
-    return y[:, :, :oh, :ow]
 
 
 class _ConvBase(Layer):
@@ -178,25 +114,18 @@ class Convolution(_ConvBase):
 
     def apply(self, blobs, bottoms, rng, train):
         cp = self.lp.convolution_param
-        (kh, kw), (sh, sw), (ph, pw), (dh, dw) = self._geometry(bottoms[0].shape)
+        _, (sh, sw), (ph, pw), (dh, dw) = self._geometry(bottoms[0].shape)
         x, w = bottoms[0], blobs[0]
         group = max(1, cp.group)
-        if _s2d_eligible(x.shape, kh, kw, sh, sw, ph, pw, dh, dw, group):
-            # space-to-depth lowering for the classic thin-input strided
-            # stem (AlexNet conv1: 3ch, 11x11/4): fold the stride into
-            # the channel dim so the MXU contracts over s*s*C instead of
-            # C=3 — an exact re-bracketing of the same dot products
-            y = _s2d_conv(x, w, kh, kw, sh, sw)
-        else:
-            y = lax.conv_general_dilated(
-                x,
-                w,
-                window_strides=(sh, sw),
-                padding=[(ph, ph), (pw, pw)],
-                rhs_dilation=(dh, dw),
-                dimension_numbers=("NCHW", "OIHW", "NCHW"),
-                feature_group_count=group,
-            )
+        y = lax.conv_general_dilated(
+            x,
+            w,
+            window_strides=(sh, sw),
+            padding=[(ph, ph), (pw, pw)],
+            rhs_dilation=(dh, dw),
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            feature_group_count=group,
+        )
         if cp.bias_term:
             y = y + blobs[1][None, :, None, None]
         return [y], None
@@ -490,19 +419,6 @@ class LRN(Layer):
         x = bottoms[0]
         n = p.local_size
         if p.norm_region.upper() == "ACROSS_CHANNELS":
-            # The Pallas kernel is opt-in: measured on v5e the XLA lowering
-            # of the custom_vjp form below is slightly faster (the kernel
-            # pays a relayout into its flat block view), but the kernel is
-            # kept as the template for shapes/backends where reduce_window
-            # lowers badly.
-            if os.environ.get("SPARKNET_PALLAS_LRN") and x.ndim == 4:
-                from sparknet_tpu.ops import pallas_lrn
-
-                return [
-                    pallas_lrn.lrn_across_channels(
-                        x, int(n), float(p.alpha), float(p.beta), float(p.k)
-                    )
-                ], None
             return [
                 lrn_across_channels(
                     x, int(n), float(p.alpha), float(p.beta), float(p.k)

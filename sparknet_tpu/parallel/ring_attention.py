@@ -64,7 +64,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
     kernel's custom_vjp).  ``None`` takes the kernel wherever it lowers
     natively (``pallas_attention.lowerable()``); ``True`` forces it
     (interpreter mode off-TPU — the test/bench pin), ``False`` keeps
-    the einsum path (``--dense_attention``)."""
+    the einsum path, which every backend but the TPU takes by default."""
     from sparknet_tpu.ops import pallas_attention
 
     if use_flash is None:

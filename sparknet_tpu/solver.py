@@ -515,8 +515,6 @@ class Solver:
             log(f"    [Forward] Input {b} data: {asum(batch[b]):.6g}")
         for layer in net.layers:
             for top in layer.lp.top:
-                if top not in out.blobs:
-                    continue  # fused-away intermediate (SPARKNET_FUSION)
                 log(
                     f"    [Forward] Layer {layer.name}, top blob {top} "
                     f"data: {asum(out.blobs[top]):.6g}"

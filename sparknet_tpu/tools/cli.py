@@ -308,8 +308,7 @@ def _cmd_train(args) -> int:
     # queue
     it = int(jax.device_get(state.iter))
     # pipelined round feed: the next window is assembled and device_put
-    # on a producer thread while the current one trains (--serial_feed
-    # restores assemble-then-put on this loop, identical numerics)
+    # on a producer thread while the current one trains
     from sparknet_tpu.data import RoundFeed
 
     # --shuffle_epochs: deterministic epoch passes over the partition,
@@ -349,7 +348,6 @@ def _cmd_train(args) -> int:
     feed = RoundFeed(
         assemble,
         sharding=trainer.batch_sharding if trainer is not None else None,
-        pipelined=not args.serial_feed,
         num_rounds=max(0, -(-(max_iter - it) // args.tau)),
     )
     r = 0
@@ -1205,10 +1203,6 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--async_snapshot", action="store_true",
                    help="write snapshots on a background thread")
-    p.add_argument("--serial_feed", action="store_true",
-                   help="disable the pipelined round feed: assemble and "
-                   "H2D run on the training loop instead of a producer "
-                   "thread")
     p.add_argument("--devices", type=int, default=1,
                    help="N>1: synchronous allreduce DP over the first N "
                    "local devices (the caffe train --gpu=0,..,N-1 analog; "

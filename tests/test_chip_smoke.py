@@ -20,7 +20,6 @@ TINY = {
     "mla_shapes": (("tiny", 1, 16, 2, 128, 8, "float32"),),
     "lm_loss_shapes": (("tiny", 32, 128, 300, True),),
     "comm_legs": (("int8", True),),
-    "lrn_shape": (1, 5, 3, 3),
     "lm_rounds": 2,
     "lm_args": (
         "--workers=2", "--batch=2", "--dim=16", "--depth=1", "--seq_len=16",

@@ -1,26 +1,25 @@
-"""Horizontal sibling-conv fusion (default on; SPARKNET_HFUSE=0 opts
-out): the Inception branch convs reading one bottom run as a single
-concatenated-output convolution.  Must be numerically exact vs the
-unfused path in f32, preserve the full blob map, and leave gradients
-identical."""
-
-import os
+"""Horizontal sibling-conv fusion: the Inception branch convs reading one
+bottom run as a single concatenated-output convolution.  Must be
+numerically exact vs the unfused path in f32, preserve the full blob map,
+and leave gradients identical."""
 
 import numpy as np
 import pytest
 import jax
-import jax.numpy as jnp
 
-from sparknet_tpu import models
+from sparknet_tpu import config, models
+from sparknet_tpu import net as net_module
 from sparknet_tpu.net import JaxNet
+
+# a group minimum above any group's size: the unfused reference
+NO_FUSION = 10**6
 
 
 @pytest.fixture
 def hfuse_env(monkeypatch):
-    monkeypatch.setenv("SPARKNET_HFUSE", "1")
-    # guard tests use minimal 2-conv fixtures; production default is 3+
-    # members (2-way groups measured slower on v5e, PERF.md)
-    monkeypatch.setenv("SPARKNET_HFUSE_MIN", "2")
+    # guard tests use minimal 2-conv fixtures; the production minimum is
+    # 3 members (2-way groups measured slower on v5e)
+    monkeypatch.setattr(net_module, "HCONV_MIN_MEMBERS", 2)
 
 
 def _tiny_googlenet():
@@ -42,10 +41,9 @@ def test_plan_finds_inception_groups(hfuse_env):
 @pytest.mark.slow
 def test_fused_forward_backward_exact(monkeypatch):
     netp = _tiny_googlenet()
-    monkeypatch.setenv("SPARKNET_HFUSE", "0")
-    base = JaxNet(netp, phase="TRAIN")
-    monkeypatch.setenv("SPARKNET_HFUSE", "1")
     fused = JaxNet(netp, phase="TRAIN")
+    monkeypatch.setattr(net_module, "HCONV_MIN_MEMBERS", NO_FUSION)
+    base = JaxNet(netp, phase="TRAIN")
     assert not base._hconv_groups and fused._hconv_groups
 
     params, stats = base.init(0)
@@ -88,12 +86,81 @@ def test_fused_forward_backward_exact(monkeypatch):
         )
 
 
+SIBLINGS = """
+name: "siblings"
+layer { name: "data" type: "HostData" top: "x" top: "label"
+  java_data_param { shape { dim: 2 dim: 4 dim: 9 dim: 9 } shape { dim: 2 } } }
+layer { name: "ca" type: "Convolution" bottom: "x" top: "a"
+  convolution_param { num_output: 3 %(geom)s weight_filler { type: "xavier" }
+    bias_filler { type: "gaussian" std: 0.1 } } }
+layer { name: "cb" type: "Convolution" bottom: "x" top: "b"
+  convolution_param { num_output: 5 %(geom)s weight_filler { type: "xavier" }
+    bias_filler { type: "gaussian" std: 0.1 } } }
+layer { name: "cc" type: "Convolution" bottom: "x" top: "c"
+  convolution_param { num_output: 2 %(geom)s weight_filler { type: "xavier" }
+    bias_filler { type: "gaussian" std: 0.1 } } }
+layer { name: "cat" type: "Concat" bottom: "a" bottom: "b" bottom: "c" top: "abc" }
+layer { name: "relu" type: "ReLU" bottom: "abc" top: "abc" }
+layer { name: "fc" type: "InnerProduct" bottom: "abc" top: "logits"
+  inner_product_param { num_output: 7 weight_filler { type: "xavier" } } }
+layer { name: "loss" type: "SoftmaxWithLoss" bottom: "logits" bottom: "label"
+  top: "loss" }
+"""
+
+
+@pytest.mark.parametrize(
+    "geom",
+    ["kernel_size: 1", "kernel_size: 3 pad: 1", "kernel_size: 3 stride: 2"],
+    ids=["1x1", "3x3-pad1", "3x3-stride2"],
+)
+def test_three_member_group_matches_unfused(monkeypatch, geom):
+    """A group of three siblings fuses at the production minimum and gives
+    the unfused net's loss, every named blob and every parameter gradient
+    to float32 rounding."""
+    netp = config.parse_net_prototxt(SIBLINGS % {"geom": geom})
+    fused = JaxNet(netp, phase="TRAIN")
+    monkeypatch.setattr(net_module, "HCONV_MIN_MEMBERS", NO_FUSION)
+    base = JaxNet(netp, phase="TRAIN")
+    assert list(fused._hconv_groups) == [1] and not base._hconv_groups
+    assert fused._hconv_groups[1]["sizes"] == [3, 5, 2]
+
+    params, stats = base.init(3)
+    rng = np.random.RandomState(4)
+    batch = {
+        "x": rng.randn(2, 4, 9, 9).astype(np.float32),
+        "label": np.array([1, 5], np.float32),
+    }
+
+    def run(net):
+        def loss(p):
+            out = net.apply(p, stats, batch, rng=jax.random.PRNGKey(0))
+            return out.loss, out.blobs
+
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, has_aux=True)(params)
+
+    (loss_b, blobs_b), grads_b = run(base)
+    (loss_f, blobs_f), grads_f = run(fused)
+    np.testing.assert_allclose(float(loss_f), float(loss_b), rtol=1e-6)
+    assert set(blobs_f) == set(blobs_b)
+    for name in blobs_b:
+        np.testing.assert_allclose(
+            np.asarray(blobs_f[name]), np.asarray(blobs_b[name]),
+            rtol=1e-6, atol=1e-6, err_msg=name,
+        )
+    assert set(grads_f) == set(grads_b)
+    for layer in grads_b:
+        for i, (gf, gb) in enumerate(zip(grads_f[layer], grads_b[layer])):
+            np.testing.assert_allclose(
+                np.asarray(gf), np.asarray(gb), rtol=1e-5, atol=1e-6,
+                err_msg=f"{layer}[{i}]",
+            )
+
+
 def test_member_top_collision_blocks_fusion(hfuse_env):
     """A member's top name legally rebound/read by a layer between the
     leader and the member must block fusion: early production would
     change what that layer sees."""
-    from sparknet_tpu import config
-
     NET = """
     name: "m"
     layer { name: "data" type: "HostData" top: "x"
@@ -132,8 +199,6 @@ def test_later_rebinding_does_not_corrupt_slice_sizes(hfuse_env):
     with a different channel count must not change the group's slice
     sizes (sizes come from each member's num_output, not the final
     binding of the name)."""
-    from sparknet_tpu import config
-
     NET = """
     name: "m"
     layer { name: "data" type: "HostData" top: "x"
@@ -171,8 +236,6 @@ def test_later_rebinding_does_not_corrupt_slice_sizes(hfuse_env):
 def test_inplace_bottom_rewrite_blocks_fusion(hfuse_env):
     """Two convs reading blob X with an in-place ReLU on X between them
     must NOT fuse (they see different versions of X)."""
-    from sparknet_tpu import config
-
     NET = """
     name: "m"
     layer { name: "data" type: "HostData" top: "x"

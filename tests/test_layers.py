@@ -191,6 +191,81 @@ def test_conv_grad():
     check_grad(l, [x], blobs)
 
 
+def _direct_conv(x, w, stride, pad, group):
+    """Convolution as a plain sum over kernel taps in float64 numpy, with
+    its input and weight gradients for an output gradient ``g``: the
+    independent reference for the Convolution layer."""
+    x = np.pad(np.asarray(x, np.float64), ((0, 0), (0, 0), (pad, pad), (pad, pad)))
+    w = np.asarray(w, np.float64)
+    n, c, hp, wp = x.shape
+    o, cg, kh, kw = w.shape
+    oh, ow = (hp - kh) // stride + 1, (wp - kw) // stride + 1
+    og = o // group
+
+    def taps():
+        for gi in range(group):
+            ci, oi = slice(gi * cg, (gi + 1) * cg), slice(gi * og, (gi + 1) * og)
+            for p in range(kh):
+                for q in range(kw):
+                    rows = slice(p, p + stride * (oh - 1) + 1, stride)
+                    cols = slice(q, q + stride * (ow - 1) + 1, stride)
+                    yield ci, oi, p, q, rows, cols
+
+    y = np.zeros((n, o, oh, ow))
+    for ci, oi, p, q, rows, cols in taps():
+        y[:, oi] += np.einsum("nchw,oc->nohw", x[:, ci, rows, cols], w[oi, :, p, q])
+
+    def grads(g):
+        dx, dw = np.zeros_like(x), np.zeros_like(w)
+        for ci, oi, p, q, rows, cols in taps():
+            dx[:, ci, rows, cols] += np.einsum("nohw,oc->nchw", g[:, oi], w[oi, :, p, q])
+            dw[oi, :, p, q] += np.einsum("nohw,nchw->oc", g[:, oi], x[:, ci, rows, cols])
+        return dx[:, :, pad : hp - pad, pad : wp - pad], dw
+
+    return y, grads
+
+
+@pytest.mark.parametrize(
+    "kernel,stride,pad,group,cin,cout,hw",
+    [
+        (11, 4, 0, 1, 3, 8, 27),  # CaffeNet conv1: the thin strided stem
+        (5, 1, 2, 2, 6, 4, 7),  # conv2
+        (3, 1, 1, 1, 4, 6, 6),  # conv3
+        (3, 1, 1, 2, 6, 4, 6),  # conv4, conv5
+    ],
+    ids=["conv1", "conv2", "conv3", "conv4"],
+)
+def test_convolution_matches_direct_sum(kernel, stride, pad, group, cin, cout, hw):
+    """The Convolution layer at CaffeNet's geometries (kernel, stride, pad,
+    group; narrow channels) against the direct sum: output, and the input
+    and weight gradients of a random output gradient."""
+    l = _layer(
+        'name: "c" type: "Convolution" convolution_param { '
+        f"num_output: {cout} kernel_size: {kernel} stride: {stride} "
+        f"pad: {pad} group: {group} }}"
+    )
+    rng = np.random.RandomState(kernel * 10 + group)
+    x = rng.randn(2, cin, hw, hw).astype(np.float32)
+    w = rng.randn(cout, cin // group, kernel, kernel).astype(np.float32)
+    b = rng.randn(cout).astype(np.float32)
+
+    def f(x, w):
+        (y,), _ = l.apply([w, jnp.asarray(b)], [x], None, True)
+        return y
+
+    with jax.default_matmul_precision("highest"):
+        y, vjp = jax.vjp(f, jnp.asarray(x), jnp.asarray(w))
+        want, grads = _direct_conv(x, w, stride, pad, group)
+        want = want + b[None, :, None, None]
+        assert y.shape == want.shape
+        np.testing.assert_allclose(np.asarray(y), want, rtol=1e-5, atol=1e-4)
+        g = rng.randn(*want.shape).astype(np.float32)
+        dx, dw = vjp(jnp.asarray(g))
+        want_dx, want_dw = grads(np.asarray(g, np.float64))
+        np.testing.assert_allclose(np.asarray(dx), want_dx, rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(dw), want_dw, rtol=1e-5, atol=1e-4)
+
+
 def test_pool_grads():
     for pool in ("MAX", "AVE"):
         l = _layer(
@@ -438,29 +513,6 @@ def test_lrn_fast_negpow_matches_pow():
             np.asarray(jnp.power(s, -beta)),
             rtol=2e-5,
         )
-
-
-@pytest.mark.slow
-def test_pallas_lrn_matches_xla_path():
-    """The Pallas LRN kernel (interpret mode off-TPU) pins value and
-    gradient against the XLA custom_vjp path."""
-    from sparknet_tpu.ops.pallas_lrn import lrn_across_channels as pl_lrn
-    from sparknet_tpu.ops.vision import lrn_across_channels as xla_lrn
-
-    for shape, n, alpha, beta, k in [
-        ((2, 32, 7, 5), 5, 1e-4, 0.75, 1.0),
-        ((1, 16, 4, 4), 3, 0.5, 0.6, 2.0),
-        ((2, 8, 5, 5), 11, 0.1, 0.75, 1.0),  # window wider than C
-    ]:
-        x = jnp.asarray(RNG.randn(*shape), jnp.float32) * 2
-        np.testing.assert_allclose(
-            np.asarray(pl_lrn(x, n, alpha, beta, k)),
-            np.asarray(xla_lrn(x, n, alpha, beta, k)),
-            atol=1e-5,
-        )
-        g1 = jax.grad(lambda v: jnp.sum(jnp.sin(pl_lrn(v, n, alpha, beta, k))))(x)
-        g2 = jax.grad(lambda v: jnp.sum(jnp.sin(xla_lrn(v, n, alpha, beta, k))))(x)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
 
 
 def test_analytic_flops_alexnet():
